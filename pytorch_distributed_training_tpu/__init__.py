@@ -26,10 +26,3 @@ Layer map (mirrors SURVEY.md L1-L8, re-architected for TPU):
 """
 
 __version__ = "0.1.0"
-
-# Publish jax.shard_map on pre-graft JAX installs (no-op on the real
-# toolchain); must run before any submodule builds a step.
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-del _jax_compat

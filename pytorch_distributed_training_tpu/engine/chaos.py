@@ -720,17 +720,8 @@ class ChaosSoakEngine:
 
     def _train_once(self, tmp: str, needs_pool: bool, use_async: bool,
                     spec: Optional[str]) -> Dict:
-        import jax
-
         from .runner import Runner
 
-        if not hasattr(jax, "shard_map"):
-            # same opt-in as bench.py's driver: single-device CPU soak runs
-            # are numerically exact under the compat graft (jax_compat.py)
-            os.environ.setdefault("PDT_JAX_COMPAT", "1")
-            from ..utils import jax_compat
-
-            jax_compat.install()
         fault.reset_counters()
         injector = fault.install(spec)
         try:

@@ -170,9 +170,8 @@ class TraceProfiler:
 def _scalar_sync(tree) -> float:
     """Force execution of everything ``tree`` depends on.
 
-    Host materialization of one element, not ``block_until_ready`` — the
-    latter has been observed returning early through the remote-device
-    transport (bench.py's ~250x under-report pathology)."""
+    Host materialization of one element: the value cannot reach the host
+    before every step it depends on has run."""
     import jax
 
     leaf = jax.tree_util.tree_leaves(tree)[0]

@@ -106,11 +106,11 @@ class Runner:
         self.iter: int = 0
         self.tb_writer = None
         self._telemetry: Optional[Telemetry] = None
+        self._queue_handlers: list = []
 
     def __call__(self):
         logger = logging.getLogger("Runner")
-        if self.logger_queue is not None:
-            logger.addHandler(QueueHandler(self.logger_queue))
+        self._attach_queue(logger)
         logger.setLevel(logging.INFO)
         if self.multiprocessing:
             # Reference spawns one process per GPU here (:130-132); the TPU
@@ -120,7 +120,20 @@ class Runner:
                 "drives all local devices from one process (flag is a no-op)"
             )
         logger.info("Start from direct call")
-        self.worker(0)
+        try:
+            self.worker(0)
+        finally:
+            # the loggers are process-global by name: a handler left behind
+            # would feed the NEXT run's records into this run's closed queue
+            for named, handler in self._queue_handlers:
+                named.removeHandler(handler)
+            self._queue_handlers.clear()
+
+    def _attach_queue(self, logger: logging.Logger) -> None:
+        if self.logger_queue is not None:
+            handler = QueueHandler(self.logger_queue)
+            logger.addHandler(handler)
+            self._queue_handlers.append((logger, handler))
 
     # ------------------------------------------------------------------ setup
     def worker(self, local_id: int):
@@ -140,19 +153,28 @@ class Runner:
 
         self.logger = logging.getLogger(f"worker_rank_{self.current_rank}")  # confined: api
         self.logger.propagate = False
-        if self.logger_queue is not None:
-            self.logger.addHandler(QueueHandler(self.logger_queue))
+        self._attach_queue(self.logger)
         self.logger.setLevel(logging.INFO)
 
         if self.current_rank == 0:
             self.tb_writer = self.tb_writer_constructor()
 
+        device = jax.devices()[0]
         self.logger.info(
-            "Use %d TPU device(s) across %d process(es), current rank: %d",
+            "Use %d %s device(s) (%s) across %d process(es), current rank: %d",
             self.world_size,
+            device.platform,
+            device.device_kind,
             jax.process_count(),
             self.current_rank,
         )
+        if (self.dist_backend or "").lower() == "tpu" and device.platform != "tpu":
+            # the flag names a runtime, it does not pick one (CPU test runs
+            # pass ``tpu`` too) — say so instead of letting the log imply a chip
+            self.logger.warning(
+                "--dist-backend tpu, but JAX found no TPU: running on %s",
+                device.platform,
+            )
 
         cfg = self.global_cfg
         train_cfg = cfg["training"]
@@ -161,7 +183,9 @@ class Runner:
         # cache directory — the autotune analog of the reference's
         # ``cudnn.benchmark`` (train_distributed.py:54, SURVEY §2.3).  Set
         # BEFORE any step is built so the first jit of this process can
-        # already hit a previous launch's entry.
+        # already hit a previous launch's entry.  Where the cache lands
+        # (JAX_COMPILATION_CACHE_DIR wins; a relative path anchors at the
+        # checkout) is utils.enable_compile_cache's rule.
         compile_cache = train_cfg.get("compile_cache")
         if compile_cache:
             path = enable_compile_cache(str(compile_cache))

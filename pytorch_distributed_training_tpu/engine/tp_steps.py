@@ -40,6 +40,30 @@ def _token_spec(mesh: Mesh) -> P:
         return P(DATA_AXIS, SEQUENCE_AXIS)
     return P(DATA_AXIS, None)
 
+
+def _token_ce(logits, labels, mesh: Mesh, label_smoothing: float = 0.0):
+    """Mean token CE of ``[B, S, V]`` logits as a shard_map island.
+
+    On TPU ``cross_entropy_loss`` is a Pallas kernel, and a Mosaic call has
+    no GSPMD partitioning rule (the v5e compiler: "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map") — the
+    same reason attention runs in an island (ops/attention.py).  Each
+    device takes the CE of its own ``[B/dp, S/sp]`` tokens; the shards are
+    equal-sized, so the pmean of the local means is the global mean.
+    """
+    spec = _token_spec(mesh)
+    axes = tuple(a for a in spec if a is not None)
+
+    def local(lg, lb):
+        loss = cross_entropy_loss(
+            lg.reshape(-1, lg.shape[-1]), lb.reshape(-1), label_smoothing
+        )
+        return jax.lax.pmean(loss, axes)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(*spec, None), spec), out_specs=P()
+    )(logits, labels)
+
 __all__ = ["build_tp_lm_train_step", "build_tp_lm_eval_step"]
 
 # Step-family label for the static collective-order oracle (see
@@ -111,10 +135,7 @@ def build_tp_lm_train_step(
         logits, inter = model.apply(
             {"params": p}, tokens, mutable="intermediates"
         )
-        vocab = logits.shape[-1]
-        loss = cross_entropy_loss(
-            logits.reshape(-1, vocab), labels.reshape(-1), label_smoothing
-        )
+        loss = _token_ce(logits, labels, mesh, label_smoothing)
         for path, leaf in jax.tree_util.tree_flatten_with_path(inter)[0]:
             if any(
                 str(getattr(key, "key", key)) == "moe_aux" for key in path
@@ -223,7 +244,7 @@ def build_tp_lm_eval_step(model, mesh: Mesh, zero: int = 0):
         vocab = logits.shape[-1]
         flat_logits = logits.reshape(-1, vocab)
         flat_labels = labels.reshape(-1)
-        loss = cross_entropy_loss(flat_logits, flat_labels)
+        loss = _token_ce(logits, labels, mesh)
         acc1, acc5 = accuracy(flat_logits, flat_labels, topk=(1, 5))
         return loss, acc1, acc5
 

@@ -65,8 +65,8 @@ def fold_stem_kernel(w7):
 
     kh, kw, c, o = w7.shape
     assert (kh, kw) == (7, 7), w7.shape
-    # numpy for concrete kernels (checkpoint import, eager init — 49 eager
-    # device ops would cost seconds per dispatch on remote-device infra);
+    # numpy for concrete kernels (checkpoint import, eager init — no need
+    # for 49 eager device dispatches);
     # jnp .at[].set() only when tracing (the init can run under jit)
     traced = isinstance(w7, jax.core.Tracer)
     if traced:

@@ -97,6 +97,8 @@ _BLOCK_K = 1024
 # chip (see PERF.md round 5).
 _BLOCK_Q_FUSED = 512
 _BLOCK_K_FUSED = 1024
+# Tiles for f32-operand dots; see ``_blocks``.
+_BLOCK_F32 = 512
 # VMEM budget for the RESIDENT kernels' K/V rows (f32): each instance holds
 # 2 full [S, D] f32 operands plus tiles/accumulators; stay well under the
 # ~16MB scoped VMEM.  Sequences past this budget no longer fall back to the
@@ -576,8 +578,17 @@ def _pick_block(pref: int, s_len: int) -> int:
     return b
 
 
-def _blocks(s_len: int):
-    return _pick_block(_BLOCK_Q, s_len), _pick_block(_BLOCK_K, s_len)
+def _blocks(s_len: int, bf16_dots: bool):
+    """Preferred (block_q, block_k).  Kernels that feed the MXU f32 operands
+    (f32 inputs, or the ring path's f32-output mode) get 512x512: at
+    1024x1024 the live f32 [bq, bk] tiles of the split backward overflow
+    the 16MB scoped-VMEM stack under the v5e compiler (18.29M at S=2048
+    D=128 f32; 17.55M at D=64 bf16-in/f32-out) — the smaller tile is the
+    largest that compiles at every width tests/test_chip_compile.py asks."""
+    pref_q, pref_k = (_BLOCK_Q, _BLOCK_K) if bf16_dots else (
+        _BLOCK_F32, _BLOCK_F32
+    )
+    return _pick_block(pref_q, s_len), _pick_block(pref_k, s_len)
 
 
 def _blocks_fused(s_len: int):
@@ -604,7 +615,7 @@ def _make(
         from jax.experimental.pallas import tpu as pltpu
 
         bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len)
+        bq, bk = _blocks(s_len, bf16_dots)
         nk = s_len // bk
         kern = functools.partial(
             _fwd_stream_kernel, scale=scale, causal=causal, block_q=bq,
@@ -641,7 +652,7 @@ def _make(
         if stream:
             return _forward_stream(q, k, v)
         bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len)
+        bq, bk = _blocks(s_len, bf16_dots)
         kern = functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
             bf16_dots=bf16_dots,
@@ -685,7 +696,7 @@ def _make(
         q, k, v, o, lse = res
         g, g_lse = cts
         bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len)
+        bq, bk = _blocks(s_len, bf16_dots)
         nq, nk = s_len // bq, s_len // bk
         delta = jnp.sum(
             g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
@@ -753,7 +764,7 @@ def _make(
         q, k, v, o, lse = res
         g, g_lse = cts  # cotangents for (o, lse)
         bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len)
+        bq, bk = _blocks(s_len, bf16_dots)
         # d(lse)/d(s) = p, so an lse cotangent folds into the kernels as a
         # shift of delta: ds = p * (dp - (delta - g_lse)) — this is what
         # makes the ring-attention combine (which consumes lse) exactly

@@ -9,7 +9,9 @@ the reference's TCPStore rendezvous URL (``--dist-url tcp://host:port``,
 
 Backend-name mapping: the reference defaults ``--dist-backend nccl``; the
 TPU runtime accepts ``tpu`` / ``xla`` (and treats ``nccl`` as a compat alias
-with a warning, so reference launch scripts keep working unmodified).
+with a warning, so reference launch scripts keep working unmodified).  The
+name selects nothing: JAX runs on the platform it finds, and the log line
+below says which one that is.
 """
 from __future__ import annotations
 
@@ -68,9 +70,12 @@ def initialize_distributed(
         num_processes=num_nodes,
         process_id=rank,
     )
+    device = jax.devices()[0]
     log.info(
-        "jax.distributed initialized: process %d/%d, %d global devices",
+        "jax.distributed initialized: process %d/%d, %d global %s devices (%s)",
         rank,
         num_nodes,
         jax.device_count(),
+        device.platform,
+        device.device_kind,
     )

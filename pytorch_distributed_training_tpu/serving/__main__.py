@@ -8,7 +8,9 @@ Builds an :class:`.engine.InferenceEngine` from the config, fires
 varying length within the seq buckets; classification: random images),
 waits on every future, and reports p50/p99 latency, max queue depth, and
 items/sec through the repo's logging funnel — the final line is one JSON
-object, same convention as ``bench.py``.
+object, same convention as ``bench.py``.  Compiled programs persist where
+``utils.enable_compile_cache`` says (``JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.xla_cache``), so a relaunch skips the bucket-grid compiles.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ import numpy as np
 
 from ..config_parsing import get_serve_cfg, get_train_logger
 from ..logger import MultiProcessLoggerListener
+from ..utils import enable_compile_cache
 from .engine import InferenceEngine
 
 
@@ -86,4 +89,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # process-global JAX config belongs to the process entry, not to main():
+    # tests call main() in-process and must not inherit a cache directory
+    enable_compile_cache()
     sys.exit(main())

@@ -9,6 +9,7 @@ and records a global base seed from which the framework derives
 """
 from __future__ import annotations
 
+import os
 import random
 from typing import Generator, Iterable, Optional, Tuple
 
@@ -22,6 +23,10 @@ __all__ = [
 ]
 
 _BASE_SEED: Optional[int] = None
+# the directory that holds the package: relative cache paths anchor here
+_CHECKOUT_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 
 def make_deterministic(seed: int) -> None:
@@ -53,35 +58,46 @@ def get_base_seed(default: int = 0) -> int:
     return _BASE_SEED if _BASE_SEED is not None else default
 
 
-def enable_compile_cache(directory: str) -> str:
-    """Point JAX's persistent compilation cache at ``directory``.
+def enable_compile_cache(directory: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
     The TPU-native analog of the reference's ``cudnn.benchmark = True``
     (train_distributed.py:54, SURVEY.md §2.3 autotune row): cuDNN autotune
     amortizes kernel selection across runs; XLA's persistent cache amortizes
-    whole-program compilation across *launches* — the second launch of the
-    same program skips the ~40s ResNet-50 step compile entirely.
+    whole-program compilation across *launches*.
+
+    One rule for every entry point (the Runner's ``training.compile_cache``,
+    ``python -m …serving``, ``bench.py``, ``chip_smoke.py``):
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: the cache is there.  JAX reads the
+      variable itself, so nothing here writes ``jax_compilation_cache_dir``
+      and ``directory`` is ignored — whoever launches the process decides
+      where compiled programs persist.
+    - not set: ``directory`` (default ``.xla_cache``), a relative path
+      resolved against the checkout root.  The directory is where the next
+      launch looks, so it must not follow the working directory.
 
     Thresholds are zeroed so every executable is cached regardless of compile
-    time or size (the default 1s/64KB floors would skip small eval steps whose
-    recompilation still costs seconds through a remote-device transport).
+    time or size (the default 1s floor would skip the small eval and
+    serving-bucket programs a relaunch needs just as much).
     """
-    import os
-
     import jax
 
-    directory = os.path.expanduser(directory)
-    os.makedirs(directory, exist_ok=True)
-    if jax.config.jax_compilation_cache_dir not in (None, directory):
-        # the cache object is initialized lazily ONCE per process; a dir
-        # change after first use is silently ignored without a reset
-        try:
-            from jax._src import compilation_cache as _cc
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        directory = env_dir
+    else:
+        directory = os.path.expanduser(directory or ".xla_cache")
+        if not os.path.isabs(directory):
+            directory = os.path.join(_CHECKOUT_ROOT, directory)
+        os.makedirs(directory, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != directory:
+            from jax.experimental.compilation_cache import compilation_cache
 
-            _cc.reset_cache()
-        except Exception:  # pragma: no cover - private-API drift tolerance
-            pass
-    jax.config.update("jax_compilation_cache_dir", directory)
+            # the cache object is initialized lazily ONCE per process; a
+            # dir set after first use is silently ignored without a reset
+            compilation_cache.reset_cache()
+            jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     # -1 disables the size floor; 0 would mean "filesystem-dependent default",
     # which can silently reinstate a 64KB floor on some backends

@@ -113,21 +113,11 @@ def test_resume_bit_exact_vs_straight_run(tmp_path):
 
 @pytest.fixture
 def one_device_graft(monkeypatch):
-    """``jax.shard_map`` compat-grafted for this test only, pinned to a
-    ONE-device mesh — collectives over a size-1 axis are identity, so the
-    pre-vma graft's autodiff caveat (utils/jax_compat.py) does not apply
-    and the real train step runs bit-deterministically on vanilla JAX."""
+    """Pin the Runner to a ONE-device mesh: the resume logic under test is
+    device-count independent, and one device keeps the run quick."""
     from pytorch_distributed_training_tpu.engine import paths
     from pytorch_distributed_training_tpu.parallel import make_mesh
 
-    if not hasattr(jax, "shard_map"):
-        from pytorch_distributed_training_tpu.utils import jax_compat
-
-        monkeypatch.setenv("PDT_JAX_COMPAT", "1")
-        jax_compat.install()
-        wrapper = jax.shard_map
-        del jax.shard_map
-        monkeypatch.setattr(jax, "shard_map", wrapper, raising=False)
     mesh = make_mesh(jax.devices()[:1])
     monkeypatch.setattr(paths, "make_mesh", lambda *a, **kw: mesh)
     return mesh
@@ -811,7 +801,6 @@ def test_bench_ckpt_cli():
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu",
-        PDT_JAX_COMPAT="1",  # inert on grafted JAX; single device = exact
         PYTHONPATH=root + os.pathsep + env.get("PYTHONPATH", ""),
         BENCH_CKPT_ITERS="8", BENCH_CKPT_INTERVAL="4",
         BENCH_CKPT_VOCAB="256", BENCH_CKPT_SEQ="32", BENCH_CKPT_EMBED="32",

@@ -1,0 +1,116 @@
+"""Ask the TPU's compiler about every Pallas kernel on the main path.
+
+Every other test forces the CPU, where the kernels run in interpreter mode
+or give way to XLA, so none of them asks Mosaic anything.  The TPU compiler
+is installed alongside JAX and compiles for a chip that is DESCRIBED, not
+attached: each case below lowers one kernel at the widths ``chip_smoke.py``
+runs (271M LM: batch 8 x seq 2048, 8 heads x 128, vocab 32768; ResNet-50:
+128 x 1000 logits) for one device of a ``v5e:2x2`` and asserts the Mosaic
+custom call is in the compiled program.  Nothing executes — a compile that
+passes is not a chip run.  Whole-step compiles take minutes and stay out of
+tier-1.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pytorch_distributed_training_tpu.ops.flash_attention import flash_attention
+from pytorch_distributed_training_tpu.ops.fused_ce import fused_cross_entropy
+from pytorch_distributed_training_tpu.ops.fused_elementwise import (
+    _make_add_ln,
+    _make_bias_gelu,
+)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described v5e 2x2, persistent cache off around it
+    (an entry written for a described device cannot be read back without
+    one: the next compile would warn and compile again)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _attn_loss(q, k, v):
+    return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True)
+
+
+_flash_fwd_bwd = jax.grad(_attn_loss, argnums=(0, 1, 2))
+
+
+def _ce_fwd_bwd(logits, labels):
+    return jax.value_and_grad(fused_cross_entropy)(logits, labels)
+
+
+# The public wrappers pick interpreter mode from ``jax.default_backend()``,
+# which is the CPU here; the test steers past that to the Mosaic builders.
+def _add_ln(x, delta, scale, bias):
+    return _make_add_ln(False, 1e-6)(x, delta, scale, bias, x.dtype)
+
+
+def _bias_gelu(u, bias):
+    return _make_bias_gelu(False)(u, bias)
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+QKV_2K = [((8, 2048, 8, 128), None)] * 3
+QKV_16K = [((1, 16384, 8, 128), None)] * 3
+
+# (fn, [(shape, dtype or None for the case dtype)], case dtype, least count
+# of Mosaic calls in the compiled program)
+CASES = {
+    "flash_fwd_bf16": (_flash_fwd, QKV_2K, BF16, 1),
+    "flash_fused_bwd_bf16": (_flash_fwd_bwd, QKV_2K, BF16, 2),
+    "flash_split_bwd_f32": (_flash_fwd_bwd, QKV_2K, F32, 3),
+    "flash_streamed_16k_fwd_bwd": (_flash_fwd_bwd, QKV_16K, BF16, 3),
+    "fused_ce_lm_16384x32768": (
+        _ce_fwd_bwd, [((16384, 32768), F32), ((16384,), I32)], F32, 2,
+    ),
+    "fused_ce_resnet_128x1000": (
+        _ce_fwd_bwd, [((128, 1000), F32), ((128,), I32)], F32, 2,
+    ),
+    "add_layernorm_16384x1024": (
+        _add_ln,
+        [((16384, 1024), None), ((16384, 1024), None),
+         ((1024,), F32), ((1024,), F32)],
+        BF16, 1,
+    ),
+    "bias_gelu_16384x4096": (
+        _bias_gelu, [((16384, 4096), None), ((4096,), None)], BF16, 1,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, name):
+    fn, arg_specs, dtype, n_calls = CASES[name]
+    args = [
+        jax.ShapeDtypeStruct(shape, dt or dtype, sharding=chip)
+        for shape, dt in arg_specs
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= n_calls, (
+        f"{name}: expected >= {n_calls} Mosaic call(s) in the compiled program"
+    )

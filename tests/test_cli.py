@@ -3,11 +3,17 @@
 The crash path (reference train_distributed.py:77-86): a failure inside
 the runner must log CRITICAL, delete ONLY the TensorBoard event directory
 (the reference's rmtree bug deleted the whole log dir — we implement the
-intent), keep the text log, stop the listener cleanly, and exit 0.
+intent), keep the text log and stop the listener cleanly.  The reference
+then exits 0; here the crash propagates and the CLI exits 1, so a launcher
+cannot take a crashed run for a finished one.
 """
 import os
 import subprocess
 import sys
+
+import pytest
+
+import bench  # importing it runs nothing: every mode sits behind __main__
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -50,11 +56,19 @@ def test_cli_crash_path_cleans_tb_only(tmp_path):
         text=True,
         timeout=300,
     )
-    # reference behavior: handled crash, clean exit
-    assert proc.returncode == 0, proc.stdout + proc.stderr
+    # logged and cleaned up as the reference does, then a failing status
+    assert proc.returncode == 1, proc.stdout + proc.stderr
     log_file = log_dir / "bad.log"
     assert log_file.exists()
     content = log_file.read_text()
     assert "CRITICAL" in content and "NoSuchModel" in content
     # only the TB event dir is removed; the text log survives
     assert not (log_dir / "tf-board-logs").exists()
+
+
+def test_peak_table_raises_on_unknown_device_kind():
+    """A utilization is a share of a stated peak: a device the table does
+    not know is an error, never a null or a default."""
+    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        bench.peak_bf16_flops("TPU v9 imaginary")

@@ -1,22 +1,14 @@
 """Bucketed, backward-overlapped gradient reduction (engine/comm.py).
 
-Parity strategy (and why each comparison is trustworthy on this image):
+Parity strategy:
 
 - The overlap path differentiates the LOCAL loss — the backward carries no
-  collective — so its AD is plain per-device autodiff, exact under every
-  shard_map implementation.  The reduction then happens as FORWARD-only
-  collectives, which the pre-vma experimental shard_map executes correctly.
-  8-device overlap/zero1 runs are therefore compared against an UNSHARDED
-  plain-jax reference.
-- The legacy (implicit) path differentiates through an in-body collective,
-  whose pre-vma AD transpose is wrong on multi-device meshes (see
-  utils/jax_compat.py) — baseline-vs-overlap comparisons are therefore
-  restricted to 1-device meshes, where collectives are identity and both
-  paths are exact (and the parity is BITWISE).
-
-The ``shard_map_compat`` fixture self-provisions ``jax.shard_map`` per test
-and removes the graft on teardown, so this file passes on the vanilla CPU
-image without changing any other test file's environment.
+  collective — and the reduction then happens as FORWARD-only collectives.
+  8-device overlap/zero1 runs are compared against an UNSHARDED plain-jax
+  reference.
+- The legacy (implicit) path differentiates through an in-body collective;
+  baseline-vs-overlap comparisons run on 1-device meshes, where collectives
+  are identity and the parity is BITWISE.
 """
 import jax
 import jax.numpy as jnp
@@ -32,30 +24,9 @@ from pytorch_distributed_training_tpu.engine.comm import (
     zero1_init,
     zero1_slot_count,
 )
-from pytorch_distributed_training_tpu.utils import jax_compat
 
 DATA = "data"
 SEQ_AXIS = "sequence"
-
-
-@pytest.fixture()
-def shard_map_compat(monkeypatch):
-    """Graft ``jax.shard_map`` for one test, restore the world after.
-
-    Scoped per-test (not module/session) so alphabetically-later test files
-    keep seeing the unmodified jax module — the tier-1 failure set of the
-    shard_map-dependent suites must not change underneath them.
-    """
-    if hasattr(jax, "shard_map"):  # real toolchain graft: nothing to do
-        yield
-        return
-    monkeypatch.setenv("PDT_JAX_COMPAT", "1")
-    jax_compat.install()
-    assert hasattr(jax, "shard_map")
-    try:
-        yield
-    finally:
-        delattr(jax, "shard_map")
 
 
 # --------------------------------------------------------------------- #
@@ -225,7 +196,7 @@ def _run_reduce(tree, cfg, op):
 
 @pytest.mark.parametrize("op", ["psum", "pmean"])
 @pytest.mark.parametrize("bucket_mb", [25.0, 64 / 2**20])
-def test_bucketed_reduce_matches_monolithic_bitwise(shard_map_compat, op, bucket_mb):
+def test_bucketed_reduce_matches_monolithic_bitwise(op, bucket_mb):
     """Concatenation commutes with elementwise reduction: whatever the
     bucketing (one giant bucket or a long barrier chain of tiny ones), the
     reduced tree must equal the per-leaf collective BITWISE."""
@@ -236,7 +207,7 @@ def test_bucketed_reduce_matches_monolithic_bitwise(shard_map_compat, op, bucket
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_reduce_dtype_cast_roundtrip(shard_map_compat):
+def test_reduce_dtype_cast_roundtrip():
     """reduce_dtype=bfloat16: the collective runs in bf16 but every output
     leaf comes back in its own dtype, close to the f32 reduction."""
     tree = _grad_tree(seed=1)
@@ -250,7 +221,7 @@ def test_reduce_dtype_cast_roundtrip(shard_map_compat):
         )
 
 
-def test_bucket_bytes_histogram_recorded(shard_map_compat):
+def test_bucket_bytes_histogram_recorded():
     from pytorch_distributed_training_tpu.telemetry import get_registry, reset_registry
 
     reset_registry()
@@ -297,7 +268,7 @@ def _dp_fixtures(batch=16, seed=5):
     return model, opt, state, img, label
 
 
-def test_dp_overlap_bitwise_on_single_device(shard_map_compat):
+def test_dp_overlap_bitwise_on_single_device():
     """1-device mesh: collectives are identity in both paths, so the
     bucketed explicit reduction must reproduce the legacy step BITWISE."""
     from pytorch_distributed_training_tpu.engine import build_train_step
@@ -319,7 +290,7 @@ def test_dp_overlap_bitwise_on_single_device(shard_map_compat):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_dp_overlap_8dev_matches_unsharded(shard_map_compat):
+def test_dp_overlap_8dev_matches_unsharded():
     """8-device overlap step == plain-jax full-batch step.  The overlap
     backward is collective-free (exact local AD) and pmean(g_local) over a
     power-of-two mesh is the full-batch mean up to reassociation."""
@@ -388,7 +359,7 @@ def _lm_reference(mk, params, opt, tokens, labels, steps=1):
     return params
 
 
-def test_sp_overlap_bitwise_on_single_device(shard_map_compat):
+def test_sp_overlap_bitwise_on_single_device():
     """(1, 1) mesh: the SP objective's psum is identity, so legacy vs
     overlap must agree BITWISE at grad_accum == 1 (identical sum)."""
     from pytorch_distributed_training_tpu.engine import TrainState, build_lm_train_step
@@ -410,7 +381,7 @@ def test_sp_overlap_bitwise_on_single_device(shard_map_compat):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_sp_overlap_8dev_matches_unsharded(shard_map_compat):
+def test_sp_overlap_8dev_matches_unsharded():
     from pytorch_distributed_training_tpu.engine import TrainState, build_lm_train_step
     from pytorch_distributed_training_tpu.parallel import make_sp_mesh
     from pytorch_distributed_training_tpu.schedulers import multi_step_lr
@@ -428,7 +399,7 @@ def test_sp_overlap_8dev_matches_unsharded(shard_map_compat):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5)
 
 
-def test_sp_overlap_grad_accum_composition(shard_map_compat):
+def test_sp_overlap_grad_accum_composition():
     """grad_accum=2 under overlap: micros accumulate locally, ONE bucketed
     reduction per step (DDP no_sync semantics) — same total, reassociated."""
     from pytorch_distributed_training_tpu.engine import TrainState, build_lm_train_step
@@ -448,7 +419,7 @@ def test_sp_overlap_grad_accum_composition(shard_map_compat):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5)
 
 
-def test_zero1_8dev_matches_unsharded(shard_map_compat):
+def test_zero1_8dev_matches_unsharded():
     """Two ZeRO-1 steps (reduce-scatter + sharded update + all-gather) ==
     two plain full-batch steps.  Two steps exercise the momentum buffers
     living as flat 1/n shards, including SGD's first-step buffer init, and

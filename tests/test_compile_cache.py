@@ -26,15 +26,15 @@ def _restore_cache_config():
     yield
     for name, value in saved.items():
         jax.config.update(name, value)
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax.experimental.compilation_cache import compilation_cache
 
-        _cc.reset_cache()  # drop the initialized cache object too
-    except Exception:
-        pass
+    compilation_cache.reset_cache()  # drop the initialized cache object too
 
 
-def test_enable_compile_cache_writes_entries(tmp_path, _restore_cache_config):
+def test_enable_compile_cache_writes_entries(
+    tmp_path, _restore_cache_config, monkeypatch
+):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = tmp_path / "xla-cache"
     returned = enable_compile_cache(str(cache_dir))
     assert returned == str(cache_dir)
@@ -52,10 +52,49 @@ def test_enable_compile_cache_writes_entries(tmp_path, _restore_cache_config):
     assert entries, "no cache entries written"
 
 
-def test_runner_config_key_wires_cache(tmp_path, _restore_cache_config):
+def test_env_variable_places_the_cache(tmp_path, _restore_cache_config, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is where the launcher put it.
+    The helper leaves ``jax_compilation_cache_dir`` alone (JAX reads the
+    variable itself at import) and only zeroes the two thresholds."""
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache(str(tmp_path / "ignored")) == placed
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "ignored").exists()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
+def test_default_and_relative_paths_anchor_at_the_checkout(
+    tmp_path, _restore_cache_config, monkeypatch
+):
+    """Variable not set: ``<checkout>/.xla_cache`` by default, and a relative
+    ``training.compile_cache`` lands under the checkout — never under the
+    working directory, which a relaunch may not share."""
+    from pytorch_distributed_training_tpu import utils
+
+    # the real anchor is the directory that holds the package and tests/
+    assert utils._CHECKOUT_ROOT == os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))
+    )
+    checkout = tmp_path / "checkout"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(utils, "_CHECKOUT_ROOT", str(checkout))
+    monkeypatch.chdir(tmp_path)  # somewhere else than the checkout
+    assert enable_compile_cache() == str(checkout / ".xla_cache")
+    assert jax.config.jax_compilation_cache_dir == str(checkout / ".xla_cache")
+    assert enable_compile_cache("run/xla-cache") == str(checkout / "run/xla-cache")
+    assert (checkout / "run/xla-cache").is_dir()
+    assert not (tmp_path / "run").exists()
+
+
+def test_runner_config_key_wires_cache(tmp_path, _restore_cache_config, monkeypatch):
     """training.compile_cache: the Runner enables the cache before building
     its compiled steps, so a config-driven run populates the directory."""
     from pytorch_distributed_training_tpu.engine import Runner
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
 
     cache_dir = tmp_path / "run-cache"
     cfg = {

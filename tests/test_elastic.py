@@ -314,23 +314,14 @@ def test_kill_peer_emergency_save_and_mesh_reshape_resume(tmp_path):
 # ------------------------------------------- in-process end-to-end (1 proc)
 @pytest.fixture
 def one_device_graft(monkeypatch):
-    """``jax.shard_map`` compat-grafted for this test only, pinned to a
-    ONE-device mesh (size-1 collectives are identity, so the pre-vma
-    graft's autodiff caveat in utils/jax_compat.py does not apply)."""
+    """Pin the Runner's meshes to ONE device (the recovery logic under test
+    is device-count independent)."""
     import jax
 
     from pytorch_distributed_training_tpu.engine import paths
     from pytorch_distributed_training_tpu.parallel import make_mesh
     from pytorch_distributed_training_tpu.parallel.mesh import make_sp_mesh
 
-    if not hasattr(jax, "shard_map"):
-        from pytorch_distributed_training_tpu.utils import jax_compat
-
-        monkeypatch.setenv("PDT_JAX_COMPAT", "1")
-        jax_compat.install()
-        wrapper = jax.shard_map
-        del jax.shard_map
-        monkeypatch.setattr(jax, "shard_map", wrapper, raising=False)
     one = jax.devices()[:1]
     # pin BOTH mesh builders the runner paths use: with >1 device the
     # graft's old-transpose gradients make each device apply its own

@@ -52,27 +52,12 @@ def _fault_hygiene():
 
 @pytest.fixture
 def one_device_mesh(monkeypatch):
-    """A ONE-device mesh for the step/runner tests, with ``jax.shard_map``
-    compat-grafted for this test only on pre-graft installs.
-
-    The dev image's vanilla JAX lacks the toolchain's ``jax.shard_map``;
-    the opt-in alias in utils/jax_compat.py has wrong pmean/psum autodiff
-    on multi-device meshes but is EXACT when every collective spans a
-    size-1 axis — and the guard/rollback/retry logic under test is
-    device-count independent, so these tests pin it on one device rather
-    than joining the known shard_map failure set (the graft is scoped via
-    monkeypatch so the rest of the session keeps vanilla behavior)."""
+    """A ONE-device mesh for the step/runner tests: the guard/rollback/retry
+    logic under test is device-count independent, so these tests pin it on
+    one device and stay quick."""
     from pytorch_distributed_training_tpu.engine import paths
     from pytorch_distributed_training_tpu.parallel import make_mesh
 
-    if not hasattr(jax, "shard_map"):
-        from pytorch_distributed_training_tpu.utils import jax_compat
-
-        monkeypatch.setenv("PDT_JAX_COMPAT", "1")
-        jax_compat.install()
-        wrapper = jax.shard_map
-        del jax.shard_map
-        monkeypatch.setattr(jax, "shard_map", wrapper, raising=False)
     mesh = make_mesh(jax.devices()[:1])
     monkeypatch.setattr(paths, "make_mesh", lambda *a, **kw: mesh)
     return mesh

@@ -38,20 +38,11 @@ def _fault_hygiene():
 
 @pytest.fixture
 def one_device_mesh(monkeypatch):
-    """ONE-device mesh with ``jax.shard_map`` compat-grafted when absent —
-    same scoping rationale as the fault-tolerance suite's fixture: the
-    sentinel logic under test is device-count independent."""
+    """ONE-device mesh — same rationale as the fault-tolerance suite's
+    fixture: the sentinel logic under test is device-count independent."""
     from pytorch_distributed_training_tpu.engine import paths
     from pytorch_distributed_training_tpu.parallel import make_mesh
 
-    if not hasattr(jax, "shard_map"):
-        from pytorch_distributed_training_tpu.utils import jax_compat
-
-        monkeypatch.setenv("PDT_JAX_COMPAT", "1")
-        jax_compat.install()
-        wrapper = jax.shard_map
-        del jax.shard_map
-        monkeypatch.setattr(jax, "shard_map", wrapper, raising=False)
     mesh = make_mesh(jax.devices()[:1])
     monkeypatch.setattr(paths, "make_mesh", lambda *a, **kw: mesh)
     return mesh

@@ -195,8 +195,6 @@ def test_bench_decompose_cli(tmp_path):
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu",
-        PDT_JAX_COMPAT="1",  # inert on grafted JAX; enables the seed
-        # shard_map path on vanilla installs (single device = exact)
         PYTHONPATH=_ROOT + os.pathsep + env.get("PYTHONPATH", ""),
         BENCH_LM_VOCAB="256", BENCH_LM_SEQ="64", BENCH_LM_BATCH="2",
         BENCH_LM_EMBED="32", BENCH_LM_DEPTH="2", BENCH_LM_HEADS="4",

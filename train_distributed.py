@@ -9,7 +9,9 @@ no-op under the single-controller-per-host design (SURVEY.md §7 deviations).
 Crash handling reproduces the reference's *intent*, not its bug: on failure
 only the TensorBoard event subdir (``<log-dir>/tf-board-logs``) is removed —
 the reference's ``shutil.rmtree(log_dir, "tf-board-logs")`` (:82) passes the
-subdir name as ``ignore_errors`` and would delete the whole log dir.
+subdir name as ``ignore_errors`` and would delete the whole log dir.  The
+reference then falls off ``main()`` with status 0; here a crashed run exits
+with status 1, so a launcher or a smoke run cannot take it for a finished one.
 """
 import argparse
 import os
@@ -31,7 +33,14 @@ from pytorch_distributed_training_tpu.utils import make_deterministic
 START_METHOD = "spawn"
 
 
-def main():
+def main(argv=None) -> Runner:
+    """Parse the flags, train, and return the finished :class:`Runner`.
+
+    A failure inside the runner is logged CRITICAL with its traceback, the
+    TensorBoard event dir is removed, the log listener is stopped, and the
+    exception propagates: the CLI exits with status 1, and an in-process
+    caller (``chip_smoke.py``) sees the failure itself.
+    """
     parser = argparse.ArgumentParser(description="TPU ImageNet Training")
     parser.add_argument("--num-nodes", default=-1, type=int,
                         help="number of hosts for distributed training")
@@ -48,7 +57,7 @@ def main():
     parser.add_argument("--file-name-cfg", type=str)
     parser.add_argument("--log-dir", type=str)
     parser.add_argument("--cfg-filepath", type=str)
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     if args.seed is not None:
         print("Set seed:", args.seed)
@@ -80,9 +89,11 @@ def main():
         logger.critical("While running, exception:\n%s\nTraceback:\n%s", str(e), str(tb))
         shutil.rmtree(os.path.join(args.log_dir, TB_SUBDIR), ignore_errors=True)
         time.sleep(1.5)
+        raise
     finally:
         # make sure listener is stopped
         logger_listener.stop()
+    return runner
 
 
 if __name__ == "__main__":
