@@ -1,0 +1,11 @@
+"""Output tokens the client received inside the window, per second of the
+window (every token by its own time stamp)."""
+from benchmark import loadgen
+
+META = {"source": "host_clock"}
+
+
+def read(run):
+    if not run.serve:
+        return None
+    return loadgen.tokens_per_s(run.serve["records"], run.serve["t0"], run.seconds)
