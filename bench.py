@@ -16,11 +16,6 @@ Additional modes (VERDICT round-1 item #1 — prove host-side throughput):
                              device_prefetch + sharded device_put), i.e. the
                              real deployment data path, not device-resident
                              arrays.
-  python bench.py decompose — machine-readable LM step-time decomposition:
-                             attention / mlp_matmul / elementwise /
-                             ce_softmax / optimizer / host_infeed buckets
-                             that partition step_ms exactly (one JSON line;
-                             BENCH_DECOMP_OUT=path also writes it to disk).
   python bench.py ckpt     — checkpoint save-stall A/B: short LM run with
                              periodic saves, synchronous vs async
                              (training.checkpoint.async) — save-step stall,
@@ -332,12 +327,8 @@ def bench_e2e():
 
 
 def _lm_setup():
-    """Shared LM-bench construction for the ``lm`` and ``decompose`` modes.
-
-    Reads the BENCH_LM_* env surface, builds the model/optimizer/step at
-    the flagship shapes, and returns everything both modes need — so the
-    decomposition provably profiles the SAME program the scoreboard times.
-    """
+    """LM-bench construction for the ``lm`` mode: reads the BENCH_LM_* env
+    surface and builds the model/optimizer/step at the flagship shapes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -487,75 +478,6 @@ def bench_lm():
             }
         )
     )
-
-
-def bench_decompose():
-    """Machine-readable LM step-time decomposition (the round-6 tentpole).
-
-    Builds the EXACT program ``bench.py lm`` scores (same env surface, same
-    modules, same optimizer), measures its step time, then re-times each
-    component family as an isolated compiled probe at the step's shapes
-    (engine/profiling.decompose_lm_step).  Prints one JSON line whose
-    ``buckets`` partition step_ms exactly; ``raw_ms`` carries the unscaled
-    probe times for honesty about overlap.
-
-      BENCH_DECOMP_ITERS  fori iterations per probe window (default 10)
-      BENCH_DECOMP_OUT    also write the JSON to this path
-      BENCH_WINDOWS       probe windows, best-of-N (default 3)
-
-    The optimization loop this feeds: sort ``buckets`` descending, attack
-    the top one (remat policy, tail fusion, fused optimizer — all wired as
-    env knobs on the bench and config keys on the runner), re-run, repeat.
-    """
-    import jax
-
-    from pytorch_distributed_training_tpu.engine.profiling import (
-        decompose_lm_step,
-    )
-
-    s = _lm_setup()
-    state, step, inp, lab = s["state"], s["step"], s["inp"], s["lab"]
-
-    for _ in range(3):
-        state, loss = step(state, inp, lab)
-    float(loss)
-
-    def one_window(iters):
-        nonlocal state
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            state, loss = step(state, inp, lab)
-        float(loss)
-        return time.perf_counter() - t0
-
-    iters = int(os.environ.get("BENCH_ITERS", "20"))
-    dt, dt_median = _best_window_dt(one_window, iters)
-    step_ms = dt / iters * 1e3
-
-    out = decompose_lm_step(
-        s["lm"], s["opt"], state.params, state.opt_state, inp, lab, step_ms,
-        iters=int(os.environ.get("BENCH_DECOMP_ITERS", "10")),
-        windows=int(os.environ.get("BENCH_WINDOWS", "3")),
-    )
-    out = {
-        "metric": f"TransformerLM step decomposition (seq {s['seq']}, "
-        f"batch {s['batch'] // jax.device_count()}/chip, depth {s['depth']}, "
-        f"{s['heads']} heads x {s['embed'] // s['heads']})",
-        "value": out["step_ms"],
-        "unit": "ms/step",
-        "vs_baseline": None,
-        "device": jax.devices()[0].device_kind,
-        "median_step_ms": round(dt_median / iters * 1e3, 3),
-        "fused_tails": s["fused_tails"],
-        "fused_opt": s["fused_opt"],
-        **out,
-    }
-    line = json.dumps(out)
-    print(line)
-    path = os.environ.get("BENCH_DECOMP_OUT")
-    if path:
-        with open(path, "w") as f:
-            f.write(line + "\n")
 
 
 def bench_flash():
@@ -3000,8 +2922,6 @@ if __name__ == "__main__":
         bench_e2e()
     elif mode == "lm":
         bench_lm()
-    elif mode == "decompose":
-        bench_decompose()
     elif mode == "flash":
         bench_flash()
     elif mode == "ckpt":

@@ -44,6 +44,7 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from ..telemetry.spans import span
 from .datasets import fetch_sample, sample_rng
 from .sampler import DistributedShardSampler
 
@@ -242,11 +243,14 @@ class DataLoader:
             )
 
         def postprocess(slot_view: np.ndarray, label_view: np.ndarray):
-            if slot_view.dtype == np.uint8 and self.output_dtype == "float32":
-                imgs = self._normalize_u8(slot_view)  # writes a fresh array
-            else:
-                imgs = np.array(slot_view)  # copy out: slot is recycled next
-            return imgs, np.array(label_view)
+            # the workers' own decode runs in other processes; what this
+            # process pays per batch is this copy out of the slot
+            with span("batch_assemble", n=len(label_view)):
+                if slot_view.dtype == np.uint8 and self.output_dtype == "float32":
+                    imgs = self._normalize_u8(slot_view)  # writes a fresh array
+                else:
+                    imgs = np.array(slot_view)  # copy out: slot is recycled next
+                return imgs, np.array(label_view)
 
         return self._pool.run_epoch(batches, epoch, postprocess)
 
@@ -258,9 +262,10 @@ class DataLoader:
         stop = threading.Event()
 
         def assemble(b):
-            if self.worker_mode == "native":
-                return self._assemble_native(b, epoch)
-            return self._assemble(b, epoch, pool)
+            with span("batch_assemble", n=len(b)):
+                if self.worker_mode == "native":
+                    return self._assemble_native(b, epoch)
+                return self._assemble(b, epoch, pool)
 
         def producer():
             try:
@@ -276,7 +281,8 @@ class DataLoader:
         t.start()
         try:
             while True:
-                item = out_q.get()
+                with span("loader_wait"):
+                    item = out_q.get()
                 if item is None:
                     break
                 if isinstance(item, BaseException):

@@ -53,6 +53,7 @@ from multiprocessing import shared_memory
 
 import numpy as np
 
+from ..telemetry.spans import span
 from .datasets import fetch_sample
 
 __all__ = ["ProcessLoaderPool"]
@@ -234,7 +235,8 @@ class ProcessLoaderPool:
                 next_yield += 1
                 yield out
                 continue
-            r = self._collect_one()
+            with span("loader_wait"):
+                r = self._collect_one()
             if r[0] != gen:  # stale result from an abandoned epoch
                 continue
             _, seq, slot, err = r

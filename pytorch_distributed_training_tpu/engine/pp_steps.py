@@ -91,6 +91,7 @@ def _stage_applies(model, seq_axis=None):
             )
         return x + pe[None].astype(model.dtype)
 
+    @jax.named_scope("forward")
     def apply_blocks(blocks_local, x):
         def layer(x, p):
             return block.apply({"params": p}, x), None
@@ -106,6 +107,7 @@ def _stage_applies(model, seq_axis=None):
         x, _ = jax.lax.scan(f, x, blocks_local)
         return x
 
+    @jax.named_scope("loss_head")
     def apply_head(shared, x):
         h = ln.apply({"params": shared["ln"]}, x)
         return head.apply({"params": shared["head"]}, h)
@@ -522,7 +524,8 @@ def build_pp_lm_train_step(
     def step_body(params, opt_state, tokens, labels):
         grads, loss = grads_fn(params, tokens, labels)
         lr = lr_fn(opt_state.step)
-        new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
         return new_params, new_opt, loss
 
     def compile_for(state: TrainState):
@@ -588,9 +591,10 @@ def build_pp_lm_train_step(
                 if moment_sh is not None:
                     grads = jax.lax.with_sharding_constraint(grads, moment_sh)
                 lr = lr_fn(state.opt_state.step)
-                new_params, new_opt = optimizer.update(
-                    grads, state.opt_state, state.params, lr
-                )
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.update(
+                        grads, state.opt_state, state.params, lr
+                    )
                 new_params = jax.lax.with_sharding_constraint(
                     new_params, param_sh
                 )

@@ -452,6 +452,7 @@ class Runner:
             capture_iters=self.telemetry_capture_iters,
             capture_at_iter=self.telemetry_capture_at_iter,
             capture_dir=self.telemetry_capture_dir,
+            capture_python_tracer=self.telemetry_capture_python_tracer,
             logger=self.logger,
         )
 
@@ -1040,127 +1041,130 @@ class Runner:
         self._last_step_applied = True
         # --- the reference outer loop (:251-265), line for line -------------
         while self.iter < train_cfg["train_iters"]:
-            step_t0 = time.monotonic()
-            if self._watchdog:
-                self._watchdog.step_started(self.iter)
-            self._apply_step_faults()
-            if self._elastic is not None:
-                # pre-step liveness gate: a peer that died BETWEEN steps is
-                # caught here, before this process enters any collective —
-                # the committed state is still saveable (emergency path)
-                self._elastic.check_peers()
-            with tel.span("data_wait", step=self.iter):
-                g_img, g_label = next(iter_generator)
-            if self._elastic is not None:
-                # elastic mode's documented per-step cost: the step runs
-                # under the peer-loss guard and is synced to completion, so
-                # a peer dying MID-collective turns an indefinite hang into
-                # a diagnosed PeerLostError within the heartbeat timeout
-                with tel.span("step_dispatch", step=self.iter):
-                    self._elastic.guard(
-                        self._synced_train_iter, g_img, g_label,
-                        what=f"train step {self.iter}",
-                    )
-            else:
-                with tel.span("step_dispatch", step=self.iter):
-                    self.train_iter(g_img, g_label)
-            self._advance_pipeline()
-            if self._watchdog:
-                self._watchdog.step_finished()
-            replayed = self.iter <= self._max_iter_seen
-            self._max_iter_seen = max(self._max_iter_seen, self.iter)
-            tel.note_step(
-                time.monotonic() - step_t0,
-                applied=self._last_step_applied,
-                replayed=replayed,
-            )
-            if (
-                self.anomaly_enabled
-                and self._consec_anomalies >= self.anomaly_max_consec
-            ):
-                rb_t0 = time.monotonic()
-                with tel.span("rollback", step=self.iter):
-                    iter_generator = self._rollback(iter_generator, train_cfg)
-                tel.note_lost("rollback", time.monotonic() - rb_t0)
-                continue
-            if self._integrity is not None and self._integrity.due(self.iter):
-                # between steps the state is quiescent and owned (no
-                # donation conflict with the compiled step) — fingerprint,
-                # vote, and either retain a new known-good snapshot or
-                # enter the classify-then-quarantine ladder
-                with tel.span("integrity_check", step=self.iter):
-                    self.state, verdict = self._integrity.check(
-                        self.state, self.iter
-                    )
-                if verdict["persistent"]:
-                    raise DivergedReplicaError(
-                        f"replica(s) {verdict['persistent']} stayed outside "
-                        f"the healthy fingerprint majority for "
-                        f"{self._integrity.max_consecutive} consecutive "
-                        f"checks at step {self.iter} — persistent "
-                        "corruption, quarantining",
-                        ranks=verdict["persistent"], step=self.iter,
-                    )
-                if verdict["local_diverged"]:
-                    rc_t0 = time.monotonic()
-                    with tel.span("integrity_restore", step=self.iter):
-                        iter_generator = self._integrity_recover(
-                            iter_generator, verdict
+            # one step of the profiler's trace: a viewer groups the spans
+            # below, and the device work they launch, under this number
+            with jax.profiler.StepTraceAnnotation("train", step_num=self.iter):
+                step_t0 = time.monotonic()
+                if self._watchdog:
+                    self._watchdog.step_started(self.iter)
+                self._apply_step_faults()
+                if self._elastic is not None:
+                    # pre-step liveness gate: a peer that died BETWEEN steps is
+                    # caught here, before this process enters any collective —
+                    # the committed state is still saveable (emergency path)
+                    self._elastic.check_peers()
+                with tel.span("data_wait", step=self.iter):
+                    g_img, g_label = next(iter_generator)
+                if self._elastic is not None:
+                    # elastic mode's documented per-step cost: the step runs
+                    # under the peer-loss guard and is synced to completion, so
+                    # a peer dying MID-collective turns an indefinite hang into
+                    # a diagnosed PeerLostError within the heartbeat timeout
+                    with tel.span("step_dispatch", step=self.iter):
+                        self._elastic.guard(
+                            self._synced_train_iter, g_img, g_label,
+                            what=f"train step {self.iter}",
                         )
-                    tel.note_lost(
-                        "integrity_restore", time.monotonic() - rc_t0
-                    )
+                else:
+                    with tel.span("step_dispatch", step=self.iter):
+                        self.train_iter(g_img, g_label)
+                self._advance_pipeline()
+                if self._watchdog:
+                    self._watchdog.step_finished()
+                replayed = self.iter <= self._max_iter_seen
+                self._max_iter_seen = max(self._max_iter_seen, self.iter)
+                tel.note_step(
+                    time.monotonic() - step_t0,
+                    applied=self._last_step_applied,
+                    replayed=replayed,
+                )
+                if (
+                    self.anomaly_enabled
+                    and self._consec_anomalies >= self.anomaly_max_consec
+                ):
+                    rb_t0 = time.monotonic()
+                    with tel.span("rollback", step=self.iter):
+                        iter_generator = self._rollback(iter_generator, train_cfg)
+                    tel.note_lost("rollback", time.monotonic() - rb_t0)
                     continue
-                # healthy consensus (a diverged SIMULATED peer restores
-                # its own copy; our state is good) — retain it as the
-                # recovery point for the next check
-                self._integrity.retain(
-                    self.state, self.iter, self._pipeline_extras()
-                )
-            if self._preempt and self._globally_preempted():
-                self.logger.warning(
-                    "Preemption signal received: saving checkpoint at iter "
-                    "%d and exiting",
-                    self.iter,
-                )
-                self.checkpointer.save(
-                    self.iter, self.state, extras=self._pipeline_extras()
-                )
-                self.checkpointer.wait()
-                return
-            if self.profiler:
-                self.profiler.after_step(self.iter, sync=self.state)
-
-            def is_val():
-                p1 = self.iter != 0
-                p2 = (self.iter + 1) % train_cfg["val_interval"] == 0
-                p3 = self.iter == train_cfg["train_iters"] - 1
-                return (p1 and p2) or p3
-
-            if is_val():
-                # keep validation (and checkpoint I/O below) out of the trace:
-                # the window is a bounded steady-state sample of train steps
-                if self.profiler:
-                    self.profiler.stop(sync=self.state)
-                with tel.span("eval", step=self.iter):
-                    self.validate()
-            if self.checkpointer and self.checkpointer.should_save(
-                self.iter, train_cfg["train_iters"]
-            ):
-                if self.profiler:
-                    self.profiler.stop(sync=self.state)
-                with tel.span("ckpt_save", step=self.iter):
+                if self._integrity is not None and self._integrity.due(self.iter):
+                    # between steps the state is quiescent and owned (no
+                    # donation conflict with the compiled step) — fingerprint,
+                    # vote, and either retain a new known-good snapshot or
+                    # enter the classify-then-quarantine ladder
+                    with tel.span("integrity_check", step=self.iter):
+                        self.state, verdict = self._integrity.check(
+                            self.state, self.iter
+                        )
+                    if verdict["persistent"]:
+                        raise DivergedReplicaError(
+                            f"replica(s) {verdict['persistent']} stayed outside "
+                            f"the healthy fingerprint majority for "
+                            f"{self._integrity.max_consecutive} consecutive "
+                            f"checks at step {self.iter} — persistent "
+                            "corruption, quarantining",
+                            ranks=verdict["persistent"], step=self.iter,
+                        )
+                    if verdict["local_diverged"]:
+                        rc_t0 = time.monotonic()
+                        with tel.span("integrity_restore", step=self.iter):
+                            iter_generator = self._integrity_recover(
+                                iter_generator, verdict
+                            )
+                        tel.note_lost(
+                            "integrity_restore", time.monotonic() - rc_t0
+                        )
+                        continue
+                    # healthy consensus (a diverged SIMULATED peer restores
+                    # its own copy; our state is good) — retain it as the
+                    # recovery point for the next check
+                    self._integrity.retain(
+                        self.state, self.iter, self._pipeline_extras()
+                    )
+                if self._preempt and self._globally_preempted():
+                    self.logger.warning(
+                        "Preemption signal received: saving checkpoint at iter "
+                        "%d and exiting",
+                        self.iter,
+                    )
                     self.checkpointer.save(
                         self.iter, self.state, extras=self._pipeline_extras()
                     )
-                if self.profiler:
-                    # with checkpoint.async the write is in flight — block
-                    # until it commits so the profiler window can't reopen
-                    # over background checkpoint I/O
                     self.checkpointer.wait()
-            # retrace-probe poll + on-demand capture window + periodic export
-            tel.after_step(self.iter, sync=self.state)
-            self.iter += 1
+                    return
+                if self.profiler:
+                    self.profiler.after_step(self.iter, sync=self.state)
+
+                def is_val():
+                    p1 = self.iter != 0
+                    p2 = (self.iter + 1) % train_cfg["val_interval"] == 0
+                    p3 = self.iter == train_cfg["train_iters"] - 1
+                    return (p1 and p2) or p3
+
+                if is_val():
+                    # keep validation (and checkpoint I/O below) out of the trace:
+                    # the window is a bounded steady-state sample of train steps
+                    if self.profiler:
+                        self.profiler.stop(sync=self.state)
+                    with tel.span("eval", step=self.iter):
+                        self.validate()
+                if self.checkpointer and self.checkpointer.should_save(
+                    self.iter, train_cfg["train_iters"]
+                ):
+                    if self.profiler:
+                        self.profiler.stop(sync=self.state)
+                    with tel.span("ckpt_save", step=self.iter):
+                        self.checkpointer.save(
+                            self.iter, self.state, extras=self._pipeline_extras()
+                        )
+                    if self.profiler:
+                        # with checkpoint.async the write is in flight — block
+                        # until it commits so the profiler window can't reopen
+                        # over background checkpoint I/O
+                        self.checkpointer.wait()
+                # retrace-probe poll + on-demand capture window + periodic export
+                tel.after_step(self.iter, sync=self.state)
+                self.iter += 1
 
     def _globally_preempted(self) -> bool:
         """Whether to act on preemption at THIS iteration, agreed across
@@ -1191,10 +1195,14 @@ class Runner:
             img_dtype = np.uint8  # normalized in-graph (4x smaller transfer)
         else:
             img_dtype = np.float32
-        img = np.asarray(img, dtype=img_dtype)
-        label = np.asarray(label, dtype=np.int32)
-        g_img = jax.make_array_from_process_local_data(self._img_sharding, img)
-        g_label = jax.make_array_from_process_local_data(self._label_sharding, label)
+        nbytes = np.size(img) * np.dtype(img_dtype).itemsize + np.size(label) * 4
+        with self._tspan("h2d_put", bytes=nbytes):
+            img = np.asarray(img, dtype=img_dtype)
+            label = np.asarray(label, dtype=np.int32)
+            g_img = jax.make_array_from_process_local_data(self._img_sharding, img)
+            g_label = jax.make_array_from_process_local_data(
+                self._label_sharding, label
+            )
         return g_img, g_label
 
     def _tspan(self, kind: str, **extra):

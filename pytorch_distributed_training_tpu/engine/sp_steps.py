@@ -54,10 +54,11 @@ def lm_loss_local(logits, labels, global_tokens: int, label_smoothing: float = 0
     normalization the SP gradient math needs.
     """
     vocab = logits.shape[-1]
-    local_mean = cross_entropy_loss(
-        logits.reshape(-1, vocab), labels.reshape(-1), label_smoothing
-    )
-    return local_mean * (labels.size / global_tokens)
+    with jax.named_scope("loss_head"):
+        local_mean = cross_entropy_loss(
+            logits.reshape(-1, vocab), labels.reshape(-1), label_smoothing
+        )
+        return local_mean * (labels.size / global_tokens)
 
 
 def build_lm_train_step(
@@ -137,7 +138,8 @@ def build_lm_train_step(
         global_tokens = b_local * s_local * n_data * n_seq
 
         def loss_fn(p, tok, lab):
-            logits = model.apply({"params": p}, tok)
+            with jax.named_scope("forward"):
+                logits = model.apply({"params": p}, tok)
             # objective = GLOBAL mean CE per token: psum of the local partial
             # sums (each already /global_tokens).  Differentiating this
             # replicated scalar yields the exact global gradient directly —
@@ -192,7 +194,10 @@ def build_lm_train_step(
                 # psum per bucket reproduces the implicit reduction exactly
                 grads = reduce_gradients(grads, comm, axes, op="psum")
                 loss = jax.lax.psum(loss, axes)
-            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.update(
+                    grads, opt_state, params, lr
+                )
         if not guard:
             return new_params, new_opt, loss
         (gnorm_ref,) = guard_args

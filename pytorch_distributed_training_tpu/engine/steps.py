@@ -186,13 +186,15 @@ def build_train_step(
         img = normalize(img)
 
         def loss_fn(p):
-            out, mutated = model.apply(
-                {"params": p, "batch_stats": batch_stats},
-                img,
-                train=True,
-                mutable=["batch_stats"],
-            )
-            loss = cross_entropy_loss(out, label, label_smoothing)
+            with jax.named_scope("forward"):
+                out, mutated = model.apply(
+                    {"params": p, "batch_stats": batch_stats},
+                    img,
+                    train=True,
+                    mutable=["batch_stats"],
+                )
+            with jax.named_scope("loss_head"):
+                loss = cross_entropy_loss(out, label, label_smoothing)
             # Make the OBJECTIVE the global-batch mean (each replica's CE is
             # the mean over its local shard).  Differentiating this is the
             # DDP-reducer equivalent: the cotangent of the replicated params
@@ -260,13 +262,16 @@ def build_train_step(
             # with the same fixed point; deviation documented in SURVEY §2.3).
             new_bs = jax.lax.pmean(new_bs, DATA_AXIS)
         lr = lr_fn(opt_state.step)
-        if fold_ema:
-            new_params, new_opt, new_ema = optimizer.update_with_ema(
-                grads, opt_state, params, lr, ema, float(ema_decay)
-            )
-        else:
-            new_params, new_opt = optimizer.update(grads, opt_state, params, lr)
-            new_ema = ema
+        with jax.named_scope("optimizer"):
+            if fold_ema:
+                new_params, new_opt, new_ema = optimizer.update_with_ema(
+                    grads, opt_state, params, lr, ema, float(ema_decay)
+                )
+            else:
+                new_params, new_opt = optimizer.update(
+                    grads, opt_state, params, lr
+                )
+                new_ema = ema
         if not guard:
             return new_params, new_bs, new_opt, loss, new_ema
         (gnorm_ref,) = guard_args
@@ -305,6 +310,7 @@ def build_train_step(
         out_specs=(rep, rep, rep, rep, rep) + ((rep, rep) if guard else ()),
     )
 
+    @jax.named_scope("optimizer")
     def _ema_outside(ok, old_ema, new_params):
         # replicated elementwise update — no collective needed, so it
         # lives outside the shard_map

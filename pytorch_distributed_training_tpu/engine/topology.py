@@ -707,11 +707,11 @@ def parse_telemetry(r, train_cfg: dict) -> None:
         )
 
     cap = tl.get("capture") or {}
-    unknown = set(cap) - {"signal", "n_iters", "at_iter", "dir"}
+    unknown = set(cap) - {"signal", "n_iters", "at_iter", "dir", "python_tracer"}
     if unknown:
         raise ValueError(
             f"training.telemetry.capture: unknown key(s) {sorted(unknown)} "
-            "(want signal/n_iters/at_iter/dir)"
+            "(want signal/n_iters/at_iter/dir/python_tracer)"
         )
     from ..telemetry.capture import parse_signal
 
@@ -725,6 +725,7 @@ def parse_telemetry(r, train_cfg: dict) -> None:
         int(cap["at_iter"]) if cap.get("at_iter") is not None else None
     )
     r.telemetry_capture_dir = cap.get("dir")
+    r.telemetry_capture_python_tracer = bool(cap.get("python_tracer", False))
     if r.telemetry_capture_iters < 1:
         raise ValueError(
             "training.telemetry.capture.n_iters must be >= 1, got "

@@ -132,10 +132,12 @@ def build_tp_lm_train_step(
         # nothing.  Only ``moe_aux`` entries join the objective: other
         # sown intermediates (telemetry, debugging) must NOT leak into
         # the loss (r2 code-review finding).  Validation stays pure CE.
-        logits, inter = model.apply(
-            {"params": p}, tokens, mutable="intermediates"
-        )
-        loss = _token_ce(logits, labels, mesh, label_smoothing)
+        with jax.named_scope("forward"):
+            logits, inter = model.apply(
+                {"params": p}, tokens, mutable="intermediates"
+            )
+        with jax.named_scope("loss_head"):
+            loss = _token_ce(logits, labels, mesh, label_smoothing)
         for path, leaf in jax.tree_util.tree_flatten_with_path(inter)[0]:
             if any(
                 str(getattr(key, "key", key)) == "moe_aux" for key in path
@@ -197,8 +199,11 @@ def build_tp_lm_train_step(
         # arXiv:2004.13336 expressed declaratively.  Bitwise parity with the
         # per-leaf path is pinned in tests/test_profiling.py (incl. a ZeRO-1
         # GSPMD case); whether concat beats per-leaf under ZeRO is a chip
-        # measurement (`bench.py decompose`), not an assumption.
-        new_params, new_opt = optimizer.update(grads, state.opt_state, state.params, lr)
+        # measurement (the ``optimizer`` scope in a trace), not an assumption.
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(
+                grads, state.opt_state, state.params, lr
+            )
         return (
             TrainState(
                 params=new_params, batch_stats=state.batch_stats,

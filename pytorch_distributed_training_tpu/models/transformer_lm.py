@@ -307,5 +307,8 @@ class TransformerLM(nn.Module):
                 lora_adapters=self.lora_adapters,
                 name=f"block{i}",
             )(x, decode_pos, block_tables, adapter_ids)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln")(x)
-        return nn.Dense(self.vocab_size, dtype=jnp.float32, name="head")(x)
+        # a scope of the trace, not of the parameters: the final norm and
+        # the logits matmul read as ``loss_head`` with the CE that follows
+        with jax.named_scope("loss_head"):
+            x = nn.LayerNorm(dtype=self.dtype, name="ln")(x)
+            return nn.Dense(self.vocab_size, dtype=jnp.float32, name="head")(x)

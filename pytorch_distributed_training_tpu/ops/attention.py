@@ -274,7 +274,8 @@ class MultiHeadAttention(nn.Module):
         qkv = qkv.reshape(b, s, self.num_heads, 3, head_dim)
         q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
         if self.decode and self.paged:
-            out = self._paged_attention(q, k, v, decode_pos, block_tables)
+            with jax.named_scope("paged_attention"):
+                out = self._paged_attention(q, k, v, decode_pos, block_tables)
         elif self.decode:
             out = self._decode_attention(q, k, v, decode_pos)
         elif self.seq_axis is None:

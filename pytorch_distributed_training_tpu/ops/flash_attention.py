@@ -646,6 +646,7 @@ def _make(
                 pltpu.VMEM((bq, d), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_fwd_stream",
         )(q, k, v)
 
     def _forward(q, k, v):
@@ -680,6 +681,7 @@ def _make(
                 _out_struct((bh, s_len, 1), jnp.float32, q),
             ],
             interpret=interpret,
+            name="flash_fwd",
         )(q, k, v)
 
     @jax.custom_vjp
@@ -723,6 +725,7 @@ def _make(
             out_shape=_out_struct(q.shape, q.dtype, q),
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd_dq_stream",
         )(q, k, v, g, lse, delta)
         # dK/dV: K tile outer, Q tile inner (index maps swap roles)
         kout = lambda b, j, i: (b, j, 0)  # noqa: E731
@@ -755,6 +758,7 @@ def _make(
                 pltpu.VMEM((bk, d), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_bwd_dkv_stream",
         )(q, k, v, g, lse, delta)
         return dq, dk, dv
 
@@ -809,6 +813,7 @@ def _make(
                     _out_struct(v.shape, jnp.float32, v),
                 ],
                 interpret=interpret,
+                name="flash_bwd",
             )(q, k, v, g, lse, delta)
             return dq, dk32.astype(k.dtype), dv32.astype(v.dtype)
         dq = pl.pallas_call(
@@ -829,6 +834,7 @@ def _make(
             out_specs=pl.BlockSpec((1, bq, d), row),
             out_shape=_out_struct(q.shape, q.dtype, q),
             interpret=interpret,
+            name="flash_bwd_dq",
         )(q, k, v, g, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(
@@ -854,6 +860,7 @@ def _make(
                 _out_struct(v.shape, v.dtype, v),
             ],
             interpret=interpret,
+            name="flash_bwd_dkv",
         )(q, k, v, g, lse, delta)
         return dq, dk, dv
 

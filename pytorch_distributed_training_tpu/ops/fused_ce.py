@@ -121,6 +121,7 @@ def _make(interpret: bool):
                 _out_struct((b, 1), jnp.float32, logits),
             ],
             interpret=interpret,
+            name="fused_ce_fwd",
         )(logits, labels2)
         return nll, lse
 
@@ -152,6 +153,7 @@ def _make(interpret: bool):
             out_specs=pl.BlockSpec((tile, c), lambda i: (i, 0)),
             out_shape=_out_struct((b, c), logits.dtype, logits),
             interpret=interpret,
+            name="fused_ce_bwd",
         )(logits, labels2, lse, scale)
         return dlogits, None
 

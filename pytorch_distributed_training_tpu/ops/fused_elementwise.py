@@ -122,6 +122,7 @@ def _make_add_ln(interpret: bool, eps: float):
                 _out_struct((rows, feat), out_dtype, x),
             ],
             interpret=interpret,
+            name="fused_add_ln",
         )(x, delta, scale.reshape(1, feat), bias.reshape(1, feat))
         return s, y
 
@@ -248,6 +249,7 @@ def _make_bias_gelu(interpret: bool):
             out_specs=pl.BlockSpec((tile, feat), lambda i: (i, 0)),
             out_shape=_out_struct((rows, feat), u.dtype, u),
             interpret=interpret,
+            name="fused_bias_gelu",
         )(u, bias.reshape(1, feat))
 
     @jax.custom_vjp

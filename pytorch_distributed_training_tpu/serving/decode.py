@@ -71,10 +71,10 @@ def _make_sampler(temperature: float):
     temperature 0 (keys ignored), else a per-row categorical draw — vmapped
     so row r's draw consumes ONLY ``keys[r]`` and ``logits[r]`` and is
     bitwise independent of every other row."""
-    if temperature == 0.0:
-        return lambda logits, keys: jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
+    @jax.named_scope("sample")
     def sample(logits, keys):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         draw = lambda k, l: jax.random.categorical(k, l / temperature)
         return jax.vmap(draw)(keys, logits).astype(jnp.int32)
 

@@ -35,7 +35,14 @@ from typing import Dict, List, Optional
 
 from ..telemetry.registry import MetricsRegistry
 
-__all__ = ["ServingMetrics", "aggregate_snapshots"]
+__all__ = ["TICK_PHASES", "ServingMetrics", "aggregate_snapshots"]
+
+# The phases a productive scheduler tick's wall time is split over, in the
+# order they run: each is a span of that kind (a child of ``tick``,
+# serving/scheduler.py) and a histogram ``tick_<kind>_ms`` here.
+TICK_PHASES = (
+    "admit", "prefill", "decode_prep", "decode_step", "readback", "deliver",
+)
 
 # reservoir per distribution: big enough that p99 of a uniform sample is a
 # tight estimate, small enough to cap memory at a few KB per engine
@@ -73,6 +80,31 @@ class ServingMetrics:
         self._dispatch_gap_ms = self._registry.histogram(
             "decode_dispatch_gap_ms", _RESERVOIR
         )
+        # where a productive tick's wall time goes (PR 24): one
+        # observation a tick in each phase's histogram (0 for a phase the
+        # tick did not run, so the means add up to the wall's), the whole
+        # tick, and admit + decode_prep as "prep", the host work that
+        # stands before the dispatch
+        self._tick_wall_ms = self._registry.histogram(
+            "tick_wall_ms", _RESERVOIR
+        )
+        self._tick_prep_ms = self._registry.histogram(
+            "tick_prep_ms", _RESERVOIR
+        )
+        self._tick_phase_ms = {
+            kind: self._registry.histogram(f"tick_{kind}_ms", _RESERVOIR)
+            for kind in TICK_PHASES
+        }
+        # a request's life as the engine sees it, on the scheduler's own
+        # stamps: submit to first admission, submit to first token
+        # pushed, every gap between a request's consecutive pushes
+        # (pooled), and how long a prefill held up rows already decoding
+        self._life_ms = {
+            name: self._registry.histogram(name, _RESERVOIR)
+            for name in (
+                "queue_wait_ms", "ttft_ms", "itl_ms", "prefill_stall_ms",
+            )
+        }
         self._items = 0  # guarded by: self._lock
         self._first_t: Optional[float] = None  # guarded by: self._lock
         self._last_t: Optional[float] = None  # guarded by: self._lock
@@ -241,6 +273,35 @@ class ServingMetrics:
         through between dispatches on the sync path."""
         self._tick_host_ms.observe(float(host_ms))
 
+    def record_tick_phases(
+        self, wall_ms: float, phase_ms: Dict[str, float]
+    ) -> None:
+        """One productive tick: its wall time and the milliseconds of
+        each of :data:`TICK_PHASES` (absent = the phase did not run)."""
+        self._tick_wall_ms.observe(float(wall_ms))
+        self._tick_prep_ms.observe(
+            phase_ms.get("admit", 0.0) + phase_ms.get("decode_prep", 0.0)
+        )
+        for kind, hist in self._tick_phase_ms.items():
+            hist.observe(phase_ms.get(kind, 0.0))
+
+    def record_queue_wait(self, ms: float) -> None:
+        """Submit to first admission into a slot, one a request."""
+        self._life_ms["queue_wait_ms"].observe(float(ms))
+
+    def record_first_token(self, ms: float) -> None:
+        """Submit to the first token pushed, one a request."""
+        self._life_ms["ttft_ms"].observe(float(ms))
+
+    def record_token_gap(self, ms: float) -> None:
+        """The gap between two consecutive pushes of one request."""
+        self._life_ms["itl_ms"].observe(float(ms))
+
+    def record_prefill_stall(self, ms: float) -> None:
+        """A tick's prefill phase that rows already decoding sat through:
+        what their next token waited beyond a plain decode step."""
+        self._life_ms["prefill_stall_ms"].observe(float(ms))
+
     def record_dispatch_gap(self, gap_ms: float) -> None:
         """Host wall time between two consecutive decode dispatch
         enqueues during back-to-back decode ticks.  The sync path's gap
@@ -359,6 +420,23 @@ class ServingMetrics:
             out["decode_dispatch_gap_ms_p50"] = float(gap["p50"])
             out["decode_dispatch_gap_ms_p99"] = float(gap["p99"])
             out["decode_dispatch_gap_ms_mean"] = float(gap["mean"])
+        wall = self._tick_wall_ms.snapshot()
+        if wall["count"]:
+            out["tick_wall_ms_p50"] = float(wall["p50"])
+            out["tick_wall_ms_mean"] = float(wall["mean"])
+            out["tick_prep_ms_p50"] = float(
+                self._tick_prep_ms.snapshot()["p50"]
+            )
+            for kind, hist in self._tick_phase_ms.items():
+                phase = hist.snapshot()
+                out[f"tick_{kind}_ms_p50"] = float(phase["p50"])
+                out[f"tick_{kind}_ms_mean"] = float(phase["mean"])
+        for name, hist in self._life_ms.items():
+            life = hist.snapshot()
+            if life["count"]:
+                out[f"{name}_count"] = int(life["count"])
+                out[f"{name}_p50"] = float(life["p50"])
+                out[f"{name}_p95"] = float(life["p95"])
         with self._lock:
             ready_ms = self._scale_up_ready_ms
         if ready_ms is not None:
@@ -435,6 +513,7 @@ _AGG_MAX = (
     "tick_host_ms_p50", "tick_host_ms_p99",
     "decode_dispatch_gap_ms_p50", "decode_dispatch_gap_ms_p99",
     "scale_up_ready_ms",
+    "queue_wait_ms_p95", "ttft_ms_p95", "itl_ms_p95", "prefill_stall_ms_p95",
 )
 
 
@@ -463,7 +542,7 @@ def aggregate_snapshots(
                 maxes[key] = max(maxes.get(key, val), val)
             elif key.endswith("_per_sec") or key in _AGG_SUM or (
                 not key.startswith("health_")
-                and not key.endswith(("_mean", "_p50", "_p99", "_rate"))
+                and not key.endswith(("_mean", "_p50", "_p95", "_p99", "_rate"))
             ):
                 sums[key] = sums.get(key, 0) + val
     out.update(sums)

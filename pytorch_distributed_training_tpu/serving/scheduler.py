@@ -52,6 +52,20 @@ exact through per-request ``dispatched`` counters and the drained
 stream is bitwise token-identical to the sync path (greedy and
 sampled).  See :meth:`ContinuousScheduler._decode_step_async`.
 
+Where a tick's time goes (PR 24): every tick is a ``tick`` span whose
+children are its phases — ``admit``, ``prefill``, ``decode_prep``,
+``decode_step`` (the dispatch; telemetry/slo.py pairs recoveries with this
+kind), ``readback`` (blocked on the sampled tokens), ``deliver`` (push,
+``on_token``, retire) — the same kinds from the sync, async-ring and
+speculative bodies; each phase's milliseconds also land in a histogram of
+:class:`ServingMetrics` for every productive tick.  A request is stamped at
+submit, first admission, first and every later token, and leaves one
+``request`` record on the span ring when it retires; ``queue_wait_ms``,
+``ttft_ms``, ``itl_ms`` and ``prefill_stall_ms`` are histograms of the same
+stamps.  During a profiler trace the spans are annotations on the host
+plane (telemetry/spans.py), so an idle gap of the device reads as the phase
+the scheduler was in.
+
 Single-process by design (for now): inputs are handed to jit uncommitted
 rather than sharded over the mesh — multi-host serving stays on the
 batcher path until the scheduler learns sharded block tables.
@@ -78,7 +92,7 @@ import numpy as np
 from ..engine import fault
 from ..engine.watchdog import StepWatchdog
 from ..telemetry.registry import get_registry
-from ..telemetry.spans import span
+from ..telemetry.spans import record as record_span, span
 from ..ops.quant import quantize_tree
 from . import kv_transfer
 from .batcher import OverloadedError
@@ -98,6 +112,7 @@ class _PagedRequest:
         "prompt", "max_new", "future", "enqueued_at", "deadline",
         "on_token", "row_key", "admission", "slot", "tokens", "poison",
         "adapter", "adapter_name", "draft_admission", "dispatched",
+        "rid", "admitted_at", "first_token_at", "last_token_at",
     )
 
     def __init__(self, prompt, max_new, deadline, on_token, row_key):
@@ -122,11 +137,42 @@ class _PagedRequest:
         # device.  Invariant: dispatched >= len(tokens); equal in sync
         # mode and whenever the ring is empty for this row.
         self.dispatched = 0
+        # the request's life, stamped by the scheduler on the clock of
+        # ``enqueued_at``: first admission, first and latest token pushed
+        self.rid = -1
+        self.admitted_at: Optional[float] = None
+        self.first_token_at: Optional[float] = None
+        self.last_token_at: Optional[float] = None
 
     @property
     def gen_idx(self) -> int:
         """Generated-token count so far == index of the NEXT token."""
         return len(self.tokens)
+
+
+class _Phase:
+    """One phase of the current tick: a span of ``kind`` whose duration is
+    also added to the scheduler's account of this tick (a phase may run
+    more than once a tick: the async ring drains several entries)."""
+
+    __slots__ = ("_sched", "_kind", "_span", "_t0")
+
+    def __init__(self, sched, kind, extra):
+        self._sched = sched
+        self._kind = kind
+        self._span = span(kind, step=sched._tick_no, **extra)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ms = (time.perf_counter() - self._t0) * 1e3
+        self._span.__exit__(*exc)
+        acct = self._sched._phase_ms
+        acct[self._kind] = acct.get(self._kind, 0.0) + ms
+        return False
 
 
 class ContinuousScheduler:
@@ -324,6 +370,7 @@ class ContinuousScheduler:
         self._pad_key = jax.random.PRNGKey(0)
         self._base_rng = jax.random.PRNGKey(int(seed))
         self._seq_no = 0  # guarded by: self._cond
+        self._req_no = 0  # guarded by: self._cond
 
         # _slots is the scheduler thread's working set: only _admit /
         # _fail_inflight / drain touch it cross-thread, and they take the
@@ -360,6 +407,8 @@ class ContinuousScheduler:
         # read them cross-thread as best-effort diagnostics
         self._tick_no = 0  # confined: _loop
         self._tick_phase = ""  # confined: _loop
+        # this tick's milliseconds by phase kind (see _Phase)
+        self._phase_ms: Dict[str, float] = {}  # confined: _loop
 
         # async-pipeline state (all confined: _loop).  _inflight holds
         # (tok_dev, finite_dev, rows) per dispatched-but-undrained step;
@@ -522,6 +571,8 @@ class ContinuousScheduler:
                 deadline=(time.monotonic() + dl / 1000.0) if dl else None,
                 on_token=on_token, row_key=rng,
             )
+            req.rid = self._req_no
+            self._req_no += 1
             req.adapter = aid
             req.adapter_name = adapter
             if replay:
@@ -822,16 +873,16 @@ class ContinuousScheduler:
                 # waits into _tick_block_s) — the host-overhead number the
                 # async pipeline exists to hide
                 self._tick_block_s = 0.0
+                self._phase_ms = {}
                 t_tick0 = time.perf_counter()
-                did = self._tick_inner()
+                with span("tick", step=self._tick_no):
+                    did = self._tick_inner()
                 if did:
+                    wall_ms = (time.perf_counter() - t_tick0) * 1000.0
                     self.metrics.record_tick(
-                        max(
-                            time.perf_counter() - t_tick0
-                            - self._tick_block_s,
-                            0.0,
-                        ) * 1000.0
+                        max(wall_ms - self._tick_block_s * 1000.0, 0.0)
                     )
+                    self.metrics.record_tick_phases(wall_ms, self._phase_ms)
             finally:
                 if self._watchdog is not None:
                     self._watchdog.step_finished()
@@ -882,10 +933,24 @@ class ContinuousScheduler:
         self._tick_phase = "kv_transfer"
         did_xfer = self._service_kv_transfers()
         self._tick_phase = "admit"
-        newly = self._admit()
+        with self._phase("admit"):
+            newly = self._admit()
         self._tick_phase = "prefill"
         if newly:
-            self._prefill(newly)
+            decoding = self.active() - len(newly)
+            suffix = [r.prompt.size - r.admission.cached_len for r in newly]
+            with self._phase(
+                "prefill", rows=len(newly), tokens=sum(suffix),
+                bucket=self._bucket_for(
+                    max(suffix), self.seq_buckets, "prefill suffix"
+                ),
+                reqs=[r.rid for r in newly],
+            ):
+                self._prefill(newly)
+            if decoding > 0:
+                # what the rows already decoding waited for their next
+                # token while this tick's admissions were prefilled
+                self.metrics.record_prefill_stall(self._phase_ms["prefill"])
         self._tick_phase = "inject"
         self._consult_injector()
         n_active = self.active()
@@ -899,6 +964,9 @@ class ContinuousScheduler:
                 self._decode_step()
         self._publish_pool_gauges()
         return bool(newly) or n_active > 0 or did_xfer
+
+    def _phase(self, kind: str, **extra) -> _Phase:
+        return _Phase(self, kind, extra)
 
     def _bump(self, name: str, n: int = 1) -> None:
         """Engine-local AND process-global: the snapshot shows the
@@ -1105,6 +1173,11 @@ class ContinuousScheduler:
                         break
                     req.draft_admission = dadm
                 self._queue.popleft()
+                if req.admitted_at is None:  # not a hot-restart requeue
+                    req.admitted_at = time.monotonic()
+                    self.metrics.record_queue_wait(
+                        (req.admitted_at - req.enqueued_at) * 1000.0
+                    )
                 req.admission = adm
                 req.slot = free[len(newly)]
                 self._slots[req.slot] = req
@@ -1465,34 +1538,38 @@ class ContinuousScheduler:
     def _decode_step(self) -> None:
         """One single-token step for every occupied slot."""
         t0 = time.perf_counter()
-        active = [req for req in self._slots if req is not None]
-        self._poison_shim(active)
-        prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(active)
+        with self._phase("decode_prep"):
+            active = [req for req in self._slots if req is not None]
+            self._poison_shim(active)
+            prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(active)
         n_active = len(active)
         self._note_dispatch_gap()
         # the span marks this tick as PRODUCTIVE serving work — the
         # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
         # preceding poison_bisect/serving_restart recovery span)
-        with span("decode_step", step=self._tick_no, active=n_active):
+        with self._phase("decode_step", active=n_active):
             tok, finite, self._pool = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables,
                 jnp.stack(keys), gen_idx, aids,
             )
         rb0 = time.perf_counter()
-        tok = np.asarray(tok)
-        finite = np.asarray(finite)
+        with self._phase("readback"):
+            tok = np.asarray(tok)
+            finite = np.asarray(finite)
         t1 = time.perf_counter()
         self._tick_block_s += t1 - rb0
-        for req in active:
-            if not finite[req.slot]:
-                # on-device output guard: evict the NaN emitter, every
-                # other row's logits are untouched (disjoint block tables)
-                self._evict_poisoned(
-                    req, cause=None, trigger="non-finite decode logits"
-                )
-                continue
-            self._push_token(req, int(tok[req.slot]))
+        with self._phase("deliver"):
+            for req in active:
+                if not finite[req.slot]:
+                    # on-device output guard: evict the NaN emitter, every
+                    # other row's logits are untouched (disjoint block
+                    # tables)
+                    self._evict_poisoned(
+                        req, cause=None, trigger="non-finite decode logits"
+                    )
+                    continue
+                self._push_token(req, int(tok[req.slot]))
         self.metrics.record_decode(n_tokens=n_active, decode_s=t1 - t0)
         self.metrics.record_iteration(
             active_slots=n_active, total_slots=self.slots_n,
@@ -1557,44 +1634,24 @@ class ContinuousScheduler:
         and once its blocks recycle, any stale overrun rows are masked
         exactly like every other recycled-block row.
         """
-        active = [req for req in self._slots if req is not None]
-        self._poison_shim(active)
-        # host-exact dispatch cap: a row never dispatches past its token
-        # budget, so only EOS (host-unknown until drain) can overrun
-        disp = [r for r in active if r.dispatched < r.max_new]
+        with self._phase("decode_prep"):
+            active = [req for req in self._slots if req is not None]
+            self._poison_shim(active)
+            # host-exact dispatch cap: a row never dispatches past its
+            # token budget, so only EOS (host-unknown until drain) can
+            # overrun
+            disp = [r for r in active if r.dispatched < r.max_new]
+            if disp:
+                (fresh_mask, fresh_tok, pos, tables, gen_idx, aids, keys,
+                 rows) = self._fed_arrays(disp)
         if disp:
-            W = self.slots_n
-            fresh_mask = np.zeros((W,), bool)
-            fresh_tok = np.zeros((W,), np.int32)
-            pos = np.full((W,), -1, np.int32)
-            tables = np.zeros((W, self.table_blocks), np.int32)
-            gen_idx = np.zeros((W,), np.int32)
-            aids = np.full((W,), -1, np.int32)
-            keys = [self._pad_key] * W
-            rows = []
-            for req in disp:
-                i = req.slot
-                d = req.dispatched
-                if d == req.gen_idx:
-                    # nothing of this row is in flight: its last token is
-                    # host-known (fresh prefill, refill, or post-recovery
-                    # rollback) and overrides the stale carry in-graph
-                    fresh_mask[i] = True
-                    fresh_tok[i] = req.tokens[-1]
-                pos[i] = req.prompt.size + d - 1
-                ids = self._table_ids(req)
-                tables[i, : len(ids)] = ids
-                gen_idx[i] = d
-                aids[i] = req.adapter
-                keys[i] = req.row_key
-                rows.append((req, i, d))
             prev = self._carry_tok
             if prev is None:
                 # first dispatch of a pipeline run: every dispatched row
                 # is fresh by construction, the zeros are never sampled
                 prev = self._zero_carry()
             self._note_dispatch_gap()
-            with span("decode_step", step=self._tick_no, active=len(disp)):
+            with self._phase("decode_step", active=len(disp)):
                 tok, finite, self._pool = self._fns.decode_step_fed(
                     self._qparams if self._quant else self.params,
                     self._pool, prev, fresh_mask, fresh_tok, pos, tables,
@@ -1621,6 +1678,37 @@ class ContinuousScheduler:
         self._tick_block_s += t1 - t0
         if pushed:
             self.metrics.record_decode(n_tokens=pushed, decode_s=t1 - t0)
+
+    def _fed_arrays(self, disp: List[_PagedRequest]):
+        """Fixed-width inputs of ``decode_step_fed`` with ``disp`` live,
+        derived from each row's ``dispatched`` counter, and the rows'
+        ``(request, slot, token index)`` for the drain."""
+        W = self.slots_n
+        fresh_mask = np.zeros((W,), bool)
+        fresh_tok = np.zeros((W,), np.int32)
+        pos = np.full((W,), -1, np.int32)
+        tables = np.zeros((W, self.table_blocks), np.int32)
+        gen_idx = np.zeros((W,), np.int32)
+        aids = np.full((W,), -1, np.int32)
+        keys = [self._pad_key] * W
+        rows = []
+        for req in disp:
+            i = req.slot
+            d = req.dispatched
+            if d == req.gen_idx:
+                # nothing of this row is in flight: its last token is
+                # host-known (fresh prefill, refill, or post-recovery
+                # rollback) and overrides the stale carry in-graph
+                fresh_mask[i] = True
+                fresh_tok[i] = req.tokens[-1]
+            pos[i] = req.prompt.size + d - 1
+            ids = self._table_ids(req)
+            tables[i, : len(ids)] = ids
+            gen_idx[i] = d
+            aids[i] = req.adapter
+            keys[i] = req.row_key
+            rows.append((req, i, d))
+        return fresh_mask, fresh_tok, pos, tables, gen_idx, aids, keys, rows
 
     def _zero_carry(self):
         """A mesh-replicated, COMMITTED int32[slots] zeros vector whose
@@ -1654,23 +1742,25 @@ class ContinuousScheduler:
         token was never part of the committed stream.  Returns the
         number of tokens pushed."""
         tok_dev, finite_dev, rows = entry
-        tok = np.asarray(tok_dev)
-        finite = np.asarray(finite_dev)
+        with self._phase("readback"):
+            tok = np.asarray(tok_dev)
+            finite = np.asarray(finite_dev)
         pushed = 0
-        for req, slot, idx in rows:
-            if req.admission is None or idx != req.gen_idx:
-                continue
-            if not finite[slot]:
-                # the on-device output guard, observed async_depth ticks
-                # late: the emitter's own table re-reads its NaN rows
-                # every overrun step, so the flag stays false and the
-                # eviction lands on exactly this request
-                self._evict_poisoned(
-                    req, cause=None, trigger="non-finite decode logits"
-                )
-                continue
-            self._push_token(req, int(tok[slot]))
-            pushed += 1
+        with self._phase("deliver"):
+            for req, slot, idx in rows:
+                if req.admission is None or idx != req.gen_idx:
+                    continue
+                if not finite[slot]:
+                    # the on-device output guard, observed async_depth
+                    # ticks late: the emitter's own table re-reads its NaN
+                    # rows every overrun step, so the flag stays false and
+                    # the eviction lands on exactly this request
+                    self._evict_poisoned(
+                        req, cause=None, trigger="non-finite decode logits"
+                    )
+                    continue
+                self._push_token(req, int(tok[slot]))
+                pushed += 1
         return pushed
 
     def flush_async(self) -> None:
@@ -1737,16 +1827,17 @@ class ContinuousScheduler:
         positions past its coverage are causally masked.
         """
         t0 = time.perf_counter()
-        active = [req for req in self._slots if req is not None]
-        self._poison_shim(active)
-        W = self.slots_n
-        k = self._spec.k
-        bs = self._kv.block_size
-        # clamp each row's proposal count to its remaining budget so no
-        # verify write can land past the reserved footprint
-        k_eff = {r.slot: min(k, r.max_new - r.gen_idx) for r in active}
+        with self._phase("decode_prep"):
+            active = [req for req in self._slots if req is not None]
+            self._poison_shim(active)
+            W = self.slots_n
+            k = self._spec.k
+            bs = self._kv.block_size
+            # clamp each row's proposal count to its remaining budget so
+            # no verify write can land past the reserved footprint
+            k_eff = {r.slot: min(k, r.max_new - r.gen_idx) for r in active}
 
-        with span("decode_step", step=self._tick_no, active=len(active)):
+        with self._phase("decode_step", active=len(active)):
             # -- draft: k+1 greedy single-token steps (step j feeds the
             # committed tail for j=0, else proposal j-1, at position
             # P+j, producing proposal j).  Step k_eff is a pure K/V
@@ -1820,38 +1911,40 @@ class ContinuousScheduler:
             logits, self._pool = self._fns.verify(
                 self.params, self._pool, ver_tok, ver_pos, vtables, aids,
             )
-            rb0 = time.perf_counter()
+        rb0 = time.perf_counter()
+        with self._phase("readback"):
             logits = np.asarray(logits)
-            self._tick_block_s += time.perf_counter() - rb0
+        self._tick_block_s += time.perf_counter() - rb0
 
         # -- host accept/reject + commit -------------------------------
         t1 = time.perf_counter()
         emitted_total = proposed = accepted = 0
-        for req in active:
-            i = req.slot
-            ke = k_eff[i]
-            if not np.isfinite(logits[i, : ke + 1]).all():
-                self._evict_poisoned(
-                    req, cause=None, trigger="non-finite verify logits"
-                )
-                continue
-            target = logits[i, : ke + 1].argmax(-1).astype(np.int32)
-            n_acc, emit = greedy_accept(draft_tok[i, :ke], target)
-            if n_acc == ke and req.gen_idx + len(emit) > req.max_new:
-                emit = emit[:-1]  # no room for the bonus under the cap
-            proposed += ke
-            accepted += n_acc
-            # commit-by-swap: the branch boundary block becomes real, the
-            # displaced block becomes the next round's pristine spare
-            P = req.prompt.size + req.gen_idx - 1
-            bi = P // bs
-            ids = req.admission.block_ids
-            ids[bi], ids[-1] = ids[-1], ids[bi]
-            for t in emit:
-                self._push_token(req, int(t))
-                emitted_total += 1
-                if req.admission is None:
-                    break  # retired (eos / cap) mid-round
+        with self._phase("deliver"):
+            for req in active:
+                i = req.slot
+                ke = k_eff[i]
+                if not np.isfinite(logits[i, : ke + 1]).all():
+                    self._evict_poisoned(
+                        req, cause=None, trigger="non-finite verify logits"
+                    )
+                    continue
+                target = logits[i, : ke + 1].argmax(-1).astype(np.int32)
+                n_acc, emit = greedy_accept(draft_tok[i, :ke], target)
+                if n_acc == ke and req.gen_idx + len(emit) > req.max_new:
+                    emit = emit[:-1]  # no room for the bonus under the cap
+                proposed += ke
+                accepted += n_acc
+                # commit-by-swap: the branch boundary block becomes real, the
+                # displaced block becomes the next round's pristine spare
+                P = req.prompt.size + req.gen_idx - 1
+                bi = P // bs
+                ids = req.admission.block_ids
+                ids[bi], ids[-1] = ids[-1], ids[bi]
+                for t in emit:
+                    self._push_token(req, int(t))
+                    emitted_total += 1
+                    if req.admission is None:
+                        break  # retired (eos / cap) mid-round
         self._bump("spec_rounds")
         if proposed:
             self._bump("spec_proposed", proposed)
@@ -1871,6 +1964,13 @@ class ContinuousScheduler:
         req.tokens.append(tok)
         if req.dispatched < len(req.tokens):
             req.dispatched = len(req.tokens)
+        now = time.monotonic()
+        if req.last_token_at is not None:
+            self.metrics.record_token_gap((now - req.last_token_at) * 1000.0)
+        elif len(req.tokens) == 1:  # not the continuation of a replay
+            req.first_token_at = now
+            self.metrics.record_first_token((now - req.enqueued_at) * 1000.0)
+        req.last_token_at = now
         if req.on_token is not None:
             try:
                 req.on_token(tok)
@@ -1888,6 +1988,16 @@ class ContinuousScheduler:
 
     def _retire(self, req: _PagedRequest) -> None:
         self._slots[req.slot] = None
+        now = time.monotonic()
+        # the request's life as one record on the span ring: a hang dump
+        # (and the span file of an operator who sets one) ties the phases
+        # that name this ``req`` to what the request waited for
+        record_span(
+            "request", req.enqueued_at, now - req.enqueued_at, req=req.rid,
+            admitted_at=req.admitted_at, first_token_at=req.first_token_at,
+            finished_at=round(now, 6), tokens=len(req.tokens),
+            prefix_blocks=req.admission.n_shared,
+        )
         self._kv.release(req.admission)
         req.admission = None
         self._release_draft(req)

@@ -26,7 +26,22 @@ import threading
 import time
 from typing import Optional
 
-__all__ = ["OnDemandProfiler", "parse_signal"]
+__all__ = ["OnDemandProfiler", "parse_signal", "start_trace"]
+
+
+def start_trace(directory: str, python_tracer: bool = False) -> None:
+    """Start a ``jax.profiler`` trace, for both of the program's windows
+    (this module's and ``engine/profiling.py``'s).  The python tracer is
+    off unless asked for: the program's spans are annotations in the trace
+    (telemetry/spans.py) and name a gap without python frames, and the
+    frames are dear (PERF.md, PR 23: a serving tick of 5.5 ms read 8.6 to
+    11.6 ms under it; stopping a 1.4 s trace took 22 s under sixteen loader
+    threads)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python_tracer else 0
+    jax.profiler.start_trace(directory, profiler_options=options)
 
 
 def parse_signal(spec) -> Optional[int]:
@@ -55,6 +70,7 @@ class OnDemandProfiler:
         n_iters: int = 5,
         signum: Optional[int] = None,
         at_iter: Optional[int] = None,
+        python_tracer: bool = False,
         logger: Optional[logging.Logger] = None,
     ):
         if int(n_iters) < 1:
@@ -63,6 +79,7 @@ class OnDemandProfiler:
         self.n_iters = int(n_iters)
         self.at_iter = None if at_iter is None else int(at_iter)
         self.signum = signum
+        self.python_tracer = bool(python_tracer)
         self._logger = logger or logging.getLogger(__name__)
         self._armed = threading.Event()
         self._tracing_from: Optional[int] = None
@@ -99,14 +116,12 @@ class OnDemandProfiler:
             self._start(it + 1)
 
     def _start(self, from_iter: int) -> None:
-        import jax
-
         out = os.path.join(
             self.trace_dir, f"capture_{self._captures}_iter{from_iter}"
         )
         os.makedirs(out, exist_ok=True)
         try:
-            jax.profiler.start_trace(out)
+            start_trace(out, python_tracer=self.python_tracer)
         except Exception as e:
             # a second live trace in the process (e.g. TraceProfiler's
             # window) raises — skip this capture rather than kill the run

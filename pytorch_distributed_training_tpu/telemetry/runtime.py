@@ -52,6 +52,7 @@ class Telemetry:
         capture_iters: int = 5,
         capture_at_iter: Optional[int] = None,
         capture_dir: Optional[str] = None,
+        capture_python_tracer: bool = False,
         logger: Optional[logging.Logger] = None,
     ):
         self.enabled = bool(enabled)
@@ -102,6 +103,7 @@ class Telemetry:
                 n_iters=capture_iters,
                 signum=capture_signal,
                 at_iter=capture_at_iter,
+                python_tracer=capture_python_tracer,
                 logger=self._logger,
             )
 

@@ -27,17 +27,94 @@ thread, ``engine/elastic.py``'s guard) emit spans without threading a
 handle through every constructor — the same pattern as the fault-counter
 ledger.  The default recorder is ring-only; the Runner swaps in its
 configured recorder for the duration of the run.
+
+One call, two sinks, one clock: while a span is open it also holds a
+``jax.profiler.TraceAnnotation`` of the same name, with ``step`` and the
+extra fields as its arguments, so that during a profiler trace every
+program span lies on the host plane of the ``.xplane.pb`` on the
+profiler's own clock, beside the device's operations (with no trace
+running the annotation costs a few hundred nanoseconds).  The class is
+taken lazily and only from a ``jax`` that is already loaded: this module
+stays stdlib-only for the loader's worker processes, which never import
+JAX and whose spans go to the ring alone.
+
+A record names its cause: ``parent`` is the kind of the span that was
+open on the same thread when this one started (``loader_wait`` and
+``h2d_put`` inside ``data_wait``; a tick's phases inside ``tick``), null
+at the top.  Nesting is a property of the thread, not of a recorder, so
+the stack is one thread-local for the module.
 """
 from __future__ import annotations
 
 import contextlib
 import json
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
 
-__all__ = ["SpanRecorder", "get_recorder", "set_recorder", "span"]
+__all__ = ["SpanRecorder", "get_recorder", "record", "set_recorder", "span"]
+
+_OPEN = threading.local()  # .kinds: this thread's stack of open span kinds
+_NO_SPAN = contextlib.nullcontext()  # what a disabled recorder hands out
+
+
+def _open_kinds() -> List[str]:
+    try:
+        return _OPEN.kinds
+    except AttributeError:
+        _OPEN.kinds = []
+        return _OPEN.kinds
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` once ``jax`` is loaded in this
+    process, else None; never imports it."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
+class _Span:
+    """One open span: a plain class, not a generator, because the
+    scheduler opens several a tick."""
+
+    __slots__ = ("_recorder", "_kind", "_step", "_extra", "_parent",
+                 "_annotation", "_t0", "_wall")
+
+    def __init__(self, recorder, kind, step, extra):
+        self._recorder = recorder
+        self._kind = kind
+        self._step = step
+        self._extra = extra
+
+    def __enter__(self):
+        kinds = _open_kinds()
+        self._parent = kinds[-1] if kinds else None
+        kinds.append(self._kind)
+        cls = _annotation_class()
+        if cls is None:
+            self._annotation = None
+        else:
+            args = {k: v for k, v in self._extra.items() if v is not None}
+            if self._step is not None:
+                args["step"] = self._step
+            self._annotation = cls(self._kind, **args)
+            self._annotation.__enter__()
+        self._wall = time.time()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        dur_s = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _open_kinds().pop()
+        self._recorder._record(
+            self._kind, self._step, self._t0, self._wall, dur_s,
+            self._parent, self._extra,
+        )
+        return False
 
 
 class SpanRecorder:
@@ -59,22 +136,27 @@ class SpanRecorder:
         self._file = open(path, "a") if path else None
         self.enabled = True
 
-    @contextlib.contextmanager
     def span(self, kind: str, step: Optional[int] = None, **extra):
+        """Context manager around one phase; ``extra`` fields (``req``,
+        ``n``, ``bytes``...) ride in the record and in the annotation."""
         if not self.enabled:
-            yield
-            return
-        t0 = time.monotonic()
-        wall = time.time()
-        try:
-            yield
-        finally:
-            self._record(kind, step, t0, wall, time.monotonic() - t0, extra)
+            return _NO_SPAN
+        return _Span(self, kind, step, extra)
 
-    def _record(self, kind, step, t0, wall, dur_s, extra) -> None:
+    def record(self, kind: str, t0: float, dur_s: float,
+               step: Optional[int] = None, **extra) -> None:
+        """An interval that is already over, from its ``time.monotonic()``
+        start (a request's life, known only at its retirement).  Ring and
+        file only: the profiler takes no back-dated annotation."""
+        if self.enabled:
+            wall = time.time() - (time.monotonic() - t0)
+            self._record(kind, step, t0, wall, dur_s, None, extra)
+
+    def _record(self, kind, step, t0, wall, dur_s, parent, extra) -> None:
         rec: Dict = {
             "kind": kind,
             "step": step,
+            "parent": parent,
             "host": self.host,
             "t": round(t0, 6),
             "wall": round(wall, 3),
@@ -141,3 +223,9 @@ def set_recorder(recorder: Optional[SpanRecorder]) -> SpanRecorder:
 def span(kind: str, step: Optional[int] = None, **extra):
     """Record a phase span on the current recorder (context manager)."""
     return get_recorder().span(kind, step=step, **extra)
+
+
+def record(kind: str, t0: float, dur_s: float,
+           step: Optional[int] = None, **extra) -> None:
+    """Record a finished interval on the current recorder."""
+    get_recorder().record(kind, t0, dur_s, step=step, **extra)

@@ -78,39 +78,66 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 QKV_2K = [((8, 2048, 8, 128), None)] * 3
 QKV_16K = [((1, 16384, 8, 128), None)] * 3
 
-# (fn, [(shape, dtype or None for the case dtype)], case dtype, least count
-# of Mosaic calls in the compiled program)
+# (fn, [(shape, dtype or None for the case dtype)], case dtype, the kernels
+# of the compiled program by their ``name=``)
 CASES = {
-    "flash_fwd_bf16": (_flash_fwd, QKV_2K, BF16, 1),
-    "flash_fused_bwd_bf16": (_flash_fwd_bwd, QKV_2K, BF16, 2),
-    "flash_split_bwd_f32": (_flash_fwd_bwd, QKV_2K, F32, 3),
-    "flash_streamed_16k_fwd_bwd": (_flash_fwd_bwd, QKV_16K, BF16, 3),
+    "flash_fwd_bf16": (_flash_fwd, QKV_2K, BF16, ["flash_fwd"]),
+    "flash_fused_bwd_bf16": (
+        _flash_fwd_bwd, QKV_2K, BF16, ["flash_fwd", "flash_bwd"],
+    ),
+    "flash_split_bwd_f32": (
+        _flash_fwd_bwd, QKV_2K, F32,
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
+    ),
+    "flash_streamed_16k_fwd_bwd": (
+        _flash_fwd_bwd, QKV_16K, BF16,
+        ["flash_fwd_stream", "flash_bwd_dq_stream", "flash_bwd_dkv_stream"],
+    ),
     "fused_ce_lm_16384x32768": (
-        _ce_fwd_bwd, [((16384, 32768), F32), ((16384,), I32)], F32, 2,
+        _ce_fwd_bwd, [((16384, 32768), F32), ((16384,), I32)], F32,
+        ["fused_ce_fwd", "fused_ce_bwd"],
     ),
     "fused_ce_resnet_128x1000": (
-        _ce_fwd_bwd, [((128, 1000), F32), ((128,), I32)], F32, 2,
+        _ce_fwd_bwd, [((128, 1000), F32), ((128,), I32)], F32,
+        ["fused_ce_fwd", "fused_ce_bwd"],
     ),
     "add_layernorm_16384x1024": (
         _add_ln,
         [((16384, 1024), None), ((16384, 1024), None),
          ((1024,), F32), ((1024,), F32)],
-        BF16, 1,
+        BF16, ["fused_add_ln"],
     ),
     "bias_gelu_16384x4096": (
-        _bias_gelu, [((16384, 4096), None), ((4096,), None)], BF16, 1,
+        _bias_gelu, [((16384, 4096), None), ((4096,), None)], BF16,
+        ["fused_bias_gelu"],
     ),
 }
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(chip, name):
-    fn, arg_specs, dtype, n_calls = CASES[name]
+    import re
+
+    fn, arg_specs, dtype, kernels = CASES[name]
     args = [
         jax.ShapeDtypeStruct(shape, dt or dtype, sharding=chip)
         for shape, dt in arg_specs
     ]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert text.count("tpu_custom_call") >= n_calls, (
-        f"{name}: expected >= {n_calls} Mosaic call(s) in the compiled program"
+    # the kernel's name is the component of the call's op_name before
+    # ``pallas_call``, bare under a scope (``.../loss_head/fused_ce_bwd/
+    # pallas_call``) or wrapped where it is the outermost one
+    # (``transpose(jvp(fused_ce_bwd))/pallas_call``); XLA names the
+    # instruction after that component (``%fused_ce_bwd.1`` or
+    # ``%transpose_jvp_fused_ce_bwd__.1``), and benchmark/xplane.py reads both
+    mosaic = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        op_name = re.search(r'op_name="([^"]*)/pallas_call"', line).group(1)
+        kernel = re.sub(r"\w+\(|\)", "", op_name.split("/")[-1])
+        assert kernel in line.split(" = ")[0], line[:120]
+        mosaic.append(kernel)
+    assert sorted(mosaic) == sorted(kernels), (
+        f"{name}: the compiled program's Mosaic calls are named {mosaic}"
     )

@@ -196,3 +196,57 @@ def test_get_dataset_factory():
         get_dataset("cifar10", "/x", "train")
     with pytest.raises(FileNotFoundError):
         get_dataset("imagenet", "/nonexistent", "train")
+
+
+# ------------------------------------------------------------------- spans
+@pytest.mark.parametrize("mode", ["thread", "native", "process"])
+def test_loader_emits_one_batch_assemble_a_batch(mode, tmp_path):
+    """Every assembly backend brackets each batch in one ``batch_assemble``
+    span carrying its sample count, and the consumer's wait for a batch in
+    ``loader_wait`` (telemetry/spans.py; the benchmark's input-pipeline
+    metrics read both)."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    if mode == "native":
+        from PIL import Image
+
+        from pytorch_distributed_training_tpu.native import native_available
+
+        if not native_available():
+            pytest.skip("native library unavailable")
+        rng = np.random.default_rng(0)
+        for cls in ("a", "b"):
+            d = tmp_path / "train" / cls
+            d.mkdir(parents=True)
+            for i in range(6):
+                pixels = rng.integers(0, 256, size=(40, 48, 3), dtype=np.uint8)
+                Image.fromarray(pixels).save(d / f"{i}.jpg", "JPEG")
+        ds = get_dataset("imagenet", str(tmp_path), "train")
+    else:
+        ds = SyntheticDataset(n_samples=12, n_classes=3, image_size=8)
+    rec = set_recorder(SpanRecorder(ring=64))
+    loader = DataLoader(
+        ds, batch_size=4, sampler=SequentialSampler(len(ds)), num_workers=2,
+        drop_last=True, worker_mode=mode,
+    )
+    try:
+        assert loader.worker_mode == mode
+        batches = list(loader)
+    finally:
+        loader.close()
+        set_recorder(None)
+    assert len(batches) == 3
+    spans = rec.recent()
+    made = [s for s in spans if s["kind"] == "batch_assemble"]
+    assert [s["n"] for s in made] == [4, 4, 4]
+    waits = [s for s in spans if s["kind"] == "loader_wait"]
+    assert len(waits) >= 3 and all(s["parent"] is None for s in waits)
+    if mode == "process":
+        # the workers decode in other processes; this process copies out
+        assert {s["thread"] for s in made} == {waits[0]["thread"]}
+    else:
+        # a producer thread assembles ahead of the consumer
+        assert {s["thread"] for s in made}.isdisjoint({waits[0]["thread"]})

@@ -95,3 +95,30 @@ def test_marker_pass_registered_in_framework():
     MarkerConventionPass from ALL_PASSES would silently disable the
     convention everywhere (CLI, bench lint, this gate)."""
     assert MarkerConventionPass in analysis.ALL_PASSES
+
+
+def test_every_pallas_call_in_ops_is_named():
+    """A kernel's ``name=`` becomes its instruction's name in the compiled
+    program and so in a profiler trace; without one the trace calls it
+    after whatever transformation wrapped it (``%jvp__.1``) and the
+    benchmark's per-kernel readers find nothing.  Names are the stable
+    ones PERF.md lists, each used once."""
+    names = []
+    for path in sorted((_PKG / "ops").glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"
+            ):
+                continue
+            named = [kw.value for kw in node.keywords if kw.arg == "name"]
+            where = f"{path.name}:{node.lineno}"
+            assert named, f"{where}: pallas_call without name="
+            assert isinstance(named[0], ast.Constant), f"{where}: name= not a literal"
+            names.append(named[0].value)
+    assert sorted(names) == sorted([
+        "flash_fwd", "flash_fwd_stream", "flash_bwd", "flash_bwd_dq",
+        "flash_bwd_dkv", "flash_bwd_dq_stream", "flash_bwd_dkv_stream",
+        "fused_ce_fwd", "fused_ce_bwd", "fused_add_ln", "fused_bias_gelu",
+    ])

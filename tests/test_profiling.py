@@ -1,9 +1,6 @@
 """Config-gated jax.profiler trace hooks (SURVEY.md §5.1 rebuild item) and
-the round-6 step-time decomposition + remat/fusion recovery oracles."""
+the round-6 remat/fusion recovery oracles."""
 import os
-import subprocess
-import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -11,8 +8,6 @@ import numpy as np
 import pytest
 
 from pytorch_distributed_training_tpu.engine import TraceProfiler
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_from_config_absent_returns_none():
@@ -77,10 +72,6 @@ def test_window_opens_once(tmp_path):
     assert not prof._active
 
 
-# --------------------------------------------------------------------- #
-# Round 6: programmatic step-time decomposition
-# --------------------------------------------------------------------- #
-
 _VOCAB, _SEQ, _BATCH = 128, 32, 2
 
 
@@ -99,122 +90,6 @@ def _tiny_batch(seed=0):
     rng = np.random.default_rng(seed)
     toks = rng.integers(0, _VOCAB, (_BATCH, _SEQ + 1)).astype(np.int32)
     return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
-
-
-def _single_device_step(lm, opt):
-    """A faithful single-device LM train step (no shard_map — runs on the
-    vanilla-jax tier-1 path): fwd CE, grad, optimizer update."""
-    from pytorch_distributed_training_tpu.ops import cross_entropy_loss
-
-    def loss_fn(p, tok, lab):
-        logits = lm.apply({"params": p}, tok)
-        return cross_entropy_loss(
-            logits.reshape(-1, lm.vocab_size), lab.reshape(-1)
-        )
-
-    @jax.jit
-    def step(params, opt_state, tok, lab):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tok, lab)
-        new_p, new_o = opt.update(grads, opt_state, params, 1e-3)
-        return new_p, new_o, loss
-
-    return step
-
-
-@pytest.mark.slow
-def test_decompose_buckets_partition_step_time():
-    """Bucket contract: non-negative, fixed key set, and the published
-    buckets sum to step_ms within 10% (by construction they partition it
-    exactly; the assertion pins the contract against refactors)."""
-    from pytorch_distributed_training_tpu.engine.profiling import (
-        decompose_lm_step,
-    )
-    from pytorch_distributed_training_tpu.optimizers import AdamW
-
-    lm = _tiny_lm()
-    inp, lab = _tiny_batch()
-    params = lm.init(jax.random.PRNGKey(0), inp)["params"]
-    opt = AdamW(lr=1e-3, weight_decay=0.1)
-    opt_state = opt.init(params)
-    step = _single_device_step(lm, opt)
-
-    p, o = params, opt_state
-    p, o, loss = step(p, o, inp, lab)  # compile
-    float(loss)
-    t0 = time.perf_counter()
-    for _ in range(3):
-        p, o, loss = step(p, o, inp, lab)
-    float(loss)
-    step_ms = (time.perf_counter() - t0) / 3 * 1e3
-
-    out = decompose_lm_step(
-        lm, opt, params, opt_state, inp, lab, step_ms, iters=2, windows=1
-    )
-    want = {
-        "attention", "mlp_matmul", "elementwise", "ce_softmax", "optimizer",
-        "host_infeed",
-    }
-    assert set(out["buckets"]) == want
-    assert set(out["raw_ms"]) == want - {"host_infeed"}
-    for k, v in out["buckets"].items():
-        assert v >= 0.0, f"bucket {k} negative: {v}"
-    for k, v in out["raw_ms"].items():
-        assert v >= 0.0, f"raw {k} negative: {v}"
-    total = sum(out["buckets"].values())
-    assert abs(total - out["step_ms"]) <= 0.1 * out["step_ms"] + 0.01
-    assert out["overlap_factor"] > 0
-
-
-def test_decompose_respects_ema_fold():
-    """The optimizer bucket times the step's REAL update: with an EMA decay
-    and a fused optimizer it must route through update_with_ema (a crash
-    here would mean the probe and the step diverge)."""
-    from pytorch_distributed_training_tpu.engine.profiling import (
-        decompose_lm_step,
-    )
-    from pytorch_distributed_training_tpu.optimizers import AdamW
-
-    lm = _tiny_lm()
-    inp, lab = _tiny_batch()
-    params = lm.init(jax.random.PRNGKey(0), inp)["params"]
-    opt = AdamW(lr=1e-3, weight_decay=0.1, fused=True)
-    out = decompose_lm_step(
-        lm, opt, params, opt.init(params), inp, lab, 100.0,
-        iters=1, windows=1, ema=params, ema_decay=0.99,
-    )
-    assert out["buckets"]["optimizer"] >= 0.0
-
-
-@pytest.mark.slow
-def test_bench_decompose_cli(tmp_path):
-    """End-to-end ``bench.py decompose`` at a tiny config: one JSON line
-    whose buckets partition step_ms, plus the BENCH_DECOMP_OUT file."""
-    import json
-
-    out_path = tmp_path / "decomp.json"
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS="cpu",
-        PYTHONPATH=_ROOT + os.pathsep + env.get("PYTHONPATH", ""),
-        BENCH_LM_VOCAB="256", BENCH_LM_SEQ="64", BENCH_LM_BATCH="2",
-        BENCH_LM_EMBED="32", BENCH_LM_DEPTH="2", BENCH_LM_HEADS="4",
-        BENCH_ITERS="2", BENCH_WINDOWS="1", BENCH_DECOMP_ITERS="2",
-        BENCH_COMPILE_CACHE="0",
-        BENCH_DECOMP_OUT=str(out_path),
-    )
-    env.pop("XLA_FLAGS", None)  # single-device: fastest + exact under compat
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py"), "decompose"],
-        env=env, capture_output=True, text=True, timeout=540,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    line = proc.stdout.strip().splitlines()[-1]
-    out = json.loads(line)
-    assert out["unit"] == "ms/step"
-    total = sum(out["buckets"].values())
-    assert abs(total - out["step_ms"]) <= 0.1 * out["step_ms"] + 0.01
-    assert all(v >= 0 for v in out["buckets"].values())
-    assert json.loads(out_path.read_text())["buckets"] == out["buckets"]
 
 
 # --------------------------------------------------------------------- #

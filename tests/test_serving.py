@@ -1446,3 +1446,167 @@ def test_fleet_add_replica_warms_and_records_readiness(lm_and_params):
     router.shutdown()
     for r in fleet.replicas:
         r.close()
+
+
+# --------------------------------------------------------------------- #
+# tick phases and the request's life (PR 24): spans + ServingMetrics
+
+
+def _decode_bodies():
+    from pytorch_distributed_training_tpu.serving.speculative import (
+        SpeculativeSpec,
+    )
+
+    return {
+        "sync": {},
+        "async_ring": {"async_depth": 2},
+        "speculative": {"speculative": SpeculativeSpec(k=2)},
+    }
+
+
+@pytest.mark.parametrize("body", ["sync", "async_ring", "speculative"])
+def test_scheduler_tick_phase_spans_and_request_records(
+    lm_and_params, mode_prompts, body
+):
+    """Every decode body splits a tick into the same kinds, each a child
+    of ``tick``; every retired request leaves one ``request`` record with
+    its id and its four stamps in order; the counts of the life histograms
+    follow the requests and tokens served."""
+    from pytorch_distributed_training_tpu.serving.metrics import TICK_PHASES
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rec = set_recorder(SpanRecorder(ring=4096))
+    try:
+        sched = _paged_sched(model, params, **_decode_bodies()[body])
+        results = _sched_results(sched, mode_prompts)
+        snap = sched.metrics.snapshot()
+        sched.close()
+    finally:
+        set_recorder(None)
+    spans = rec.recent()
+    ticks = [s for s in spans if s["kind"] == "tick"]
+    phases = [s for s in spans if s["kind"] in TICK_PHASES]
+    assert {s["kind"] for s in phases} == set(TICK_PHASES)
+    assert all(s["parent"] == "tick" for s in phases)
+    assert all(s["parent"] is None for s in ticks)
+    assert {s["step"] for s in phases} <= {s["step"] for s in ticks}
+    prefill = next(s for s in phases if s["kind"] == "prefill")
+    assert prefill["rows"] == 3 and prefill["bucket"] == 8
+    assert prefill["tokens"] == sum(len(p) for p in mode_prompts)
+    assert prefill["reqs"] == [0, 1, 2]
+    # telemetry/slo.py pairs recoveries with the productive kind
+    assert any(s["kind"] == "decode_step" and "active" in s for s in phases)
+
+    requests = [s for s in spans if s["kind"] == "request"]
+    assert sorted(s["req"] for s in requests) == [0, 1, 2]
+    for s, res in zip(sorted(requests, key=lambda s: s["req"]), results):
+        assert s["parent"] is None and s["tokens"] == res["gen_len"]
+        assert (s["t"] <= s["admitted_at"] <= s["first_token_at"]
+                <= s["finished_at"])
+        assert s["prefix_blocks"] == 0
+
+    n_req = len(results)
+    n_tok = sum(r["gen_len"] for r in results)
+    assert snap["queue_wait_ms_count"] == n_req
+    assert snap["ttft_ms_count"] == n_req
+    assert snap.get("itl_ms_count", 0) == n_tok - n_req
+    for key in ("queue_wait_ms_p95", "ttft_ms_p95", "tick_prep_ms_p50",
+                "tick_readback_ms_p50", "tick_deliver_ms_p50",
+                "tick_wall_ms_mean"):
+        assert snap[key] >= 0.0, key
+    assert snap["ttft_ms_p95"] >= snap["queue_wait_ms_p95"]
+    # one observation a productive tick in every phase's histogram, and the
+    # phases' means add up to no more than the tick's wall
+    hists = sched.metrics._registry.snapshot()["histograms"]
+    n_ticks = hists["tick_wall_ms"]["count"]
+    # (close() drains with one more tick that finds nothing to do: it has
+    # a span, and only ``admit`` under it, and no place in the histograms)
+    productive = {s["step"] for s in phases if s["kind"] != "admit"}
+    assert n_ticks == hists["tick_host_ms"]["count"] == len(productive)
+    assert len(ticks) == n_ticks + 1
+    assert all(hists[f"tick_{k}_ms"]["count"] == n_ticks for k in TICK_PHASES)
+    assert hists["tick_prep_ms"]["count"] == n_ticks
+    total = sum(snap[f"tick_{k}_ms_mean"] for k in TICK_PHASES)
+    assert 0.5 * snap["tick_wall_ms_mean"] < total <= snap["tick_wall_ms_mean"]
+
+
+def test_prefill_stall_counts_only_prefills_that_met_decoding_rows(
+    lm_and_params,
+):
+    """Scripted: two requests prefill into an empty engine (nothing waits:
+    no stall); a third is admitted while the long one is still decoding
+    (one stall, as long as that tick's prefill phase)."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rng = np.random.default_rng(5)
+    p_long = rng.integers(2, VOCAB, 6).astype(np.int32)
+    p_short = rng.integers(2, VOCAB, 3).astype(np.int32)
+    p_queued = rng.integers(2, VOCAB, 4).astype(np.int32)
+    rec = set_recorder(SpanRecorder(ring=1024))
+    try:
+        sched = _paged_sched(
+            model, params, slots=2, batch_buckets=[2], eos_id=None,
+        )
+        futs = [
+            sched.submit(p_long), sched.submit(p_short, max_new_tokens=2),
+            sched.submit(p_queued),
+        ]
+        _run_scheduler_to_done(sched, futs)
+        snap = sched.metrics.snapshot()
+        sched.close()
+    finally:
+        set_recorder(None)
+    prefills = [s for s in rec.recent() if s["kind"] == "prefill"]
+    assert [s["reqs"] for s in prefills] == [[0, 1], [2]]
+    assert snap["prefill_stall_ms_count"] == 1
+    hist = sched.metrics._registry.snapshot()["histograms"]["prefill_stall_ms"]
+    # the histogram holds the phase account of that tick: the span, seen
+    # from inside it, is a few microseconds shorter
+    assert hist["max"] == pytest.approx(prefills[1]["ms"], rel=0.2, abs=0.05)
+    # the queued request waited for the short one's slot, the others did not
+    waits = {s["req"]: s["admitted_at"] - s["t"]
+             for s in rec.recent() if s["kind"] == "request"}
+    assert waits[2] > max(waits[0], waits[1])
+    assert snap["queue_wait_ms_count"] == 3
+
+
+def test_fleet_aggregate_does_not_sum_percentiles():
+    from pytorch_distributed_training_tpu.serving.metrics import (
+        aggregate_snapshots,
+    )
+
+    a = {"requests": 2, "ttft_ms_p95": 10.0, "itl_ms_p50": 3.0,
+         "queue_wait_ms_count": 2}
+    b = {"requests": 3, "ttft_ms_p95": 30.0, "itl_ms_p50": 4.0,
+         "queue_wait_ms_count": 3}
+    out = aggregate_snapshots({"r0": a, "r1": b})
+    assert out["requests"] == 5 and out["queue_wait_ms_count"] == 5
+    assert out["ttft_ms_p95"] == 30.0  # the worst replica bounds the fleet
+    assert "itl_ms_p50" not in out
+
+
+def test_decode_step_carries_the_trace_scopes(lm_and_params):
+    """Inside the decode program the paged attention and the sampling are
+    named, so a trace tells the pool gather from the rest of the step."""
+    import re
+
+    model, params = lm_and_params
+    sched = _paged_sched(model, params)
+    prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
+    text = sched._fns.decode_step.lower(
+        sched.params, sched._pool, prev, pos, tables, jnp.stack(keys),
+        gen_idx, aids,
+    ).as_text(debug_info=True)
+    sched.close()
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    assert [n for n in names if "/attn/paged_attention/" in n]
+    assert "jit(decode_step)/sample" in names
+    assert [n for n in names if "loss_head/head" in n]  # the logits matmul

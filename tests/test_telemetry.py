@@ -190,6 +190,133 @@ def test_span_from_worker_thread_lands_in_shared_ring():
     assert recs[0]["thread"] != threading.main_thread().name
 
 
+def test_span_parent_follows_nesting_per_thread_not_across_threads():
+    rec = SpanRecorder(ring=16)
+    set_recorder(rec)
+    inside = threading.Event()
+    release = threading.Event()
+
+    def _other():
+        # opened while the main thread is inside data_wait: no child of it
+        inside.wait(timeout=10)
+        with span("batch_assemble", n=4):
+            pass
+        release.set()
+
+    t = threading.Thread(target=_other)
+    t.start()
+    with span("data_wait", step=3):
+        inside.set()
+        assert release.wait(timeout=10)
+        with rec.span("loader_wait"):  # the method and the free function
+            pass                       # share one stack of open spans
+        with span("h2d_put", step=3, bytes=64):
+            pass
+    t.join(timeout=10)
+    assert not t.is_alive()
+    with span("step_dispatch", step=3):
+        pass
+    parents = {r["kind"]: r["parent"] for r in rec.recent()}
+    assert parents == {
+        "batch_assemble": None, "loader_wait": "data_wait",
+        "h2d_put": "data_wait", "data_wait": None, "step_dispatch": None,
+    }
+
+
+def test_record_of_a_finished_interval_has_no_parent():
+    import time
+
+    rec = SpanRecorder(ring=4)
+    set_recorder(rec)
+    t0 = time.monotonic() - 0.25
+    with span("tick", step=9):
+        # a request's life is known at its retirement, inside a tick
+        from pytorch_distributed_training_tpu.telemetry.spans import record
+
+        record("request", t0, 0.25, req=17, tokens=5)
+    first = rec.recent()[0]
+    assert first["kind"] == "request" and first["parent"] is None
+    assert first["req"] == 17 and first["tokens"] == 5
+    assert first["ms"] == pytest.approx(250.0) and first["t"] == round(t0, 6)
+    assert abs(first["wall"] - (time.time() - 0.25)) < 1.0
+
+
+def test_span_lies_in_the_profilers_host_plane_with_its_step(tmp_path):
+    """One call, two sinks: during a jax.profiler trace a span is also a
+    TraceAnnotation, so the .xplane.pb's host plane holds it under its kind
+    with ``step`` and the extra fields as the event's stats."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from pytorch_distributed_training_tpu.telemetry.capture import start_trace
+
+    rec = SpanRecorder(ring=8)
+    start_trace(str(tmp_path))  # python tracer off: annotations only
+    try:
+        with jax.profiler.StepTraceAnnotation("train", step_num=41):
+            with rec.span("data_wait", step=41):
+                with rec.span("h2d_put", step=41, bytes=4096):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    found = {}
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                names.add(event.name)
+                if event.name in ("data_wait", "h2d_put", "train"):
+                    found[event.name] = (
+                        dict(event.stats), event.start_ns, event.duration_ns
+                    )
+    assert set(found) == {"data_wait", "h2d_put", "train"}
+    assert found["data_wait"][0]["step"] == 41
+    assert found["h2d_put"][0] == {"step": 41, "bytes": 4096}
+    assert found["train"][0]["step_num"] == 41
+    # one clock: the child lies inside its parent on the profiler's time
+    outer, inner = found["data_wait"], found["h2d_put"]
+    assert outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+    # and no python frames were collected to name them
+    assert not any(name.startswith("$") for name in names)
+    assert [r["parent"] for r in rec.recent()] == ["data_wait", None]
+
+
+def test_spans_import_and_record_where_jax_is_not_loaded():
+    """Loader worker processes import telemetry.spans and never JAX: the
+    annotation class is taken lazily, and only from a loaded jax."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from pytorch_distributed_training_tpu.telemetry.spans import "
+        "SpanRecorder\n"
+        "import pytorch_distributed_training_tpu.data.worker_pool\n"
+        "rec = SpanRecorder(ring=4)\n"
+        "with rec.span('batch_assemble', n=2):\n"
+        "    with rec.span('inner'):\n"
+        "        pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "print([(r['kind'], r['parent']) for r in rec.recent()])\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=root,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == (
+        "[('inner', 'batch_assemble'), ('batch_assemble', None)]"
+    )
+
+
 # ------------------------------------------------------------------- goodput
 def test_goodput_buckets_and_ratio():
     g = GoodputTracker()
@@ -354,7 +481,8 @@ def test_on_demand_profiler_window_bookkeeping(tmp_path, monkeypatch):
 
     calls = []
     monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda d: calls.append(("start", d))
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: calls.append(("start", d)),
     )
     monkeypatch.setattr(
         jax.profiler, "stop_trace", lambda: calls.append(("stop",))
@@ -370,10 +498,58 @@ def test_on_demand_profiler_window_bookkeeping(tmp_path, monkeypatch):
     prof.close()
 
 
+@pytest.mark.parametrize("window", ["on_demand", "config_window"])
+@pytest.mark.parametrize("python_tracer", [False, True])
+def test_profiler_windows_pass_the_python_tracer_switch(
+    tmp_path, monkeypatch, window, python_tracer
+):
+    """Both windows start through telemetry.capture.start_trace: the python
+    tracer is off unless their section says ``python_tracer: true``."""
+    import jax
+
+    from pytorch_distributed_training_tpu.engine import TraceProfiler
+    from pytorch_distributed_training_tpu.engine.topology import (
+        parse_telemetry,
+    )
+
+    seen = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: seen.append(profiler_options),
+    )
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    section = {"dir": str(tmp_path)}
+    if python_tracer:
+        section["python_tracer"] = True
+    if window == "on_demand":
+        import types
+
+        r = types.SimpleNamespace()
+        parse_telemetry(r, {"telemetry": {
+            "dir": str(tmp_path),
+            "capture": dict(section, at_iter=1, signal=None),
+        }})
+        tel = Telemetry(
+            dir=r.telemetry_dir, use_tensorboard=False,
+            capture_signal=None, capture_at_iter=r.telemetry_capture_at_iter,
+            capture_python_tracer=r.telemetry_capture_python_tracer,
+        )
+        tel.after_step(0)
+        tel.close()
+    else:
+        prof = TraceProfiler.from_config({"profile": dict(section, start_iter=0)})
+        prof.after_step(0)
+        prof.stop()
+    (options,) = seen
+    assert options.python_tracer_level == (1 if python_tracer else 0)
+
+
 def test_on_demand_profiler_signal_arm_and_restore(tmp_path, monkeypatch):
     import jax
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda d, profiler_options=None: None
+    )
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     prev = signal.getsignal(signal.SIGUSR2)
     prof = OnDemandProfiler(
@@ -393,7 +569,7 @@ def test_on_demand_profiler_signal_arm_and_restore(tmp_path, monkeypatch):
 def test_on_demand_profiler_start_failure_is_nonfatal(tmp_path, monkeypatch):
     import jax
 
-    def boom(d):
+    def boom(d, profiler_options=None):
         raise RuntimeError("another trace is live")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)

@@ -104,3 +104,38 @@ def test_sp_step_ulysses_matches_single_device():
         jax.tree_util.tree_leaves(state2.params),
     ):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5)
+
+
+def test_lm_step_carries_the_trace_scopes():
+    """The step's operations carry ``forward``, ``loss_head`` (final norm,
+    logits matmul, CE: model and step builder both) and ``optimizer`` in
+    their op_name, forward and transposed: a profiler trace groups device
+    time by them (benchmark/metrics/loss_head_ms_per_step.py and
+    optimizer_ms_per_step.py read them).  The kernels' own names are held
+    in tests/test_chip_compile.py, on the program compiled for the chip."""
+    import re
+
+    tokens, labels = _data()
+    opt = SGD(lr=0.05, momentum=0.9)
+    mesh = make_sp_mesh(sequence_parallelism=4)  # DP(2) x SP(4)
+    params = _model(None).init(jax.random.PRNGKey(0), tokens)["params"]
+    state = TrainState(params=params, batch_stats={}, opt_state=opt.init(params))
+    step = build_lm_train_step(
+        _model("sequence"), opt, multi_step_lr(0.05, [], 0.1), mesh,
+        donate=False,
+    )
+    text = step.lower(state, tokens, labels).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+
+    def under(*parts):
+        return [n for n in names if all(p in n for p in parts)]
+
+    assert under("jvp(forward)", "block0"), "forward pass not under `forward`"
+    assert under("transpose(jvp(forward))", "block0")
+    # the final norm and the head inside the model, the CE in the builder
+    assert under("jvp(forward)", "loss_head/ln")
+    assert under("jvp(forward)", "loss_head/head")
+    assert under("jvp(loss_head)") and under("transpose(jvp(loss_head))")
+    assert [n for n in names if n.startswith("optimizer/")]
+    # nothing of a decoder block is under the head's scope
+    assert not under("loss_head", "block")
