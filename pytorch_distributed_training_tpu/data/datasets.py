@@ -39,6 +39,8 @@ import numpy as np
 __all__ = [
     "get_dataset",
     "fetch_sample",
+    "fetch_sample_into",
+    "fills_in_place",
     "sample_rng",
     "sample_crop_params",
     "SyntheticDataset",
@@ -85,15 +87,26 @@ class SyntheticDataset:
     def __len__(self) -> int:
         return self.n_samples
 
-    def __getitem__(self, idx: int) -> Tuple[np.ndarray, np.int64]:
+    def fill_sample(self, idx: int, out: np.ndarray) -> np.int64:
+        """Write sample ``idx``'s pixels into ``out`` (a C-contiguous
+        float32 ``[size, size, 3]`` array, e.g. a row of the loader's batch)
+        and return its label: the in-place method ``fetch_sample_into``
+        looks for, so a batch costs no per-sample array and no copy."""
+        shape = (self.image_size, self.image_size, 3)
+        if out.shape != shape:
+            raise ValueError(
+                f"sample {idx}: shape {shape} does not fit a row of shape {out.shape}"
+            )
         rng = np.random.default_rng(self._salt * 1_000_003 + idx)
         label = idx % self.n_classes
-        img = rng.standard_normal(
-            (self.image_size, self.image_size, 3), dtype=np.float32
-        )
+        rng.standard_normal(out=out, dtype=np.float32)
         # class-dependent mean shift: learnable but not trivially separable
-        img += 0.1 * ((label % 16) - 8) / 8.0
-        return img, np.int64(label)
+        out += 0.1 * ((label % 16) - 8) / 8.0
+        return np.int64(label)
+
+    def __getitem__(self, idx: int) -> Tuple[np.ndarray, np.int64]:
+        img = np.empty((self.image_size, self.image_size, 3), np.float32)
+        return img, self.fill_sample(idx, img)
 
 
 class SyntheticTextDataset:
@@ -228,6 +241,33 @@ def fetch_sample(dataset, idx: int, seed: int, epoch: int):
     if get is not None:
         return get(idx, sample_rng(seed, epoch, idx))
     return dataset[int(idx)]
+
+
+def fills_in_place(dataset) -> bool:
+    """Whether ``fetch_sample_into`` has the dataset write its own rows."""
+    return hasattr(dataset, "fill_sample")
+
+
+def fetch_sample_into(dataset, idx: int, seed: int, epoch: int, out: np.ndarray):
+    """Write sample ``idx`` into ``out`` (a row of a batch) and return its
+    second half (label or target array).
+
+    A dataset with ``fill_sample(idx, out)`` generates straight into the row;
+    any other is fetched exactly as :func:`fetch_sample` does and copied in.
+    Either way the work runs in the calling thread, so a pool of callers
+    spreads the copy too.  A sample that is not of the row's shape and dtype
+    raises ``ValueError``: numpy would broadcast or cast it in silence.
+    """
+    if fills_in_place(dataset):
+        return dataset.fill_sample(int(idx), out)
+    img, label = fetch_sample(dataset, idx, seed, epoch)
+    if img.shape != out.shape or img.dtype != out.dtype:
+        raise ValueError(
+            f"sample {idx}: {img.dtype}{list(img.shape)} does not match the "
+            f"batch's rows, {out.dtype}{list(out.shape)} (probed from the first sample)"
+        )
+    out[...] = img
+    return label
 
 
 def sample_crop_params(

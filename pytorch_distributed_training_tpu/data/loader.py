@@ -16,7 +16,15 @@ offers three assembly backends, selected by ``worker_mode``:
     pure-Python datasets.
   - ``"thread"``: in-process thread pool; right for datasets whose
     ``__getitem__`` releases the GIL (numpy-heavy synthetic data) and for
-    tiny smoke runs.
+    tiny smoke runs.  The batch's array is allocated first and every worker
+    writes its sample into its own row of it (``fetch_sample_into``): a
+    dataset with ``fill_sample(idx, out)`` generates straight into the row,
+    any other sample is fetched as before and copied in by the worker that
+    fetched it, so no single thread copies a whole batch.  Each batch gets a
+    fresh array, first touched (page-faulted) by the workers; it is never
+    recycled, because the runtime's re-layout thread still reads a batch
+    after the engine's put returns and ``device_prefetch`` keeps two in
+    flight.  ``num_workers: 0`` fills the same rows in the producer thread.
 
 Every backend prefetches assembled batches through a bounded queue so host
 work overlaps device compute — the role pinned memory + ``non_blocking`` H2D
@@ -45,7 +53,7 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 from ..telemetry.spans import span
-from .datasets import fetch_sample, sample_rng
+from .datasets import fetch_sample, fetch_sample_into, fills_in_place, sample_rng
 from .sampler import DistributedShardSampler
 
 __all__ = ["DataLoader"]
@@ -87,6 +95,7 @@ class DataLoader:
         self.output_dtype = output_dtype
         self.seed = int(getattr(sampler, "seed", 0))
         self._pool = None  # lazily-created ProcessLoaderPool
+        self._spec = None  # lazily-probed sample shapes (_sample_spec)
         self.worker_mode = self._resolve_mode(worker_mode)
         if output_dtype == "uint8" and getattr(dataset, "norm_mean", None) is None:
             raise ValueError(
@@ -159,19 +168,53 @@ class DataLoader:
             return normalize_batch(imgs, mean, std)
         return imgs.astype(np.float32) / 255.0
 
+    def _sample_spec(self, idx: int, epoch: int):
+        """(image shape, image dtype, shape of the second half), probed once
+        per loader from one sample: what a batch's arrays are made from."""
+        if self._spec is None:
+            img, label = fetch_sample(self.dataset, int(idx), self.seed, epoch)
+            self._spec = (img.shape, img.dtype, np.shape(label))
+        return self._spec
+
     def _assemble(
         self, indices: np.ndarray, epoch: int, pool: Optional[ThreadPoolExecutor]
     ):
-        """Thread/sync path: per-sample Python fetch + batch normalize."""
-        fetch = lambda i: fetch_sample(self.dataset, int(i), self.seed, epoch)  # noqa: E731
+        """Thread/sync path: a fresh batch array, each row written in place.
+
+        Row ``j`` is one task, ``fetch_sample_into(dataset, indices[j], ...,
+        imgs[j])``, mapped over ``pool`` (or looped when there is none): the
+        dataset's own ``fill_sample`` where it has one, else the usual fetch
+        and a copy into the row by the thread that fetched.  The second half
+        (a label, or the ``tokens`` dataset's target array) lands in row ``j``
+        of an int64 array.  uint8 rows are normalized afterwards in one
+        batched call.  A sample that is not of the probed shape raises
+        ``ValueError`` from the iterator.  The arrays are new every batch:
+        a yielded batch is still read by the runtime after the put, so a
+        recycled buffer would be a data race.
+        """
+        n = len(indices)
+        img_shape, img_dtype, label_shape = self._sample_spec(indices[0], epoch)
+        imgs = np.empty((n, *img_shape), img_dtype)
+        labels = np.empty((n, *label_shape), np.int64)
+
+        def fill_row(j: int) -> None:
+            idx = int(indices[j])
+            label = fetch_sample_into(self.dataset, idx, self.seed, epoch, imgs[j])
+            if np.shape(label) != label_shape:
+                raise ValueError(
+                    f"sample {idx}: second half of shape {list(np.shape(label))} "
+                    f"does not match the batch's, {list(label_shape)}"
+                )
+            labels[j] = label
+
         if pool is not None:
-            samples = list(pool.map(fetch, indices))
+            for _ in pool.map(fill_row, range(n)):  # reads every task's error
+                pass
         else:
-            samples = [fetch(i) for i in indices]
-        imgs = np.stack([s[0] for s in samples])
+            for j in range(n):
+                fill_row(j)
         if imgs.dtype == np.uint8 and self.output_dtype == "float32":
             imgs = self._normalize_u8(imgs)
-        labels = np.asarray([s[1] for s in samples], dtype=np.int64)
         return imgs, labels
 
     def _assemble_native(self, indices: np.ndarray, epoch: int):
@@ -230,14 +273,12 @@ class DataLoader:
         if self._pool is None:
             from .worker_pool import ProcessLoaderPool
 
-            probe_img, _ = fetch_sample(
-                self.dataset, int(batches[0][0]), self.seed, epoch
-            )
+            sample_shape, sample_dtype, _ = self._sample_spec(batches[0][0], epoch)
             self._pool = ProcessLoaderPool(
                 self.dataset,
                 batch_size=self.batch_size,
-                sample_shape=probe_img.shape,
-                sample_dtype=probe_img.dtype,
+                sample_shape=sample_shape,
+                sample_dtype=sample_dtype,
                 num_workers=max(1, self.num_workers),
                 seed=self.seed,
             )
@@ -261,10 +302,15 @@ class DataLoader:
         out_q: queue.Queue = queue.Queue(maxsize=self.prefetch_batches)
         stop = threading.Event()
 
+        own_rows = fills_in_place(self.dataset)
+
         def assemble(b):
-            with span("batch_assemble", n=len(b)):
-                if self.worker_mode == "native":
+            if self.worker_mode == "native":
+                with span("batch_assemble", n=len(b)):
                     return self._assemble_native(b, epoch)
+            # in_place: rows the dataset wrote itself (the rest were copied
+            # in by the thread that fetched them)
+            with span("batch_assemble", n=len(b), in_place=len(b) if own_rows else 0):
                 return self._assemble(b, epoch, pool)
 
         def producer():

@@ -10,6 +10,10 @@ from pytorch_distributed_training_tpu.data import (
     SyntheticDataset,
     get_dataset,
 )
+from pytorch_distributed_training_tpu.data.datasets import (
+    fetch_sample,
+    fetch_sample_into,
+)
 from pytorch_distributed_training_tpu.utils import make_iter_dataloader
 
 
@@ -198,13 +202,150 @@ def test_get_dataset_factory():
         get_dataset("imagenet", "/nonexistent", "train")
 
 
+# ------------------------------------------------- rows written in place
+def _small_dataset(name, root):
+    """One small dataset of each kind ``_assemble`` serves: float32 images
+    the dataset writes itself, array-valued second halves, PIL uint8 images."""
+    if name == "synthetic":
+        return SyntheticDataset(n_samples=22, n_classes=5, image_size=8)
+    if name == "synthetic_text":
+        return get_dataset("synthetic_text", "/none", "train", n_classes=64,
+                           n_samples=22, seq_len=16)
+    if name == "tokens":
+        rng = np.random.default_rng(5)
+        rng.integers(0, 500, size=22 * 16 + 1).astype(np.uint16).tofile(
+            root / "train.bin")
+        return get_dataset("tokens", str(root), "train", seq_len=16)
+    from PIL import Image
+
+    rng = np.random.default_rng(0)
+    for cls in ("a", "b"):
+        d = root / "train" / cls
+        d.mkdir(parents=True)
+        for i in range(11):
+            pixels = rng.integers(0, 256, size=(20, 24, 3), dtype=np.uint8)
+            Image.fromarray(pixels).save(d / f"{i}.png")
+    return get_dataset("imagenet", str(root), "train", image_size=16)
+
+
+@pytest.mark.parametrize("num_workers", [0, 4])
+@pytest.mark.parametrize(
+    "name,output_dtype",
+    [("synthetic", "float32"), ("synthetic_text", "float32"),
+     ("tokens", "float32"), ("imagefolder", "float32"),
+     ("imagefolder", "uint8")],
+)
+def test_loader_batches_equal_the_plain_stack(name, output_dtype, num_workers, tmp_path):
+    """The thread and synchronous loaders' batches are, bit for bit, what
+    stacking ``fetch_sample`` of the same indices gives (the assembly the
+    loader had before its workers wrote rows in place, kept here as the
+    plain reference), tail wrap included."""
+    from pytorch_distributed_training_tpu.native import normalize_batch
+
+    ds = _small_dataset(name, tmp_path)
+    loader = DataLoader(
+        ds, batch_size=8, sampler=RandomSampler(len(ds), seed=3),
+        num_workers=num_workers, drop_last=False, worker_mode="thread",
+        output_dtype=output_dtype,
+    )
+    loader.set_epoch(1)
+    indices = loader._batch_indices()
+    assert len(indices) == 3 and set(indices[-1][6:]) <= set(indices[0])  # wraps
+    batches = list(loader)
+    assert len(batches) == 3
+    for idx, (imgs, labels) in zip(indices, batches):
+        samples = [fetch_sample(ds, int(i), loader.seed, 1) for i in idx]
+        want = np.stack([s[0] for s in samples])
+        if want.dtype == np.uint8 and output_dtype == "float32":
+            want = normalize_batch(want, ds.norm_mean, ds.norm_std)
+        want_labels = np.asarray([s[1] for s in samples], dtype=np.int64)
+        assert imgs.dtype == want.dtype and labels.dtype == np.int64
+        assert imgs.flags["C_CONTIGUOUS"]
+        np.testing.assert_array_equal(imgs, want)
+        np.testing.assert_array_equal(labels, want_labels)
+
+
+@pytest.mark.parametrize("split", ["train", "val"])
+@pytest.mark.parametrize("idx", [0, 7, 19])
+def test_synthetic_fill_sample_equals_getitem(split, idx):
+    """``fill_sample`` into a batch row gives the pixels ``__getitem__``
+    gives, and both give what the generator draws when it allocates."""
+    ds = SyntheticDataset(n_samples=20, n_classes=6, image_size=8, split=split)
+    batch = np.full((3, 8, 8, 3), np.nan, np.float32)
+    label = ds.fill_sample(idx, batch[1])
+    img, want_label = ds[idx]
+    np.testing.assert_array_equal(batch[1], img)
+    assert label == want_label == idx % 6 and isinstance(label, np.int64)
+    assert np.isnan(batch[0]).all() and np.isnan(batch[2]).all()
+    rng = np.random.default_rng(ds._salt * 1_000_003 + idx)
+    drawn = rng.standard_normal((8, 8, 3), dtype=np.float32)
+    drawn += 0.1 * (((idx % 6) % 16) - 8) / 8.0
+    np.testing.assert_array_equal(img, drawn)
+    with pytest.raises(ValueError, match=f"sample {idx}"):
+        ds.fill_sample(idx, np.empty((8, 8), np.float32))
+
+
+class _OneOddSample:
+    """Index-seeded dataset whose sample 5 differs in one half."""
+
+    def __init__(self, odd_img, odd_label):
+        self.odd = (odd_img, odd_label)
+
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, idx):
+        if idx == 5:
+            return self.odd
+        return np.full((4, 4, 3), idx, np.float32), np.int64(idx)
+
+
+@pytest.mark.parametrize("num_workers", [0, 4])
+@pytest.mark.parametrize(
+    "odd",
+    [(np.zeros((4, 1, 3), np.float32), np.int64(5)),  # would broadcast
+     (np.zeros((2, 4, 3), np.float32), np.int64(5)),
+     (np.zeros((4, 4, 3), np.float64), np.int64(5)),  # would be cast
+     (np.zeros((4, 4, 3), np.float32), np.zeros(2, np.int64))],
+    ids=["broadcastable", "shape", "dtype", "second-half"],
+)
+def test_loader_raises_on_a_sample_unlike_the_probe(odd, num_workers):
+    """One sample that is not of the probed shape and dtype raises
+    ``ValueError`` naming its index out of the iterator, where ``np.stack``
+    raised; nothing is broadcast or cast into the row."""
+    ds = _OneOddSample(*odd)
+    loader = DataLoader(ds, batch_size=4, sampler=SequentialSampler(len(ds)),
+                        num_workers=num_workers, worker_mode="thread")
+    it = iter(loader)
+    imgs, labels = next(it)
+    np.testing.assert_array_equal(labels, [0, 1, 2, 3])
+    with pytest.raises(ValueError, match="sample 5"):
+        next(it)
+
+
+def test_fetch_sample_into_copies_where_the_dataset_has_no_fill_sample(tmp_path):
+    ds = _small_dataset("tokens", tmp_path)
+    row = np.zeros((2, 16), np.int32)
+    targets = fetch_sample_into(ds, 3, seed=0, epoch=0, out=row[1])
+    want, want_targets = ds[3]
+    np.testing.assert_array_equal(row[1], want)
+    np.testing.assert_array_equal(targets, want_targets)
+    assert not row[0].any()
+
+
 # ------------------------------------------------------------------- spans
-@pytest.mark.parametrize("mode", ["thread", "native", "process"])
+@pytest.mark.parametrize("mode", ["thread", "thread-copied", "native", "process"])
 def test_loader_emits_one_batch_assemble_a_batch(mode, tmp_path):
     """Every assembly backend brackets each batch in one ``batch_assemble``
     span carrying its sample count, and the consumer's wait for a batch in
     ``loader_wait`` (telemetry/spans.py; the benchmark's input-pipeline
-    metrics read both)."""
+    metrics read both).  In thread mode the span also says how many rows the
+    dataset wrote itself (``in_place``): all of them for ``synthetic``, none
+    for a dataset whose samples the workers copy in."""
+    in_place = {"thread": 4, "thread-copied": 0}.get(mode)
+    copied = mode == "thread-copied"
+    if copied:
+        mode = "thread"
     from pytorch_distributed_training_tpu.telemetry import (
         SpanRecorder,
         set_recorder,
@@ -225,6 +366,9 @@ def test_loader_emits_one_batch_assemble_a_batch(mode, tmp_path):
                 pixels = rng.integers(0, 256, size=(40, 48, 3), dtype=np.uint8)
                 Image.fromarray(pixels).save(d / f"{i}.jpg", "JPEG")
         ds = get_dataset("imagenet", str(tmp_path), "train")
+    elif copied:
+        ds = get_dataset("synthetic_text", "/none", "train", n_classes=32,
+                         n_samples=12, seq_len=8)
     else:
         ds = SyntheticDataset(n_samples=12, n_classes=3, image_size=8)
     rec = set_recorder(SpanRecorder(ring=64))
@@ -242,6 +386,7 @@ def test_loader_emits_one_batch_assemble_a_batch(mode, tmp_path):
     spans = rec.recent()
     made = [s for s in spans if s["kind"] == "batch_assemble"]
     assert [s["n"] for s in made] == [4, 4, 4]
+    assert [s.get("in_place") for s in made] == [in_place] * 3
     waits = [s for s in spans if s["kind"] == "loader_wait"]
     assert len(waits) >= 3 and all(s["parent"] is None for s in waits)
     if mode == "process":
