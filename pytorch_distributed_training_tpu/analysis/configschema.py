@@ -368,8 +368,52 @@ def _has_seed_binding(fn: ast.AST) -> bool:
     return False
 
 
+def _model_family_fields(cls: ast.ClassDef) -> Optional[List[ast.AnnAssign]]:
+    """The annotated fields of a class that states ``is_language_model =
+    True`` (a language-model family: its ``model:`` section's keys are the
+    module's fields, forwarded verbatim by ``get_model``); None for any
+    other class."""
+    stated = any(
+        isinstance(stmt, ast.Assign)
+        and any(isinstance(t, ast.Name) and t.id == "is_language_model"
+                for t in stmt.targets)
+        and isinstance(stmt.value, ast.Constant) and stmt.value.value is True
+        for stmt in cls.body
+    )
+    if not stated:
+        return None
+    return [
+        stmt for stmt in cls.body
+        if isinstance(stmt, ast.AnnAssign) and isinstance(stmt.target, ast.Name)
+    ]
+
+
+def _extract_model_families(module: SourceModule, schema: Schema) -> None:
+    """The open ``model`` section learns the keys a language-model family
+    takes (a field annotated plainly ``int``/``float``/``bool``/``str``
+    pins its type; anything else is ``any``).  ``vocab_size`` and ``dtype``
+    are the engine's to pass, not the config's."""
+    for cls in ast.walk(module.tree):
+        if not isinstance(cls, ast.ClassDef):
+            continue
+        for field in _model_family_fields(cls) or ():
+            key = field.target.id
+            if key in ("vocab_size", "dtype"):
+                continue
+            section = schema.setdefault(("model",), _Section())
+            if section.source is None:
+                section.source = (module.rel, cls.lineno)
+            info = section.keys.setdefault(key, _KeyInfo())
+            kind = field.annotation
+            if isinstance(kind, ast.Name) and kind.id in _CASTS:
+                info.types.add(kind.id)
+            if info.default is None and field.value is not None:
+                info.default = ast.unparse(field.value)
+
+
 def extract_schema(modules: Sequence[SourceModule]) -> Schema:
-    """Build the accepted-config schema from every parser in `modules`."""
+    """Build the accepted-config schema from every parser in `modules`,
+    and from the fields of the language-model families."""
     schema: Schema = {}
     for module in modules:
         for node in ast.walk(module.tree):
@@ -377,6 +421,7 @@ def extract_schema(modules: Sequence[SourceModule]) -> Schema:
                 continue
             if _is_parser(node) or _has_seed_binding(node):
                 _FunctionExtractor(module, node, schema).extract()
+        _extract_model_families(module, schema)
     return schema
 
 

@@ -64,7 +64,15 @@ def parse_topology(r, cfg: dict, train_cfg: dict, train_dataset) -> None:
     # first-class from the config surface — ``model.name: TransformerLM`` +
     # an LM dataset + optional ``training.sequence_parallelism``
     # (ring/Ulysses over a sequence mesh axis, parallel.sequence).
-    r.is_lm = model_name.lower() == "transformerlm"
+    # Asked of the model's class (models.model_class), not of its name.
+    from ..models import model_class
+
+    family = model_class(model_name)
+    r.is_lm = bool(getattr(family, "is_language_model", False))
+    refusal = getattr(family, "training_unsupported", None)
+    if refusal:
+        # a served-only family must not fall into the TransformerLM paths
+        raise ValueError(f"model.name: {model_name} cannot be trained: {refusal}")
     # MoE (model.moe_experts > 0, ops/moe.py): trains on the GSPMD path
     # whatever the parallelism degrees — the routing einsums and the
     # sown aux loss need the partitioner's global-token view, and under

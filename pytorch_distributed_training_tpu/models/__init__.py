@@ -8,7 +8,14 @@ use ``ResNet50`` (config/ResNet50.yml:31).
 Families: the reference's ResNet-18/34/50/101/152 (README.md:7-13) plus a
 ViT family (ViT-Ti16/S16/B16) and a decoder-only ``TransformerLM`` (the
 long-context / sequence-parallel model) added beyond the reference — the
-config surface only pins ``model.name``, so new names slot straight in.
+config surface only pins ``model.name``, so new names slot straight in —
+and ``DeepseekV2`` (:mod:`.deepseek_v2`: latent attention, dropless experts;
+served, not trained).
+
+What a model IS is stated by its class, not compared by name:
+``is_language_model`` (tokens in, logits out; the ``num_classes`` slot is
+the vocabulary) and ``training_unsupported`` (a message where the training
+path must refuse it).  :func:`model_class` gives the class for a name.
 """
 from __future__ import annotations
 
@@ -16,6 +23,7 @@ from typing import Any, Optional
 
 import jax.numpy as jnp
 
+from .deepseek_v2 import DeepseekV2LM
 from .resnet import RESNET_CONFIGS, BasicBlock, Bottleneck, ResNet
 from .transformer_lm import TransformerLM
 from .vit import VIT_CONFIGS, ViT
@@ -23,6 +31,8 @@ from .vit import VIT_CONFIGS, ViT
 __all__ = [
     "get_model",
     "list_models",
+    "model_class",
+    "DeepseekV2LM",
     "ResNet",
     "BasicBlock",
     "Bottleneck",
@@ -32,11 +42,25 @@ __all__ = [
 
 _CANONICAL = {name.lower(): name for name in RESNET_CONFIGS}
 _CANONICAL.update({name.lower(): name for name in VIT_CONFIGS})
-_CANONICAL["transformerlm"] = "TransformerLM"
+# the language-model families: name -> class (the class takes
+# ``vocab_size=num_classes`` and the ``model:`` section's keys verbatim)
+_LM_FAMILIES = {"TransformerLM": TransformerLM, "DeepseekV2": DeepseekV2LM}
+_CANONICAL.update({name.lower(): name for name in _LM_FAMILIES})
 
 
 def list_models():
-    return sorted(RESNET_CONFIGS) + sorted(VIT_CONFIGS) + ["TransformerLM"]
+    return sorted(RESNET_CONFIGS) + sorted(VIT_CONFIGS) + sorted(_LM_FAMILIES)
+
+
+def model_class(model_name: str):
+    """The module class behind a zoo name (case-insensitive)."""
+    key = model_name.lower()
+    if key not in _CANONICAL:
+        raise KeyError(f"unknown model '{model_name}' (have: {list_models()})")
+    name = _CANONICAL[key]
+    if name in _LM_FAMILIES:
+        return _LM_FAMILIES[name]
+    return ResNet if name in RESNET_CONFIGS else ViT
 
 
 def get_model(
@@ -58,15 +82,16 @@ def get_model(
         section here (e.g. ``embed_dim/depth/num_heads/max_len/seq_axis``
         for ``TransformerLM``).
 
-    For ``TransformerLM`` the reference's ``num_classes`` slot is the
-    vocabulary size (``dataset.n_classes`` in the config).
+    For a language model (``TransformerLM``, ``DeepseekV2``) the reference's
+    ``num_classes`` slot is the vocabulary size (``dataset.n_classes`` in the
+    config).
     """
     key = model_name.lower()
     if key not in _CANONICAL:
         raise KeyError(f"unknown model '{model_name}' (have: {list_models()})")
     name = _CANONICAL[key]
-    if name == "TransformerLM":
-        return TransformerLM(vocab_size=num_classes, dtype=dtype, **kwargs)
+    if name in _LM_FAMILIES:
+        return _LM_FAMILIES[name](vocab_size=num_classes, dtype=dtype, **kwargs)
     if name in RESNET_CONFIGS:
         block_cls, stage_sizes = RESNET_CONFIGS[name]
         return ResNet(

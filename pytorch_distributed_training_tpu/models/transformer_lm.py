@@ -146,6 +146,9 @@ class DecoderBlock(nn.Module):
 class TransformerLM(nn.Module):
     """Causal LM over integer tokens ``[B, S(_local)] -> logits [B, S, V]``."""
 
+    # what serving/engine.py and engine/topology.py ask of a model's class
+    is_language_model = True
+
     vocab_size: int
     max_len: int = 1024
     embed_dim: int = 256

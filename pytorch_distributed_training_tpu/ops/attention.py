@@ -15,6 +15,14 @@ Strategy selection is static (trace-time):
 
 All strategies compute the same math (softmax(QK^T/sqrt(d))V) — tested
 equivalent in tests/test_sequence_parallel.py.
+
+The paged pool (serving/kv_pool.py) holds rows of whatever the layer's
+attention stores, one row a token a layer, in the ``"cache"`` collection.
+Two layouts exist: a K/V PAIR (:class:`MultiHeadAttention`: two leaves
+``[pool_rows, H, hd]``) and a LATENT row (:class:`..ops.mla.MLAttention`:
+one leaf ``[pool_rows, rank + rope]`` shared by every head).  Serving code
+finds the leaves through :func:`pool_leaf_role`, which the attention
+modules answer, never by spelling a leaf's name itself.
 """
 from __future__ import annotations
 
@@ -29,7 +37,28 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.sequence import ring_attention, ulysses_attention
 from ..utils.vma import varying_axes_of
 
-__all__ = ["dot_product_attention", "MultiHeadAttention"]
+__all__ = ["dot_product_attention", "MultiHeadAttention", "pool_leaf_role"]
+
+# The paged pool's leaves, under the names the attention modules give them.
+# A SCORED leaf is one a query's logits are computed against: a NaN in one of
+# its rows makes the logits of the row's owner NaN and is masked to -inf for
+# every other reader (scheduler ``_corrupt_pool_rows`` rests on that).
+KEY_POOL, VALUE_POOL, LATENT_POOL = "k_pool", "v_pool", "latent_pool"
+_POOL_ROLES = {KEY_POOL: "scored", VALUE_POOL: "value", LATENT_POOL: "scored"}
+
+
+def pool_leaf_role(path, leaf, pool_rows: int) -> Optional[str]:
+    """``"scored"`` / ``"value"`` for a per-row leaf of the paged pool,
+    ``None`` for anything else in the cache tree.  ``path`` is the leaf's
+    jax key path; a pool leaf is one an attention module declared (by the
+    name of its variable) AND whose leading dimension is the pool's rows."""
+    if not (hasattr(leaf, "ndim") and leaf.ndim >= 1 and leaf.shape[0] == pool_rows):
+        return None
+    for part in reversed(path):
+        role = _POOL_ROLES.get(str(getattr(part, "key", getattr(part, "name", ""))))
+        if role:
+            return role
+    return None
 
 def _use_flash(q) -> bool:
     """Trace-time flash-kernel eligibility for the local-attention path.
@@ -388,11 +417,11 @@ class MultiHeadAttention(nn.Module):
         b, s, num_heads, head_dim = q.shape
         pool_rows = nb * bs
         k_pool = self.variable(
-            "cache", "k_pool", jnp.zeros, (pool_rows, num_heads, head_dim),
+            "cache", KEY_POOL, jnp.zeros, (pool_rows, num_heads, head_dim),
             self.dtype,
         )
         v_pool = self.variable(
-            "cache", "v_pool", jnp.zeros, (pool_rows, num_heads, head_dim),
+            "cache", VALUE_POOL, jnp.zeros, (pool_rows, num_heads, head_dim),
             self.dtype,
         )
         valid = positions >= 0  # [B, S]

@@ -1,0 +1,247 @@
+"""Multi-head latent attention (MLA, DeepSeek-V2) with a paged latent cache.
+
+The sibling of :mod:`.attention` for models whose cache row is NOT a K/V
+pair: a token leaves one row of ``kv_lora_rank + qk_rope_head_dim`` values a
+layer (the normalised latent ``c`` and the rotated key ``k_pe``, shared by
+every head) and each head's ``k_nope`` and ``v`` are linear maps of ``c``:
+
+    q = x W_q -> per head q_nope | q_pe;   x W_kv_a -> c | k_pe;  c <- RMSNorm(c)
+    c W_kv_b -> per head k_nope | v;       rotary on q_pe and k_pe only
+    scores = (q_nope.k_nope + q_pe.k_pe) * softmax_scale,  o = softmax(scores) v
+
+Two forms compute the same function and read the same rows:
+
+  - EXPANDED: ``k_nope`` and ``v`` are rebuilt from the rows a query can see
+    and ordinary attention runs over them.  Cheaper when many queries share
+    the rows (prefill, the plain forward).
+  - ABSORBED: ``W_kv_b``'s two halves move to the query's side,
+    ``q_lat = q_nope W_UK^T`` and ``o = (P c) W_UV``, so a query attends over
+    the latent rows themselves and nothing of a head's width is ever built
+    for a cached position.  One query a row (decode).
+
+The paged path is :meth:`..attention.MultiHeadAttention._paged_attention`'s
+contract over ONE pool leaf ``[pool_rows, rank + rope]`` a layer in the
+``"cache"`` collection: scatter this call's rows (padding dropped out of
+bounds), gather each row's logical sequence through its block table, mask
+keys to ``key_pos <= q_pos``, and zero dead rows before they meet any
+product, so that a NaN in a row stays with the request that owns it.
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+
+from .attention import LATENT_POOL
+
+__all__ = ["MLAttention", "rms_norm", "yarn_inv_freq", "yarn_mscale"]
+
+
+def rms_norm(x, weight, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * w``, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]) -> np.ndarray:
+    """Rotary frequencies ``[dim / 2]``; with a YaRN ``rope_scaling`` the
+    published ones divided by ``factor`` where a dimension turns fewer than
+    ``beta_slow`` times over the original context, kept where it turns more
+    than ``beta_fast`` times, a linear ramp between."""
+    freq = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not scaling:
+        return freq.astype(np.float32)
+    original = scaling["original_max_position_embeddings"]
+
+    def correction_dim(rotations):
+        return dim * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    return (freq / scaling["factor"] * ramp + freq * (1 - ramp)).astype(np.float32)
+
+
+def _rotate(x, cos, sin):
+    """The published layout: pairs ``(x0, x1), (x2, x3), ...`` de-interleaved
+    into two halves, then rotated as halves.  ``cos``/``sin`` [..., d/2]
+    broadcast against ``x [..., d]``."""
+    first = x[..., 0::2].astype(jnp.float32)
+    second = x[..., 1::2].astype(jnp.float32)
+    return jnp.concatenate(
+        [first * cos - second * sin, second * cos + first * sin], axis=-1
+    ).astype(x.dtype)
+
+
+class MLAttention(nn.Module):
+    """Latent attention over ``x [B, S, D]``; bias-free projections held in
+    ``dtype``.  ``rope_scaling`` is the config's dict as a tuple of items
+    (flax fields are hashed)."""
+
+    num_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    rms_norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+    # serving (the MultiHeadAttention contract): ``decode`` + ``paged`` read
+    # and write the shared pool; ``decode`` alone (a contiguous cache a
+    # batch) is not written for this family
+    decode: bool = False
+    paged: bool = False
+    kv_block_size: int = 0
+    kv_num_blocks: int = 0
+    # queries of one call above which the expanded form runs a batch row at
+    # a time (its float32 scores are [H, S, L] a row)
+    absorb_max_queries: int = 1
+
+    @nn.compact
+    def __call__(self, x, positions=None, block_tables=None):
+        b, s, dim = x.shape
+        h, dn, dr, dv, r = (self.num_heads, self.qk_nope_head_dim,
+                            self.qk_rope_head_dim, self.v_head_dim,
+                            self.kv_lora_rank)
+        init = nn.initializers.lecun_normal()
+        wq = self.param("wq", init, (dim, h * (dn + dr)), self.dtype)
+        wkv_a = self.param("wkv_a", init, (dim, r + dr), self.dtype)
+        kv_norm = self.param("kv_norm", nn.initializers.ones, (r,), self.dtype)
+        wkv_b = self.param("wkv_b", init, (r, h * (dn + dv)), self.dtype)
+        wo = self.param("wo", init, (h * dv, dim), self.dtype)
+        scaling = dict(self.rope_scaling) if self.rope_scaling else None
+
+        if self.decode and not self.paged:
+            raise NotImplementedError(
+                "latent attention serves through the paged pool only "
+                "(serving.scheduler.enabled); the contiguous per-batch cache "
+                "of build_generate_fn is not written for it"
+            )
+        if self.paged and (positions is None or block_tables is None):
+            raise ValueError("paged mode needs positions and block_tables")
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        valid = positions >= 0  # [B, S]
+        safe_pos = jnp.maximum(positions, 0)
+
+        q = jnp.dot(x, wq).reshape(b, s, h, dn + dr)
+        q_nope, q_pe = q[..., :dn], q[..., dn:]
+        ckv = jnp.dot(x, wkv_a)
+        c = rms_norm(ckv[..., :r], kv_norm, self.rms_norm_eps)
+        k_pe = ckv[..., r:]
+        angles = safe_pos.astype(jnp.float32)[..., None] * jnp.asarray(
+            yarn_inv_freq(dr, self.rope_theta, scaling))  # [B, S, dr/2]
+        # cos/sin carry mscale(factor, mscale) / mscale(factor, mscale_all_dim)
+        # and the scores mscale(factor, mscale_all_dim)^2
+        amp, m_all = 1.0, 1.0
+        if scaling:
+            m_all = yarn_mscale(scaling["factor"], scaling["mscale_all_dim"])
+            amp = yarn_mscale(scaling["factor"], scaling["mscale"]) / m_all
+        cos, sin = jnp.cos(angles) * amp, jnp.sin(angles) * amp
+        q_pe = _rotate(q_pe, cos[:, :, None], sin[:, :, None])
+        k_pe = _rotate(k_pe, cos, sin)
+        rows = jnp.concatenate([c, k_pe], axis=-1).astype(self.dtype)  # [B,S,r+dr]
+        scale = (dn + dr) ** -0.5 * m_all * m_all
+
+        if self.paged:
+            bs, nb = self.kv_block_size, self.kv_num_blocks
+            if bs <= 0 or nb <= 0:
+                raise ValueError(
+                    f"paged mode needs kv_block_size/kv_num_blocks > 0, "
+                    f"got {bs}/{nb}"
+                )
+            pool_rows = nb * bs
+            pool = self.variable(
+                "cache", LATENT_POOL, jnp.zeros, (pool_rows, r + dr), self.dtype
+            )
+            blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)
+            phys = jnp.where(valid, blk * bs + safe_pos % bs, pool_rows)  # OOB=drop
+            filled = pool.value.at[phys.reshape(-1)].set(
+                rows.reshape(b * s, r + dr), mode="drop"
+            )
+            pool.value = filled
+            length = block_tables.shape[1] * bs
+            # gathered a BLOCK at a time: a block's rows lie together in
+            # the pool, so one table entry moves a [bs, r + dr] slab (row by
+            # row the same gather was a third of a decode step; splitting
+            # only the row axis keeps the view free of a relayout)
+            keys = filled.reshape(nb, bs, r + dr)[block_tables].reshape(
+                b, length, r + dr
+            )  # [B, L, r+dr] in logical-position order
+            key_pos = jnp.arange(length, dtype=jnp.int32)
+        else:
+            keys, key_pos = rows, jnp.arange(s, dtype=jnp.int32)
+        # padding queries keep key 0 live so that their softmax stays finite
+        live = key_pos[None, None, :] <= safe_pos[:, :, None]  # [B, S, L]
+        # a row dead for a row's every query is zeroed before any product:
+        # recycled blocks keep an evicted request's contents and padded table
+        # entries alias block 0, and 0 * NaN would carry a NaN across requests
+        # (causal, so live for any query of the row = live for its last one)
+        keys = jnp.where(live.any(axis=1)[:, :, None], keys, 0)
+
+        w_kv = wkv_b.reshape(r, h, dn + dv)
+        if s <= self.absorb_max_queries:
+            out = _absorbed(q_nope, q_pe, keys, live, w_kv, r, dn, scale)
+        else:
+            out = jax.lax.map(
+                lambda a: _expanded(*a, w_kv, r, dn, scale),
+                (q_nope, q_pe, keys, live),
+            )
+        return jnp.dot(out.reshape(b, s, h * dv).astype(self.dtype), wo)
+
+
+def _softmax(scores, live):
+    """Causal softmax in float32 over the last axis; ``live [..., S, L]``
+    broadcasts over the heads in front."""
+    return jax.nn.softmax(jnp.where(live, scores, -jnp.inf), axis=-1)
+
+
+def _expanded(q_nope, q_pe, keys, live, w_kv, r, dn, scale):
+    """One batch row: ``q_* [S, H, d]``, ``keys [L, r+dr]``, ``live [S, L]``."""
+    f32 = jnp.float32
+    c, k_pe = keys[:, :r], keys[:, r:]
+    kv = jnp.einsum("lr,rhd->lhd", c, w_kv)  # [L, H, dn+dv], the cache's dtype
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    scores = (
+        jnp.einsum("shd,lhd->hsl", q_nope.astype(f32), k_nope.astype(f32))
+        + jnp.einsum("shd,ld->hsl", q_pe.astype(f32), k_pe.astype(f32))
+    ) * scale
+    p = _softmax(scores, live[None])
+    return jnp.einsum("hsl,lhd->shd", p, v.astype(f32))
+
+
+def _absorbed(q_nope, q_pe, keys, live, w_kv, r, dn, scale):
+    """All rows at once: ``q_* [B, S, H, d]``, ``keys [B, L, r+dr]``.  The
+    products against the rows take them as they are stored (no float32
+    copy of ``[B, L, r+dr]`` is made: at 32 rows of 2,560 positions that
+    copy was the step's largest operation) and accumulate in float32."""
+    f32 = jnp.float32
+    # the CPU's dot thunk has no bfloat16 x bfloat16 = float32 for these
+    # shapes: there the operands are upcast, which rounds nothing
+    dt = keys.dtype if jax.default_backend() == "tpu" else f32
+    c, k_pe = keys[..., :r].astype(dt), keys[..., r:].astype(dt)
+    w_uk, w_uv = w_kv[..., :dn].astype(dt), w_kv[..., dn:].astype(dt)
+    q_nope = q_nope.astype(dt)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk, preferred_element_type=f32)
+    scores = (
+        jnp.einsum("bshr,blr->bhsl", q_lat.astype(dt), c, preferred_element_type=f32)
+        + jnp.einsum("bshd,bld->bhsl", q_pe.astype(dt), k_pe,
+                     preferred_element_type=f32)
+    ) * scale
+    p = _softmax(scores, live[:, None])
+    o_lat = jnp.einsum("bhsl,blr->bshr", p.astype(dt), c, preferred_element_type=f32)
+    return jnp.einsum("bshr,rhd->bshd", o_lat.astype(dt), w_uv,
+                      preferred_element_type=f32)

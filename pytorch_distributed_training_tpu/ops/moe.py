@@ -47,13 +47,13 @@ because they are the part reviewers argue about):
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-__all__ = ["MoEMLP"]
+__all__ = ["MoEMLP", "DroplessMoE", "grouped_matmul", "in_token_chunks", "swiglu"]
 
 
 class MoEMLP(nn.Module):
@@ -136,3 +136,199 @@ class MoEMLP(nn.Module):
         )
         # bias on empty capacity slots is harmless: their combine weight is 0
         return jnp.einsum("gsec,gecd->gsd", combine.astype(dt), ye)
+
+
+# --------------------------------------------------------------------------
+# Dropless experts (serving): every routed token is computed.  Beside the
+# one-hot capacity layer above, not inside it: that one is static-shaped
+# einsum work for the SPMD partitioner and drops what overflows; this one
+# sorts the token-expert pairs by expert and runs ONE grouped product a
+# projection over the experts it holds, so its cost follows the pairs and
+# the weights it reads follow the experts that got a token.
+
+# rows of the sorted pairs a grouped product's tile takes (the rows are
+# padded up to a multiple of it)
+GMM_ROW_TILE = 128
+
+
+def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    """(rows, contraction, columns) of one tile of the Pallas grouped
+    product: whole contraction where it fits the fast memory beside a
+    column tile, so that an expert's weights pass through once."""
+    tn = next(t for t in (512, 256, 128) if n % t == 0 or t == 128)
+    tk = k if k * tn * 2 <= (2 << 20) and k % 128 == 0 else 512
+    return GMM_ROW_TILE, tk, tn
+
+
+def grouped_matmul(lhs, rhs, group_sizes, out_dtype):
+    """``lhs[rows of group g] @ rhs[g]`` for every group: ``lhs [m, k]``
+    sorted by group, ``rhs [G, k, n]``, ``group_sizes [G]`` int32 whose sum
+    may fall short of ``m`` (the rows past it belong to no group and come
+    back undefined: the caller masks them).  On a TPU the megablox Pallas
+    kernel (``moe_gmm`` in a trace); elsewhere ``lax.ragged_dot``."""
+    from .flash_attention import flash_enabled
+
+    with jax.named_scope("moe_gmm"):
+        if not flash_enabled():
+            return jax.lax.ragged_dot(
+                lhs, rhs, group_sizes, preferred_element_type=out_dtype
+            )
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(
+            lhs, rhs, group_sizes, preferred_element_type=out_dtype,
+            tiling=_gmm_tiling(lhs.shape[1], rhs.shape[2]),
+        )
+
+
+def in_token_chunks(fn, chunk: int, *arrays):
+    """``fn`` over pieces of ``chunk`` leading rows of ``arrays`` (padded
+    with zeros to a whole number of pieces), one piece at a time
+    (``lax.map``): a long call holds one piece's temporaries, not all.
+    Returns ``fn``'s outputs stacked ``[pieces, ...]``."""
+    pad = -arrays[0].shape[0] % chunk
+    pieces = tuple(
+        jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (-1, chunk) + a.shape[1:])
+        for a in arrays
+    )
+    return jax.lax.map(lambda piece: fn(*piece), pieces)
+
+
+def _gated(h, dtype):
+    """``silu(gate) * up`` of ``h = [gate | up]``, in float32."""
+    half = h.shape[-1] // 2
+    gate, up = h[..., :half].astype(jnp.float32), h[..., half:].astype(jnp.float32)
+    return (jax.nn.silu(gate) * up).astype(dtype)
+
+
+def swiglu(x, gate_up, down):
+    """``(silu(x W_gate) * x W_up) W_down`` with gate and up side by side
+    in one tensor, the gate first."""
+    return jnp.dot(_gated(jnp.dot(x, gate_up), x.dtype), down)
+
+
+class DroplessMoE(nn.Module):
+    """Top-k routed SwiGLU experts beside a shared expert, no capacity:
+    ``y = sum_k s_k Expert_{i_k}(x) + Shared(x)`` over tokens ``x [N, dim]``.
+
+    The router scores ALL ``num_experts`` in float32 (softmax, from the
+    float32-cast input) and takes the ``top_k`` largest; the gates are those
+    scores as they are (``norm_topk_prob`` renormalises them over the chosen
+    k) times ``routed_scaling_factor``, applied in the combine.
+
+    ``experts_held = (first, count)`` tells the layer which experts live
+    here (default: all).  It still routes over all of them and returns only
+    the held experts' part of the sum — one chip's share of an expert-
+    parallel layer, run without its exchange.  The shared expert is what
+    every share computes alike: :meth:`routed_part` and :meth:`shared_part`
+    give the two halves apart so that shares can be added up with it counted
+    once; ``__call__`` is their sum.
+
+    Parameters (``dtype``): ``router [dim, E]``, ``w_gate_up [held, dim, 2h]``
+    (gate first), ``w_down [held, h, dim]``, and with ``shared_hidden > 0``
+    ``shared_gate_up [dim, 2s]``, ``shared_down [s, dim]``.
+    """
+
+    dim: int
+    num_experts: int
+    top_k: int
+    hidden: int
+    shared_hidden: int = 0
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.float32
+    # tokens routed and computed in one piece; a longer call runs in pieces
+    # of this many (a 32 x 2048 prefill would otherwise hold its six-fold
+    # copy of the activations at once)
+    token_chunk: int = 8192
+
+    @property
+    def _held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.num_experts)
+
+    def setup(self):
+        E, k = self.num_experts, self.top_k
+        if not 1 <= k <= E:
+            raise ValueError(f"top_k ({k}) must be in [1, num_experts={E}]")
+        first, held = self._held
+        if not (0 <= first and held >= 1 and first + held <= E):
+            raise ValueError(
+                f"experts_held {self.experts_held} is not a range of the "
+                f"{E} experts"
+            )
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
+        self.router = self.param("router", init, (self.dim, E), self.dtype)
+        self.w_gate_up = self.param(
+            "w_gate_up", init, (held, self.dim, 2 * self.hidden), self.dtype)
+        self.w_down = self.param(
+            "w_down", init, (held, self.hidden, self.dim), self.dtype)
+        if self.shared_hidden:
+            self.shared_gate_up = self.param(
+                "shared_gate_up", init, (self.dim, 2 * self.shared_hidden),
+                self.dtype)
+            self.shared_down = self.param(
+                "shared_down", init, (self.shared_hidden, self.dim), self.dtype)
+
+    def __call__(self, x, token_mask=None):
+        """``(y [N, dim], group_sizes [held])``: the layer's output and how
+        many of this call's token-expert pairs each held expert computed."""
+        routed, sizes = self.routed_part(x, token_mask)
+        return routed + self.shared_part(x), sizes
+
+    def shared_part(self, x):
+        if not self.shared_hidden:
+            return jnp.zeros_like(x)
+        with jax.named_scope("moe_shared"):
+            return swiglu(x, self.shared_gate_up, self.shared_down)
+
+    def routed_part(self, x, token_mask=None):
+        """The held experts' part of the gated sum.  ``token_mask [N]``
+        (False = padding) keeps a token out of the routing altogether: it is
+        neither computed nor counted."""
+        n = x.shape[0]
+        if token_mask is None:
+            token_mask = jnp.ones((n,), bool)
+        if n <= self.token_chunk:
+            return self._routed(x, token_mask)
+        ys, sizes = in_token_chunks(self._routed, self.token_chunk, x, token_mask)
+        return ys.reshape(-1, x.shape[1])[:n], sizes.sum(axis=0)
+
+    def _routed(self, x, token_mask):
+        n, k = x.shape[0], self.top_k
+        first, held = self._held
+        with jax.named_scope("moe_route"):
+            logits = jnp.dot(
+                x.astype(jnp.float32), self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            scores = jax.nn.softmax(logits, axis=-1)
+            gates, experts = jax.lax.top_k(scores, k)  # [n, k]
+            if self.norm_topk_prob:
+                gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+            gates = gates * self.routed_scaling_factor
+            # the pairs (token, expert) of this share, sorted by expert; a
+            # pair whose expert lives elsewhere, or whose token is padding,
+            # sorts behind every group and is never computed
+            local = experts.reshape(-1) - first
+            mine = (local >= 0) & (local < held) & jnp.repeat(token_mask, k)
+            group = jnp.where(mine, local, held).astype(jnp.int32)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+            rows = n * k
+            padded = -(-rows // GMM_ROW_TILE) * GMM_ROW_TILE
+            token_of = jnp.pad(order // k, (0, padded - rows))
+            lhs = x[token_of]  # [padded, dim]
+        h = grouped_matmul(lhs, self.w_gate_up, sizes, self.dtype)
+        out = grouped_matmul(_gated(h, self.dtype), self.w_down, sizes, self.dtype)
+        with jax.named_scope("moe_combine"):
+            # back to (token, choice) order; the rows of no group are
+            # undefined and are taken out by ``where``, never by a product
+            pairs = out[jnp.argsort(order)].reshape(n, k, -1)
+            y = jnp.sum(
+                jnp.where(mine.reshape(n, k, 1), pairs.astype(jnp.float32), 0.0)
+                * gates[..., None], axis=1,
+            )
+        return y.astype(x.dtype), sizes
+

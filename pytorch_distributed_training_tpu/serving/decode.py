@@ -249,6 +249,11 @@ class _PagedFns:
     training anomaly guard, letting the scheduler evict a NaN-producing
     request without a Python exception (padding rows read stale pool
     rows, so only ACTIVE rows' flags are meaningful).
+    For a model with expert layers (``model.moe_shape``) the two
+    decode programs return a FOURTH value, ``moe_stats`` int32 [2]: the
+    experts that received a token this step and the largest count at one
+    expert, each summed over the expert layers; callers that do not want it
+    unpack ``tok, finite, pool, *_``.
     ``verify(params, pool, tokens, positions, block_tables, adapter_ids)
     -> (logits [B, S, V] f32, pool)`` — the speculative-decoding scoring
     program: prefill-shaped (scatters the fed tokens' K/V), but returns
@@ -324,6 +329,13 @@ def build_paged_fns(
         kv_block_size=int(block_size), kv_num_blocks=int(num_blocks),
     )
     has_lora = getattr(paged_model, "lora_adapters", 0) > 0
+    # a model with expert layers (it says so) sows two counts a call into
+    # ``moe_stats``: the decode programs return them as a FOURTH output,
+    # int32 [2] = (experts that got a token, largest count at one expert),
+    # each summed over the expert layers.  A model with none: the programs
+    # and their outputs are what they were.
+    has_experts = getattr(paged_model, "moe_shape", None) is not None
+    mutable = ["cache", "moe_stats"] if has_experts else ["cache"]
     pool_rows = int(num_blocks) * int(block_size)
     # no eos_id here: EOS detection is the HOST's job in paged mode — the
     # scheduler reads every token anyway (to stream it and retire slots),
@@ -331,23 +343,44 @@ def build_paged_fns(
     # (eos / per-request max_new) live in one place
     sample = _make_sampler(temperature)
 
-    def _apply(params, pool, tokens, positions, block_tables, adapter_ids):
+    def _apply(params, pool, tokens, positions, block_tables, adapter_ids,
+               **more):
         args = (tokens, positions, block_tables)
         if has_lora:
             args = args + (adapter_ids,)
         return paged_model.apply(
-            {"params": params, "cache": pool}, *args, mutable=["cache"],
+            {"params": params, "cache": pool}, *args, mutable=mutable, **more,
         )
+
+    def _step_outputs(tok, logits, variables):
+        out = (tok, jnp.isfinite(logits).all(axis=-1), variables["cache"])
+        if has_experts:
+            stats = variables["moe_stats"]
+            out += (jnp.stack([
+                jnp.sum(jnp.stack(stats["experts_hit"])),
+                jnp.sum(jnp.stack(stats["expert_load_max"])),
+            ]).astype(jnp.int32),)
+        return out
 
     @jax.jit
     def prefill(
         params, pool, tokens, positions, block_tables, last_col, row_keys,
         gen_index, adapter_ids=None,
     ):
-        logits, variables = _apply(
-            params, pool, tokens, positions, block_tables, adapter_ids
-        )
-        last = jnp.take_along_axis(logits, last_col[:, None, None], axis=1)[:, 0]
+        if getattr(paged_model, "takes_logit_cols", False):
+            # the model gives the logits of one column a row (a [B, S, V]
+            # in float32 need not fit beside its weights)
+            logits, variables = _apply(
+                params, pool, tokens, positions, block_tables, adapter_ids,
+                logit_cols=last_col,
+            )
+            last = logits[:, 0]
+        else:
+            logits, variables = _apply(
+                params, pool, tokens, positions, block_tables, adapter_ids
+            )
+            last = jnp.take_along_axis(
+                logits, last_col[:, None, None], axis=1)[:, 0]
         tok = sample(last, _token_keys(row_keys, gen_index))
         return tok, jnp.isfinite(last).all(axis=-1), variables["cache"]
 
@@ -363,7 +396,7 @@ def build_paged_fns(
             adapter_ids,
         )
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
-        return tok, jnp.isfinite(logits[:, 0]).all(axis=-1), variables["cache"]
+        return _step_outputs(tok, logits[:, 0], variables)
 
     @jax.jit
     def decode_step_fed(
@@ -382,7 +415,7 @@ def build_paged_fns(
             adapter_ids,
         )
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
-        return tok, jnp.isfinite(logits[:, 0]).all(axis=-1), variables["cache"]
+        return _step_outputs(tok, logits[:, 0], variables)
 
     @jax.jit
     def verify(params, pool, tokens, positions, block_tables, adapter_ids=None):

@@ -1,10 +1,16 @@
 """Paged KV-cache pool: block allocator, prefix cache, admission control.
 
-Host-side bookkeeping for the paged attention mode (ops/attention.py
-``MultiHeadAttention.paged``): device memory is ONE preallocated pool of
-``num_blocks`` blocks of ``block_size`` token rows per layer, and each
-in-flight request owns a list of physical block ids covering its prompt
-plus its whole generation budget.  The vLLM construction (PagedAttention,
+Host-side bookkeeping for the paged attention mode: device memory is ONE
+preallocated pool of ``num_blocks`` blocks of ``block_size`` token rows per
+layer, and each in-flight request owns a list of physical block ids
+covering its prompt plus its whole generation budget.  A row is whatever
+the layer's attention stores for one token, and this module never looks
+inside one; two layouts exist (ops/attention.py ``pool_leaf_role``): the
+K/V PAIR of ``MultiHeadAttention.paged`` (two leaves ``[rows, heads,
+head_dim]``) and the LATENT row of ``ops/mla.py::MLAttention.paged`` (one
+leaf ``[rows, kv_lora_rank + qk_rope_head_dim]`` shared by every head).
+Blocks, tables, prefix keys and admission count rows, so both share this
+code unchanged.  The vLLM construction (PagedAttention,
 Kwon et al. SOSP'23) — cache memory stops being per-batch contiguous
 slabs sized for the worst case and becomes a recyclable heap, which is
 what lets the iteration-level scheduler (serving/scheduler.py) keep

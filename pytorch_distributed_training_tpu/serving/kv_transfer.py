@@ -33,6 +33,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from ..ops.attention import pool_leaf_role
+
 __all__ = [
     "BlockPayload",
     "BlockRef",
@@ -54,22 +56,21 @@ def _path_name(path) -> str:
 
 
 def pool_row_leaves(pool, n_rows: int) -> List[Tuple[str, Any]]:
-    """``(name, leaf)`` for every per-row KV pool leaf, sorted by name.
+    """``(name, leaf)`` for every per-row leaf of the paged pool, sorted by
+    name: a K/V pair's two leaves or a latent cache's one, a layer.
 
-    Identified structurally the same way the chaos SDC injector finds
-    its corruption targets (scheduler ``_corrupt_pool_rows``): leading
-    dimension equal to ``num_blocks * block_size`` and a path naming a
-    k/v pool.  Sorted order makes the leaf set deterministic on both
-    ends of a transfer, which the chained checksum relies on.
+    Found by what the attention modules declare
+    (:func:`..ops.attention.pool_leaf_role`: the variable they made, with
+    the pool's rows in front), the same way the chaos SDC injector finds
+    its corruption targets (scheduler ``_corrupt_pool_rows``).  Sorted
+    order makes the leaf set deterministic on both ends of a transfer,
+    which the chained checksum relies on.
     """
     flat = jax.tree_util.tree_flatten_with_path(pool)[0]
-    out: List[Tuple[str, Any]] = []
-    for path, leaf in flat:
-        name = _path_name(path)
-        if "k_pool" not in name and "v_pool" not in name:
-            continue
-        if hasattr(leaf, "shape") and leaf.ndim >= 1 and leaf.shape[0] == n_rows:
-            out.append((name, leaf))
+    out = [
+        (_path_name(path), leaf) for path, leaf in flat
+        if pool_leaf_role(path, leaf, n_rows)
+    ]
     out.sort(key=lambda kv: kv[0])
     return out
 

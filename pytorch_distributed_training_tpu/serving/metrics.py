@@ -105,6 +105,17 @@ class ServingMetrics:
                 "queue_wait_ms", "ttft_ms", "itl_ms", "prefill_stall_ms",
             )
         }
+        # a model with expert layers (serving/decode.py's fourth output), one
+        # observation a productive tick, each summed over the expert
+        # layers: the experts that got a token, the largest count at one
+        # expert, and that count over the mean count of a held expert
+        self._moe = {
+            name: self._registry.histogram(name, _RESERVOIR)
+            for name in (
+                "moe_experts_hit", "moe_expert_load_max",
+                "moe_load_max_over_mean",
+            )
+        }
         self._items = 0  # guarded by: self._lock
         self._first_t: Optional[float] = None  # guarded by: self._lock
         self._last_t: Optional[float] = None  # guarded by: self._lock
@@ -302,6 +313,20 @@ class ServingMetrics:
         what their next token waited beyond a plain decode step."""
         self._life_ms["prefill_stall_ms"].observe(float(ms))
 
+    def record_moe(self, experts_hit: int, load_max: int, tokens: int,
+                   shape) -> None:
+        """One decode step of a model with expert layers: ``experts_hit``
+        and ``load_max`` summed over its ``shape[0]`` expert layers,
+        ``tokens`` live rows each choosing ``shape[1]`` of the ``shape[2]``
+        experts held."""
+        layers, top_k, held = shape
+        self._moe["moe_experts_hit"].observe(float(experts_hit))
+        self._moe["moe_expert_load_max"].observe(float(load_max))
+        if tokens:
+            self._moe["moe_load_max_over_mean"].observe(
+                (load_max / layers) / (tokens * top_k / held)
+            )
+
     def record_dispatch_gap(self, gap_ms: float) -> None:
         """Host wall time between two consecutive decode dispatch
         enqueues during back-to-back decode ticks.  The sync path's gap
@@ -437,6 +462,12 @@ class ServingMetrics:
                 out[f"{name}_count"] = int(life["count"])
                 out[f"{name}_p50"] = float(life["p50"])
                 out[f"{name}_p95"] = float(life["p95"])
+        for name, hist in self._moe.items():
+            moe = hist.snapshot()
+            if moe["count"]:
+                out[f"{name}_count"] = int(moe["count"])
+                out[f"{name}_mean"] = float(moe["mean"])
+                out[f"{name}_p50"] = float(moe["p50"])
         with self._lock:
             ready_ms = self._scale_up_ready_ms
         if ready_ms is not None:

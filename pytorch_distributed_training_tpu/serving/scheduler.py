@@ -93,6 +93,7 @@ from ..engine import fault
 from ..engine.watchdog import StepWatchdog
 from ..telemetry.registry import get_registry
 from ..telemetry.spans import record as record_span, span
+from ..ops.attention import pool_leaf_role
 from ..ops.quant import quantize_tree
 from . import kv_transfer
 from .batcher import OverloadedError
@@ -285,6 +286,9 @@ class ContinuousScheduler:
         self._lora = lora
         self._spec = speculative
         self._has_lora = getattr(model, "lora_adapters", 0) > 0
+        # (expert layers, experts a token, experts) of a model that has
+        # expert layers, as the model states it; None for any other
+        self._moe_shape = getattr(model, "moe_shape", None)
         if self._lora is not None and not self._has_lora:
             raise ValueError(
                 "a LoRA registry was given but the model has no stacked "
@@ -1392,7 +1396,7 @@ class ContinuousScheduler:
                 gi[i] = k
                 aids[i] = req.adapter
                 keys[i] = req.row_key
-            tok, finite, self._pool = self._fns.decode_step(
+            tok, finite, self._pool, *_ = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables, jnp.stack(keys), gi, aids,
             )
@@ -1466,17 +1470,20 @@ class ContinuousScheduler:
         return req
 
     def _corrupt_pool_rows(self, req: _PagedRequest) -> None:
-        """NaN the KEY-pool row of ``req``'s last WRITTEN position.
+        """NaN the SCORED pool row of ``req``'s last WRITTEN position: the
+        key row of a K/V pair, the one row of a latent cache.
 
         That position's block sits past the prefix-cache registration cap
         ((prompt_len-1)//block_size), so it is exclusively owned — the
-        poison is per-request by construction.  Only ``k_pool`` rows are
-        corrupted: a NaN key makes the OWNER's attention logits NaN
-        (position is live for it) while every other reader — including a
-        later request recycling the freed block — masks it to -inf before
-        the softmax.  A NaN VALUE row would leak through recycling: masked
-        positions get exactly-zero softmax weight, and 0 * NaN is NaN in
-        the value contraction.
+        poison is per-request by construction.  Only leaves a query is
+        scored against are corrupted (:func:`..ops.attention.
+        pool_leaf_role`): a NaN there makes the OWNER's attention logits
+        NaN (position is live for it) while every other reader — including
+        a later request recycling the freed block — masks it to -inf before
+        the softmax and zeroes the dead row before any product.  A NaN in a
+        pair's VALUE row would leak through recycling: masked positions get
+        exactly-zero softmax weight, and 0 * NaN is NaN in the value
+        contraction.
         """
         bs = self._kv.block_size
         p = req.prompt.size + max(req.gen_idx, 1) - 2
@@ -1484,14 +1491,8 @@ class ContinuousScheduler:
         n_rows = self._kv.num_blocks * bs
 
         def corrupt(path, leaf):
-            names = {
-                str(getattr(part, "key", getattr(part, "name", "")))
-                for part in path
-            }
             if (
-                "k_pool" in names
-                and hasattr(leaf, "ndim") and leaf.ndim >= 1
-                and leaf.shape[0] == n_rows
+                pool_leaf_role(path, leaf, n_rows) == "scored"
                 and jnp.issubdtype(leaf.dtype, jnp.floating)
             ):
                 return leaf.at[row].set(jnp.nan)
@@ -1548,7 +1549,7 @@ class ContinuousScheduler:
         # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
         # preceding poison_bisect/serving_restart recovery span)
         with self._phase("decode_step", active=n_active):
-            tok, finite, self._pool = self._fns.decode_step(
+            tok, finite, self._pool, *moe = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables,
                 jnp.stack(keys), gen_idx, aids,
@@ -1557,6 +1558,7 @@ class ContinuousScheduler:
         with self._phase("readback"):
             tok = np.asarray(tok)
             finite = np.asarray(finite)
+            self._record_moe(moe, n_active)
         t1 = time.perf_counter()
         self._tick_block_s += t1 - rb0
         with self._phase("deliver"):
@@ -1577,6 +1579,14 @@ class ContinuousScheduler:
             total_blocks=self._kv.num_blocks,
         )
 
+    def _record_moe(self, moe, n_rows: int) -> None:
+        """File a decode step's expert counts (``decode.py``: the fourth
+        output of a model with expert layers, ``[]`` for any other), read
+        back beside the step's tokens."""
+        if moe:
+            hit, load_max = (int(v) for v in np.asarray(moe[0]))
+            self.metrics.record_moe(hit, load_max, n_rows, self._moe_shape)
+
     def _decode_probe(self, reqs: List[_PagedRequest]) -> None:
         """Re-drive the decode dispatch for a SUBSET of the active slots —
         the supervisor's bisect primitive.  Inputs are identical to the
@@ -1584,7 +1594,7 @@ class ContinuousScheduler:
         pure: probing commits nothing the real step would not."""
         self._poison_shim(reqs)
         prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(reqs)
-        tok, _, self._pool = self._fns.decode_step(
+        tok, _, self._pool, *_ = self._fns.decode_step(
             self._qparams if self._quant else self.params,
             self._pool, prev, pos, tables,
             jnp.stack(keys), gen_idx, aids,
@@ -1652,7 +1662,7 @@ class ContinuousScheduler:
                 prev = self._zero_carry()
             self._note_dispatch_gap()
             with self._phase("decode_step", active=len(disp)):
-                tok, finite, self._pool = self._fns.decode_step_fed(
+                tok, finite, self._pool, *moe = self._fns.decode_step_fed(
                     self._qparams if self._quant else self.params,
                     self._pool, prev, fresh_mask, fresh_tok, pos, tables,
                     jnp.stack(keys), gen_idx, aids,
@@ -1660,7 +1670,7 @@ class ContinuousScheduler:
             for req in disp:
                 req.dispatched += 1
             self._carry_tok = tok
-            self._inflight.append((tok, finite, rows))
+            self._inflight.append((tok, finite, rows, moe))
             self.metrics.record_iteration(
                 active_slots=len(disp), total_slots=self.slots_n,
                 blocks_in_use=self._kv.blocks_in_use,
@@ -1741,10 +1751,11 @@ class ContinuousScheduler:
         host stream was rolled back since dispatch are discarded — their
         token was never part of the committed stream.  Returns the
         number of tokens pushed."""
-        tok_dev, finite_dev, rows = entry
+        tok_dev, finite_dev, rows, moe = entry
         with self._phase("readback"):
             tok = np.asarray(tok_dev)
             finite = np.asarray(finite_dev)
+            self._record_moe(moe, len(rows))
         pushed = 0
         with self._phase("deliver"):
             for req, slot, idx in rows:
@@ -1869,7 +1880,7 @@ class ContinuousScheduler:
                         aids[i] = req.adapter
                 if not any_row:
                     break
-                tok, _, self._draft_pool = self._draft_fns.decode_step(
+                tok, _, self._draft_pool, *_ = self._draft_fns.decode_step(
                     self._draft_params, self._draft_pool, prev, pos, dtables,
                     pad_keys, gi, aids,
                 )

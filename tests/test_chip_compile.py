@@ -141,3 +141,27 @@ def test_kernel_compiles_for_v5e(chip, name):
     assert sorted(mosaic) == sorted(kernels), (
         f"{name}: the compiled program's Mosaic calls are named {mosaic}"
     )
+
+
+@pytest.mark.parametrize("tokens", [32, 2048], ids=["decode_32_rows", "prefill_2048"])
+def test_grouped_products_of_the_expert_layer_compile_for_v5e(chip, tokens, monkeypatch):
+    """The dropless expert layer at DeepSeek-V2-Lite's widths (64 experts of
+    2048 x 1408, six a token): its two grouped products are the megablox
+    Pallas kernel, under the scope the benchmark's readers look for."""
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.ops.moe import DroplessMoE
+
+    # the layer asks ``jax.default_backend()``, which is the CPU here
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    layer = DroplessMoE(dim=2048, num_experts=64, top_k=6, hidden=1408,
+                        shared_hidden=2816, dtype=BF16)
+    x = jax.ShapeDtypeStruct((tokens, 2048), BF16, sharding=chip)
+    shapes = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((8, 2048), BF16)))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
+    text = jax.jit(layer.apply).lower(params, x).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all("/moe_gmm/" in line and "pallas_call" in line for line in calls)

@@ -79,7 +79,7 @@ def test_all_shipped_configs_validate_against_generated_schema():
     """pdt-analyze's config-schema pass infers the accepted key/type
     surface from the parse_*/from_config sites and statically validates
     the shipped YAMLs: no unknown keys in closed sections, no type
-    mismatches, no dead allow-set keys.  Pin all 15 configs clean."""
+    mismatches, no dead allow-set keys.  Pin all 16 configs clean."""
     import pathlib
 
     from pytorch_distributed_training_tpu.analysis import core
@@ -87,7 +87,7 @@ def test_all_shipped_configs_validate_against_generated_schema():
 
     repo = pathlib.Path(__file__).parent.parent
     pkg = repo / "pytorch_distributed_training_tpu"
-    assert len(list((repo / "config").glob("*.yml"))) == 15
+    assert len(list((repo / "config").glob("*.yml"))) == 16
     ctx = core.AnalysisContext(package_root=pkg, repo_root=repo)
     findings = ConfigSchemaPass().run(core.collect_modules(pkg, repo), ctx)
     assert findings == [], "\n".join(f.format() for f in findings)

@@ -54,6 +54,33 @@ def lm_and_params():
     return model, params
 
 
+def small_latent_lm():
+    """The second LM family at a toy size: latent (MLA) rows in the pool,
+    dropless experts (models/deepseek_v2.py)."""
+    from pytorch_distributed_training_tpu.models import get_model
+
+    return get_model(
+        "DeepseekV2", num_classes=VOCAB, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2, num_attention_heads=4,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
+        max_position_embeddings=32,
+    )
+
+
+@pytest.fixture(scope="module", params=["kv_pair", "latent"])
+def family_and_params(request, lm_and_params):
+    """Both layouts of a pool row: the K/V pair of ``TransformerLM`` and the
+    one latent leaf of the ``DeepseekV2`` family."""
+    if request.param == "kv_pair":
+        return lm_and_params
+    model = small_latent_lm()
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
 def _prompts(seed=3, lens=(2, 6, 4)):
     rng = np.random.default_rng(seed)
     return [rng.integers(2, VOCAB, ln).astype(np.int32) for ln in lens]
@@ -169,10 +196,12 @@ def test_poison_isolation_decode_raise(lm_and_params):
     assert sched._kv.blocks_in_use == 0
 
 
-def test_poison_isolation_nan_output_guard(lm_and_params):
+def test_poison_isolation_nan_output_guard(family_and_params):
     """serve_nan: the on-device isfinite guard evicts the NaN emitter
-    with NO Python exception; other rows stay bit-exact."""
-    model, params = lm_and_params
+    with NO Python exception; other rows stay bit-exact.  Holds for a
+    K/V-pair pool and for a latent one: the row poisoned is read only by
+    its owner."""
+    model, params = family_and_params
     _, clean = _run_under_spec(model, params, None, prefix_cache=False)
     ref = [f.result()["tokens"] for f in clean]
 
@@ -191,10 +220,10 @@ def test_poison_isolation_nan_output_guard(lm_and_params):
     assert sched._kv.blocks_in_use == 0
 
 
-def test_poisoned_blocks_recycle_cleanly(lm_and_params):
+def test_poisoned_blocks_recycle_cleanly(family_and_params):
     """A NaN-poisoned request's freed blocks must be reusable: requests
     admitted AFTER the eviction decode on recycled blocks bit-exactly."""
-    model, params = lm_and_params
+    model, params = family_and_params
     model_ref, clean = _run_under_spec(model, params, None, prefix_cache=False)
     ref = [f.result()["tokens"] for f in clean]
 
