@@ -17,9 +17,20 @@ Kernel structure: grid ``(BH, S/block_q)``; each instance holds its Q tile
 plus the FULL K/V rows for that (batch, head) in VMEM (S·D f32 ≤ ~2MB for
 S=4096, D=128 — the dispatch gate in ops/attention.py falls back to XLA
 when the estimate would overflow VMEM) and runs a ``fori_loop`` over K
-blocks carrying ``(m, l, acc)`` in registers.  Causal masking also BOUNDS
-the loop — K blocks entirely above the diagonal are never visited, so the
-causal forward does ~half the FLOPs, not masked-full work.
+blocks carrying ``(m, l, acc)`` in registers.
+
+Causal work follows the diagonal (``_diag_walk``), in the resident forward
+and the fused backward alike.  K tiles wholly under a Q tile's diagonal run
+the loop body with no mask arithmetic at all.  The ``block_q`` x
+``block_q`` square on the diagonal is walked in blocks of ``sub`` columns:
+each block is ONE step over every row from the block's first down, only the
+``sub`` x ``sub`` sub-tile the diagonal crosses builds the iota mask, and the
+sub-tiles above it are not visited.  Rows of a Q tile are independent in the
+online softmax, so the rows a step completes are written out and leave the
+carry.  At S=2048 the kernels compute scores for 1.125x the ``S (S + 1) / 2``
+pairs on and under the diagonal (``_causal_pairs``), 1.5x before, and mask
+2048 x 256 of them, every visited pair before.  Without ``causal`` the
+kernels are one loop over K tiles with no mask, as they always were.
 
 Backward is the standard flash recomputation wired through
 ``jax.custom_vjp``.  For resident shapes it is ONE fused kernel
@@ -66,17 +77,35 @@ from jax.experimental import pallas as pl
 __all__ = ["flash_attention", "flash_attention_lse", "flash_shapes_ok", "flash_enabled"]
 
 _NEG = -1e30  # finite mask value; see module docstring
-# Preferred tile sizes, swept on the bench chip (v5e, S=2048, D=64, bf16,
-# causal fwd+bwd): (256, 512) measured 10.4ms vs 16.3ms for (128, 128) —
-# a 1.57x kernel speedup from fewer grid steps and larger MXU feeds.
-# ``_blocks`` halves them until they divide the sequence, so any
-# 128-multiple (and tiny interpreter-test shapes) still works.
-# Round-4 sweep on the bench chip at the LM bench attention shape
-# (B4 H16 S2048 D64, fwd+bwd, chained timing): 256/512 6.40ms (the round-2
-# default), 512/512 5.92, 512/1024 5.15, 1024/512 5.21, **1024/1024
-# 5.12ms** — 1.25x; 2048-row tiles exceed VMEM.  Larger tiles win because
-# D=64 underfills the MXU contraction, so per-tile overheads (grid steps,
-# m/l bookkeeping) amortize over more rows.
+# Preferred tile sizes; ``_pick_block`` halves them until they divide the
+# sequence, so any 128-multiple (and tiny interpreter-test shapes) still
+# works.  Swept on a TPU v5e (``.bench_flash_tiles.py``: BH 64, bf16,
+# causal, calls chained inside one jit, ms a call; "bwd" is forward +
+# backward less the forward, so it holds 0.32 ms of XLA around the kernel):
+#
+#   S 2048             block_q x block_k, sub    D 128  fwd / bwd    D 64
+#   before the walk    1024 x 1024 / 512 x 1024  1.006 / 1.799   1.002 / 1.812
+#   walk, old tiles    same, 256                 0.758 / 1.855   0.754 / 1.849
+#                      same, 512                 0.747 / 1.803   0.742 / 1.795
+#   bwd turned         1024 x 512, 256 | 512         - / 1.603 | 1.597
+#   ONE Q tile         2048, 128                 0.726 / 2.151
+#                      2048, 256                 0.533 / 1.461   0.524 / 1.474
+#                      2048, 512                 0.569 / 1.483   0.557 / 1.495
+#   S 1024, one tile   1024, 256 (before)        0.193 / 0.468 (0.335 / 0.617)
+#   S 4096, fwd only   1024 x 1024 | 512 | 256, sub 256: 1.273 | 1.326 | 1.576
+#                      (before 1.520; a 2048-row tile overflows VMEM there)
+#
+# What the rows say.  D 64 times as D 128: the MXU pads either to 128.  A step
+# costs by its COLUMNS far more than by its rows (presumably each K/V block
+# is transposed and loaded as MXU weights once a step, whatever the rows):
+# so steps are tall, every row under a column block in one product, and a Q
+# tile is the whole sequence while that fits VMEM (``_BLOCK_Q_WHOLE``).
+# Sub-tiles under 256 lose more to the count of steps than they save in
+# pairs; 512 visits 1.25x the causal pairs for 256's 1.125x.  The mask
+# itself is cheap (masking every row of a step, not its top sub-tile: 0.537
+# / 1.470).  Past ``_BLOCK_Q_WHOLE`` the tiles under the diagonal stay wide:
+# a [1024, 256] tile repeats the per-row softmax bookkeeping four times as
+# often as a [1024, 1024] one.
 # Grid-dimension semantics for Mosaic (ADVICE r3 #1): the batch*heads and
 # row-block dims are embarrassingly parallel — marking them lets megacore
 # parts (v4/v5p: 2 TensorCores/chip) split the grid; only the dim a VMEM
@@ -93,10 +122,16 @@ _BLOCK_K = 1024
 # The FUSED backward keeps s/p/dp/ds (plus their bf16 dot copies) live in
 # one kernel body — at 1024x1024 those f32 tiles alone are ~16MB and Mosaic
 # OOMs the 16MB scoped-VMEM stack (measured: 16.74M at S=2048 D=64 BH=64).
-# Halving the Q tile halves every [bq, bk] intermediate; swept on the bench
-# chip (see PERF.md round 5).
+# Halving the Q tile halves every [bq, bk] intermediate (causal sequences
+# past ``_BLOCK_Q_WHOLE`` run it at 512 x 512, ``_tiles``).
 _BLOCK_Q_FUSED = 512
 _BLOCK_K_FUSED = 1024
+# Columns of one step of the square on the diagonal, and the longest causal
+# sequence that is ONE Q tile (bf16 dots: q, o, K, V, dK, dV and the [2048,
+# 256] f32 step tiles fit the 16MB scoped VMEM at D <= 128; at S=4096 a
+# 2048-row tile does not).
+_BLOCK_DIAG = 256
+_BLOCK_Q_WHOLE = 2048
 # Tiles for f32-operand dots; see ``_blocks``.
 _BLOCK_F32 = 512
 # VMEM budget for the RESIDENT kernels' K/V rows (f32): each instance holds
@@ -177,40 +212,56 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+def _diag_walk(block_q, sub):
+    """The ``block_q`` x ``block_q`` square on the diagonal as a static
+    schedule of steps ``(row0, rows, col0, cols)``, offsets relative to the
+    square: a block of ``sub`` columns is taken by every row from its first
+    down, in ONE step; what lies above it is not in the schedule.  The
+    diagonal crosses only the step's top ``cols`` rows: they alone are
+    masked, and they are complete once the step is done."""
+    return [(c0, block_q - c0, c0, sub) for c0 in range(0, block_q, sub)]
+
+
+def _mask_above_diagonal(s):
+    """``s``: the scores of one step of :func:`_diag_walk`, ``[rows, cols]``
+    with the diagonal through its top ``cols`` x ``cols`` square; the rows
+    under that square pass through untouched."""
+    rows, cols = s.shape
+    top = s[:cols]
+    on_or_under = jax.lax.broadcasted_iota(jnp.int32, top.shape, 0) >= (
+        jax.lax.broadcasted_iota(jnp.int32, top.shape, 1)
+    )
+    top = jnp.where(on_or_under, top, _NEG)
+    return top if rows == cols else jnp.concatenate([top, s[cols:]], axis=0)
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref,
-    *, scale, causal, block_q, block_k, bf16_dots,
+    *, scale, causal, block_q, block_k, sub, bf16_dots,
 ):
     i = pl.program_id(1)
     s_len = k_ref.shape[1]
-    nk = s_len // block_k
-    if bf16_dots:
-        q = q_ref[0]  # bf16 into the MXU; scale folds into s below
-    else:
-        q = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
+    d = q_ref.shape[-1]
 
-    if causal:
-        # K blocks strictly above this Q tile's last row never contribute
-        nj = jnp.minimum(nk, ((i + 1) * block_q + block_k - 1) // block_k)
-    else:
-        nj = nk
+    def load_q(rows):
+        if bf16_dots:
+            return q_ref[0, rows, :]  # bf16 into the MXU; scale folds into s
+        return q_ref[0, rows, :].astype(jnp.float32) * scale
 
-    def body(j, carry):
+    def step(q, col0, cols, carry, masked=False):
         m_prev, l_prev, acc = carry
-        kb = k_ref[0, pl.ds(j * block_k, block_k), :]
-        vb = v_ref[0, pl.ds(j * block_k, block_k), :]
+        kb = k_ref[0, pl.ds(col0, cols), :]
+        vb = v_ref[0, pl.ds(col0, cols), :]
         if not bf16_dots:
             kb = kb.astype(jnp.float32)
             vb = vb.astype(jnp.float32)
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
+        )  # [rows, cols]
         if bf16_dots:
             s = s * scale
-        if causal:
-            qg = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            kg = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(qg >= kg, s, _NEG)
+        if masked:
+            s = _mask_above_diagonal(s)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])
@@ -221,15 +272,35 @@ def _fwd_kernel(
         )
         return m_new, l_new, acc
 
-    d = q_ref.shape[-1]
-    carry0 = (
+    def finish(rows, carry):
+        m, l, acc = carry
+        o_ref[0, rows, :] = (acc / l[:, None]).astype(o_ref.dtype)
+        lse_ref[0, rows, 0] = m + jnp.log(l)
+
+    carry = (
         jnp.full((block_q,), _NEG, jnp.float32),
         jnp.zeros((block_q,), jnp.float32),
         jnp.zeros((block_q, d), jnp.float32),
     )
-    m, l, acc = jax.lax.fori_loop(0, nj, body, carry0)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :, 0] = m + jnp.log(l)
+    # K tiles wholly under the diagonal (every tile when not causal): all
+    # rows of the Q tile at once, no mask arithmetic; a Q tile that is the
+    # whole sequence has none, and no loop is built for it
+    n_full = (i * block_q) // block_k if causal else s_len // block_k
+    if not causal or block_q < s_len:
+        q = load_q(slice(None))
+        carry = jax.lax.fori_loop(
+            0, n_full, lambda j, c: step(q, j * block_k, block_k, c), carry
+        )
+    if not causal:
+        finish(slice(None), carry)
+        return
+    # the square on the diagonal: rows of a Q tile are independent in the
+    # online softmax, so the rows a step completes leave the carry
+    for row0, rows, col0, cols in _diag_walk(block_q, sub):
+        start = pl.multiple_of(i * block_q + col0, sub)
+        carry = step(load_q(slice(row0, row0 + rows)), start, cols, carry, True)
+        finish(slice(row0, row0 + cols), tuple(x[:cols] for x in carry))
+        carry = tuple(x[cols:] for x in carry)
 
 
 def _dq_kernel(
@@ -279,7 +350,7 @@ def _dq_kernel(
 
 def _dqkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-    *, scale, causal, block_q, block_k, bf16_dots,
+    *, scale, causal, block_q, block_k, sub, bf16_dots,
 ):
     """Fused backward: one pass over the (Q tile, K tile) pairs produces dQ,
     dK AND dV.  Grid is (BH, S/block_q) with the Q-tile dim sequential
@@ -288,31 +359,29 @@ def _dqkv_kernel(
     (batch, head) and the kernel accumulates into them in place (zeroed at
     the first Q tile).  ``s``/``p``/``dp``/``ds`` are computed once per
     visited tile pair — the split path computes them twice (once in each
-    pass).  Accumulation order over tiles is identical to the split
+    pass).  Causal work follows the diagonal exactly as in ``_fwd_kernel``;
+    without ``causal`` the accumulation order over tiles is the split
     kernels' (ascending i for dK/dV, ascending j for dQ, f32 adds), so the
-    results are bitwise-equal to the split path (pinned in
+    results are bitwise-equal to the split path there (pinned in
     tests/test_flash_attention.py)."""
     i = pl.program_id(1)
     s_len = k_ref.shape[1]
-    nk = s_len // block_k
+    d = q_ref.shape[-1]
 
     @pl.when(i == 0)
     def _init():
         dk_ref[...] = jnp.zeros(dk_ref.shape, dk_ref.dtype)
         dv_ref[...] = jnp.zeros(dv_ref.shape, dv_ref.dtype)
 
-    q = q_ref[0] if bf16_dots else q_ref[0].astype(jnp.float32)
-    do = do_ref[0] if bf16_dots else do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
-    nj = (
-        jnp.minimum(nk, ((i + 1) * block_q + block_k - 1) // block_k)
-        if causal
-        else nk
-    )
+    def load(rows):
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        if not bf16_dots:
+            q, do = q.astype(jnp.float32), do.astype(jnp.float32)
+        return q, do, lse_ref[0, rows, 0], delta_ref[0, rows, 0]
 
-    def body(j, dq):
-        ks = pl.ds(j * block_k, block_k)
+    def step(operands, col0, cols, dq, masked=False):
+        q, do, lse, delta = operands
+        ks = pl.ds(col0, cols)
         kb = k_ref[0, ks, :]
         vb = v_ref[0, ks, :]
         if not bf16_dots:
@@ -321,11 +390,9 @@ def _dqkv_kernel(
         s = scale * jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        if causal:
-            qg = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            kg = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(qg >= kg, s, _NEG)
-        p = jnp.exp(s - lse[:, None])  # [bq, bk]
+        if masked:
+            s = _mask_above_diagonal(s)
+        p = jnp.exp(s - lse[:, None])  # [rows, cols]
         pc = p.astype(jnp.bfloat16) if bf16_dots else p
         dv_ref[0, ks, :] = dv_ref[0, ks, :] + jax.lax.dot_general(
             pc, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -342,9 +409,21 @@ def _dqkv_kernel(
             dsc, kb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    d = q_ref.shape[-1]
-    dq = jax.lax.fori_loop(0, nj, body, jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    n_full = (i * block_q) // block_k if causal else s_len // block_k
+    dq = jnp.zeros((block_q, d), jnp.float32)
+    if not causal or block_q < s_len:
+        tile = load(slice(None))
+        dq = jax.lax.fori_loop(
+            0, n_full, lambda j, acc: step(tile, j * block_k, block_k, acc), dq
+        )
+    if not causal:
+        dq_ref[0] = dq.astype(dq_ref.dtype)
+        return
+    for row0, rows, col0, cols in _diag_walk(block_q, sub):
+        start = pl.multiple_of(i * block_q + col0, sub)
+        dq = step(load(slice(row0, row0 + rows)), start, cols, dq, True)
+        dq_ref[0, row0:row0 + cols, :] = dq[:cols].astype(dq_ref.dtype)
+        dq = dq[cols:]
 
 
 def _dkv_kernel(
@@ -595,6 +674,46 @@ def _blocks_fused(s_len: int):
     return _pick_block(_BLOCK_Q_FUSED, s_len), _pick_block(_BLOCK_K_FUSED, s_len)
 
 
+def _tiles(s_len: int, bf16_dots: bool, causal: bool, fused: bool = False):
+    """``(block_q, block_k, sub)`` of the resident forward, or of the fused
+    backward.  Causal: ``block_k`` is the width of a K tile wholly under a Q
+    tile's diagonal, ``sub`` that of a column block of the square on it
+    (``_diag_walk``).  With bf16 dots a causal sequence of up to
+    ``_BLOCK_Q_WHOLE`` is ONE Q tile, so every step has all the rows under
+    its column block.  Longer ones keep the Q tile they had and a K tile no
+    wider than it, so that the tiles under the diagonal end where the
+    square begins (no more VMEM than before: the fused backward at S=3072
+    D=64 compiles at 512 x 1024 and at 512 x 512, not at 1024 x 512).
+    Not causal: the preferred pair and no ``sub``."""
+    bq, bk = _blocks_fused(s_len) if fused else _blocks(s_len, bf16_dots)
+    if not causal:
+        return bq, bk, None
+    if bf16_dots and s_len <= _BLOCK_Q_WHOLE:
+        sub = _pick_block(_BLOCK_DIAG, s_len)
+        return s_len, sub, sub
+    return bq, min(bk, bq), _pick_block(_BLOCK_DIAG, bq)
+
+
+def _causal_pairs(s_len: int, causal: bool, tiles):
+    """``(visited, masked)``: the query-key pairs of one head that the
+    resident forward or the fused backward computes scores for at
+    ``tiles = (block_q, block_k, sub)``, and those of them that go through
+    the mask's iota, compare and select.  The schedule is the kernels' own
+    (``_diag_walk`` and the bound on the full tiles); ``S (S + 1) / 2`` pairs
+    are needed."""
+    block_q, block_k, sub = tiles
+    visited = masked = 0
+    for i in range(s_len // block_q):
+        if not causal:
+            visited += block_q * s_len
+            continue
+        visited += block_q * ((i * block_q) // block_k) * block_k
+        for _, rows, _, cols in _diag_walk(block_q, sub):
+            visited += rows * cols
+            masked += cols * cols
+    return visited, masked
+
+
 @functools.lru_cache(maxsize=None)
 def _make(
     causal: bool, interpret: bool, scale: float, out_f32: bool = False,
@@ -649,14 +768,21 @@ def _make(
             name="flash_fwd_stream",
         )(q, k, v)
 
+    # jitted: every layer of a model calls this with the same shapes, and a
+    # jit is traced and lowered ONCE for them.  On a TPU the lowering of a
+    # pallas_call runs Mosaic's passes over the unrolled kernel, which no
+    # persistent cache skips: un-jitted, the 32 calls of the 271M LM's step
+    # cost every launch 44 s of lowering (4.7 s before the kernels' causal
+    # steps were unrolled).  Only the kernels are inside.
+    @jax.jit
     def _forward(q, k, v):
         if stream:
             return _forward_stream(q, k, v)
         bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len, bf16_dots)
+        bq, bk, sub = _tiles(s_len, bf16_dots, causal)
         kern = functools.partial(
             _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-            bf16_dots=bf16_dots,
+            sub=sub, bf16_dots=bf16_dots,
         )
         row = lambda b, i: (b, i, 0)  # noqa: E731
         full = lambda b, i: (b, 0, 0)  # noqa: E731
@@ -767,8 +893,6 @@ def _make(
             return attn_bwd_stream(res, cts)
         q, k, v, o, lse = res
         g, g_lse = cts  # cotangents for (o, lse)
-        bh, s_len, d = q.shape
-        bq, bk = _blocks(s_len, bf16_dots)
         # d(lse)/d(s) = p, so an lse cotangent folds into the kernels as a
         # shift of delta: ds = p * (dp - (delta - g_lse)) — this is what
         # makes the ring-attention combine (which consumes lse) exactly
@@ -777,20 +901,32 @@ def _make(
             g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
         )  # [bh, s, 1] (3-D for the same Mosaic block rule as lse)
         delta = delta - g_lse.astype(jnp.float32)
+        bh, s_len, d = q.shape
+        fused = _fused_bwd_ok(
+            s_len, d, jnp.dtype(q.dtype).itemsize, bf16_dots, interpret
+        )
+        dq, dk, dv = _backward(q, k, v, g, lse, delta, fused=fused)
+        # the fused kernel hands dK/dV over in f32: the same single
+        # end-rounding as the split path's
+        return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+    # the kernels alone, jitted for the reason given at ``_forward``
+    @functools.partial(jax.jit, static_argnames="fused")
+    def _backward(q, k, v, g, lse, delta, *, fused):
+        bh, s_len, d = q.shape
+        bq, bk = _blocks(s_len, bf16_dots)
         row = lambda b, i: (b, i, 0)  # noqa: E731
         full = lambda b, i: (b, 0, 0)  # noqa: E731
-        if _fused_bwd_ok(
-            s_len, d, jnp.dtype(q.dtype).itemsize, bf16_dots, interpret
-        ):
+        if fused:
             # One pass: dK/dV accumulate into revisited f32 output blocks
             # (VMEM-resident across the Q-tile grid dim, which must
             # therefore be sequential) and are cast to the primal dtype
-            # outside — the same single end-rounding as the split path.
-            bq, bk = _blocks_fused(s_len)
-            dq, dk32, dv32 = pl.pallas_call(
+            # by the caller.
+            bq, bk, sub = _tiles(s_len, bf16_dots, causal, fused=True)
+            return pl.pallas_call(
                 functools.partial(
                     _dqkv_kernel, scale=scale, causal=causal, block_q=bq,
-                    block_k=bk, bf16_dots=bf16_dots,
+                    block_k=bk, sub=sub, bf16_dots=bf16_dots,
                 ),
                 grid=(bh, s_len // bq),
                 compiler_params=_sem("parallel", "arbitrary"),
@@ -815,7 +951,6 @@ def _make(
                 interpret=interpret,
                 name="flash_bwd",
             )(q, k, v, g, lse, delta)
-            return dq, dk32.astype(k.dtype), dv32.astype(v.dtype)
         dq = pl.pallas_call(
             functools.partial(
                 _dq_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,

@@ -77,6 +77,11 @@ def _bias_gelu(u, bias):
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 QKV_2K = [((8, 2048, 8, 128), None)] * 3
 QKV_16K = [((1, 16384, 8, 128), None)] * 3
+# causal sequences past one whole-sequence Q tile (2048 rows): the forward
+# keeps 1024-row tiles (a 2048-row tile overflows VMEM at 4096), the fused
+# backward 512 x 512 (1024 x 512 overflows it at 3072 x 64)
+QKV_4K = [((4, 4096, 8, 128), None)] * 3
+QKV_3K_D64 = [((4, 3072, 16, 64), None)] * 3
 
 # (fn, [(shape, dtype or None for the case dtype)], case dtype, the kernels
 # of the compiled program by their ``name=``)
@@ -84,6 +89,10 @@ CASES = {
     "flash_fwd_bf16": (_flash_fwd, QKV_2K, BF16, ["flash_fwd"]),
     "flash_fused_bwd_bf16": (
         _flash_fwd_bwd, QKV_2K, BF16, ["flash_fwd", "flash_bwd"],
+    ),
+    "flash_fwd_bf16_4096": (_flash_fwd, QKV_4K, BF16, ["flash_fwd"]),
+    "flash_fused_bwd_bf16_3072x64": (
+        _flash_fwd_bwd, QKV_3K_D64, BF16, ["flash_fwd", "flash_bwd"],
     ),
     "flash_split_bwd_f32": (
         _flash_fwd_bwd, QKV_2K, F32,

@@ -19,12 +19,40 @@ from pytorch_distributed_training_tpu.ops.flash_attention import flash_attention
 B, S, H, D = 2, 256, 4, 32
 
 
-def _qkv(seed=0, s=S):
+def _qkv(seed=0, s=S, b=B, h=H, d=D):
     rng = np.random.default_rng(seed)
     return tuple(
-        jnp.asarray(rng.normal(size=(B, s, H, D)).astype(np.float32))
+        jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
         for _ in range(3)
     )
+
+
+def _assert_matches_naive(q, k, v, causal, atol=2e-5, gatol=5e-5):
+    """Forward and dq/dk/dv of the kernels (interpreter) against the naive
+    path on the same inputs, upcast to f32 where they are bf16."""
+    f32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    ref = dot_product_attention(*f32, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), atol=atol
+    )
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+    g_ref = jax.grad(
+        loss(lambda q, k, v: dot_product_attention(q, k, v, causal=causal)),
+        argnums=(0, 1, 2),
+    )(*f32)
+    g_fa = jax.grad(
+        loss(lambda q, k, v: flash_attention(q, k, v, causal=causal, interpret=True)),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b, name in zip(g_ref, g_fa, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(b, np.float32), np.asarray(a), atol=gatol, err_msg=f"d{name}"
+        )
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -62,39 +90,22 @@ def test_backward_matches_naive(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_multi_k_block_online_softmax(causal):
-    """S=1536 = 3 K blocks of 512 x 6 Q tiles of 256 (the production
-    asymmetric tile pair): the online-softmax rescaling across K blocks
-    (m/l carry), the causal nj loop bound, and the dkv i0 start all run
-    multiple iterations — forward AND all three grads vs the naive
-    reference (the r2 review caught the 512 tile silently single-blocking
-    the old S=384 version of this test)."""
+    """S=1536 in f32: three 512 x 512 tiles a side, so the online-softmax
+    rescaling across K blocks (m/l carry), the full tiles under the
+    diagonal and the two column blocks of each square on it all run (the
+    fused backward takes the same tiles here) — forward AND all three grads
+    vs the naive reference (the r2 review caught the 512 tile silently
+    single-blocking the old S=384 version of this test)."""
     q, k, v = _qkv(seed=2, s=1536)
-    ref = dot_product_attention(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-    g_ref = jax.grad(
-        lambda q, k, v: jnp.sum(jnp.sin(dot_product_attention(q, k, v, causal=causal))),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    g_fa = jax.grad(
-        lambda q, k, v: jnp.sum(
-            jnp.sin(flash_attention(q, k, v, causal=causal, interpret=True))
-        ),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    for a, b, name in zip(g_ref, g_fa, "qkv"):
-        np.testing.assert_allclose(
-            np.asarray(b), np.asarray(a), atol=5e-5, err_msg=f"d{name}"
-        )
+    _assert_matches_naive(q, k, v, causal=causal)
 
 
 def test_halved_tile_fallback():
-    """S=384: bq falls back to 128 (256 does not divide) while bk becomes a
-    whole-array tile — the mixed fallback geometry must stay exact."""
+    """S=384: both tiles become one whole-array 384 tile (1024 and 512 do not
+    divide it), which IS the diagonal, walked in three chunks of 128 rows
+    (256 does not divide 384) — forward and all three grads stay exact."""
     q, k, v = _qkv(seed=2, s=384)
-    ref = dot_product_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    _assert_matches_naive(q, k, v, causal=True)
 
 
 def test_bf16_inputs():
@@ -221,20 +232,28 @@ def test_streamed_backward_matches_naive(causal, force_stream):
         )
 
 
-def test_streamed_matches_resident_bitwise(force_stream):
-    """Same blocks, same f32 accumulate order => the streamed kernels are
-    not just close to the resident ones, they are IDENTICAL (the grid-dim
-    loop visits K tiles in the same order as the in-kernel fori_loop)."""
+@pytest.mark.parametrize("causal", [False, True])
+def test_streamed_matches_resident_bitwise(causal, force_stream):
+    """Same blocks, same f32 accumulate order => without a mask the streamed
+    kernels are not just close to the resident ones, they are IDENTICAL (the
+    grid-dim loop visits K tiles in the same order as the in-kernel
+    fori_loop).  Causal, the resident forward walks its one 512 tile, which
+    IS the diagonal, in two column blocks where the streamed one takes it
+    whole: other partial sums per row, so equal to f32 rounding only (bitwise
+    until PR 28)."""
     from pytorch_distributed_training_tpu.ops import flash_attention as fa
 
     q, k, v = _qkv(seed=9, s=512)
-    o_stream = np.asarray(flash_attention(q, k, v, causal=True, interpret=True))
+    o_stream = np.asarray(flash_attention(q, k, v, causal=causal, interpret=True))
     fa._make.cache_clear()
     import os
 
     del os.environ["PDT_FLASH_FORCE_STREAM"]
-    o_res = np.asarray(flash_attention(q, k, v, causal=True, interpret=True))
-    np.testing.assert_array_equal(o_stream, o_res)
+    o_res = np.asarray(flash_attention(q, k, v, causal=causal, interpret=True))
+    if causal:
+        np.testing.assert_allclose(o_stream, o_res, rtol=1e-5, atol=1e-6)
+    else:
+        np.testing.assert_array_equal(o_stream, o_res)
 
 
 def test_streamed_lse_grad(force_stream):
@@ -288,12 +307,16 @@ def split_bwd(monkeypatch):
 def test_fused_bwd_matches_split_bitwise(causal, dtype, split_bwd, monkeypatch):
     """Fused and split backwards accumulate the same per-tile f32 values in
     the same ascending order with one end-rounding each => bitwise-equal
-    grads, in both dot-precision modes (s=1536 runs multiple tile pairs
-    incl. the causal loop bounds on both sides).  The split path is pinned
-    to the fused path's tile pair: tile geometry determines f32 summation
-    ORDER, so bitwise equality is only defined at matching tiles (the
-    production defaults differ — fused halves the Q tile for scoped VMEM;
-    cross-tile agreement is covered by the naive-reference tolerances)."""
+    grads without a mask, in both dot-precision modes (s=1536 runs multiple
+    tile pairs).  The split path is pinned to the fused path's tile pair:
+    tile geometry determines f32 summation ORDER, so bitwise equality is
+    only defined at matching tiles (the production defaults differ — fused
+    halves the Q tile for scoped VMEM; cross-tile agreement is covered by
+    the naive-reference tolerances).  Causal, the fused kernel follows the
+    diagonal (one Q tile of 1536 rows, column blocks of 256) and the split
+    pair, which runs in no cell, still masks whole tiles: the same pairs in
+    other partial sums, so the two causal cases hold to the rounding of one
+    result — f32 to 1e-5, bf16 to one ulp (bitwise until PR 28)."""
     from pytorch_distributed_training_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "_BLOCK_Q", fa._BLOCK_Q_FUSED)
@@ -329,10 +352,14 @@ def test_fused_bwd_matches_split_bitwise(causal, dtype, split_bwd, monkeypatch):
     g_fused = grads(q, k, v)
     assert calls, "fused path was not taken"
     for a, b, name in zip(g_split, g_fused, "qkv"):
-        np.testing.assert_array_equal(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            err_msg=f"d{name}",
-        )
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if causal:
+            # bf16: one ulp, and near zero the f32 sums' own noise (both
+            # sides are 6e-3 to 4e-2 from the naive f32 path)
+            rtol, atol = (2.0 ** -7, 5e-4) if dtype == jnp.bfloat16 else (1e-5, 1e-5)
+            np.testing.assert_allclose(a, b, rtol=rtol, atol=atol, err_msg=f"d{name}")
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=f"d{name}")
 
 
 def test_fused_bwd_gate():
@@ -403,3 +430,158 @@ def test_gate_no_longer_caps_sequence():
     assert flash_shapes_ok(16384, 128)
     assert flash_shapes_ok(65536, 128)
     assert not flash_shapes_ok(100, 64)  # still requires s % 128 == 0
+
+
+# ----------------------------------------------------------------------
+# The causal walk (PR 28): the resident forward and the fused backward take
+# the K tiles wholly under a Q tile's diagonal without a mask, walk the
+# square on the diagonal in sub-tiles and do not visit what lies above it.
+# ----------------------------------------------------------------------
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Main tiles of 256 (fused backward 256 x 128) over sub-tiles of 64, and
+    no whole-sequence Q tile: a sequence of 512 then has a full tile, crossed
+    sub-tiles and skipped ones in both kernels, at sizes the interpreter runs
+    in seconds."""
+    from pytorch_distributed_training_tpu.ops import flash_attention as fa
+
+    sizes = {"_BLOCK_Q": 256, "_BLOCK_K": 256, "_BLOCK_F32": 256,
+             "_BLOCK_Q_FUSED": 256, "_BLOCK_K_FUSED": 128, "_BLOCK_DIAG": 64,
+             "_BLOCK_Q_WHOLE": 0}
+    for name, size in sizes.items():
+        monkeypatch.setattr(fa, name, size)
+    fa._make.cache_clear()
+    yield fa
+    fa._make.cache_clear()
+
+
+def _walk_qkv(seed, s, d, dtype):
+    return tuple(x.astype(dtype) for x in _qkv(seed, s, b=1, h=2, d=d))
+
+
+# bf16: the kernels round p and ds to bf16 before their products and the
+# gradients once at the end; a left-out or doubly counted sub-tile is an
+# error of order 0.1 to 1 at these shapes
+_WALK_TOL = {jnp.float32: (2e-5, 5e-5), jnp.bfloat16: (2e-2, 6e-2)}
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_causal_walk_matches_naive(small_tiles, dtype, d):
+    """Two main tiles a side: Q tile 1 has a full K tile under it (no mask),
+    each square on the diagonal four column blocks of crossed and skipped
+    sub-tiles; the fused backward (256 x 128) takes two full tiles there.
+    Forward and all three grads, both dot precisions."""
+    fa = small_tiles
+    assert fa._tiles(512, True, True) == (256, 256, 64)
+    assert fa._tiles(512, True, True, fused=True) == (256, 128, 64)
+    q, k, v = _walk_qkv(13, 512, d, dtype)
+    atol, gatol = _WALK_TOL[dtype]
+    _assert_matches_naive(q, k, v, causal=True, atol=atol, gatol=gatol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_tile_sequence_is_the_diagonal(small_tiles, dtype):
+    """S = one main tile: no full tile exists, the only tile is the square on
+    the diagonal (four chunks here; at the module's sizes a sequence of 128
+    is one masked step, which test_forward_matches_naive's S=256 covers)."""
+    q, k, v = _walk_qkv(14, 256, 64, dtype)
+    atol, gatol = _WALK_TOL[dtype]
+    _assert_matches_naive(q, k, v, causal=True, atol=atol, gatol=gatol)
+
+
+def _cover(fa, s_len, tiles):
+    """How often the walk's schedule visits and masks each query-key pair:
+    two [S, S] count arrays built from the kernels' own ``_diag_walk`` and
+    their bound on the full tiles."""
+    block_q, block_k, sub = tiles
+    visited = np.zeros((s_len, s_len), int)
+    masked = np.zeros((s_len, s_len), int)
+    for i in range(s_len // block_q):
+        r0 = i * block_q
+        visited[r0:r0 + block_q, :(r0 // block_k) * block_k] += 1
+        for row0, rows, col0, cols in fa._diag_walk(block_q, sub):
+            visited[r0 + row0:r0 + row0 + rows, r0 + col0:r0 + col0 + cols] += 1
+            masked[r0 + row0:r0 + row0 + cols, r0 + col0:r0 + col0 + cols] += 1
+    return visited, masked
+
+
+@pytest.mark.parametrize(
+    "s_len,tiles",
+    [(2048, "fwd"), (2048, "fused"), (384, "fwd"), (1536, "fused"),
+     (4096, "fwd"), (3072, "fused"), (1024, "f32"),
+     (512, (256, 256, 64)), (512, (256, 128, 64)), (512, (512, 128, 128))],
+)
+def test_walk_covers_the_causal_pairs_once(s_len, tiles):
+    """No pair on or under the diagonal is left out or visited twice, the
+    mask is built only where the diagonal crosses a sub-tile, and
+    ``_causal_pairs`` counts what the schedule does."""
+    from pytorch_distributed_training_tpu.ops import flash_attention as fa
+
+    if isinstance(tiles, str):
+        tiles = fa._tiles(s_len, tiles != "f32", True, fused=tiles == "fused")
+    visited, masked = _cover(fa, s_len, tiles)
+    causal = np.tril(np.ones((s_len, s_len), int))
+    assert visited.max() == 1 and (visited >= causal).all()
+    sub = tiles[2]
+    on_diagonal = np.kron(np.eye(s_len // sub, dtype=int), np.ones((sub, sub), int))
+    np.testing.assert_array_equal(masked, on_diagonal)
+    # what is visited above the diagonal lies inside the crossed sub-tiles
+    assert ((visited - causal) <= on_diagonal).all()
+    assert fa._causal_pairs(s_len, True, tiles) == (visited.sum(), masked.sum())
+    assert fa._causal_pairs(s_len, False, tiles) == (s_len * s_len, 0)
+
+
+def test_walk_visits_at_most_a_quarter_more_than_causal_at_2048():
+    """The cell's shape: both kernels visited 1.5 x the causal pairs before
+    the walk (three 1024 x 1024 tiles, six of 512 x 1024) and masked all of
+    them; now at most 1.25 x, and the mask touches the diagonal's sub-tiles
+    alone."""
+    from pytorch_distributed_training_tpu.ops import flash_attention as fa
+
+    causal = 2048 * 2049 // 2
+    for fused in (False, True):
+        tiles = fa._tiles(2048, True, True, fused=fused)
+        visited, masked = fa._causal_pairs(2048, True, tiles)
+        assert causal <= visited <= 1.25 * causal
+        assert masked == 2048 * tiles[2]
+
+
+def _kernel_primitives(fn, *args):
+    """Names of the primitives inside the Pallas kernels of ``fn``'s jaxpr."""
+    names = []
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside:
+                names.append(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inside or eqn.primitive.name == "pallas_call")
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False)
+    return names
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_non_causal_kernels_build_no_mask_and_one_loop(dtype):
+    """``causal=False`` callers (ViT, the ring's past blocks) keep the loops
+    they had: one loop over K tiles a kernel, no iota, compare or select, no
+    unrolled sub-tile steps — the causal kernels of the same shapes do."""
+    q, k, v = (x.astype(dtype) for x in _qkv(seed=15, s=1536))
+
+    def grads(causal):
+        return lambda q, k, v: jax.grad(
+            lambda q, k, v: jnp.sum(
+                flash_attention(q, k, v, causal=causal, interpret=True).astype(jnp.float32)
+            ),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    plain = _kernel_primitives(grads(False), q, k, v)
+    assert not {"iota", "select_n", "ge"} & set(plain)
+    loops = [n for n in plain if n in ("while", "scan")]
+    # forward + fused backward (the interpreter admits f32 to the fused one)
+    assert len(loops) == 2 and plain.count("dot_general") == 2 + 5
+    walked = _kernel_primitives(grads(True), q, k, v)
+    assert {"iota", "select_n"} <= set(walked)
+    assert walked.count("dot_general") > plain.count("dot_general")
