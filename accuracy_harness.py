@@ -24,8 +24,8 @@ the scaled-down version of that evidence end to end:
      schedule — the reference recipe's semantics — from the SAME init.
 
 Identical recipe, identical init, identical data order: final top-1 must
-agree within run-to-run noise.  ``bench.py accuracy`` drives all four
-stages and prints one JSON line with both numbers.
+agree within run-to-run noise.  ``python accuracy_harness.py all`` drives
+all four stages and prints both numbers.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """CLI: ``python -m pytorch_distributed_training_tpu.analysis``.
 
 Exit code 0 when no unsuppressed (and non-baselined) findings remain,
-1 otherwise — the tier-1 gate and ``bench.py lint`` both key off it.
+1 otherwise — the tier-1 gate keys off it.
 
 Examples::
 

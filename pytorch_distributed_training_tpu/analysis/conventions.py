@@ -3,12 +3,8 @@
 Migrated from the ad-hoc AST guard that used to live entirely inside
 ``tests/test_marker_convention.py`` (PRs 2-7 grew it one rule at a time);
 the test file now just invokes this pass, so the rules run identically
-from the CLI, ``bench.py lint``, and the tier-1 gate.  Three sub-rules:
+from the CLI and the tier-1 gate.  Three sub-rules:
 
-  - **bench-slow**: a test function whose body drives ``bench.py`` (by
-    subprocess or an in-process ``bench_*()`` entry point) pays model
-    compiles + timed windows and must be ``@pytest.mark.slow`` — the
-    tier-1 gate runs ``-m 'not slow'`` inside a fixed budget.
   - **fault-chaos**: a test touching the fault machinery
     (FaultInjector/watchdog/elastic/worker-pool kill paths) AND a heavy
     indicator (process spawns/kills, wall-clock sleeps) is a chaos test
@@ -20,8 +16,8 @@ from the CLI, ``bench.py lint``, and the tier-1 gate.  Three sub-rules:
   - **pass-registration**: every ``AnalysisPass`` subclass defined under
     ``analysis/`` must appear in the ``ALL_PASSES`` tuple in
     ``analysis/__init__.py``.  A pass that exists but is not registered
-    silently runs nowhere — not in the CLI, not in ``bench.py lint``,
-    not in the tier-1 gate — which is exactly the failure mode a lint
+    silently runs nowhere — not in the CLI, not in the tier-1 gate —
+    which is exactly the failure mode a lint
     framework must refuse to allow for itself.
 
 The tests scan covers ``tests/test_*.py``; the counter scan covers the
@@ -42,26 +38,6 @@ from .core import (
 )
 
 __all__ = ["MarkerConventionPass", "is_counter_store"]
-
-# Anything that runs a bench — shelling out to bench.py OR calling a bench
-# entry point in-process — pays compiles and timed windows.
-BENCH_DRIVERS = (
-    "bench.py",
-    "import bench",
-    "bench_ckpt(",
-    "bench_chaos(",
-    "bench_serve(",
-    "bench_chaos_serve(",
-    "bench_chaos_integrity(",
-    "bench_overlap(",
-    "bench_chaos_fleet(",
-    "bench_fleet_serve(",
-    "bench_soak(",
-    "bench_serve_modes(",
-    "bench_autoscale(",
-    "bench_disagg(",
-    "bench_chaos_disagg(",
-)
 
 FAULT_MACHINERY = (
     "FaultInjector",
@@ -120,8 +96,8 @@ def is_counter_store(node: ast.AST) -> bool:
 class MarkerConventionPass(AnalysisPass):
     rule = "marker-convention"
     description = (
-        "bench-driving tests are slow-marked, fault-machinery tests are "
-        "slow/chaos-marked, counters route through telemetry/registry"
+        "fault-machinery tests are slow/chaos-marked, counters route "
+        "through telemetry/registry, every pass is registered"
     )
 
     def run(self, modules: Sequence[SourceModule], ctx: AnalysisContext) -> List[Finding]:
@@ -152,23 +128,6 @@ class MarkerConventionPass(AnalysisPass):
                     continue
                 body_src = ast.unparse(node)
                 decorators = [ast.unparse(d) for d in node.decorator_list]
-                if any(b in body_src for b in BENCH_DRIVERS) and not any(
-                    "slow" in d for d in decorators
-                ):
-                    findings.append(
-                        Finding(
-                            rule=self.rule,
-                            severity=SEVERITY_ERROR,
-                            path=rel,
-                            line=node.lineno,
-                            message=(
-                                f"{node.name} drives bench.py (subprocess or "
-                                "in-process bench_* entry point) without "
-                                "@pytest.mark.slow — tier-1 runs -m 'not "
-                                "slow' in a fixed budget"
-                            ),
-                        )
-                    )
                 if (
                     any(m in body_src for m in FAULT_MACHINERY)
                     and any(h in body_src for h in HEAVY_INDICATORS)
@@ -234,8 +193,8 @@ class MarkerConventionPass(AnalysisPass):
                         message=(
                             f"{name} subclasses AnalysisPass but is missing "
                             "from ALL_PASSES in analysis/__init__.py — an "
-                            "unregistered pass runs nowhere (CLI, bench.py "
-                            "lint, tier-1 gate all iterate ALL_PASSES)"
+                            "unregistered pass runs nowhere (the CLI and the "
+                            "tier-1 gate both iterate ALL_PASSES)"
                         ),
                     )
                 )

@@ -111,7 +111,7 @@ class AnalysisContext:
     """Shared inputs handed to every pass."""
 
     package_root: Path  # the pytorch_distributed_training_tpu/ dir
-    repo_root: Path  # its parent (where tests/ and bench.py live)
+    repo_root: Path  # its parent (where tests/ and config/ live)
     tests_dir: Optional[Path] = None  # overridable for fixture tests
     config_dir: Optional[Path] = None  # overridable for fixture tests
 

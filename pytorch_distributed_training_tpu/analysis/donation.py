@@ -3,8 +3,8 @@
 ``jax.jit(..., donate_argnums=(0,))`` hands the argument's device buffer
 to XLA for reuse: after the call the caller's array is logically dead —
 touching it raises on strict backends and silently reads reused memory on
-others (the bench.py "donated-buffer fix (fresh_state per phase)" in PR 5
-was exactly this bug).  The pass enforces the contract statically:
+others (the "donated-buffer fix (fresh_state per phase)" of PR 5 was
+exactly this bug).  The pass enforces the contract statically:
 
   - **registration**: a def decorated ``@jax.jit(donate_argnums=...)`` or
     ``@functools.partial(jax.jit, donate_argnums=...)``, or a module/local

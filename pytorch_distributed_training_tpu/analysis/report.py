@@ -1,7 +1,7 @@
 """Reporters: human text and machine JSON for analysis results.
 
 The JSON schema is pinned by tests/test_static_analysis.py — CI consumers
-(bench.py lint, the chaos harness) parse it, so additive evolution only.
+parse it, so additive evolution only.
 """
 from __future__ import annotations
 

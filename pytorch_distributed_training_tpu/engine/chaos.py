@@ -3,7 +3,7 @@
 Every recovery ladder in the repo — anomaly guard, hung-step watchdog,
 retrying/async checkpoints, elastic peer loss, integrity sentinel, serving
 poison-bisect/hot-restart, fleet failover — is proved one fault at a time
-by its bespoke chaos bench.  At pod scale failures *overlap*: a rank dies
+by its own tests.  At pod scale failures *overlap*: a rank dies
 while an async write is in flight, an SDC flip lands during post-rollback
 replay, a request poisons the engine mid-drain.  This module provokes the
 compound cases deterministically and holds each scenario to shared
@@ -48,9 +48,9 @@ then checks:
   trace spans (telemetry/slo.py): recovery-span start to the end of the
   first productive step/tick after it.
 
-``bench.py soak`` drives ``ChaosSoakEngine.run()`` and emits the one-line
-JSON (per-scenario MTTR, goodput ratio, recovery counters, coverage
-matrix).
+``tests/test_chaos_soak.py`` drives ``ChaosSoakEngine.run()``; its summary
+carries per-scenario MTTR, goodput ratio, recovery counters and the
+coverage matrix.
 """
 from __future__ import annotations
 

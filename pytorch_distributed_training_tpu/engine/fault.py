@@ -141,8 +141,8 @@ counters every subsystem bumps (``skipped_steps``, ``rollbacks``,
 process-global telemetry registry (``telemetry/registry.py`` — also
 stdlib-only); ``bump``/``counters``/``reset_counters`` here are the
 stable API the fault layer and its tests were built on, now thin views of
-that one ledger so ``bench.py --chaos`` and the telemetry snapshot read
-the same numbers.
+that one ledger so the chaos tests and the telemetry snapshot read the
+same numbers.
 """
 from __future__ import annotations
 

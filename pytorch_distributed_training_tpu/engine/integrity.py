@@ -43,9 +43,10 @@ same detect → classify → recover ladder the crash paths use:
   exactly like the truncated case.
 
 Injection: ``sdc_flip@step[:rank]`` and ``ckpt_corrupt@step`` through the
-``PDT_FAULT_SPEC`` grammar (engine/fault.py); the chaos proof is
-``bench.py chaos-integrity``.  All ``integrity_*`` counters flow through
-the telemetry registry like every other recovery counter.
+``PDT_FAULT_SPEC`` grammar (engine/fault.py); the end-to-end proof is
+``tests/test_integrity.py::test_runner_flip_recovery_end_to_end``.  All
+``integrity_*`` counters flow through the telemetry registry like every
+other recovery counter.
 """
 from __future__ import annotations
 

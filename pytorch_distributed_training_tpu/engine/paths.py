@@ -2,7 +2,7 @@
 
 Extracted from ``Runner.worker``'s four-way if-ladder (round-3 VERDICT
 weak #5).  Each path is DATA — a ``PathSpec(name, predicate, build)`` row —
-selected by the first matching predicate, so adding a fifth path is one row
+selected by the first matching predicate, so adding a path is one row
 plus one builder, not another elif with cross-constraints.
 
 Every builder sets on the Runner: ``mesh``, ``state`` (device_put with the
@@ -53,26 +53,6 @@ def _reject_anomaly(r, path: str):
         )
 
 
-def _comm(r):
-    """The parsed ``training.comm`` block, ``None`` when absent/legacy."""
-    return getattr(r, "comm", None)
-
-
-def _comm_overlap(r) -> bool:
-    c = _comm(r)
-    return c is not None and c.overlap
-
-
-def _reject_comm(r, path: str):
-    if _comm_overlap(r):
-        raise ValueError(
-            "training.comm.overlap is not wired for the "
-            f"{path} execution path (supported: image-dp, ring-sp, and "
-            "ring-sp with zero stage 1) — the GSPMD partitioner schedules "
-            "its own communication overlap there"
-        )
-
-
 def _token_shardings(r, mesh, seq_axis):
     """Tokens/targets are [batch, seq]: data axis on rows, the path's
     sequence axis (or None) on columns — same for inputs and labels."""
@@ -91,7 +71,6 @@ def _build_pipeline(r, seed, train_dataset):
     from .pp_steps import build_pp_lm_eval_step, build_pp_lm_train_step
 
     _reject_anomaly(r, "pipeline")
-    _reject_comm(r, "pipeline")
     if r.model.depth % r.pipe_par != 0:
         raise ValueError(
             f"model.depth ({r.model.depth}) must be divisible by "
@@ -154,7 +133,6 @@ def _build_gspmd(r, seed, train_dataset):
     from .tp_steps import build_tp_lm_eval_step, build_tp_lm_train_step
 
     _reject_anomaly(r, "gspmd")
-    _reject_comm(r, "gspmd")
     if r.model.num_heads % r.tensor_par != 0:
         # the Megatron column split lands on whole-head boundaries
         raise ValueError(
@@ -199,54 +177,6 @@ def _build_ring_sp(r, seed, train_dataset):
         grad_accum=r.grad_accum,
         label_smoothing=r.label_smoothing,
         anomaly_factor=_anomaly_factor(r),
-        comm=_comm(r),
-    )
-    r.eval_step = build_lm_eval_step(r.model, r.mesh)
-    _token_shardings(r, r.mesh, SEQUENCE_AXIS)
-
-
-def _build_ring_sp_zero1(r, seed, train_dataset):
-    # ZeRO-1 without the GSPMD partitioner (arXiv 2004.13336 done by hand):
-    # the ring-sp step with comm.overlap, but the per-bucket psum becomes
-    # psum_scatter + a 1/n-sharded flat optimizer update + all_gather
-    # (engine/comm.py) — moments never materialize unsharded.  Selected
-    # over the gspmd row when comm.overlap is on and zero == 1 with no
-    # tensor/expert parallelism.
-    from .comm import zero1_init, zero1_shardings
-
-    _reject_anomaly(r, "ring-sp-zero1")
-    if r.seq_par > 1:
-        raise ValueError(
-            "training.comm.overlap with zero stage 1 requires "
-            "sequence_parallelism == 1 (gradient shards are scattered "
-            "over the data axis only)"
-        )
-    r.mesh = make_sp_mesh(1)
-    sample = jnp.zeros((1, r.seq_len), jnp.int32)
-    params = r.model.init(jax.random.PRNGKey(seed), sample)["params"]
-    if r.pretrained:
-        params = r._apply_pretrained_lm(params)
-    n_data = r.mesh.shape[DATA_AXIS]
-    state = TrainState(
-        params=params, batch_stats={},
-        opt_state=zero1_init(r.optimizer, params, r.comm, n_data),
-    )
-    rep = replicated_sharding(r.mesh)
-    r.state = jax.device_put(
-        state,
-        TrainState(
-            params=jax.tree.map(lambda _: rep, params),
-            batch_stats={},
-            opt_state=zero1_shardings(state.opt_state, r.mesh, DATA_AXIS),
-            ema={},
-        ),
-    )
-    r.train_step = build_lm_train_step(
-        r.model, r.optimizer, r.scheduler.lr_fn, r.mesh,
-        grad_accum=r.grad_accum,
-        label_smoothing=r.label_smoothing,
-        comm=r.comm,
-        zero1=True,
     )
     r.eval_step = build_lm_eval_step(r.model, r.mesh)
     _token_shardings(r, r.mesh, SEQUENCE_AXIS)
@@ -280,7 +210,6 @@ def _build_image_dp(r, seed, train_dataset):
         label_smoothing=r.label_smoothing,
         ema_decay=r.ema_decay,
         anomaly_factor=_anomaly_factor(r),
-        comm=_comm(r),
     )
     r.eval_step = build_eval_step(r.model, r.mesh, input_norm=r._input_norm)
     r._img_sharding = batch_sharding(r.mesh, ndim=4)
@@ -289,17 +218,6 @@ def _build_image_dp(r, seed, train_dataset):
 
 PATHS = (
     PathSpec("pipeline", lambda r: r.is_lm and r.pipe_par > 1, _build_pipeline),
-    # comm.overlap + zero stage 1 takes the manual reduce-scatter path;
-    # zero >= 2 / tensor / expert parallelism still route to gspmd (which
-    # rejects comm.overlap with the documented error)
-    PathSpec(
-        "ring-sp-zero1",
-        lambda r: (
-            r.is_lm and _comm_overlap(r) and r.zero == 1
-            and r.tensor_par == 1 and not r.is_moe
-        ),
-        _build_ring_sp_zero1,
-    ),
     PathSpec(
         "gspmd",
         lambda r: r.is_lm and (r.tensor_par > 1 or r.zero or r.is_moe),

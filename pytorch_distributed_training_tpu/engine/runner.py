@@ -67,7 +67,6 @@ from .profiling import TraceProfiler
 from .steps import TrainState
 from .topology import (
     parse_batch,
-    parse_comm,
     parse_elastic,
     parse_fault_tolerance,
     parse_integrity,
@@ -209,9 +208,15 @@ class Runner:
         # documented config error lives there).
         parse_topology(self, cfg, train_cfg, train_dataset)
         host_batch = parse_batch(self, train_cfg)
-        # Gradient-communication keys (additive, off by default): bucketed
-        # backward-overlapped reduction + ZeRO-1 routing (engine/comm.py).
-        parse_comm(self, train_cfg)
+        # ``training.comm`` (removed in PR 29) is refused, not ignored: its
+        # explicit reduction counted the gradient world_size times.
+        if "comm" in train_cfg:
+            raise ValueError(
+                "training.comm is no longer accepted: the step's own "
+                "differentiation reduces the gradient, so overlap, bucket_mb "
+                "and reduce_dtype have no replacement; for optimizer state "
+                "sharded over the data axis (ZeRO-1) set training.zero: 1"
+            )
         # Fault-tolerance keys (additive, all off by default) + the fault
         # injector: the PDT_FAULT_SPEC env var wins over the config key so a
         # chaos wrapper can override any run (engine/fault.py).

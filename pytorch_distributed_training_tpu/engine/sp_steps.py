@@ -35,7 +35,6 @@ from ..ops import cross_entropy_loss
 from ..parallel.mesh import DATA_AXIS
 from ..parallel.sequence import SEQUENCE_AXIS
 from ..telemetry.retrace import register_compiled
-from .comm import reduce_gradients, zero1_slot_count, zero1_specs, zero1_update
 from .steps import TrainState
 
 __all__ = ["build_lm_train_step", "build_lm_eval_step", "lm_loss_local"]
@@ -72,8 +71,6 @@ def build_lm_train_step(
     grad_accum: int = 1,
     label_smoothing: float = 0.0,
     anomaly_factor=None,
-    comm=None,
-    zero1: bool = False,
 ):
     """Compile one DP x SP training iteration for a :class:`TransformerLM`.
 
@@ -92,46 +89,15 @@ def build_lm_train_step(
     applied)``, with params/opt-state ``jnp.where``-gated back to their
     inputs on a non-finite or spiking step.
 
-    ``comm``: optional :class:`..engine.comm.CommConfig`.  With
-    ``comm.overlap`` the differentiated objective is the LOCAL partial sum
-    (no collective in the backward) and the gradient ``psum`` happens
-    afterward as one bucketed collective per bucket in reverse-backward
-    order (engine/comm.py).  Identical sum => bitwise parity at
-    ``grad_accum == 1``; with accumulation the micros sum locally first
-    (DDP ``no_sync`` semantics: one reduction per step instead of one per
-    micro), the same total reassociated — <= 1e-6.
-
-    ``zero1``: with ``comm.overlap``, replace the per-bucket ``psum`` +
-    replicated update with ``psum_scatter`` + a 1/n-sharded flat optimizer
-    update + ``all_gather`` (ZeRO-1 weight-update sharding, arXiv
-    2004.13336).  ``opt_state`` must be a :class:`..engine.comm.Zero1State`
-    (see :func:`..engine.comm.zero1_init`); moments never materialize
-    unsharded.  Data-parallel only: requires a trivial sequence axis.
+    Gradient reduction is the differentiation's own (module docstring): no
+    explicit gradient collective exists in the ``shard_map`` step families,
+    and sharded optimizer state is ``training.zero`` on the GSPMD family
+    (:mod:`.tp_steps`).
     """
     axes = (data_axis, seq_axis)
     n_data = mesh.shape[data_axis]
     n_seq = mesh.shape[seq_axis]
     guard = anomaly_factor is not None
-    overlap = comm is not None and comm.overlap
-    if zero1:
-        if not overlap:
-            raise ValueError(
-                "zero1 weight-update sharding requires training.comm.overlap "
-                "(the bucketed schedule is what gets reduce-scattered)"
-            )
-        if guard:
-            raise ValueError(
-                "training.fault_tolerance.anomaly is not wired for the "
-                "zero1 comm path (the sharded update has no replicated "
-                "gradient to take a norm of)"
-            )
-        if n_seq > 1:
-            raise ValueError(
-                "training.comm.overlap with zero stage 1 requires "
-                "sequence_parallelism == 1 (gradient shards are scattered "
-                "over the data axis only)"
-            )
-        zero1_slot_count(optimizer)  # validates the optimizer is elementwise
 
     def body(params, opt_state, tokens, labels, *guard_args):
         b_local, s_local = tokens.shape
@@ -146,13 +112,7 @@ def build_lm_train_step(
             # shard_map's AD transpose psums the replicated params' cotangent
             # across both mesh axes (an explicit post-grad psum would
             # double-count; regression-tested in tests/test_transformer_lm.py).
-            # comm.overlap differentiates the LOCAL partial instead; the
-            # same psum then runs after the backward, bucketed and pinned
-            # into a reverse-backward schedule (engine/comm.py) — the
-            # identical sum, so parity is bitwise at grad_accum == 1.
             local = lm_loss_local(logits, lab, global_tokens, label_smoothing)
-            if overlap:
-                return local
             return jax.lax.psum(local, axes)
 
         if grad_accum > 1:
@@ -180,24 +140,10 @@ def build_lm_train_step(
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens, labels)
         lr = lr_fn(opt_state.step)
-        if zero1:
-            # per bucket: psum_scatter -> sharded flat update -> all_gather
-            # (engine/comm.py); the loss psum is purely for reporting
-            new_params, new_opt = zero1_update(
-                optimizer, comm, grads, params, opt_state, lr,
-                data_axis, n_data,
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(
+                grads, opt_state, params, lr
             )
-            loss = jax.lax.psum(loss, axes)
-        else:
-            if overlap:
-                # grads/loss are local partial sums here; one bucketed
-                # psum per bucket reproduces the implicit reduction exactly
-                grads = reduce_gradients(grads, comm, axes, op="psum")
-                loss = jax.lax.psum(loss, axes)
-            with jax.named_scope("optimizer"):
-                new_params, new_opt = optimizer.update(
-                    grads, opt_state, params, lr
-                )
         if not guard:
             return new_params, new_opt, loss
         (gnorm_ref,) = guard_args
@@ -222,17 +168,11 @@ def build_lm_train_step(
 
     rep = P()
     tok_spec = P(data_axis, seq_axis)
-    # zero1 opt state is 1/n-sharded over the data axis (spec prefix:
-    # slots split, step replicated); everything else stays replicated
-    opt_spec = zero1_specs(data_axis) if zero1 else rep
-    # distinct retrace-registry names per program family so an A/B in one
-    # process (bench.py overlap) doesn't read as a retrace storm
-    variant = "_zero1" if zero1 else ("_overlap" if overlap else "")
     sharded = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(rep, opt_spec, tok_spec, tok_spec) + ((rep,) if guard else ()),
-        out_specs=(rep, opt_spec, rep) + ((rep, rep) if guard else ()),
+        in_specs=(rep, rep, tok_spec, tok_spec) + ((rep,) if guard else ()),
+        out_specs=(rep, rep, rep) + ((rep, rep) if guard else ()),
     )
 
     if guard:
@@ -252,7 +192,7 @@ def build_lm_train_step(
                 ok.astype(jnp.float32),
             )
 
-        return register_compiled(f"lm_train_step/sp{variant}_guarded", train_step)
+        return register_compiled("lm_train_step/sp_guarded", train_step)
 
     @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
     def train_step(state: TrainState, tokens, labels):
@@ -267,7 +207,7 @@ def build_lm_train_step(
             loss,
         )
 
-    return register_compiled(f"lm_train_step/sp{variant}", train_step)
+    return register_compiled("lm_train_step/sp", train_step)
 
 
 def build_lm_eval_step(

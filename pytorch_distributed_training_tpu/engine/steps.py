@@ -32,7 +32,6 @@ from ..metrics import accuracy
 from ..ops import cross_entropy_loss
 from ..parallel.mesh import DATA_AXIS
 from ..telemetry.retrace import register_compiled
-from .comm import reduce_gradients
 
 __all__ = [
     "TrainState",
@@ -121,9 +120,15 @@ def build_train_step(
     label_smoothing: float = 0.0,
     ema_decay: Optional[float] = None,
     anomaly_factor: Optional[float] = None,
-    comm=None,
 ):
     """Compile the full training iteration as one SPMD program.
+
+    Gradient reduction lives in one place, the step's own differentiation:
+    the objective is the GLOBAL-batch mean (``pmean`` inside the
+    differentiated function), and ``shard_map``'s AD transpose ``psum``s the
+    cotangent of the replicated parameters.  No explicit gradient collective
+    exists in the ``shard_map`` step families; sharded optimizer state is
+    ``training.zero`` on the GSPMD family (:mod:`.tp_steps`).
 
     Args:
       model: a linen module whose ``apply`` takes ``(variables, img, train=...)``
@@ -167,17 +172,8 @@ def build_train_step(
         bitwise-identical.  The step then returns ``(state, loss, gnorm,
         applied)`` instead of ``(state, loss)``; ``None`` (the default)
         compiles the exact ungated program.
-      comm: optional :class:`..engine.comm.CommConfig` (config
-        ``training.comm``).  With ``comm.overlap`` the objective becomes
-        the LOCAL shard mean — the backward then carries no collective —
-        and the gradients are reduced explicitly afterward as one bucketed
-        ``pmean`` per size-bounded bucket in reverse-backward order
-        (engine/comm.py).  ``psum(g/n)`` becomes ``psum(g)/n``: bitwise on
-        power-of-two meshes, <= 1e-6 otherwise (tests/test_comm_overlap.py).
-        ``None``/``overlap: false`` compiles the exact legacy step.
     """
     normalize = _input_normalizer(input_norm)
-    overlap = comm is not None and comm.overlap
 
     def micro_loss(params, batch_stats, img, label):
         # normalize PER MICRO-BATCH: converting uint8 -> f32 up front would
@@ -195,21 +191,17 @@ def build_train_step(
                 )
             with jax.named_scope("loss_head"):
                 loss = cross_entropy_loss(out, label, label_smoothing)
-            # Make the OBJECTIVE the global-batch mean (each replica's CE is
-            # the mean over its local shard).  Differentiating this is the
-            # DDP-reducer equivalent: the cotangent of the replicated params
-            # is psum-reduced across the mesh by shard_map's AD transpose, so
-            # `grads` below is exactly the DDP-averaged gradient — an
-            # explicit post-grad collective would double-count the psum
-            # (world_size x too large; regression-tested in
+            # The OBJECTIVE is the global-batch mean (each replica's CE is
+            # the mean over its local shard).  Differentiating it is the
+            # DDP-reducer equivalent: shard_map's AD transpose psums the
+            # cotangent of the replicated params across the mesh, so `grads`
+            # below is exactly the DDP-averaged gradient.  This is the only
+            # gradient reduction: an explicit post-grad collective would
+            # count it twice (world_size x too large; regression-tested in
             # tests/test_engine.py::test_dp_step_matches_single_device).
-            # XLA still overlaps the underlying all-reduce with independent
+            # XLA overlaps the underlying all-reduce with independent
             # backward compute, like DDP's bucketed reducer (reference :198).
-            # comm.overlap instead differentiates the LOCAL mean and moves
-            # the reduction after the backward as explicit bucketed pmeans
-            # with a pinned schedule (engine/comm.py).
-            if not overlap:
-                loss = jax.lax.pmean(loss, DATA_AXIS)
+            loss = jax.lax.pmean(loss, DATA_AXIS)
             # models without batch statistics (e.g. ViT) mutate nothing
             return loss, mutated.get("batch_stats", {})
 
@@ -250,11 +242,6 @@ def build_train_step(
             )
         else:
             (loss, new_bs), grads = micro_loss(params, batch_stats, img, label)
-        if overlap:
-            # grads/loss are local shard means here; the bucketed pmeans
-            # reproduce the implicit reduction (psum(g)/n vs psum(g/n))
-            grads = reduce_gradients(grads, comm, DATA_AXIS, op="pmean")
-            loss = jax.lax.pmean(loss, DATA_AXIS)
         if not sync_bn:
             # Local BN stats diverge per replica; average them so the state
             # stays replicated (the reference's DDP broadcast_buffers keeps
@@ -346,9 +333,7 @@ def build_train_step(
                 ok.astype(jnp.float32),
             )
 
-        return register_compiled(
-            f"train_step/gspmd{'_overlap' if overlap else ''}_guarded", train_step
-        )
+        return register_compiled("train_step/gspmd_guarded", train_step)
 
     @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
     def train_step(state: TrainState, img, label):
@@ -366,9 +351,7 @@ def build_train_step(
             loss,
         )
 
-    return register_compiled(
-        f"train_step/gspmd{'_overlap' if overlap else ''}", train_step
-    )
+    return register_compiled("train_step/gspmd", train_step)
 
 
 def build_eval_step(model, mesh: Mesh, input_norm=None):

@@ -30,7 +30,6 @@ from ..parallel.sequence import SEQUENCE_AXIS
 __all__ = [
     "parse_topology",
     "parse_batch",
-    "parse_comm",
     "parse_fault_tolerance",
     "parse_elastic",
     "parse_integrity",
@@ -388,57 +387,6 @@ def parse_batch(r, train_cfg: dict) -> int:
             f"training.microbatches ({r.microbatches})"
         )
     return host_batch
-
-
-def parse_comm(r, train_cfg: dict) -> None:
-    """Parse the additive ``training.comm`` section (off by default) onto
-    the runner — bucketed, backward-overlapped gradient reduction
-    (engine/comm.py):
-
-    .. code-block:: yaml
-
-        training:
-            comm:
-                overlap: true         # bucketed explicit reduction; false
-                                      # compiles the exact legacy step
-                bucket_mb: 25         # flat-bucket size bound (MiB)
-                reduce_dtype: null    # null | float32 | bfloat16 — cast
-                                      # buckets before the collective
-                                      # (bfloat16 halves wire bytes; only
-                                      # null carries parity oracles)
-
-    ``overlap`` is wired for the image-dp and ring-sp paths (and, with
-    ``zero: 1``, selects the manual reduce-scatter ZeRO-1 path); the GSPMD
-    and pipeline paths schedule their own communication and raise the
-    documented error.
-    """
-    from .comm import CommConfig
-
-    cm = train_cfg.get("comm") or {}
-    unknown = set(cm) - {"overlap", "bucket_mb", "reduce_dtype"}
-    if unknown:
-        raise ValueError(
-            f"training.comm: unknown key(s) {sorted(unknown)} "
-            "(want overlap/bucket_mb/reduce_dtype)"
-        )
-    bucket_mb = float(cm.get("bucket_mb", 25.0))
-    if bucket_mb <= 0:
-        raise ValueError(
-            f"training.comm.bucket_mb must be > 0, got {bucket_mb}"
-        )
-    reduce_dtype = cm.get("reduce_dtype")
-    if reduce_dtype is not None and reduce_dtype not in (
-        "float32", "bfloat16",
-    ):
-        raise ValueError(
-            "training.comm.reduce_dtype must be float32 or bfloat16 (or "
-            f"null for the gradient dtype), got {reduce_dtype!r}"
-        )
-    r.comm = CommConfig(
-        overlap=bool(cm.get("overlap", False)),
-        bucket_mb=bucket_mb,
-        reduce_dtype=reduce_dtype,
-    )
 
 
 def parse_fault_tolerance(r, train_cfg: dict) -> None:

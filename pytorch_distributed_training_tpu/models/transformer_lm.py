@@ -181,9 +181,8 @@ class TransformerLM(nn.Module):
     # config only — parameter shapes/values are unchanged.
     flash_mesh: Optional[Any] = None
     # Fuse the per-block elementwise tails (residual-add+ln2, fc1
-    # bias+gelu) into single Pallas kernels — config ``model.fused_tails``
-    # (or ``BENCH_LM_FUSED_TAILS=1`` on the bench).  Checkpoint-compatible
-    # both ways; A/B'd in PERF.md round 6.
+    # bias+gelu) into single Pallas kernels — config ``model.fused_tails``.
+    # Checkpoint-compatible both ways.
     fused_tails: bool = False
     # KV-cache incremental decode (serving): ``model.clone(decode=True)``
     # gives the serving-side module — same params, plus a "cache" variable

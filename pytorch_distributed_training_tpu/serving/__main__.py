@@ -8,7 +8,7 @@ Builds an :class:`.engine.InferenceEngine` from the config, fires
 varying length within the seq buckets; classification: random images),
 waits on every future, and reports p50/p99 latency, max queue depth, and
 items/sec through the repo's logging funnel — the final line is one JSON
-object, same convention as ``bench.py``.  Compiled programs persist where
+object.  Compiled programs persist where
 ``utils.enable_compile_cache`` says (``JAX_COMPILATION_CACHE_DIR``, else
 ``<checkout>/.xla_cache``), so a relaunch skips the bucket-grid compiles.
 """

@@ -6,7 +6,7 @@ stated SLO while the chaos harness lands faults inside the scaling
 events.  Three design decisions carry the robustness story:
 
 **Deterministic, hand-driven control loop.**  The autoscaler owns no
-thread.  The driver (``bench.py autoscale``, a chaos scenario, a test)
+thread.  The driver (a chaos scenario, a test)
 calls :meth:`poll` on its own cadence with an injected ``clock`` — so a
 scaling schedule is replayable, cooldowns are testable without sleeping,
 and a decision-time hang (the ``autoscale_hang`` fault kind) lands at an

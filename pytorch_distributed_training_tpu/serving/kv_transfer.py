@@ -15,8 +15,7 @@ with a locally-recomputed one and token parity holds by construction.
 Host-staged on purpose: blocks round-trip through ``numpy`` arrays
 (device → host gather on export, host → device scatter on import)
 because the single-process fleet has no device-to-device fabric to
-model — the honest cost of that staging on CPU is measured by
-``bench.py disagg`` and documented in PERF.md, not hidden.
+model — what that staging costs on a chip has not been measured.
 
 This module is pure data plumbing — no locks, no threads, no pool
 mutation beyond the functional ``.at[].set`` scatter.  The scheduler

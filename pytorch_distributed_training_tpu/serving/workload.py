@@ -1,10 +1,9 @@
 """Trace-driven workload generator: seeded diurnal + flash-crowd traffic.
 
 The north star serves *millions of users* of bursty, diurnal traffic —
-but a bench has minutes, not days.  :class:`TraceGenerator` compresses
-that operating regime into a deterministic request trace the autoscaler
-bench (``bench.py autoscale``) replays against a real
-:class:`.fleet.ServingFleet`:
+but a run has minutes, not days.  :class:`TraceGenerator` compresses
+that operating regime into a deterministic request trace to replay
+against a real :class:`.fleet.ServingFleet`:
 
 **Diurnal cycle.**  The arrival rate follows one sinusoidal "day"
 (``diurnal_period_s`` of trace time per cycle, amplitude as a fraction

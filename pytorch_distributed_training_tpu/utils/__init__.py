@@ -67,7 +67,7 @@ def enable_compile_cache(directory: Optional[str] = None) -> str:
     whole-program compilation across *launches*.
 
     One rule for every entry point (the Runner's ``training.compile_cache``,
-    ``python -m …serving``, ``bench.py``, ``chip_smoke.py``):
+    ``python -m …serving``, ``chip_smoke.py``):
 
     - ``JAX_COMPILATION_CACHE_DIR`` set: the cache is there.  JAX reads the
       variable itself, so nothing here writes ``jax_compilation_cache_dir``

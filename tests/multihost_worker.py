@@ -30,7 +30,7 @@ failure: the survivor writes its JSON with the diagnosis + recovery
 counters and exits 0 — the driving test asserts on that record.
 
 The platform is pinned to CPU *before* JAX is imported: the parent may hold
-a chip (``bench.py`` chaos modes, ``__graft_entry__.py``), and a chip belongs
+a chip (``__graft_entry__.py``, ``chip_smoke.py``), and a chip belongs
 to one process — a worker that reached for it would fail or hang.
 """
 import json

@@ -1,8 +1,8 @@
 """Chaos soak engine (engine/chaos.py): schedule determinism, the fault
 coverage matrix, scenario well-formedness, and oracle-judged soak runs.
 
-The heavy proof lives in ``bench.py soak`` (>= 20 scenarios across all
-four families); tier-1 pins the properties that make that bench
+The heavy proof is the slow-marked 20-scenario soak at the end of this
+file; tier-1 pins the properties that make it
 trustworthy and replayable:
 
   - the scenario schedule is a pure function of the seed — a red soak
@@ -225,13 +225,18 @@ def test_soak_smoke_disagg_family():
 
 @pytest.mark.slow
 @pytest.mark.chaos
-def test_soak_mixed_families_slow():
-    """A fuller mixed soak (train + serve + fleet; elastic needs the
-    multi-process backend and is exercised by bench.py soak) — every
-    scenario green."""
+def test_soak_twenty_scenarios_in_process_families():
+    """The full soak: 20 seeded multi-fault scenarios round-robin over the
+    families that run in this process (train + serve + fleet) through the
+    real Runner / scheduler / fleet, every one judged by the shared oracles
+    and every one green.  The elastic family is left out: its template
+    kills rank 0, the coordination service's leader, and JAX's client then
+    aborts the survivor before the heartbeat layer can diagnose the loss
+    (tests/test_elastic.py proves that ladder with rank 1 as the victim)."""
     eng = ChaosSoakEngine(seed=42, families=("train", "serve", "fleet"))
-    summary = eng.run(6)
+    summary = eng.run(20)
     assert summary["failed"] == 0, [
-        r["failures"] for r in summary["results"] if not r["ok"]
+        (r["index"], r["family"], r["spec"], r["failures"])
+        for r in summary["results"] if not r["ok"]
     ]
-    assert summary["passed"] + summary["skipped"] == 6
+    assert summary["passed"] == 20

@@ -11,10 +11,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
-import bench  # importing it runs nothing: every mode sits behind __main__
-
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _BAD_CFG = """\
@@ -65,10 +61,3 @@ def test_cli_crash_path_cleans_tb_only(tmp_path):
     # only the TB event dir is removed; the text log survives
     assert not (log_dir / "tf-board-logs").exists()
 
-
-def test_peak_table_raises_on_unknown_device_kind():
-    """A utilization is a share of a stated peak: a device the table does
-    not know is an error, never a null or a default."""
-    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
-    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
-        bench.peak_bf16_flops("TPU v9 imaginary")

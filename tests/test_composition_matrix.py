@@ -14,10 +14,14 @@ test_sequence_parallel / test_tensor_parallel / test_pipeline_parallel /
 test_moe / test_grad_accum / test_ema_smoothing) — this matrix pins which
 combinations are reachable and that each one actually trains.
 """
+import types
+
+import jax
 import numpy as np
 import pytest
 
 from pytorch_distributed_training_tpu.engine import Runner
+from pytorch_distributed_training_tpu.engine.paths import PATHS, select_path
 
 LM_DATASET = {
     "name": "synthetic_text",
@@ -118,11 +122,6 @@ SUPPORTED = [
     ("lm-smoothing", _cfg(label_smoothing=0.1)),
     ("img-ema", _cfg(task="img", ema={"decay": 0.99})),
     ("img-grad-accum", _cfg(task="img", grad_accumulation=2)),
-    ("img-comm-overlap", _cfg(task="img", comm={"overlap": True,
-                                                "bucket_mb": 1})),
-    ("lm-comm-overlap", _cfg(comm={"overlap": True, "bucket_mb": 1})),
-    ("lm-comm-zero1", _cfg(zero=True, comm={"overlap": True,
-                                            "bucket_mb": 1})),
 ]
 
 # (id, cfg, error-message fragment) — combinations that MUST raise.
@@ -156,16 +155,15 @@ UNSUPPORTED = [
     ("ppxlars", _cfg(pipeline_parallelism=2, microbatches=4,
                      optimizer={"name": "LARS", "lr": 0.01}),
      "LARS is not supported with"),
-    ("commxpp", _cfg(pipeline_parallelism=2, microbatches=4,
-                     comm={"overlap": True}),
-     "comm.overlap is not wired for the pipeline"),
-    ("commxtp", _cfg(tensor_parallelism=2, comm={"overlap": True}),
-     "comm.overlap is not wired for the gspmd"),
-    ("commxzero2", _cfg(zero=2, comm={"overlap": True}),
-     "comm.overlap is not wired for the gspmd"),
-    ("comm-zero1xsp2", _cfg(zero=True, sequence_parallelism=2,
-                            comm={"overlap": True}),
-     "zero stage 1 requires"),
+    # training.comm (removed in PR 29) is refused whichever former key it
+    # carries, on either task, and the message names what replaced it
+    ("comm-overlap", _cfg(comm={"overlap": True}), "training.zero: 1"),
+    ("comm-bucket_mb", _cfg(comm={"bucket_mb": 4}), "no longer accepted"),
+    ("comm-reduce_dtype", _cfg(comm={"reduce_dtype": "bfloat16"}),
+     "no longer accepted"),
+    ("comm-zero1", _cfg(zero=1, comm={"overlap": True}), "training.zero: 1"),
+    ("comm-img", _cfg(task="img", comm={"overlap": False}),
+     "no longer accepted"),
 ]
 
 
@@ -187,3 +185,65 @@ def test_supported_composition_constructs(cfg):
 def test_unsupported_composition_raises_documented_error(cfg, msg):
     with pytest.raises(ValueError, match=msg):
         _construct(cfg)
+
+
+# ---------------------------------------------------------------- path table
+
+
+def _knobs(**kw):
+    base = dict(is_lm=True, pipe_par=1, tensor_par=1, zero=0, is_moe=False)
+    base.update(kw)
+    return types.SimpleNamespace(**base)
+
+
+def test_path_table_is_exactly_four_rows():
+    assert [p.name for p in PATHS] == ["pipeline", "gspmd", "ring-sp", "image-dp"]
+    assert select_path(_knobs()).name == "ring-sp"
+    assert select_path(_knobs(is_lm=False)).name == "image-dp"
+    assert select_path(_knobs(pipe_par=2, zero=1)).name == "pipeline"
+
+
+@pytest.mark.parametrize("zero", [1, 2, 3])
+def test_zero_at_tp1_selects_gspmd(zero):
+    """Sharded optimizer state has one home: every ``training.zero`` stage
+    at ``tensor_parallelism: 1`` is the GSPMD family's."""
+    assert select_path(_knobs(zero=zero)).name == "gspmd"
+
+
+@pytest.mark.parametrize(
+    "optimizer",
+    [
+        {"name": "SGD", "lr": 0.01, "weight_decay": 1e-4, "momentum": 0.9},
+        {"name": "AdamW", "lr": 1e-3, "weight_decay": 0.01},
+    ],
+    ids=["sgd-momentum", "adamw"],
+)
+def test_zero1_through_config_matches_unsharded_moments(optimizer):
+    """``training.zero: 1`` on 8 devices, from the config down: the Runner
+    takes the gspmd row, the moment leaves hold 1/8 of themselves per device,
+    and one step lands where the step with replicated (unsharded) moments
+    lands — the ring-sp row on the same 8 x 1 mesh, same seed, same batch."""
+    plain = _construct(_cfg(optimizer=optimizer, train_iters=1))
+    zero1 = _construct(_cfg(optimizer=optimizer, train_iters=1, zero=1))
+    assert select_path(plain).name == "ring-sp"
+    assert select_path(zero1).name == "gspmd"
+    moments = [
+        leaf for leaf in jax.tree.leaves(zero1.state.opt_state) if leaf.ndim
+    ]
+    assert moments
+    whole = [
+        leaf.shape for leaf in moments
+        if leaf.addressable_shards[0].data.size * 8 != leaf.size
+    ]
+    # the one exception is the program's own: a qkv/fc1 bias has a single
+    # dimension and the (size-1) model axis already names it
+    assert all(len(shape) == 1 for shape in whole), whole
+    assert len(whole) < len(moments) / 4, whole
+    for leaf in jax.tree.leaves(plain.state.opt_state):
+        assert leaf.sharding.is_fully_replicated
+    for a, b in zip(
+        jax.tree.leaves(plain.state.params), jax.tree.leaves(zero1.state.params)
+    ):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+        )

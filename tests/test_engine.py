@@ -95,18 +95,30 @@ def test_sync_bn_stats_update_in_train_step():
 
 
 @pytest.mark.quick
-def test_dp_step_matches_single_device():
-    """8-device DP + SyncBN step == single-device full-batch step.
+@pytest.mark.parametrize("sync_bn", [True, False], ids=["syncbn", "localbn"])
+@pytest.mark.parametrize("grad_accum", [1, 2], ids=["accum1", "accum2"])
+def test_dp_step_matches_single_device(grad_accum, sync_bn):
+    """8-device DP step == single-device full-batch step.
 
     The DDP-parity oracle: gradient averaging, SyncBN statistics, and the
     SGD update must all compose to exactly the single-device result.  In
     particular this pins the gradient scale — shard_map's AD transpose
     already psums the replicated params' cotangent, so an extra post-grad
     pmean/psum would make grads world_size x too large (caught here).
+
+    ``sync_bn`` on: BN normalizes with GLOBAL batch statistics, so the
+    single device sees the same normalization (with accumulation: the same
+    per-micro-batch rows, see ``_micro_major``).  ``sync_bn`` off: each
+    replica normalizes its own two rows, which no single-device batch
+    reproduces — the reference is a BN-free model there (ViT), where the
+    only cross-replica term left is the gradient reduction itself.
     """
     opt = SGD(lr=0.01, momentum=0.9, weight_decay=1e-4)
     lr_fn = multi_step_lr(0.01, [1000], 0.1)
-    model = get_model("ResNet18", num_classes=8, axis_name=DATA_AXIS)
+    if sync_bn:
+        model = get_model("ResNet18", num_classes=8, axis_name=DATA_AXIS)
+    else:
+        model = get_model("ViT-Ti16", num_classes=8)
     state0 = init_train_state(
         model, opt, jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3))
     )
@@ -114,22 +126,23 @@ def test_dp_step_matches_single_device():
     img = rng.standard_normal((16, 32, 32, 3)).astype(np.float32)
     label = rng.integers(0, 8, (16,)).astype(np.int32)
 
-    mesh8 = make_mesh()
-    step8 = build_train_step(model, opt, lr_fn, mesh8, sync_bn=True, donate=False)
-    s8 = jax.device_put(state0, replicated_sharding(mesh8))
-    s8, loss8 = step8(
-        s8,
-        jax.device_put(img, batch_sharding(mesh8, 4)),
-        jax.device_put(label, batch_sharding(mesh8, 1)),
-    )
+    def run(mesh, img, label):
+        step = build_train_step(
+            model, opt, lr_fn, mesh, sync_bn=sync_bn, donate=False,
+            grad_accum=grad_accum,
+        )
+        return step(
+            jax.device_put(state0, replicated_sharding(mesh)),
+            jax.device_put(img, batch_sharding(mesh, 4)),
+            jax.device_put(label, batch_sharding(mesh, 1)),
+        )
 
-    mesh1 = make_mesh(devices=jax.devices()[:1])
-    step1 = build_train_step(model, opt, lr_fn, mesh1, sync_bn=True, donate=False)
-    s1 = jax.device_put(state0, replicated_sharding(mesh1))
-    s1, loss1 = step1(
-        s1,
-        jax.device_put(img, batch_sharding(mesh1, 4)),
-        jax.device_put(label, batch_sharding(mesh1, 1)),
+    s8, loss8 = run(make_mesh(), img, label)
+    # micro-batch m on 8 devices is row m of every device's shard; one
+    # device must see those same rows as ITS micro-batch m
+    order = _micro_major(16, 8, grad_accum)
+    s1, loss1 = run(
+        make_mesh(devices=jax.devices()[:1]), img[order], label[order]
     )
 
     assert np.isclose(float(loss8), float(loss1), atol=1e-5)
@@ -137,6 +150,15 @@ def test_dp_step_matches_single_device():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
     for a, b in zip(jax.tree.leaves(s8.batch_stats), jax.tree.leaves(s1.batch_stats)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def _micro_major(batch, n_dev, grad_accum):
+    """Row order in which ONE device's micro-batch ``m`` holds the rows that
+    micro-batch ``m`` holds across ``n_dev`` devices (identity at 1)."""
+    per_dev = batch // n_dev
+    micro = per_dev // grad_accum
+    rows = np.arange(batch).reshape(n_dev, grad_accum, micro)
+    return rows.transpose(1, 0, 2).reshape(-1)
 
 
 def test_eval_step_metrics_sane():

@@ -2,12 +2,10 @@
 
 The rules themselves now live in the analysis framework
 (``pytorch_distributed_training_tpu/analysis/conventions.py``, rule
-``marker-convention``) so they run identically from the CLI,
-``bench.py lint``, and this tier-1 gate.  This file is a thin wrapper
-kept under its historical name: each test invokes the pass and asserts
-its slice of the findings is empty, preserving the exact coverage the
-standalone guard had in PRs 2-7 (bench-driving tests are slow-marked,
-fault-machinery tests are slow/chaos-marked, no ad-hoc counter stores
+``marker-convention``) so they run identically from the CLI and this
+tier-1 gate.  This file is a thin wrapper kept under its historical name:
+each test invokes the pass and asserts its slice of the findings is empty
+(fault-machinery tests are slow/chaos-marked, no ad-hoc counter stores
 outside telemetry/) plus the scan-coverage pin on the serving modules.
 """
 import ast
@@ -25,18 +23,6 @@ _PKG = _REPO / "pytorch_distributed_training_tpu"
 
 def _run_marker_pass():
     return analysis.run(rules=["marker-convention"])
-
-
-def test_bench_driving_tests_are_slow_marked():
-    """Any test driving bench.py (subprocess or in-process bench_* entry
-    point) pays compiles + timed windows and must be @pytest.mark.slow —
-    the tier-1 gate runs ``-m 'not slow'`` in a fixed budget."""
-    offenders = [
-        f.format()
-        for f in _run_marker_pass().unsuppressed
-        if "without @pytest.mark.slow" in f.message
-    ]
-    assert not offenders, offenders
 
 
 def test_fault_injection_tests_are_slow_or_chaos_marked():
@@ -93,7 +79,7 @@ def test_counter_guard_covers_new_serving_modules():
 def test_marker_pass_registered_in_framework():
     """The migration keeps the rule in the default battery: dropping
     MarkerConventionPass from ALL_PASSES would silently disable the
-    convention everywhere (CLI, bench lint, this gate)."""
+    convention everywhere (CLI, this gate)."""
     assert MarkerConventionPass in analysis.ALL_PASSES
 
 

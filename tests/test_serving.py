@@ -1273,31 +1273,6 @@ def test_engine_mode_config_validation(lm_and_params):
     eng.close()
 
 
-@pytest.mark.slow
-def test_bench_serve_artifact_rounds_no_clobber(tmp_path, monkeypatch):
-    """BENCH_SERVE_r<NN>.json persistence: auto-numbering picks the next
-    free round; a pinned round that exists is refused, never rewritten."""
-    import bench
-
-    monkeypatch.setenv("BENCH_SERVE_ARTIFACT_DIR", str(tmp_path))
-    monkeypatch.delenv("BENCH_SERVE_ROUND", raising=False)
-    p1 = bench._persist_serve_artifact({"mode": "serve", "value": 1})
-    p2 = bench._persist_serve_artifact({"mode": "serve", "value": 2})
-    assert p1.endswith("BENCH_SERVE_r01.json")
-    assert p2.endswith("BENCH_SERVE_r02.json")
-    import json as _json
-
-    with open(p1) as f:
-        assert _json.load(f)["value"] == 1
-    monkeypatch.setenv("BENCH_SERVE_ROUND", "1")
-    with pytest.raises(SystemExit, match="refusing to clobber"):
-        bench._persist_serve_artifact({"mode": "serve", "value": 3})
-    with open(p1) as f:
-        assert _json.load(f)["value"] == 1  # untouched
-    monkeypatch.setenv("BENCH_SERVE_PERSIST", "0")
-    assert bench._persist_serve_artifact({"mode": "serve"}) is None
-
-
 # --------------------------------------------------------------------- #
 # async decode pipeline (serving.scheduler.async_depth)
 

@@ -112,11 +112,11 @@ def _drive(sched, futures, limit=200, check_pool=True):
     return n
 
 
-def _run_under_spec(model, params, spec, **kw):
+def _run_under_spec(model, params, spec, prompts=None, **kw):
     fault.install(spec)
     try:
         sched = _make_sched(model, params, **kw)
-        futs = [sched.submit(p) for p in _prompts()]
+        futs = [sched.submit(p) for p in prompts or _prompts()]
         _drive(sched, futs)
         return sched, futs
     finally:
@@ -668,6 +668,56 @@ def test_hung_tick_becomes_diagnosed_restart(lm_and_params):
     snap = sched.metrics.snapshot()
     assert snap["serve_watchdog_fires"] >= 1
     assert snap["engine_restarts"] == 1
+    sched.close()
+
+
+def test_all_four_serving_faults_in_one_run(lm_and_params):
+    """Every serving recovery path fires in ONE scheduler run: a raising
+    request (bisect evicts it), a NaN emitter (output guard), a device
+    loss (hot-restart + replay) and a hung tick (watchdog -> second
+    restart).  Exactly the two poisoned futures fail; every other request
+    completes bitwise-identical to an unfaulted run, and the pool ends
+    empty with its invariants held at every tick."""
+    model, params = lm_and_params
+    # two waves over four slots
+    load = dict(
+        prompts=_prompts(seed=11, lens=(2, 6, 4, 5, 3, 7, 2, 6)),
+        max_new_tokens=8,
+    )
+    _, clean = _run_under_spec(model, params, None, **load)
+    ref = [f.result()["tokens"] for f in clean]
+
+    fault.reset_counters()
+    sched, futs = _run_under_spec(
+        model, params,
+        "serve_raise@3:1;serve_nan@6:0;serve_device_lost@10;serve_hang@14:0.5",
+        **load,
+        resilience={
+            "max_restarts": 3,
+            "poison_bisect": True,
+            "watchdog": {
+                "enabled": True, "min_seconds": 0.15, "factor": 4.0,
+                "warmup": 3, "poll_seconds": 0.02,
+            },
+        },
+    )
+    poisoned = [i for i, f in enumerate(futs) if f.exception() is not None]
+    assert len(poisoned) == 2, poisoned
+    for i in poisoned:
+        assert isinstance(futs[i].exception(), PoisonedRequestError)
+    for i, f in enumerate(futs):
+        if i not in poisoned:
+            np.testing.assert_array_equal(f.result()["tokens"], ref[i])
+    c = fault.counters()
+    for kind in ("raises", "nans", "device_lost", "hangs"):
+        assert c.get(f"injected_serve_{kind}") == 1, (kind, c)
+    assert sched._supervisor.restarts() == 2
+    snap = sched.metrics.snapshot()
+    assert snap["requests_poisoned"] == 2
+    assert snap["engine_restarts"] == 2
+    assert snap["serve_watchdog_fires"] >= 1
+    assert snap.get("replay_parity_mismatch", 0) == 0
+    assert sched._kv.blocks_in_use == 0
     sched.close()
 
 

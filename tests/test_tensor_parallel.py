@@ -173,16 +173,21 @@ def test_3d_dp_sp_tp_step_matches_single_device():
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5)
 
 
-def test_zero1_sharded_moments_match_plain():
+@pytest.mark.parametrize("opt_name", ["adamw", "sgd-momentum"])
+def test_zero1_sharded_moments_match_plain(opt_name):
     """training.zero (ZeRO-1): optimizer moments sharded over the data axis
     must yield EXACTLY the same step as fully-mirrored moments, with the
-    big moment leaves actually sharded."""
+    big moment leaves actually sharded — for both moment layouts (AdamW's
+    mu/nu pair, SGD's one momentum buffer)."""
     from pytorch_distributed_training_tpu.optimizers import AdamW
     from pytorch_distributed_training_tpu.parallel import make_3d_mesh
     from pytorch_distributed_training_tpu.parallel.tensor import tp_state_shardings
 
     tokens, labels = _data(seed=3)
-    opt = AdamW(lr=1e-3, weight_decay=0.01)
+    if opt_name == "adamw":
+        opt = AdamW(lr=1e-3, weight_decay=0.01)
+    else:
+        opt = SGD(lr=1e-3, momentum=0.9, weight_decay=1e-4)
     lr_fn = multi_step_lr(1e-3, [], 0.1)
     model = _model()
     params = model.init(jax.random.PRNGKey(0), tokens)["params"]
@@ -204,7 +209,8 @@ def test_zero1_sharded_moments_match_plain():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7)
     from conftest import uses_mesh_axis
 
-    mu_leaves = jax.tree_util.tree_leaves(s_zero.opt_state.mu)
+    moment = s_zero.opt_state.mu if opt_name == "adamw" else s_zero.opt_state.momentum
+    mu_leaves = jax.tree_util.tree_leaves(moment)
     sharded_over_data = [l for l in mu_leaves if uses_mesh_axis(l.sharding, "data")]
     assert sharded_over_data, "ZeRO must shard moment leaves over the data axis"
     # with TP active, even the row-parallel (proj/fc2) KERNEL moments shard
@@ -212,7 +218,7 @@ def test_zero1_sharded_moments_match_plain():
     # are the model-sharded 1-D biases (qkv/fc1 bias: P(model), no free dim)
     flat_mu = {
         "/".join(str(getattr(k, "key", k)) for k in path): leaf
-        for path, leaf in jax.tree_util.tree_flatten_with_path(s_zero.opt_state.mu)[0]
+        for path, leaf in jax.tree_util.tree_flatten_with_path(moment)[0]
     }
     for name in ("block0/attn/proj/kernel", "block0/mlp/fc2/kernel"):
         assert uses_mesh_axis(flat_mu[name].sharding, "data"), name
