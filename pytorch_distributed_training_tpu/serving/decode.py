@@ -30,7 +30,10 @@ token (the sampled-mode half of the decode-parity oracle).
 ``build_paged_fns`` is the paged twin over the block-table cache mode of
 ``ops/attention.py``: one prefill program per (batch, seq) bucket and ONE
 single-token step program shared by every decode iteration, both over a
-pool pytree threaded through the calls instead of a per-batch cache.
+pool pytree threaded through the calls instead of a per-batch cache.  The
+pool is DONATED to every program that returns it: the cache scatter
+updates the caller's buffers in place, and the pool passed in is dead
+once the call is made.
 
 Multi-tenant decode modes (PR 17), all default-off:
 
@@ -56,6 +59,7 @@ Multi-tenant decode modes (PR 17), all default-off:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -225,6 +229,17 @@ def build_generate_fn(
 class _PagedFns:
     """Jit set + pool factory for the paged (block-table) cache mode.
 
+    Each program consumes the pool it is given; use the one it returns.
+    ``pool`` is donated (``donate_argnames``) in all five, so the scatter
+    of a step's rows writes the buffers the caller handed over instead of
+    a copy of them, and nothing may hold a pool LEAF across a call (a
+    slice or a ``tree_map`` result is a new buffer and is safe).  A call
+    that raises before its dispatch (bad arguments, the scheduler's
+    injected faults) leaves the pool as it was; one that raises after it
+    can leave the leaves deleted (``leaf.is_deleted()``), and the caller
+    then has no pool: the scheduler rebuilds one and replays
+    (``ContinuousScheduler._pool_lost`` → hot restart), it does not probe.
+
     ``prefill(params, pool, tokens, positions, block_tables, last_col,
     row_keys, gen_index, adapter_ids) -> (tok, finite, pool)`` — scatter
     the suffix K/V into the pool and sample each row's token
@@ -362,7 +377,7 @@ def build_paged_fns(
             ]).astype(jnp.int32),)
         return out
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames="pool")
     def prefill(
         params, pool, tokens, positions, block_tables, last_col, row_keys,
         gen_index, adapter_ids=None,
@@ -384,7 +399,7 @@ def build_paged_fns(
         tok = sample(last, _token_keys(row_keys, gen_index))
         return tok, jnp.isfinite(last).all(axis=-1), variables["cache"]
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames="pool")
     def decode_step(
         params, pool, prev_tok, pos, block_tables, row_keys, gen_index,
         adapter_ids=None,
@@ -398,7 +413,7 @@ def build_paged_fns(
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
         return _step_outputs(tok, logits[:, 0], variables)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames="pool")
     def decode_step_fed(
         params, pool, prev_tok, fresh_mask, fresh_tok, pos, block_tables,
         row_keys, gen_index, adapter_ids=None,
@@ -417,14 +432,14 @@ def build_paged_fns(
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
         return _step_outputs(tok, logits[:, 0], variables)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames="pool")
     def verify(params, pool, tokens, positions, block_tables, adapter_ids=None):
         logits, variables = _apply(
             params, pool, tokens, positions, block_tables, adapter_ids
         )
         return logits.astype(jnp.float32), variables["cache"]
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames="pool")
     def copy_rows(pool, src, dst):
         src_c = jnp.clip(src, 0, pool_rows - 1)
 

@@ -498,30 +498,37 @@ class InferenceEngine:
         TTFT at the worst possible moment.  Warmup drives one throwaway
         call through each (batch-bucket × seq-bucket) prefill program and
         each decode-phase program instead: scheduler-path calls use
-        all-``-1`` positions (every pool scatter drops — the OOB idiom)
-        and DISCARD the returned pool, so the live pool is never mutated
-        and the call is safe even against a running scheduler thread.
+        all-``-1`` positions (every pool scatter drops — the OOB idiom),
+        so the pool's contents do not change.  The programs consume the
+        pool they are given, so the warm-up threads the scheduler's own
+        pool through its calls and hands the last one back: it belongs
+        BEFORE traffic, and is refused while the scheduler holds work.
 
         Returns ``{"warmup_ms", "programs"}`` (programs = compile-count
         delta, 0 when everything was already warm — warmup is
-        idempotent).  ``ServingFleet.add_replica`` calls this before
-        routing traffic to a new replica and publishes the wall time as
-        the ``scale_up_ready_ms`` gauge.
+        idempotent).  On the scheduler path it also sets the gauge
+        ``pool_aliased_bytes`` of ``metrics.snapshot()``, how much of the
+        pool the programs update in place, and logs it beside the program
+        count.  ``ServingFleet.add_replica`` calls this before routing
+        traffic to a new replica and publishes the wall time as the
+        ``scale_up_ready_ms`` gauge.
         """
         import time
 
         t0 = time.perf_counter()
         before = self.compile_count()
+        aliased = None
         if not self.is_lm:
             self._warmup_classify()
         elif self.scheduler is not None:
-            self._warmup_scheduler()
+            aliased = self._warmup_scheduler()
         else:
             self._warmup_batcher()
         warmed = self.compile_count() - before
         ms = (time.perf_counter() - t0) * 1000.0
         self.logger.info(
-            "engine warmup: %d program(s) compiled in %.0f ms", warmed, ms
+            "engine warmup: %d program(s) compiled in %.0f ms%s", warmed, ms,
+            "" if aliased is None else f", pool_aliased_bytes={aliased}",
         )
         return {"warmup_ms": ms, "programs": float(warmed)}
 
@@ -532,7 +539,8 @@ class InferenceEngine:
         calls that follow read them back.  Seven programs of a seven-layer
         expert model compiled one after another took 138 s of a cold start.
         Without that cache a compile made here could not be reused, so
-        nothing is done."""
+        nothing is done.  Lowering reads shapes only: the pool among the
+        arguments is not consumed."""
         if len(calls) < 2 or not jax.config.jax_compilation_cache_dir:
             return
         from concurrent.futures import ThreadPoolExecutor
@@ -540,90 +548,96 @@ class InferenceEngine:
         with ThreadPoolExecutor(max_workers=min(len(calls), 8)) as pool:
             list(pool.map(lambda c: c[0].lower(*c[1]).compile(), calls))
 
-    def _warmup_scheduler(self) -> None:
+    def _warm_pool_programs(self, calls, sched, attr):
+        """Run every ``(program, arguments before the pool, arguments after
+        it, index of the pool among the outputs)`` once on the scheduler's
+        pool ``attr``.  The programs consume the pool they are given
+        (``decode.py``), so each call's returned pool is rebound there and
+        is the next call's argument.  Returns the fewest bytes any of the
+        programs updates in place (``alias_size_in_bytes`` of its compiled
+        form: the whole pool where the donation took, 0 where XLA fell back
+        to a copy; None where the backend does not say)."""
+        pool = getattr(sched, attr)
+        self._compile_side_by_side(
+            [(fn, (*head, pool, *tail)) for fn, head, tail, _ in calls]
+        )
+        aliased = None
+        for fn, head, tail, at in calls:
+            out = fn(*head, pool, *tail)
+            pool = out if at is None else out[at]
+            setattr(sched, attr, pool)
+            jax.block_until_ready(pool)
+            # compiled by the call above: this only reads it back
+            mem = fn.lower(*head, pool, *tail).compile().memory_analysis()
+            if mem is not None:
+                n = int(mem.alias_size_in_bytes)
+                aliased = n if aliased is None else min(aliased, n)
+        return aliased
+
+    def _warmup_scheduler(self) -> Optional[int]:
+        """Returns ``pool_aliased_bytes`` (also set as the gauge of that
+        name): see :meth:`_warm_pool_programs`."""
         sched = self.scheduler
+        sched.require_idle()
         pad_key = sched._pad_key
         T = sched.table_blocks
-        calls = []
-        for bb in sched.batch_buckets:
-            keys = jnp.stack([pad_key] * bb)
-            gi = np.zeros((bb,), np.int32)
-            aids = np.full((bb,), -1, np.int32)
-            last_col = np.zeros((bb,), np.int32)
-            tables = np.zeros((bb, T), np.int32)
-            for sb in sched.seq_buckets:
-                calls.append((sched._fns.prefill, (
-                    sched.params, sched._pool,
-                    np.zeros((bb, sb), np.int32),
-                    np.full((bb, sb), -1, np.int32),
-                    tables, last_col, keys, gi, aids,
-                )))
         W = sched.slots_n
         pos = np.full((W,), -1, np.int32)
-        dtables = np.zeros((W, T), np.int32)
-        dgi = np.zeros((W,), np.int32)
-        daids = np.full((W,), -1, np.int32)
-        dkeys = jnp.stack([pad_key] * W)
+        tables = np.zeros((W, T), np.int32)
+        zeros = np.zeros((W,), np.int32)
+        aids = np.full((W,), -1, np.int32)
+        keys = jnp.stack([pad_key] * W)
+
+        def prefills(fns, params):
+            for bb in sched.batch_buckets:
+                bkeys = jnp.stack([pad_key] * bb)
+                for sb in sched.seq_buckets:
+                    yield (fns.prefill, (params,), (
+                        np.zeros((bb, sb), np.int32),
+                        np.full((bb, sb), -1, np.int32),
+                        np.zeros((bb, T), np.int32),
+                        np.zeros((bb,), np.int32), bkeys,
+                        np.zeros((bb,), np.int32),
+                        np.full((bb,), -1, np.int32),
+                    ), 2)
+
+        def decode(fns, params):
+            return (fns.decode_step, (params,),
+                    (zeros, pos, tables, keys, zeros, aids), 2)
+
+        fns = sched._fns
         dparams = sched._qparams if sched._quant else sched.params
-        calls.append((sched._fns.decode_step, (
-            dparams, sched._pool, np.zeros((W,), np.int32), pos, dtables,
-            dkeys, dgi, daids,
-        )))
-        self._compile_side_by_side(calls)
-        for fn, args in calls:
-            tok, *_ = fn(*args)
-            jax.block_until_ready(tok)
+        calls = [*prefills(fns, sched.params), decode(fns, dparams)]
         if sched._async_depth:
             # _zero_carry matches the program's own token-output sharding,
             # so this single call covers both the first dispatch and the
             # steady-state carried-token dispatch (one cache entry)
-            tok, *_ = sched._fns.decode_step_fed(
-                dparams, sched._pool, sched._zero_carry(),
-                np.zeros((W,), bool), np.zeros((W,), np.int32), pos,
-                dtables, dkeys, dgi, daids,
-            )
-            jax.block_until_ready(tok)
+            calls.append((fns.decode_step_fed, (dparams,), (
+                sched._zero_carry(), np.zeros((W,), bool), zeros, pos,
+                tables, keys, zeros, aids,
+            ), 2))
         if sched._spec is not None:
-            self._warmup_speculative(sched)
-
-    def _warmup_speculative(self, sched) -> None:
-        """The speculative round's extra programs: the verify scorer and
-        the fork's row copy on the target side, plus the draft model's
-        own prefill/decode set over the draft pool."""
-        W = sched.slots_n
-        T = sched.table_blocks
-        k = sched._spec.k
-        pad_keys = jnp.stack([sched._pad_key] * W)
-        aids = np.full((W,), -1, np.int32)
-        logits, _pool = sched._fns.verify(
-            sched.params, sched._pool,
-            np.zeros((W, k + 1), np.int32),
-            np.full((W, k + 1), -1, np.int32),
-            np.zeros((W, T), np.int32), aids,
-        )
-        jax.block_until_ready(logits)
-        n_rows = sched._kv.num_blocks * sched._kv.block_size
-        oob = np.full((W * sched._kv.block_size,), n_rows, np.int32)
-        jax.block_until_ready(sched._fns.copy_rows(sched._pool, oob, oob))
-        for bb in sched.batch_buckets:
-            keys = jnp.stack([sched._pad_key] * bb)
-            for sb in sched.seq_buckets:
-                tok, *_ = sched._draft_fns.prefill(
-                    sched._draft_params, sched._draft_pool,
-                    np.zeros((bb, sb), np.int32),
-                    np.full((bb, sb), -1, np.int32),
-                    np.zeros((bb, T), np.int32),
-                    np.zeros((bb,), np.int32), keys,
-                    np.zeros((bb,), np.int32), np.full((bb,), -1, np.int32),
-                )
-                jax.block_until_ready(tok)
-        tok, *_ = sched._draft_fns.decode_step(
-            sched._draft_params, sched._draft_pool,
-            np.zeros((W,), np.int32), np.full((W,), -1, np.int32),
-            np.zeros((W, T), np.int32), pad_keys,
-            np.zeros((W,), np.int32), aids,
-        )
-        jax.block_until_ready(tok)
+            # the speculative round's extra programs on the target side:
+            # the verify scorer and the fork's row copy
+            k = sched._spec.k
+            n_rows = sched._kv.num_blocks * sched._kv.block_size
+            oob = np.full((W * sched._kv.block_size,), n_rows, np.int32)
+            calls.append((fns.verify, (sched.params,), (
+                np.zeros((W, k + 1), np.int32),
+                np.full((W, k + 1), -1, np.int32), tables, aids,
+            ), 1))
+            calls.append((fns.copy_rows, (), (oob, oob), None))
+        aliased = self._warm_pool_programs(calls, sched, "_pool")
+        if sched._spec is not None:
+            # ... and the draft model's own prefill/decode set over its pool
+            dfns, dparams = sched._draft_fns, sched._draft_params
+            self._warm_pool_programs(
+                [*prefills(dfns, dparams), decode(dfns, dparams)],
+                sched, "_draft_pool",
+            )
+        if aliased is not None:
+            self.metrics.record_pool_aliased(aliased)
+        return aliased
 
     def _warmup_batcher(self) -> None:
         """Batcher-path warmup: one (prefill, decode) execution per

@@ -116,8 +116,9 @@ class BlockRef:
     sync) and the expensive part — device→host copies plus the CRC seal
     — runs on a staging executor (serving/disagg.py).  Safety: the
     slices are taken at a tick boundary while the pool is quiescent, and
-    JAX arrays are immutable, so the snapshot stays valid even after the
-    scheduler functionally replaces its pool on later ticks.
+    each is a NEW buffer, not a view of its leaf, so the snapshot stays
+    valid after later ticks donate the pool to the decode programs and
+    its leaves are updated in place.  A ref never holds a leaf itself.
     """
 
     key: tuple

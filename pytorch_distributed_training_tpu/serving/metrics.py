@@ -342,6 +342,13 @@ class ServingMetrics:
             self._scale_up_ready_ms = float(ms)
         self._registry.gauge("scale_up_ready_ms").set(float(ms))
 
+    def record_pool_aliased(self, nbytes: int) -> None:
+        """Bytes of the paged pool that the warm-up's programs update in
+        place (the fewest over the programs, from their compiled forms):
+        the pool's size where the donation took, 0 where XLA fell back to
+        copying the pool before every step."""
+        self._registry.gauge("pool_aliased_bytes").set(float(nbytes))
+
     def record_kv_transfer(
         self, *, nbytes: int, seconds: float, blocks: int
     ) -> None:

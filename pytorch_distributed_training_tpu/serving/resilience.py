@@ -21,11 +21,17 @@ fallback and walks a recovery ladder:
    anomaly guard.
 2. **Non-attributable errors → hot-restart with replay.**  Device loss
    (:class:`..engine.fault.DeviceLostError`, real ``XlaRuntimeError``),
-   a hung tick (:class:`HungTickError` from the tick watchdog), or a
-   non-reproducible probe escalate to ``_rebuild_and_requeue``: the
-   compiled prefill/decode programs and the paged pool are rebuilt and
-   every in-flight request is re-admitted; the scheduler re-prefills
-   ``prompt + tokens_generated_so_far`` and re-feeds the generated
+   a hung tick (:class:`HungTickError` from the tick watchdog), a
+   non-reproducible probe, or a LOST POOL escalate to
+   ``_rebuild_and_requeue``.  The decode programs consume the pool they
+   are given (serving/decode.py), so a call that raises after its
+   dispatch can leave the scheduler's pool deleted; injected faults raise
+   before the dispatch and leave it whole.  No probe can run on a deleted
+   pool (each would raise, and the bisect would name whoever was left),
+   so ``_pool_lost()`` is asked before the bisect and again before its
+   verdict.  The compiled prefill/decode programs and the paged pool are
+   rebuilt and every in-flight request is re-admitted; the scheduler
+   re-prefills ``prompt + tokens_generated_so_far`` and re-feeds the generated
    tokens through the SAME decode program that produced them, so the
    continuation is token-identical (the replay parity oracle pins it
    bitwise, greedy and sampled).
@@ -140,7 +146,10 @@ class ServingSupervisor:
         # us the failure (scheduler.tick), so probe/replay state below
         # is sync-equivalent: host-known streams match the device, and
         # dispatch counters are rolled back to gen_idx.
-        if not _is_device_loss(exc) and sched._tick_phase == "decode":
+        if (
+            not _is_device_loss(exc) and sched._tick_phase == "decode"
+            and not sched._pool_lost()
+        ):
             # span = the serve-side MTTR anchor (telemetry/slo.py): recovery
             # start → first post-recovery decode tick
             with span("poison_bisect", step=sched._tick_no,
@@ -186,6 +195,8 @@ class ServingSupervisor:
             cands = half if self._probe_raises(half) else cands[len(cands) // 2 :]
         if not self._probe_raises(cands):
             return False  # the fault needed company — not one request's
+        if sched._pool_lost():
+            return False  # a probe lost the pool: every later one raised
         sched._evict_poisoned(cands[0], cause=exc, trigger="decode raise")
         return True
 

@@ -28,6 +28,15 @@ accounting.  Counters flow through :class:`ServingMetrics` and are
 mirrored into the process telemetry registry (``serving_*``) so the
 one-ledger rule holds.
 
+The pool is updated in place: every program that returns the pool takes it
+donated (serving/decode.py), so each call site rebinds ``self._pool`` /
+``self._draft_pool`` to what the call returned on the line that makes the
+call, and nothing here keeps a pool LEAF across a call: exports take
+slices, the fault injector and the importer build new leaves
+(``tree_map``).  A call that raises after its dispatch can leave the pool
+deleted; :meth:`ContinuousScheduler._pool_lost` says so and the supervisor
+then rebuilds the pool and replays instead of probing.
+
 Fault tolerance (PR 9, serving/resilience.py): a tick exception no
 longer fails the world — :class:`ServingSupervisor` classifies it and
 either evicts the one poisoned request (poison-bisect over
@@ -601,6 +610,30 @@ class ContinuousScheduler:
         """
         with self._cond:
             return sum(1 for s in self._slots if s is not None)
+
+    def require_idle(self) -> None:
+        """Raise while the scheduler holds work.  The engine's warm-up asks
+        before it consumes the pool from its caller's thread: a tick
+        beside it would find the pool it reads already donated."""
+        with self._cond:
+            busy = (
+                bool(self._queue) or bool(self._xfer_q)
+                or any(s is not None for s in self._slots)
+            )
+        if busy:
+            raise RuntimeError(
+                "the warm-up consumes the scheduler's pool and cannot run "
+                "beside queued or in-flight requests: call it before traffic"
+            )
+
+    def _pool_lost(self) -> bool:
+        """True once a leaf of the pool (or of the draft's) is deleted: a
+        donating program raised AFTER it took the pool, so no program can
+        run until :meth:`_rebuild_and_requeue` has made a new one."""
+        pools = (self._pool, self._draft_pool)
+        return any(
+            leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pools)
+        )
 
     def compile_count(self) -> int:
         """Distinct XLA programs compiled so far: bounded by the prefill

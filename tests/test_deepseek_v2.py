@@ -382,3 +382,36 @@ def test_a_model_without_experts_keeps_its_programs_outputs():
         np.zeros((2, 2), np.int32), jnp.stack([jax.random.PRNGKey(0)] * 2),
         np.zeros(2, np.int32), np.full(2, -1, np.int32))
     assert len(out) == 3
+
+
+@pytest.mark.parametrize("name", ["prefill", "decode_step", "decode_step_fed"])
+def test_every_latent_pool_leaf_is_donated_to_the_program(weights, name):
+    """The latent pool's leaves (one a layer) are donated like a K/V pair's:
+    the lowered program marks each for reuse, and the pool passed in is gone
+    once the call is made; the one returned is the pool."""
+    from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
+
+    _, _, tree = weights
+    model, p = program("float32", tree)
+    fns = build_paged_fns(model, 4, 8)
+    pool = fns.init_pool(p)
+    n_leaves = len(jax.tree_util.tree_leaves(pool))
+    assert n_leaves == 3
+    keys = jnp.stack([jax.random.PRNGKey(0)] * 2)
+    row = np.zeros(2, np.int32)
+    tables = np.zeros((2, 2), np.int32)
+    none = np.full(2, -1, np.int32)
+    args = {
+        "prefill": (p, pool, np.zeros((2, 4), np.int32),
+                    np.full((2, 4), -1, np.int32), tables, row, keys, row, none),
+        "decode_step": (p, pool, row, none, tables, keys, row, none),
+        "decode_step_fed": (p, pool, row, np.zeros(2, bool), row, none, tables,
+                            keys, row, none),
+    }[name]
+    text = getattr(fns, name).lower(*args).as_text()
+    marked = text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+    assert marked == n_leaves
+    out = getattr(fns, name)(*args)
+    assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pool))
+    assert jax.tree.structure(out[2]) == jax.tree.structure(pool)
+    assert not any(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(out[2]))

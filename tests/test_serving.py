@@ -1585,3 +1585,124 @@ def test_decode_step_carries_the_trace_scopes(lm_and_params):
     assert [n for n in names if "/attn/paged_attention/" in n]
     assert "jit(decode_step)/sample" in names
     assert [n for n in names if "loss_head/head" in n]  # the logits matmul
+
+
+# --------------------------------------------------------------------- #
+# the pool is donated: each program consumes the pool it is given
+
+
+def donated_leaves(program, *args) -> int:
+    """How many arguments of the lowered program are marked for XLA to
+    reuse: aliased to an output, or offered as a donor."""
+    text = program.lower(*args).as_text()
+    return text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+
+
+def pool_program_args(sched, name):
+    """Arguments, in order, with which the scheduler calls ``_fns.<name>``
+    (no request active: every row rides at position -1)."""
+    W, T = sched.slots_n, sched.table_blocks
+    prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
+    keys = jnp.stack(keys)
+    tokens = np.zeros((W, 8), np.int32)
+    positions = np.full((W, 8), -1, np.int32)
+    oob = np.full((W,), sched._kv.num_blocks * sched._kv.block_size, np.int32)
+    return {
+        "prefill": (sched.params, sched._pool, tokens, positions, tables,
+                    np.zeros((W,), np.int32), keys, gen_idx, aids),
+        "decode_step": (sched.params, sched._pool, prev, pos, tables, keys,
+                        gen_idx, aids),
+        "decode_step_fed": (sched.params, sched._pool, prev,
+                            np.zeros((W,), bool), prev, pos, tables, keys,
+                            gen_idx, aids),
+        "verify": (sched.params, sched._pool, tokens, positions,
+                   np.zeros((W, T), np.int32), aids),
+        "copy_rows": (sched._pool, oob, oob),
+    }[name]
+
+
+POOL_PROGRAMS = ["prefill", "decode_step", "decode_step_fed", "verify", "copy_rows"]
+
+
+@pytest.mark.parametrize("name", POOL_PROGRAMS)
+def test_every_pool_leaf_is_donated_to_the_program(lm_and_params, name):
+    """ONE rule: a program that returns the pool consumes the pool it was
+    given, so its scatter writes the caller's buffers and not a copy."""
+    model, params = lm_and_params
+    sched = _paged_sched(model, params)
+    n_leaves = len(jax.tree_util.tree_leaves(sched._pool))
+    assert n_leaves >= 4  # a K and a V leaf a layer, two layers
+    args = pool_program_args(sched, name)
+    assert donated_leaves(getattr(sched._fns, name), *args) == n_leaves
+    # ... and running it leaves the caller without the pool it passed
+    out = getattr(sched._fns, name)(*args)
+    new_pool = out if name == "copy_rows" else out[1 if name == "verify" else 2]
+    sched.close()
+    assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(sched._pool))
+    assert not any(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(new_pool))
+
+
+def _warm_engine_cfg(**scheduler_more):
+    return {
+        "dataset": {"name": "synthetic_text", "n_classes": VOCAB},
+        "model": {"name": "TransformerLM", "embed_dim": 32, "depth": 2,
+                  "num_heads": 4, "max_len": 32},
+        "serving": {
+            "dtype": "float32", "max_batch_size": 4, "max_delay_ms": 2,
+            "batch_buckets": [4], "seq_buckets": [8], "max_new_tokens": 4,
+            "temperature": 0.0,
+            "scheduler": dict({"enabled": True, "slots": 4, "block_size": 4,
+                               "num_blocks": 32, "prefix_cache": False},
+                              **scheduler_more),
+        },
+    }
+
+
+@pytest.mark.parametrize("mode", ["sync", "async_ring", "speculative"])
+def test_warmup_hands_the_scheduler_its_pool_back(mode):
+    """The warm-up runs the donating programs on the scheduler's own pool:
+    what it leaves in ``_pool`` (and ``_draft_pool``) is alive, holds what
+    it held, and the gauge says how much of it the programs update in
+    place: all of it."""
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = _warm_engine_cfg(**({"async_depth": 2} if mode == "async_ring" else {}))
+    if mode == "speculative":
+        cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
+    prompt = np.asarray([4, 8, 15, 16, 23], np.int32)
+    with InferenceEngine.from_config(cfg) as engine:
+        sched = engine.scheduler
+        before = engine.submit(prompt).result(timeout=120)["tokens"]
+        assert "pool_aliased_bytes" not in engine.metrics.snapshot()
+        engine.warmup()
+        pools = [sched._pool] + (
+            [sched._draft_pool] if mode == "speculative" else [])
+        for pool in pools:
+            leaves = jax.tree_util.tree_leaves(pool)
+            assert leaves and not any(leaf.is_deleted() for leaf in leaves)
+        assert engine.warmup()["programs"] == 0  # idempotent, pool intact
+        warm = engine.compile_count()
+        after = engine.submit(prompt).result(timeout=120)["tokens"]
+        np.testing.assert_array_equal(after, before)
+        assert engine.compile_count() == warm
+        pool_bytes = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(sched._pool))
+        assert engine.metrics.snapshot()["pool_aliased_bytes"] == pool_bytes > 0
+
+
+def test_warmup_is_refused_beside_queued_work(lm_and_params):
+    """A warm-up consumes the pool from the caller's thread: with a request
+    queued or in a slot a tick could meet the donated pool, so it is
+    refused, and allowed again once the scheduler is empty."""
+    model, params = lm_and_params
+    sched = _paged_sched(model, params)
+    sched.require_idle()
+    fut = sched.submit(np.asarray([3, 4, 5], np.int32))
+    with pytest.raises(RuntimeError, match="warm-up consumes the scheduler's pool"):
+        sched.require_idle()
+    sched.tick()  # admitted: in a slot, no longer queued
+    with pytest.raises(RuntimeError, match="before traffic"):
+        sched.require_idle()
+    _run_scheduler_to_done(sched, [fut])
+    sched.require_idle()
+    sched.close()

@@ -779,3 +779,68 @@ def test_async_poison_isolation_decode_raise(lm_and_params, depth):
     snap = sched.metrics.snapshot()
     assert snap["requests_poisoned"] == 1
     assert sched._kv.blocks_in_use == 0
+
+
+# --------------------------------------------------------------------- #
+# the pool is donated: a call that raises AFTER it took the pool leaves
+# none to probe with
+
+
+def _lose_the_pool_once(sched, monkeypatch):
+    """The next ``decode_step`` behaves like a donating call that failed
+    after its dispatch: the pool it was given is deleted, and it raises an
+    error that names no device (so the ladder's first rung is the bisect)."""
+    real = sched._fns.decode_step
+    calls = []
+
+    def lossy(params, pool, *rest):
+        if not calls:
+            calls.append(1)
+            for leaf in jax.tree_util.tree_leaves(pool):
+                leaf.delete()
+            raise RuntimeError("the step failed after it consumed the pool")
+        return real(params, pool, *rest)
+
+    monkeypatch.setattr(sched._fns, "decode_step", lossy)
+    return calls
+
+
+@pytest.mark.parametrize("lost_by", ["the_step", "a_probe"])
+def test_a_lost_pool_ends_in_the_restart_not_in_a_bisect(
+    lm_and_params, monkeypatch, lost_by
+):
+    """No program can run on a deleted pool, so every probe of a bisect over
+    one would raise and the last candidate standing would be evicted for it.
+    The supervisor restarts instead: new pool, delivered tokens replayed,
+    every request that poisoned nothing completes as in a clean run."""
+    model, params = lm_and_params
+    _, clean = _run_under_spec(model, params, None)
+    ref = [f.result()["tokens"] for f in clean]
+
+    # a_probe: slot 1 raises BEFORE the dispatch at tick 2 (pool intact, the
+    # bisect starts), then the first probe that reaches the device loses it
+    fault.install("serve_raise@2:1" if lost_by == "a_probe" else None)
+    try:
+        sched = _make_sched(model, params)
+        futs = [sched.submit(p) for p in _prompts()]
+        sched.tick()  # admit + prefill + the first decode step
+        calls = _lose_the_pool_once(sched, monkeypatch)
+        _drive(sched, futs)
+    finally:
+        fault.install(None)
+    assert calls and sched._supervisor.restarts() == 1
+    assert not sched._pool_lost()
+    snap = sched.metrics.snapshot()
+    if lost_by == "the_step":
+        assert snap.get("poison_probes", 0) == 0  # never probed a dead pool
+        assert snap.get("requests_poisoned", 0) == 0
+        survivors = (0, 1, 2)
+    else:
+        # after the restart the injected raise is found and evicted alone
+        assert isinstance(futs[1].exception(), PoisonedRequestError)
+        assert snap["requests_poisoned"] == 1
+        survivors = (0, 2)
+    for i in survivors:
+        np.testing.assert_array_equal(futs[i].result()["tokens"], ref[i])
+    assert snap.get("replay_parity_mismatch", 0) == 0
+    assert sched._kv.blocks_in_use == 0
