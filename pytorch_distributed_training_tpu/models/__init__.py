@@ -9,13 +9,19 @@ Families: the reference's ResNet-18/34/50/101/152 (README.md:7-13) plus a
 ViT family (ViT-Ti16/S16/B16) and a decoder-only ``TransformerLM`` (the
 long-context / sequence-parallel model) added beyond the reference — the
 config surface only pins ``model.name``, so new names slot straight in —
-and ``DeepseekV2`` (:mod:`.deepseek_v2`: latent attention, dropless experts;
-served, not trained).
+``DeepseekV2`` (:mod:`.deepseek_v2`: latent attention, dropless experts;
+served, not trained) and ``SolarOpen2`` (:mod:`.solar_open2`: gated
+delta-rule linear layers that carry a state a sequence, one grouped-query
+layer without positions in four, dropless experts in every layer; served,
+not trained).  The two served families share their norm, head and expert
+layer through :mod:`.lm_parts`.
 
 What a model IS is stated by its class, not compared by name:
 ``is_language_model`` (tokens in, logits out; the ``num_classes`` slot is
-the vocabulary) and ``training_unsupported`` (a message where the training
-path must refuse it).  :func:`model_class` gives the class for a name.
+the vocabulary), ``training_unsupported`` (a message where the training
+path must refuse it), ``moe_shape`` (expert layers) and ``state_shape`` (a
+fixed-size state a sequence beside the paged pool: the serving layers that
+assume a cache of token rows alone refuse such a model).  :func:`model_class` gives the class for a name.
 """
 from __future__ import annotations
 
@@ -25,6 +31,7 @@ import jax.numpy as jnp
 
 from .deepseek_v2 import DeepseekV2LM
 from .resnet import RESNET_CONFIGS, BasicBlock, Bottleneck, ResNet
+from .solar_open2 import SolarOpen2LM
 from .transformer_lm import TransformerLM
 from .vit import VIT_CONFIGS, ViT
 
@@ -33,6 +40,7 @@ __all__ = [
     "list_models",
     "model_class",
     "DeepseekV2LM",
+    "SolarOpen2LM",
     "ResNet",
     "BasicBlock",
     "Bottleneck",
@@ -44,7 +52,10 @@ _CANONICAL = {name.lower(): name for name in RESNET_CONFIGS}
 _CANONICAL.update({name.lower(): name for name in VIT_CONFIGS})
 # the language-model families: name -> class (the class takes
 # ``vocab_size=num_classes`` and the ``model:`` section's keys verbatim)
-_LM_FAMILIES = {"TransformerLM": TransformerLM, "DeepseekV2": DeepseekV2LM}
+_LM_FAMILIES = {
+    "TransformerLM": TransformerLM, "DeepseekV2": DeepseekV2LM,
+    "SolarOpen2": SolarOpen2LM,
+}
 _CANONICAL.update({name.lower(): name for name in _LM_FAMILIES})
 
 
@@ -82,7 +93,7 @@ def get_model(
         section here (e.g. ``embed_dim/depth/num_heads/max_len/seq_axis``
         for ``TransformerLM``).
 
-    For a language model (``TransformerLM``, ``DeepseekV2``) the reference's
+    For a language model (``TransformerLM``, ``DeepseekV2``, ``SolarOpen2``) the reference's
     ``num_classes`` slot is the vocabulary size (``dataset.n_classes`` in the
     config).
     """

@@ -35,20 +35,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..ops.mla import MLAttention, rms_norm
-from ..ops.moe import DroplessMoE, in_token_chunks, swiglu
+from ..ops.mla import MLAttention
+from ..ops.moe import in_token_chunks, swiglu
+from .lm_parts import (
+    RMSNorm, add_moe_counts, expert_ffn, final_logits, sow_moe_stats,
+)
 
 __all__ = ["DeepseekV2LM"]
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-6
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), self.dtype)
-        return rms_norm(x, scale, self.eps)
 
 
 class GatedMLP(nn.Module):
@@ -84,22 +77,6 @@ _LAYER_FIELDS = (
 # the LM's fields a layer reads, as one hashable value (a flax module cannot
 # hold its parent as a field)
 LayerConfig = collections.namedtuple("LayerConfig", _LAYER_FIELDS)
-
-
-class Head(nn.Module):
-    """Untied, bias-free output projection: operands in ``dtype``, logits
-    accumulated and returned in float32."""
-
-    vocab_size: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(),
-            (x.shape[-1], self.vocab_size), self.dtype,
-        )
-        return jnp.dot(x, kernel, preferred_element_type=jnp.float32)
 
 
 class DecoderLayer(nn.Module):
@@ -138,19 +115,7 @@ class DecoderLayer(nn.Module):
         if not self.expert:
             out = GatedMLP(c.intermediate_size, c.dtype, name="mlp")(flat)
             return x + out.reshape(b, s, dim), None
-        with jax.named_scope("moe"):
-            out, sizes = DroplessMoE(
-                dim=dim,
-                num_experts=c.n_routed_experts,
-                top_k=c.num_experts_per_tok,
-                hidden=c.moe_intermediate_size,
-                shared_hidden=c.n_shared_experts * c.moe_intermediate_size,
-                norm_topk_prob=c.norm_topk_prob,
-                routed_scaling_factor=c.routed_scaling_factor,
-                experts_held=c.experts_held,
-                dtype=c.dtype,
-                name="moe",
-            )(flat, token_mask)
+        out, sizes = expert_ffn(c, flat, token_mask)
         return x + out.reshape(b, s, dim), sizes
 
 
@@ -281,20 +246,14 @@ class DeepseekV2LM(nn.Module):
         )
         x = jnp.take(emb, tokens, axis=0).astype(self.dtype)
         token_mask = None if decode_pos is None else (decode_pos >= 0).reshape(-1)
-        hit = load_max = jnp.zeros((), jnp.int32)
+        counts = add_moe_counts(None, None)
         config = LayerConfig(*(getattr(self, f) for f in _LAYER_FIELDS))
         for i in range(self.num_hidden_layers):
             x, sizes = DecoderLayer(
                 config=config, expert=self._is_expert_layer(i), name=f"layer{i}"
             )(x, decode_pos, block_tables, token_mask)
-            if sizes is not None:
-                hit = hit + jnp.sum(sizes > 0).astype(jnp.int32)
-                load_max = load_max + jnp.max(sizes).astype(jnp.int32)
+            counts = add_moe_counts(counts, sizes)
         if self.moe_shape:
-            self.sow("moe_stats", "experts_hit", hit)
-            self.sow("moe_stats", "expert_load_max", load_max)
-        if logit_cols is not None:
-            x = jnp.take_along_axis(x, logit_cols[:, None, None], axis=1)
-        with jax.named_scope("loss_head"):
-            x = RMSNorm(self.rms_norm_eps, self.dtype, name="norm")(x)
-            return Head(self.vocab_size, self.dtype, name="head")(x)
+            sow_moe_stats(self, counts)
+        return final_logits(
+            x, logit_cols, self.rms_norm_eps, self.vocab_size, self.dtype)

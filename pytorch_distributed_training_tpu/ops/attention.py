@@ -18,9 +18,11 @@ equivalent in tests/test_sequence_parallel.py.
 
 The paged pool (serving/kv_pool.py) holds rows of whatever the layer's
 attention stores, one row a token a layer, in the ``"cache"`` collection.
-Two layouts exist: a K/V PAIR (:class:`MultiHeadAttention`: two leaves
-``[pool_rows, H, hd]``) and a LATENT row (:class:`..ops.mla.MLAttention`:
-one leaf ``[pool_rows, rank + rope]`` shared by every head).  Serving code
+Two layouts exist: a K/V PAIR (:class:`MultiHeadAttention` and
+:class:`GroupedQueryAttention`: two leaves ``[pool_rows, Hkv, hd]``, read
+through the one :func:`paged_attention`) and a LATENT row
+(:class:`..ops.mla.MLAttention`: one leaf ``[pool_rows, rank + rope]``
+shared by every head).  Serving code
 finds the leaves through :func:`pool_leaf_role`, which the attention
 modules answer, never by spelling a leaf's name itself.
 """
@@ -37,7 +39,10 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.sequence import ring_attention, ulysses_attention
 from ..utils.vma import varying_axes_of
 
-__all__ = ["dot_product_attention", "MultiHeadAttention", "pool_leaf_role"]
+__all__ = [
+    "GroupedQueryAttention", "MultiHeadAttention", "dot_product_attention",
+    "is_state_leaf", "paged_attention", "pool_leaf_role",
+]
 
 # The paged pool's leaves, under the names the attention modules give them.
 # A SCORED leaf is one a query's logits are computed against: a NaN in one of
@@ -45,20 +50,44 @@ __all__ = ["dot_product_attention", "MultiHeadAttention", "pool_leaf_role"]
 # every other reader (scheduler ``_corrupt_pool_rows`` rests on that).
 KEY_POOL, VALUE_POOL, LATENT_POOL = "k_pool", "v_pool", "latent_pool"
 _POOL_ROLES = {KEY_POOL: "scored", VALUE_POOL: "value", LATENT_POOL: "scored"}
+# The cache tree's OTHER kind of leaf: ``[slots, ...]``, one entry a sequence
+# (:mod:`..ops.kda`: the delta-rule state and the convolution's last rows),
+# addressed by ``state_rows`` and never through a block table.  They have no
+# role among the pool's rows, whatever their leading size.
+KDA_STATE, KDA_CONV = "kda_state", "kda_conv"
+# query rows of one batch row whose scores the grouped path builds at once
+QUERY_BLOCK = 512
+STATE_LEAVES = (KDA_STATE, KDA_CONV)
+
+
+def _leaf_name(path) -> str:
+    """The name of the variable a cache leaf belongs to: the last component
+    of its jax key path that is a name at all."""
+    for part in reversed(path):
+        name = str(getattr(part, "key", getattr(part, "name", "")))
+        if name:
+            return name
+    return ""
 
 
 def pool_leaf_role(path, leaf, pool_rows: int) -> Optional[str]:
     """``"scored"`` / ``"value"`` for a per-row leaf of the paged pool,
     ``None`` for anything else in the cache tree.  ``path`` is the leaf's
     jax key path; a pool leaf is one an attention module declared (by the
-    name of its variable) AND whose leading dimension is the pool's rows."""
-    if not (hasattr(leaf, "ndim") and leaf.ndim >= 1 and leaf.shape[0] == pool_rows):
+    name of its variable) AND whose leading dimension is the pool's rows.
+    A per-slot state leaf (``STATE_LEAVES``) is told by its name: it has no
+    role here even where the slots happen to be as many as the rows."""
+    role = _POOL_ROLES.get(_leaf_name(path))
+    if role is None or not (
+        hasattr(leaf, "ndim") and leaf.ndim >= 1 and leaf.shape[0] == pool_rows
+    ):
         return None
-    for part in reversed(path):
-        role = _POOL_ROLES.get(str(getattr(part, "key", getattr(part, "name", ""))))
-        if role:
-            return role
-    return None
+    return role
+
+
+def is_state_leaf(path) -> bool:
+    """True for a per-slot state leaf of the cache tree (by its name)."""
+    return _leaf_name(path) in STATE_LEAVES
 
 def _use_flash(q) -> bool:
     """Trace-time flash-kernel eligibility for the local-attention path.
@@ -385,83 +414,224 @@ class MultiHeadAttention(nn.Module):
         return out.astype(q.dtype)
 
     def _paged_attention(self, q, k, v, positions, block_tables):
-        """Block-table gather attention against the shared paged KV pool.
-
-        ``positions`` [B, S] int32: each token's GLOBAL sequence position in
-        its request (-1 = padding column).  ``block_tables`` [B, T] int32:
-        physical pool block holding logical block ``t`` (positions
-        ``[t*bs, (t+1)*bs)``) of row ``b``.  The pool lives flattened as
-        ``[num_blocks * block_size, H, hd]`` in the "cache" collection —
-        scatter this call's k/v at their physical rows (padding scatters are
-        dropped via an out-of-bounds index), then gather each row's FULL
-        logical sequence back through its block table and mask keys to
-        ``key_pos <= q_pos``.  Because suffix k/v are scattered before the
-        gather, one code path serves cold prefill (positions 0..len-1),
-        chunked prefix-hit prefill (positions cached_len..len-1 reading the
-        shared prefix blocks), and single-token decode (S=1).  Gathered
-        garbage beyond a row's written length is masked to -inf, so recycled
-        block contents never leak into the softmax.
-        """
+        """Block-table gather attention against the shared paged KV pool
+        (:func:`paged_attention`, one K/V head a query head, the gathered
+        rows upcast to float32 as this module always had them)."""
         if self.seq_axis is not None:
             raise ValueError("paged decode is single-shard (seq_axis must be None)")
         if not self.causal:
             raise ValueError("paged decode requires causal attention")
-        bs, nb = self.kv_block_size, self.kv_num_blocks
-        if bs <= 0 or nb <= 0:
-            raise ValueError(
-                f"paged mode needs kv_block_size/kv_num_blocks > 0, "
-                f"got {bs}/{nb}"
-            )
-        if positions is None or block_tables is None:
-            raise ValueError("paged mode needs positions and block_tables")
-        b, s, num_heads, head_dim = q.shape
-        pool_rows = nb * bs
-        k_pool = self.variable(
-            "cache", KEY_POOL, jnp.zeros, (pool_rows, num_heads, head_dim),
-            self.dtype,
+        return paged_attention(
+            self, q, k, v, positions, block_tables,
+            block_size=self.kv_block_size, num_blocks=self.kv_num_blocks,
+            dtype=self.dtype, as_stored=False,
         )
-        v_pool = self.variable(
-            "cache", VALUE_POOL, jnp.zeros, (pool_rows, num_heads, head_dim),
-            self.dtype,
+
+
+def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
+                    num_blocks, dtype, as_stored, query_block=0):
+    """Block-table gather attention against the shared paged KV pool, for
+    ``q [B, S, H, hd]`` and ``k``, ``v [B, S, Hkv, hd]`` with ``H`` a
+    multiple of ``Hkv``: query head ``h`` reads K/V head ``h // (H / Hkv)``.
+
+    ``positions`` [B, S] int32: each token's GLOBAL sequence position in
+    its request (-1 = padding column).  ``block_tables`` [B, T] int32:
+    physical pool block holding logical block ``t`` (positions
+    ``[t*bs, (t+1)*bs)``) of row ``b``.  The pool lives flattened as
+    ``[num_blocks * block_size, Hkv, hd]`` in ``module``'s "cache"
+    collection — scatter this call's k/v at their physical rows (padding
+    scatters are dropped via an out-of-bounds index), then gather each row's
+    FULL logical sequence back through its block table and mask keys to
+    ``key_pos <= q_pos``.  Because suffix k/v are scattered before the
+    gather, one code path serves cold prefill (positions 0..len-1),
+    chunked prefix-hit prefill (positions cached_len..len-1 reading the
+    shared prefix blocks), and single-token decode (S=1).  Gathered
+    garbage beyond a row's written length is masked to -inf, so recycled
+    block contents never leak into the softmax.
+
+    ``as_stored=False`` upcasts the gathered rows to float32 before the
+    products (``TransformerLM``'s programs, unchanged); ``as_stored=True``
+    takes them in the pool's dtype and accumulates in float32 (at 32 rows of
+    4,608 positions the float32 copy is 1.2 GB a step).  ``query_block > 0``
+    builds the scores of a call longer than that for ``query_block`` query
+    rows of ONE batch row at a time, so that no ``[B, H, S, L]`` array
+    exists.
+    """
+    bs, nb = block_size, num_blocks
+    if bs <= 0 or nb <= 0:
+        raise ValueError(
+            f"paged mode needs kv_block_size/kv_num_blocks > 0, "
+            f"got {bs}/{nb}"
         )
-        valid = positions >= 0  # [B, S]
-        safe_pos = jnp.maximum(positions, 0)
-        blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)  # [B, S]
-        phys = jnp.where(valid, blk * bs + safe_pos % bs, pool_rows)  # OOB=drop
-        kp = k_pool.value.at[phys.reshape(-1)].set(
-            k.astype(self.dtype).reshape(b * s, num_heads, head_dim), mode="drop"
-        )
-        vp = v_pool.value.at[phys.reshape(-1)].set(
-            v.astype(self.dtype).reshape(b * s, num_heads, head_dim), mode="drop"
-        )
-        k_pool.value, v_pool.value = kp, vp
-        t_blocks = block_tables.shape[1]
-        length = t_blocks * bs
-        rows = (
-            (block_tables * bs)[:, :, None]
-            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
-        ).reshape(b, length)  # [B, L] physical rows in logical-position order
-        ck = kp[rows]  # [B, L, H, hd]
-        cv = vp[rows]
-        scale = 1.0 / math.sqrt(head_dim)
+    if positions is None or block_tables is None:
+        raise ValueError("paged mode needs positions and block_tables")
+    b, s, num_heads, head_dim = q.shape
+    kv_heads = k.shape[2]
+    group = num_heads // kv_heads
+    if group * kv_heads != num_heads:
+        raise ValueError(
+            f"{num_heads} query heads are no multiple of {kv_heads} K/V heads")
+    pool_rows = nb * bs
+    k_pool = module.variable(
+        "cache", KEY_POOL, jnp.zeros, (pool_rows, kv_heads, head_dim), dtype,
+    )
+    v_pool = module.variable(
+        "cache", VALUE_POOL, jnp.zeros, (pool_rows, kv_heads, head_dim), dtype,
+    )
+    valid = positions >= 0  # [B, S]
+    safe_pos = jnp.maximum(positions, 0)
+    blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)  # [B, S]
+    phys = jnp.where(valid, blk * bs + safe_pos % bs, pool_rows)  # OOB=drop
+    kp = k_pool.value.at[phys.reshape(-1)].set(
+        k.astype(dtype).reshape(b * s, kv_heads, head_dim), mode="drop"
+    )
+    vp = v_pool.value.at[phys.reshape(-1)].set(
+        v.astype(dtype).reshape(b * s, kv_heads, head_dim), mode="drop"
+    )
+    k_pool.value, v_pool.value = kp, vp
+    t_blocks = block_tables.shape[1]
+    length = t_blocks * bs
+    # [B, L] physical rows in logical-position order (the row-at-a-time gather)
+    rows = None if as_stored else (
+        (block_tables * bs)[:, :, None]
+        + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
+    ).reshape(b, length)
+
+    def gather(pool, tables):
+        """A batch's rows in logical order, ``[b, L, Hkv, hd]``.  Taken as
+        stored they are gathered a BLOCK at a time (the ``[blocks, bs, ...]``
+        view is a bitcast; a block is ``bs`` rows in one piece, where a row
+        at a time ran at a tenth of the memory's rate, PERF.md PR 26)."""
+        if not as_stored:
+            return pool[rows]
+        blocks = pool.reshape(nb, bs, kv_heads, head_dim)[tables]
+        return blocks.reshape(tables.shape[0], length, kv_heads, head_dim)
+
+    scale = 1.0 / math.sqrt(head_dim)
+    wide = (lambda x: x) if as_stored else (lambda x: x.astype(jnp.float32))
+    accumulate = jnp.float32 if as_stored else None
+    # one K/V head a query head: the products as they always were; a group
+    # of query heads a K/V head: the group is one more axis of q
+    if group == 1:
+        to_scores, to_out, head_axes = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd", 1
+    else:
+        to_scores, to_out, head_axes = "bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd", 2
+        q = q.reshape(b, s, kv_heads, group, head_dim)
+
+    def attend(q, q_pos, ck, cv):
+        """``q [b, s', ...]`` at positions ``q_pos [b, s']`` over the
+        gathered ``ck``, ``cv [b, L, Hkv, hd]``."""
         logits = jnp.einsum(
-            "bqhd,bkhd->bhqk", q.astype(jnp.float32), ck.astype(jnp.float32)
+            to_scores, wide(q), wide(ck), preferred_element_type=accumulate
         ) * scale
         live = (
             jnp.arange(length, dtype=jnp.int32)[None, None, :]
-            <= safe_pos[:, :, None]
-        )  # [B, S, L]; padding queries keep key 0 live so softmax stays finite
-        logits = jnp.where(live[:, None], logits, float("-inf"))
+            <= q_pos[:, :, None]
+        )  # [b, s', L]; padding queries keep key 0 live so softmax stays finite
+        logits = jnp.where(
+            live[(slice(None),) + (None,) * head_axes], logits, float("-inf"))
         p = jnp.asarray(nn.softmax(logits, axis=-1))
         # zero non-live VALUES too, not just their softmax weight: a NaN in
         # a dead gathered row (padded block-table entries alias block 0;
         # recycled blocks keep an evicted request's contents) would
         # otherwise leak through the contraction as 0 * NaN = NaN — the
         # serving output guard depends on NaN staying confined to the row
-        # that produced it.  Causal mask => a position live for any query
-        # of the row is live for its last one, so reduce over S.
-        cv = jnp.where(
-            live.any(axis=1)[:, :, None, None], cv.astype(jnp.float32), 0.0
-        )
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, cv)
-        return out.astype(q.dtype)
+        # that produced it.  Causal mask => a position live for any of
+        # these queries is live for the last of them, so reduce over s'.
+        cv = jnp.where(live.any(axis=1)[:, :, None, None], wide(cv), 0.0)
+        if as_stored:
+            p = p.astype(cv.dtype)
+        return jnp.einsum(to_out, p, cv, preferred_element_type=accumulate)
+
+    if query_block and s > query_block:
+        if s % query_block:
+            raise ValueError(
+                f"call of {s} positions is no multiple of query_block {query_block}")
+        pieces = s // query_block
+
+        def one_row(args):
+            q_row, pos_row, table_row = args  # [S, ...], [S], [T]
+            ck, cv = gather(kp, table_row[None]), gather(vp, table_row[None])
+            out = jax.lax.map(
+                lambda piece: attend(piece[0][None], piece[1][None], ck, cv)[0],
+                (q_row.reshape((pieces, query_block) + q_row.shape[1:]),
+                 pos_row.reshape(pieces, query_block)),
+            )
+            return out.reshape((s,) + out.shape[2:])
+
+        out = jax.lax.map(one_row, (q, safe_pos, block_tables))
+    else:
+        ck = gather(kp, block_tables)  # [B, L, Hkv, hd]
+        cv = gather(vp, block_tables)
+        out = attend(q, safe_pos, ck, cv)
+    if group > 1:
+        out = out.reshape(b, s, num_heads, head_dim)
+    return out.astype(q.dtype)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal softmax attention with fewer K/V heads than query heads and NO
+    position term (NoPE): ``q = x W_q`` (``H`` heads), ``k, v = x W_k, x W_v``
+    (``Hkv`` heads), query head ``h`` reads K/V head ``h // (H / Hkv)``,
+    scores ``q.k / sqrt(hd)``, softmax in float32, then with ``gate`` the
+    output gate ``o * sigmoid(x W_gate)`` (element-wise, arXiv:2505.06708)
+    before ``W_o``.  No bias.
+
+    ``decode=False``: plain causal attention over the call's own tokens.
+    ``decode=True, paged=True``: K/V rows of ``Hkv`` heads in the paged pool
+    (:func:`paged_attention`, the rows taken as stored, a long call's scores
+    built ``QUERY_BLOCK`` query rows at a time), under the scope
+    ``gqa_attention``."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    gate: bool = True
+    dtype: Any = jnp.float32
+    decode: bool = False
+    paged: bool = False
+    kv_block_size: int = 0
+    kv_num_blocks: int = 0
+
+    @nn.compact
+    def __call__(self, x, positions=None, block_tables=None):
+        b, s, dim = x.shape
+        h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        init = nn.initializers.lecun_normal()
+        wq = self.param("wq", init, (dim, h * hd), self.dtype)
+        wk = self.param("wk", init, (dim, hkv * hd), self.dtype)
+        wv = self.param("wv", init, (dim, hkv * hd), self.dtype)
+        wo = self.param("wo", init, (h * hd, dim), self.dtype)
+        with jax.named_scope("gqa_attention"):
+            q = jnp.dot(x, wq).reshape(b, s, h, hd)
+            k = jnp.dot(x, wk).reshape(b, s, hkv, hd)
+            v = jnp.dot(x, wv).reshape(b, s, hkv, hd)
+            if self.decode and not self.paged:
+                raise ValueError(
+                    "GroupedQueryAttention has no contiguous cache: decode "
+                    "mode is the paged pool's (paged=True)")
+            if self.decode:
+                out = paged_attention(
+                    self, q, k, v, positions, block_tables,
+                    block_size=self.kv_block_size,
+                    num_blocks=self.kv_num_blocks, dtype=self.dtype,
+                    as_stored=True, query_block=QUERY_BLOCK,
+                )
+            else:
+                group = h // hkv
+                scores = jnp.einsum(
+                    "bqhgd,bkhd->bhgqk", q.reshape(b, s, hkv, group, hd), k,
+                    preferred_element_type=jnp.float32,
+                ) / math.sqrt(hd)
+                causal = jnp.tril(jnp.ones((s, s), bool))
+                p = nn.softmax(jnp.where(causal, scores, float("-inf")), axis=-1)
+                out = jnp.einsum(
+                    "bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32,
+                ).astype(x.dtype)
+            out = out.reshape(b, s, h * hd)
+            if self.gate:
+                w_gate = self.param("w_gate", init, (dim, h * hd), self.dtype)
+                out = out * jax.nn.sigmoid(
+                    jnp.dot(x, w_gate).astype(jnp.float32)).astype(out.dtype)
+            return jnp.dot(out, wo)

@@ -65,6 +65,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import pool_leaf_role
 from ..ops.quant import dequantize_tree
 
 __all__ = ["build_generate_fn", "build_paged_fns"]
@@ -155,6 +156,13 @@ def build_generate_fn(
         raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
     if temperature < 0.0:
         raise ValueError(f"temperature must be >= 0, got {temperature}")
+    if getattr(model, "state_shape", None) is not None:
+        raise ValueError(
+            f"{type(model).__name__} carries a fixed-size state a sequence "
+            f"(state_shape {model.state_shape}) and the contiguous generate "
+            "path has no slots to keep it in: serve it through the "
+            "continuous scheduler (serving.scheduler.enabled: true)"
+        )
     decode_model = model.clone(decode=True)
     max_len = model.max_len
     sample = _make_sampler(temperature)
@@ -280,6 +288,10 @@ class _PagedFns:
     speculative fork's boundary-block CoW into the spare block.
     ``init_pool(params)`` — the zero pool pytree (``jax.eval_shape`` over
     the apply: correct flax cache paths, no throwaway compile).
+    For a model that carries a state a sequence (``model.state_shape``) the
+    four programs that run the model take ``state_rows`` [B] as their last
+    argument and the pool tree holds the ``[slots, ...]`` state leaves too
+    (:func:`build_paged_fns`).
     """
 
     def __init__(self, prefill, decode_step, init_pool, verify, copy_rows,
@@ -312,6 +324,7 @@ def build_paged_fns(
     num_blocks: int,
     temperature: float = 0.0,
     quant: bool = False,
+    state_slots: int = 0,
 ):
     """Compile the paged prefill/decode/verify set over a shared block pool.
 
@@ -332,7 +345,27 @@ def build_paged_fns(
     modes.  ``quant=True`` makes ``decode_step`` expect the int8 tree
     (ops/quant.quantize_tree) and dequantize in-graph; prefill and verify
     keep the plain tree.
+
+    A model that carries a fixed-size state a sequence says so
+    (``model.state_shape`` is not None).  Its cache tree then holds, beside
+    the pool's ``[pool_rows, ...]`` leaves and under the same donated
+    ``pool`` argument, ``[state_slots, ...]`` leaves (``init_pool`` makes
+    both), and every program that takes the pool takes ``state_rows`` [B]
+    int32 as its last argument: the slot each batch row's state lives in
+    (-1 = padding: nothing is written).  A row whose first position is 0
+    starts from a zero state, so a slot needs no clearing between requests.
+    The two decode programs are the scheduler's fixed-width step, whose
+    batch row ``i`` IS slot ``i``: they say so to the model
+    (``rows_are_slots=True``) and ``state_rows`` there only tells the live
+    rows from the padding.  ``copy_rows`` passes the state leaves by.  For any other model
+    ``state_rows`` stays None and the programs are what they were.
     """
+    carries_state = getattr(model, "state_shape", None) is not None
+    if carries_state and state_slots < 1:
+        raise ValueError(
+            f"{type(model).__name__} carries a state a sequence: "
+            f"build_paged_fns needs state_slots >= 1, got {state_slots}"
+        )
     if block_size < 1:
         raise ValueError(f"block_size must be >= 1, got {block_size}")
     if num_blocks < 1:
@@ -342,6 +375,7 @@ def build_paged_fns(
     paged_model = model.clone(
         decode=True, paged=True,
         kv_block_size=int(block_size), kv_num_blocks=int(num_blocks),
+        **({"state_slots": int(state_slots)} if carries_state else {}),
     )
     has_lora = getattr(paged_model, "lora_adapters", 0) > 0
     # a model with expert layers (it says so) sows two counts a call into
@@ -357,12 +391,21 @@ def build_paged_fns(
     # so the programs stay pure token-samplers and the stop conditions
     # (eos / per-request max_new) live in one place
     sample = _make_sampler(temperature)
+    # what the decode programs add to a call of a model that carries a state
+    fixed_width = {"rows_are_slots": True} if carries_state else {}
 
     def _apply(params, pool, tokens, positions, block_tables, adapter_ids,
-               **more):
+               state_rows, **more):
         args = (tokens, positions, block_tables)
         if has_lora:
             args = args + (adapter_ids,)
+        if carries_state:
+            if state_rows is None:
+                raise ValueError(
+                    f"{type(model).__name__} carries a state a sequence: "
+                    "every paged call names its rows' slots (state_rows)"
+                )
+            more["state_rows"] = state_rows
         return paged_model.apply(
             {"params": params, "cache": pool}, *args, mutable=mutable, **more,
         )
@@ -380,19 +423,20 @@ def build_paged_fns(
     @functools.partial(jax.jit, donate_argnames="pool")
     def prefill(
         params, pool, tokens, positions, block_tables, last_col, row_keys,
-        gen_index, adapter_ids=None,
+        gen_index, adapter_ids=None, state_rows=None,
     ):
         if getattr(paged_model, "takes_logit_cols", False):
             # the model gives the logits of one column a row (a [B, S, V]
             # in float32 need not fit beside its weights)
             logits, variables = _apply(
                 params, pool, tokens, positions, block_tables, adapter_ids,
-                logit_cols=last_col,
+                state_rows, logit_cols=last_col,
             )
             last = logits[:, 0]
         else:
             logits, variables = _apply(
-                params, pool, tokens, positions, block_tables, adapter_ids
+                params, pool, tokens, positions, block_tables, adapter_ids,
+                state_rows,
             )
             last = jnp.take_along_axis(
                 logits, last_col[:, None, None], axis=1)[:, 0]
@@ -402,13 +446,13 @@ def build_paged_fns(
     @functools.partial(jax.jit, donate_argnames="pool")
     def decode_step(
         params, pool, prev_tok, pos, block_tables, row_keys, gen_index,
-        adapter_ids=None,
+        adapter_ids=None, state_rows=None,
     ):
         if quant:
             params = dequantize_tree(params, jnp.float32)
         logits, variables = _apply(
             params, pool, prev_tok[:, None], pos[:, None], block_tables,
-            adapter_ids,
+            adapter_ids, state_rows, **fixed_width,
         )
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
         return _step_outputs(tok, logits[:, 0], variables)
@@ -416,7 +460,7 @@ def build_paged_fns(
     @functools.partial(jax.jit, donate_argnames="pool")
     def decode_step_fed(
         params, pool, prev_tok, fresh_mask, fresh_tok, pos, block_tables,
-        row_keys, gen_index, adapter_ids=None,
+        row_keys, gen_index, adapter_ids=None, state_rows=None,
     ):
         if quant:
             params = dequantize_tree(params, jnp.float32)
@@ -427,15 +471,17 @@ def build_paged_fns(
         prev = jnp.where(fresh_mask, fresh_tok, prev_tok)
         logits, variables = _apply(
             params, pool, prev[:, None], pos[:, None], block_tables,
-            adapter_ids,
+            adapter_ids, state_rows, **fixed_width,
         )
         tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
         return _step_outputs(tok, logits[:, 0], variables)
 
     @functools.partial(jax.jit, donate_argnames="pool")
-    def verify(params, pool, tokens, positions, block_tables, adapter_ids=None):
+    def verify(params, pool, tokens, positions, block_tables, adapter_ids=None,
+               state_rows=None):
         logits, variables = _apply(
-            params, pool, tokens, positions, block_tables, adapter_ids
+            params, pool, tokens, positions, block_tables, adapter_ids,
+            state_rows,
         )
         return logits.astype(jnp.float32), variables["cache"]
 
@@ -443,15 +489,14 @@ def build_paged_fns(
     def copy_rows(pool, src, dst):
         src_c = jnp.clip(src, 0, pool_rows - 1)
 
-        def cp(leaf):
-            if (
-                hasattr(leaf, "ndim") and leaf.ndim >= 1
-                and leaf.shape[0] == pool_rows
-            ):
+        def cp(path, leaf):
+            # the pool's per-row leaves, by what the attention modules
+            # declare; a per-slot state leaf is passed by whatever its size
+            if pool_leaf_role(path, leaf, pool_rows):
                 return leaf.at[dst].set(leaf[src_c], mode="drop")
             return leaf
 
-        return jax.tree_util.tree_map(cp, pool)
+        return jax.tree_util.tree_map_with_path(cp, pool)
 
     def init_pool(params):
         # any concrete shapes work — the pool's shape depends only on the
@@ -463,9 +508,10 @@ def build_paged_fns(
         ]
         if has_lora:
             init_args.append(jnp.zeros((1,), jnp.int32))
+        more = {"state_rows": jnp.zeros((1,), jnp.int32)} if carries_state else {}
         shapes = jax.eval_shape(
             lambda p: paged_model.apply(
-                {"params": p}, *init_args, mutable=["cache"],
+                {"params": p}, *init_args, mutable=["cache"], **more,
             )[1]["cache"],
             params,
         )

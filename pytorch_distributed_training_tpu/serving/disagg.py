@@ -234,6 +234,10 @@ class DisaggFleet:
                 fleet.replica_factory(100 + i) for i in range(n_prefill)
             ]
         self.prefill_replicas = list(prefill_replicas)
+        for rep in [*self.prefill_replicas, *fleet.replicas]:
+            # a model that carries a state a sequence cannot be served from
+            # transferred blocks: refused here, with the scheduler's reason
+            self._sched_of(rep)._refuse_kv_transfer()
         self.directory = FleetCacheDirectory(capacity)
         # membership coherence: remove_replica evicts through this hook
         fleet.cache_directory = self.directory

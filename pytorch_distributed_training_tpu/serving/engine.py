@@ -34,6 +34,7 @@ from ..parallel.mesh import (
     replicated_sharding,
 )
 from .batcher import DynamicBatcher, Request
+from ..ops.attention import is_state_leaf
 from .decode import build_generate_fn
 from .lora import LoraRegistry
 from .metrics import ServingMetrics
@@ -172,10 +173,15 @@ class InferenceEngine:
                     f"max_new_tokens {max_new_tokens} = {worst} exceeds "
                     f"model max_len {model.max_len}"
                 )
-            self._generate = build_generate_fn(
-                model, max_new_tokens, temperature=temperature, eos_id=eos_id,
-                quant=use_quant,
-            )
+            # the contiguous generate pair is the batcher path's; a model
+            # that carries a state a sequence has none (build_generate_fn
+            # refuses it with the reason) and is served by the scheduler
+            self._generate = None
+            if not (use_sched and getattr(model, "state_shape", None) is not None):
+                self._generate = build_generate_fn(
+                    model, max_new_tokens, temperature=temperature,
+                    eos_id=eos_id, quant=use_quant,
+                )
         else:
             normalize = _input_normalizer(input_norm)
 
@@ -509,7 +515,9 @@ class InferenceEngine:
         idempotent).  On the scheduler path it also sets the gauge
         ``pool_aliased_bytes`` of ``metrics.snapshot()``, how much of the
         pool the programs update in place, and logs it beside the program
-        count.  ``ServingFleet.add_replica`` calls this before routing
+        count; and the gauges ``kv_pool_bytes`` and ``state_cache_bytes``,
+        the cache tree's token rows and its per-slot state (0 for a model
+        that carries none), whose sum a full donation aliases.  ``ServingFleet.add_replica`` calls this before routing
         traffic to a new replica and publishes the wall time as the
         ``scale_up_ready_ms`` gauge.
         """
@@ -588,6 +596,10 @@ class InferenceEngine:
         aids = np.full((W,), -1, np.int32)
         keys = jnp.stack([pad_key] * W)
 
+        def no_slot(n):
+            # a model that carries a state: every row's slot is -1 (padding)
+            return sched._state_rows(np.full((n,), -1, np.int32))
+
         def prefills(fns, params):
             for bb in sched.batch_buckets:
                 bkeys = jnp.stack([pad_key] * bb)
@@ -598,12 +610,12 @@ class InferenceEngine:
                         np.zeros((bb, T), np.int32),
                         np.zeros((bb,), np.int32), bkeys,
                         np.zeros((bb,), np.int32),
-                        np.full((bb,), -1, np.int32),
+                        np.full((bb,), -1, np.int32), *no_slot(bb),
                     ), 2)
 
         def decode(fns, params):
             return (fns.decode_step, (params,),
-                    (zeros, pos, tables, keys, zeros, aids), 2)
+                    (zeros, pos, tables, keys, zeros, aids, *no_slot(W)), 2)
 
         fns = sched._fns
         dparams = sched._qparams if sched._quant else sched.params
@@ -614,7 +626,7 @@ class InferenceEngine:
             # steady-state carried-token dispatch (one cache entry)
             calls.append((fns.decode_step_fed, (dparams,), (
                 sched._zero_carry(), np.zeros((W,), bool), zeros, pos,
-                tables, keys, zeros, aids,
+                tables, keys, zeros, aids, *no_slot(W),
             ), 2))
         if sched._spec is not None:
             # the speculative round's extra programs on the target side:
@@ -637,6 +649,14 @@ class InferenceEngine:
             )
         if aliased is not None:
             self.metrics.record_pool_aliased(aliased)
+        # the cache tree's two kinds of leaf, in bytes: the pool's token rows
+        # and (a model that carries a state) the per-slot state beside them
+        flat = jax.tree_util.tree_flatten_with_path(sched._pool)[0]
+        state = sum(int(leaf.nbytes) for path, leaf in flat if is_state_leaf(path))
+        self.metrics.record_cache_bytes(
+            kv_pool_bytes=sum(int(leaf.nbytes) for _, leaf in flat) - state,
+            state_cache_bytes=state,
+        )
         return aliased
 
     def _warmup_batcher(self) -> None:

@@ -349,6 +349,14 @@ class ServingMetrics:
         copying the pool before every step."""
         self._registry.gauge("pool_aliased_bytes").set(float(nbytes))
 
+    def record_cache_bytes(self, *, kv_pool_bytes: int, state_cache_bytes: int) -> None:
+        """The cache tree as the warm-up found it: bytes of the paged pool's
+        token rows and bytes of the per-slot state beside them (0 for a
+        model that carries none).  ``pool_aliased_bytes`` is their sum
+        where every program updates the whole tree in place."""
+        self._registry.gauge("kv_pool_bytes").set(float(kv_pool_bytes))
+        self._registry.gauge("state_cache_bytes").set(float(state_cache_bytes))
+
     def record_kv_transfer(
         self, *, nbytes: int, seconds: float, blocks: int
     ) -> None:

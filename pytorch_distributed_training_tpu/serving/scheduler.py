@@ -298,6 +298,23 @@ class ContinuousScheduler:
         # (expert layers, experts a token, experts) of a model that has
         # expert layers, as the model states it; None for any other
         self._moe_shape = getattr(model, "moe_shape", None)
+        # a model that carries a fixed-size state a sequence says so: its
+        # cache tree holds [slots, ...] leaves beside the pool's rows and
+        # every program takes ``state_rows`` (serving/decode.py).  What
+        # assumes that a cache is blocks of token rows refuses it here, with
+        # the reason, rather than serve something else in silence.
+        self._state_shape = getattr(model, "state_shape", None)
+        if self._state_shape is not None:
+            if prefix_cache:
+                raise ValueError(self._state_refusal(
+                    "serving.scheduler.prefix_cache", "a block's hash names "
+                    "its token rows and cannot restore the state the layers "
+                    "reached at the block's end (set prefix_cache: false)"))
+            if speculative is not None:
+                raise ValueError(self._state_refusal(
+                    "serving.speculative", "a rejected draft token would "
+                    "have to be taken back out of the state, and the fork "
+                    "copies pool rows, not states"))
         if self._lora is not None and not self._has_lora:
             raise ValueError(
                 "a LoRA registry was given but the model has no stacked "
@@ -346,10 +363,7 @@ class ContinuousScheduler:
                 f"num_blocks is {self._kv.num_blocks}; grow the pool or "
                 "shrink seq_buckets/max_new_tokens"
             )
-        self._fns = build_paged_fns(
-            model, block_size, num_blocks, temperature=temperature,
-            quant=self._quant,
-        )
+        self._fns = self._build_fns()
         self.params = params
         # decode programs stream the int8 tree in quant mode; prefill and
         # verify always take the plain tree (compute-bound / accuracy
@@ -443,10 +457,17 @@ class ContinuousScheduler:
                 raise ValueError(
                     f"drain_deadline_ms must be > 0, got {self.drain_deadline_ms}"
                 )
+        # a bisect probe re-drives a decode step: idempotent for pool rows,
+        # but it would apply a step's update to a STATE twice.  With a state
+        # a decode failure among several requests goes to the restart, whose
+        # replay rebuilds every state from position 0.
         self._supervisor = ServingSupervisor(
             self,
             max_restarts=int(res.pop("max_restarts", 2)),
-            poison_bisect=bool(res.pop("poison_bisect", True)),
+            poison_bisect=(
+                bool(res.pop("poison_bisect", True))
+                and self._state_shape is None
+            ),
             logger=self.logger,
         )
         if res:
@@ -478,6 +499,34 @@ class ContinuousScheduler:
                 target=self._loop, name="serving-scheduler", daemon=True
             )
             self._thread.start()
+
+    def _build_fns(self):
+        return build_paged_fns(
+            self._model, self._block_size, self._num_blocks,
+            temperature=self._temperature, quant=self._quant,
+            state_slots=self.slots_n if self._state_shape is not None else 0,
+        )
+
+    def _state_refusal(self, what: str, why: str) -> str:
+        return (
+            f"{what} cannot serve {type(self._model).__name__}: the model "
+            f"carries a fixed-size state a sequence (state_shape "
+            f"{self._state_shape}) beside its pool rows, and {why}"
+        )
+
+    def _state_rows(self, slots) -> tuple:
+        """The paged programs' last argument, ``(state_rows,)``, for a call
+        whose batch row ``i`` belongs to slot ``slots[i]`` (-1 = padding);
+        ``()`` for a model that carries no state."""
+        if self._state_shape is None:
+            return ()
+        return (np.asarray(slots, np.int32),)
+
+    def _slot_rows(self, pos) -> tuple:
+        """:meth:`_state_rows` of a fixed-width decode call: row ``i`` is
+        slot ``i`` where it is live (``pos[i] >= 0``)."""
+        return self._state_rows(
+            np.where(pos >= 0, np.arange(self.slots_n), -1))
 
     # ------------------------------------------------------------------ #
     # client side
@@ -769,6 +818,7 @@ class ContinuousScheduler:
         resolving, so the importing coordinator's bounded deadline is
         exercised against a genuinely late payload.
         """
+        self._refuse_kv_transfer()
         fut: Future = Future()
         arr = np.asarray(prompt, dtype=np.int32).reshape(-1)
         with self._cond:
@@ -795,6 +845,7 @@ class ContinuousScheduler:
         transfer path's verb; :meth:`export_kv_prefix` keeps the one-shot
         payload contract.
         """
+        self._refuse_kv_transfer()
         fut: Future = Future()
         arr = np.asarray(prompt, dtype=np.int32).reshape(-1)
         with self._cond:
@@ -803,6 +854,16 @@ class ContinuousScheduler:
             self._xfer_q.append(("export_refs", (arr, namespace, stall_s), fut))
             self._cond.notify_all()
         return fut
+
+    def _refuse_kv_transfer(self) -> None:
+        """The transfer verbs move blocks of token rows between prefix
+        caches; a model that carries a state has no prefix cache to put
+        them in (``serving/disagg.py`` asks at construction)."""
+        if self._state_shape is not None:
+            raise ValueError(self._state_refusal(
+                "kv_transfer / serving.disagg", "a transferred block "
+                "carries token rows, not the state the layers reached at "
+                "its end"))
 
     def import_kv_blocks(self, payloads) -> Future:
         """Adopt transferred blocks into the local prefix cache (any thread).
@@ -816,6 +877,7 @@ class ContinuousScheduler:
         event (``kv_transfer_rejects``) and the decode side simply
         recomputes whatever did not land.
         """
+        self._refuse_kv_transfer()
         fut: Future = Future()
         with self._cond:
             if self._closed or self._dead:
@@ -1294,9 +1356,11 @@ class ContinuousScheduler:
             last_col[i] = suffix[i] - 1
             aids[i] = req.adapter
             keys[i] = req.row_key
+        slots = [r.slot for r in newly] + [-1] * (bb - len(newly))
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
             last_col, jnp.stack(keys), np.zeros((bb,), np.int32), aids,
+            *self._state_rows(slots),
         )
         rb0 = time.perf_counter()
         tok = np.asarray(tok)
@@ -1387,9 +1451,11 @@ class ContinuousScheduler:
             last_col[i] = suffix[i] - 1
             aids[i] = req.adapter
             keys[i] = req.row_key
+        slots = [r.slot for r in reqs] + [-1] * (bb - len(reqs))
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
             last_col, jnp.stack(keys), np.zeros((bb,), np.int32), aids,
+            *self._state_rows(slots),
         )
         tok = np.asarray(tok)
         finite = np.asarray(finite)
@@ -1432,6 +1498,7 @@ class ContinuousScheduler:
             tok, finite, self._pool, *_ = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables, jnp.stack(keys), gi, aids,
+                *self._slot_rows(pos),
             )
             tok = np.asarray(tok)
             finite = np.asarray(finite)
@@ -1585,7 +1652,7 @@ class ContinuousScheduler:
             tok, finite, self._pool, *moe = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables,
-                jnp.stack(keys), gen_idx, aids,
+                jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
             )
         rb0 = time.perf_counter()
         with self._phase("readback"):
@@ -1619,6 +1686,10 @@ class ContinuousScheduler:
         if moe:
             hit, load_max = (int(v) for v in np.asarray(moe[0]))
             self.metrics.record_moe(hit, load_max, n_rows, self._moe_shape)
+            # the step's counts in a trace too: the histograms hold the
+            # whole run, a reader of traced seconds needs theirs
+            with span("moe_counts", step=self._tick_no, hit=hit, rows=n_rows):
+                pass
 
     def _decode_probe(self, reqs: List[_PagedRequest]) -> None:
         """Re-drive the decode dispatch for a SUBSET of the active slots —
@@ -1630,7 +1701,7 @@ class ContinuousScheduler:
         tok, _, self._pool, *_ = self._fns.decode_step(
             self._qparams if self._quant else self.params,
             self._pool, prev, pos, tables,
-            jnp.stack(keys), gen_idx, aids,
+            jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
         )
         # surface async dispatch errors here, inside the probe's try
         jax.block_until_ready(tok)
@@ -1698,7 +1769,7 @@ class ContinuousScheduler:
                 tok, finite, self._pool, *moe = self._fns.decode_step_fed(
                     self._qparams if self._quant else self.params,
                     self._pool, prev, fresh_mask, fresh_tok, pos, tables,
-                    jnp.stack(keys), gen_idx, aids,
+                    jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
                 )
             for req in disp:
                 req.dispatched += 1
@@ -2153,10 +2224,7 @@ class ContinuousScheduler:
                 req.slot = -1
                 req.dispatched = req.gen_idx
                 self._queue.appendleft(req)
-        self._fns = build_paged_fns(
-            self._model, self._block_size, self._num_blocks,
-            temperature=self._temperature, quant=self._quant,
-        )
+        self._fns = self._build_fns()
         self._kv = PagedKVPool(
             self._num_blocks, self._block_size, self._prefix_cache
         )

@@ -181,8 +181,8 @@ def test_absorbed_attention_is_the_unabsorbed_function():
 
 
 def moe_layer(**more):
-    return DroplessMoE(dim=64, num_experts=8, top_k=3, hidden=48, shared_hidden=96,
-                       **more)
+    sizes = dict(dim=64, num_experts=8, top_k=3, hidden=48, shared_hidden=96)
+    return DroplessMoE(**dict(sizes, **more))
 
 
 def reference_layer(ref, params, x):
@@ -236,27 +236,61 @@ def test_a_padded_token_is_neither_routed_nor_counted():
     assert float(jnp.abs(routed[7:]).max()) == 0.0
 
 
-@pytest.mark.parametrize("shares", [2, 4])
-def test_expert_shares_add_up_to_the_whole_layer(ref, shares):
+def solar_reference_layer(params, x, top_k):
+    """The uncut expert layer of the Solar-Open2 reference (gates
+    renormalised over the chosen, ``norm_topk_prob: true``) on the program's
+    parameters: every expert held, one shared."""
+    path = os.path.join(ROOT, "benchmark", "reference", "solar_open2.py")
+    spec = importlib.util.spec_from_file_location("reference_solar_open2", path)
+    solar = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(solar)
+    p = params["params"]
+    half = p["w_gate_up"].shape[-1] // 2
+    shared = p["shared_gate_up"].shape[-1] // 2
+    layer = {
+        "router": p["router"], "e_gate": p["w_gate_up"][..., :half],
+        "e_up": p["w_gate_up"][..., half:], "e_down": p["w_down"],
+        "s_gate": p["shared_gate_up"][:, :shared],
+        "s_up": p["shared_gate_up"][:, shared:], "s_down": p["shared_down"],
+    }
+    arch = solar.Arch(
+        heads=4, kv_heads=2, head_dim=16, lin_heads=4, lin_dim=16, taps=4,
+        top_k=top_k, held_first=0, held=p["w_down"].shape[0], rms_eps=1e-5,
+        routed_scaling=1.0, gqa_gate=True, neg_eigval=True, pad_to=16,
+        query_block=16)
+    return np.asarray(solar.experts_layer(x, layer, arch=arch))
+
+
+@pytest.mark.parametrize("shares, experts, top_k, renormalised", [
+    (2, 8, 3, False), (4, 8, 3, False),
+    # Solar-Open2's layer: 16 experts in 4 shares of 4, the gates
+    # renormalised over the chosen 4 of ALL 16, one shared expert
+    (4, 16, 4, True),
+])
+def test_expert_shares_add_up_to_the_whole_layer(ref, shares, experts, top_k,
+                                                 renormalised):
     """One chip's share of an expert-parallel layer routes over all experts
     and returns its own experts' part; the parts of all shares and the
-    shared expert, counted once, are the uncut reference's whole layer."""
-    whole = moe_layer()
+    shared expert, counted ONCE, are the uncut reference's whole layer."""
+    sizes = dict(num_experts=experts, top_k=top_k, norm_topk_prob=renormalised)
+    whole = moe_layer(**sizes)
     x = jax.random.normal(jax.random.PRNGKey(6), (24, 64))
     params = whole.init(jax.random.PRNGKey(7), x)
-    held = 8 // shares
+    held = experts // shares
     total = np.asarray(whole.apply(params, x, method=DroplessMoE.shared_part))
     counted = 0
     for i in range(shares):
-        share = moe_layer(experts_held=(i * held, held))
+        share = moe_layer(experts_held=(i * held, held), **sizes)
         p = dict(params["params"])
         p["w_gate_up"] = p["w_gate_up"][i * held:(i + 1) * held]
         p["w_down"] = p["w_down"][i * held:(i + 1) * held]
-        part, sizes = share.apply({"params": p}, x, method=DroplessMoE.routed_part)
+        part, group_sizes = share.apply({"params": p}, x, method=DroplessMoE.routed_part)
         total = total + np.asarray(part)
-        counted += int(sizes.sum())
-    assert counted == 24 * 3
-    np.testing.assert_allclose(total, reference_layer(ref, params, x), atol=2e-5)
+        counted += int(group_sizes.sum())
+    assert counted == 24 * top_k
+    want = (solar_reference_layer(params, x, top_k) if renormalised
+            else reference_layer(ref, params, x))
+    np.testing.assert_allclose(total, want, atol=2e-5)
 
 
 def test_long_calls_run_in_pieces_with_the_same_result():
