@@ -414,9 +414,10 @@ class MultiHeadAttention(nn.Module):
         return out.astype(q.dtype)
 
     def _paged_attention(self, q, k, v, positions, block_tables):
-        """Block-table gather attention against the shared paged KV pool
-        (:func:`paged_attention`, one K/V head a query head, the gathered
-        rows upcast to float32 as this module always had them)."""
+        """Attention against the shared paged KV pool
+        (:func:`paged_attention`, one K/V head a query head; where it
+        gathers, the gathered rows upcast to float32 as this module always
+        had them)."""
         if self.seq_axis is not None:
             raise ValueError("paged decode is single-shard (seq_axis must be None)")
         if not self.causal:
@@ -440,14 +441,31 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
     ``[t*bs, (t+1)*bs)``) of row ``b``.  The pool lives flattened as
     ``[num_blocks * block_size, Hkv, hd]`` in ``module``'s "cache"
     collection — scatter this call's k/v at their physical rows (padding
-    scatters are dropped via an out-of-bounds index), then gather each row's
-    FULL logical sequence back through its block table and mask keys to
+    scatters are dropped via an out-of-bounds index), then read each row's
+    sequence back through its block table with keys masked to
     ``key_pos <= q_pos``.  Because suffix k/v are scattered before the
-    gather, one code path serves cold prefill (positions 0..len-1),
-    chunked prefix-hit prefill (positions cached_len..len-1 reading the
-    shared prefix blocks), and single-token decode (S=1).  Gathered
-    garbage beyond a row's written length is masked to -inf, so recycled
-    block contents never leak into the softmax.
+    read, cold prefill (positions 0..len-1), chunked prefix-hit prefill
+    (positions cached_len..len-1 reading the shared prefix blocks) and
+    single-token decode (S=1) are the same computation.  Rows beyond a
+    sequence's written length are masked to -inf and their values zeroed,
+    so recycled block contents never leak into the softmax.
+
+    Which call reads the pool how is decided by what the call shows:
+
+    - ``S == 1`` on a TPU (the scheduler's ``decode_step`` and
+      ``decode_step_fed``, of either served family), with rows the kernel
+      can read (:func:`..ops.paged_decode.fits`): the Pallas kernel
+      :func:`..ops.paged_decode.paged_decode` walks each row's block table
+      up to the row's own length, K and V as stored, scores, softmax and
+      accumulation in float32; ``as_stored`` and ``query_block`` have
+      nothing to say there.  No copy of the table's rows exists.
+    - ``S > 1`` (whole-prompt and chunked prefill, the speculative
+      ``verify``), and any call off a TPU: the GATHER arms below, as they
+      were: each row's FULL table is gathered into ``[B, L, Hkv, hd]`` and
+      scored.  A prefill scores hundreds of query rows against the gathered
+      copy and its cost is the products', not the gather's; a kernel for it
+      is the flash kernel's shape (query tiles against a block walk), not
+      this one's, and is left to its own change (ROADMAP Speed 3b).
 
     ``as_stored=False`` upcasts the gathered rows to float32 before the
     products (``TransformerLM``'s programs, unchanged); ``as_stored=True``
@@ -489,6 +507,21 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
         v.astype(dtype).reshape(b * s, kv_heads, head_dim), mode="drop"
     )
     k_pool.value, v_pool.value = kp, vp
+    scale = 1.0 / math.sqrt(head_dim)
+    from . import paged_decode
+    from .flash_attention import flash_enabled
+
+    if s == 1 and flash_enabled() and paged_decode.fits(head_dim, kv_heads, dtype):
+        # one position a row, on a TPU: the kernel reads the pool where it
+        # lies, each row's live blocks and no others.  A padding row
+        # (position -1) reads key 0 of its table's first block, as below.
+        out = paged_decode.paged_decode(
+            q.reshape(b, kv_heads, group, head_dim),
+            kp.reshape(nb, bs, kv_heads, head_dim),
+            vp.reshape(nb, bs, kv_heads, head_dim),
+            block_tables, safe_pos[:, 0] + 1, scale=scale,
+        )
+        return out.reshape(b, 1, num_heads, head_dim)
     t_blocks = block_tables.shape[1]
     length = t_blocks * bs
     # [B, L] physical rows in logical-position order (the row-at-a-time gather)
@@ -507,7 +540,6 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
         blocks = pool.reshape(nb, bs, kv_heads, head_dim)[tables]
         return blocks.reshape(tables.shape[0], length, kv_heads, head_dim)
 
-    scale = 1.0 / math.sqrt(head_dim)
     wide = (lambda x: x) if as_stored else (lambda x: x.astype(jnp.float32))
     accumulate = jnp.float32 if as_stored else None
     # one K/V head a query head: the products as they always were; a group
@@ -579,8 +611,9 @@ class GroupedQueryAttention(nn.Module):
 
     ``decode=False``: plain causal attention over the call's own tokens.
     ``decode=True, paged=True``: K/V rows of ``Hkv`` heads in the paged pool
-    (:func:`paged_attention`, the rows taken as stored, a long call's scores
-    built ``QUERY_BLOCK`` query rows at a time), under the scope
+    (:func:`paged_attention`: a decode step through the paged kernel, a
+    prefill's rows gathered as stored and a long call's scores built
+    ``QUERY_BLOCK`` query rows at a time), under the scope
     ``gqa_attention``."""
 
     num_heads: int

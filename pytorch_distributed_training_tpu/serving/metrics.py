@@ -63,6 +63,12 @@ class ServingMetrics:
         # block-pool utilization, both recorded as fractions in [0, 1]
         self._slot_occ = self._registry.histogram("slot_occupancy", _RESERVOIR)
         self._block_util = self._registry.histogram("block_util", _RESERVOIR)
+        # of the ``slots x table_blocks`` entries of a decode step's block
+        # tables, the share that holds a live position: what the step's
+        # attention reads (ops/paged_decode.py walks those and no others)
+        self._live_block_share = self._registry.histogram(
+            "paged_live_block_share", _RESERVOIR
+        )
         # disaggregated serving (PR 19): per-import host-staging wall
         # time; the byte/block counters ride the counter namespace
         self._kv_transfer_ms = self._registry.histogram(
@@ -273,10 +279,14 @@ class ServingMetrics:
         total_slots: int,
         blocks_in_use: int,
         total_blocks: int,
+        live_block_share: Optional[float] = None,
     ) -> None:
-        """Scheduler-state sample at one decode iteration."""
+        """Scheduler-state sample at one decode iteration
+        (``live_block_share``: of a single-position step's block tables)."""
         self._slot_occ.observe(active_slots / max(total_slots, 1))
         self._block_util.observe(blocks_in_use / max(total_blocks, 1))
+        if live_block_share is not None:
+            self._live_block_share.observe(float(live_block_share))
 
     def record_tick(self, host_ms: float) -> None:
         """One scheduler tick's HOST overhead: wall time minus the spans
@@ -444,6 +454,10 @@ class ServingMetrics:
         if util["count"]:
             out["block_util_mean"] = float(util["mean"])
             out["block_util_max"] = float(util["max"])
+        share = self._live_block_share.snapshot()
+        if share["count"]:
+            out["paged_live_block_share_mean"] = float(share["mean"])
+            out["paged_live_block_share_p50"] = float(share["p50"])
         xfer = self._kv_transfer_ms.snapshot()
         if xfer["count"]:
             out["kv_transfer_ms_p50"] = float(xfer["p50"])

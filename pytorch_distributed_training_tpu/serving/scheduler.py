@@ -528,6 +528,14 @@ class ContinuousScheduler:
         return self._state_rows(
             np.where(pos >= 0, np.arange(self.slots_n), -1))
 
+    def _live_block_share(self, pos) -> float:
+        """Of a fixed-width decode call's ``slots x table_blocks`` table
+        entries, the share whose block holds a position the step reads
+        (``pos[i]`` and all before it; a padding row reads none)."""
+        bs = self._kv.block_size
+        live = np.where(pos >= 0, pos // bs + 1, 0).sum()
+        return float(live) / (self.slots_n * self.table_blocks)
+
     # ------------------------------------------------------------------ #
     # client side
 
@@ -1677,6 +1685,7 @@ class ContinuousScheduler:
             active_slots=n_active, total_slots=self.slots_n,
             blocks_in_use=self._kv.blocks_in_use,
             total_blocks=self._kv.num_blocks,
+            live_block_share=self._live_block_share(pos),
         )
 
     def _record_moe(self, moe, n_rows: int) -> None:
@@ -1779,6 +1788,7 @@ class ContinuousScheduler:
                 active_slots=len(disp), total_slots=self.slots_n,
                 blocks_in_use=self._kv.blocks_in_use,
                 total_blocks=self._kv.num_blocks,
+                live_block_share=self._live_block_share(pos),
             )
         # drain one tick behind dispatch (ring bounded at async_depth);
         # when nothing is left to dispatch, drain EVERYTHING so the

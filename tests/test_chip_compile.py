@@ -25,6 +25,7 @@ from pytorch_distributed_training_tpu.ops.fused_elementwise import (
     _make_add_ln,
     _make_bias_gelu,
 )
+from pytorch_distributed_training_tpu.ops.paged_decode import paged_decode
 
 
 @pytest.fixture(scope="module")
@@ -74,6 +75,18 @@ def _bias_gelu(u, bias):
     return _make_bias_gelu(False)(u, bias)
 
 
+def _paged_decode(q, k_pool, v_pool, tables, lengths):
+    return paged_decode(q, k_pool, v_pool, tables, lengths, scale=128 ** -0.5)
+
+
+def _decode_call(rows, group, blocks, table):
+    """One decode step's call of the paged kernel: ``rows`` slots of 8 K/V
+    heads x ``group`` query heads of 128 over a pool of ``blocks`` blocks
+    of 16 positions, ``table`` entries a row."""
+    return [((rows, 8, group, 128), None), ((blocks, 16, 8, 128), None),
+            ((blocks, 16, 8, 128), None), ((rows, table), I32), ((rows,), I32)]
+
+
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 QKV_2K = [((8, 2048, 8, 128), None)] * 3
 QKV_16K = [((1, 16384, 8, 128), None)] * 3
@@ -119,6 +132,17 @@ CASES = {
     "bias_gelu_16384x4096": (
         _bias_gelu, [((16384, 4096), None), ((4096,), None)], BF16,
         ["fused_bias_gelu"],
+    ),
+    # lm271m.serve.steady: 8 slots, tables of 80 blocks, one K/V head a head
+    "paged_decode_lm271m": (
+        _paged_decode, _decode_call(8, 1, 1024, 80), BF16, ["paged_decode"],
+    ),
+    # solar-open2-250b.serve.long32: 32 slots x 288 blocks, 8 heads a K/V head
+    "paged_decode_solar_open2": (
+        _paged_decode, _decode_call(32, 8, 9216, 288), BF16, ["paged_decode"],
+    ),
+    "paged_decode_f32": (
+        _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
     ),
 }
 
@@ -174,3 +198,45 @@ def test_grouped_products_of_the_expert_layer_compile_for_v5e(chip, tokens, monk
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 2
     assert all("/moe_gmm/" in line and "pallas_call" in line for line in calls)
+
+
+def test_decode_step_reads_the_pool_where_it_lies(chip, monkeypatch):
+    """Two layers of the 271M LM at its serving widths (8 slots, 1,024
+    blocks of 16, tables of 80): the compiled decode step holds the paged
+    kernel under the layer's scope, no array of a whole table's rows
+    (``[8, 1280, 8, 128]``, what the gather arm builds), and updates the
+    whole pool in place."""
+    import re
+
+    import numpy as np
+
+    from pytorch_distributed_training_tpu.models.transformer_lm import TransformerLM
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
+
+    # the routing asks ``jax.default_backend()``, which is the CPU here
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    model = TransformerLM(vocab_size=32768, max_len=2048, embed_dim=1024,
+                          depth=2, num_heads=8, dtype=BF16)
+    fns = build_paged_fns(model, 16, 1024)
+    slots, table = 8, 80
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))["params"]
+    pool = jax.eval_shape(fns.init_pool, shapes)
+    keys = jax.eval_shape(lambda: jnp.stack([jax.random.PRNGKey(0)] * slots))
+    row = jax.ShapeDtypeStruct((slots,), I32)
+    args = jax.tree.map(on_chip, (
+        shapes, pool, row, row, jax.ShapeDtypeStruct((slots, table), I32),
+        keys, row, row))
+    compiled = fns.decode_step.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    assert all(re.search(r"/attn/paged_attention/[^\"]*paged_decode/pallas_call", line)
+               for line in calls)
+    assert not re.search(r"\[8,1280,8,128\]", text)
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                     for s in jax.tree.leaves(pool))
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes == 4 * 2 ** 25
