@@ -13,7 +13,10 @@ config surface only pins ``model.name``, so new names slot straight in —
 served, not trained) and ``SolarOpen2`` (:mod:`.solar_open2`: gated
 delta-rule linear layers that carry a state a sequence, one grouped-query
 layer without positions in four, dropless experts in every layer; served,
-not trained).  The two served families share their norm, head and expert
+not trained) and ``NemotronH`` (:mod:`.nemotron_h`: ONE mixer a layer by a
+pattern — Mamba-2 state-space layers that carry a second kind of state,
+latent experts, grouped-query attention with 2 K/V heads; served, not
+trained).  The three served families share their norm, head and expert
 layer through :mod:`.lm_parts`.
 
 What a model IS is stated by its class, not compared by name:
@@ -30,6 +33,7 @@ from typing import Any, Optional
 import jax.numpy as jnp
 
 from .deepseek_v2 import DeepseekV2LM
+from .nemotron_h import NemotronHLM
 from .resnet import RESNET_CONFIGS, BasicBlock, Bottleneck, ResNet
 from .solar_open2 import SolarOpen2LM
 from .transformer_lm import TransformerLM
@@ -40,6 +44,7 @@ __all__ = [
     "list_models",
     "model_class",
     "DeepseekV2LM",
+    "NemotronHLM",
     "SolarOpen2LM",
     "ResNet",
     "BasicBlock",
@@ -54,7 +59,7 @@ _CANONICAL.update({name.lower(): name for name in VIT_CONFIGS})
 # ``vocab_size=num_classes`` and the ``model:`` section's keys verbatim)
 _LM_FAMILIES = {
     "TransformerLM": TransformerLM, "DeepseekV2": DeepseekV2LM,
-    "SolarOpen2": SolarOpen2LM,
+    "SolarOpen2": SolarOpen2LM, "NemotronH": NemotronHLM,
 }
 _CANONICAL.update({name.lower(): name for name in _LM_FAMILIES})
 
@@ -93,7 +98,7 @@ def get_model(
         section here (e.g. ``embed_dim/depth/num_heads/max_len/seq_axis``
         for ``TransformerLM``).
 
-    For a language model (``TransformerLM``, ``DeepseekV2``, ``SolarOpen2``) the reference's
+    For a language model (``TransformerLM``, ``DeepseekV2``, ``SolarOpen2``, ``NemotronH``) the reference's
     ``num_classes`` slot is the vocabulary size (``dataset.n_classes`` in the
     config).
     """

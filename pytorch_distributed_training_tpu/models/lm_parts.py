@@ -55,20 +55,28 @@ def expert_ffn(c, flat, token_mask):
     their published names (``n_routed_experts``, ``num_experts_per_tok``,
     ``moe_intermediate_size``, ``n_shared_experts``, ``norm_topk_prob``,
     ``routed_scaling_factor``, ``experts_held``, ``dtype``), over tokens
-    ``flat [N, dim]``, under the scope ``moe`` and the name ``moe``.
+    ``flat [N, dim]``, under the scope ``moe`` and the name ``moe``.  A
+    family whose experts differ from softmax-routed SwiGLU of the model's
+    width says so in ``c.moe_form``: a dict of the :class:`DroplessMoE`
+    options it sets (``scoring``, ``activation``, ``latent``,
+    ``shared_hidden``, ``token_chunk``).
     Returns ``(y [N, dim], group_sizes [held])``."""
+    options = {
+        "shared_hidden": c.n_shared_experts * c.moe_intermediate_size,
+        **dict(getattr(c, "moe_form", ())),
+    }
     with jax.named_scope("moe"):
         return DroplessMoE(
             dim=flat.shape[-1],
             num_experts=c.n_routed_experts,
             top_k=c.num_experts_per_tok,
             hidden=c.moe_intermediate_size,
-            shared_hidden=c.n_shared_experts * c.moe_intermediate_size,
             norm_topk_prob=c.norm_topk_prob,
             routed_scaling_factor=c.routed_scaling_factor,
             experts_held=c.experts_held,
             dtype=c.dtype,
             name="moe",
+            **options,
         )(flat, token_mask)
 
 

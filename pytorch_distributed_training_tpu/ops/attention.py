@@ -51,13 +51,15 @@ __all__ = [
 KEY_POOL, VALUE_POOL, LATENT_POOL = "k_pool", "v_pool", "latent_pool"
 _POOL_ROLES = {KEY_POOL: "scored", VALUE_POOL: "value", LATENT_POOL: "scored"}
 # The cache tree's OTHER kind of leaf: ``[slots, ...]``, one entry a sequence
-# (:mod:`..ops.kda`: the delta-rule state and the convolution's last rows),
+# (:mod:`..ops.kda`: the delta-rule state and the convolution's last rows;
+# :mod:`..ops.mamba2`: the state-space state and its convolution's rows),
 # addressed by ``state_rows`` and never through a block table.  They have no
 # role among the pool's rows, whatever their leading size.
 KDA_STATE, KDA_CONV = "kda_state", "kda_conv"
+MAMBA_STATE, MAMBA_CONV = "mamba_state", "mamba_conv"
 # query rows of one batch row whose scores the grouped path builds at once
 QUERY_BLOCK = 512
-STATE_LEAVES = (KDA_STATE, KDA_CONV)
+STATE_LEAVES = (KDA_STATE, KDA_CONV, MAMBA_STATE, MAMBA_CONV)
 
 
 def _leaf_name(path) -> str:

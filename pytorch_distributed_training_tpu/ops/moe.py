@@ -156,7 +156,14 @@ def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
     product: whole contraction where it fits the fast memory beside a
     column tile, so that an expert's weights pass through once."""
     tn = next(t for t in (512, 256, 128) if n % t == 0 or t == 128)
-    tk = k if k * tn * 2 <= (2 << 20) and k % 128 == 0 else 512
+    def fits(t):
+        return k * t * 2 <= (2 << 20) and k % 128 == 0
+
+    if not fits(tn) and k % 512:
+        # a contraction that 512 does not divide (2,688): narrower columns
+        # that let it through whole, rather than a ragged last piece
+        tn = next((t for t in (256, 128) if t < tn and n % t == 0 and fits(t)), tn)
+    tk = k if fits(tn) else 512
     return GMM_ROW_TILE, tk, tn
 
 
@@ -208,14 +215,40 @@ def swiglu(x, gate_up, down):
     return jnp.dot(_gated(jnp.dot(x, gate_up), x.dtype), down)
 
 
+def _relu2(h, dtype):
+    """``relu(h)^2``, in float32."""
+    return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(dtype)
+
+
+# an expert's activation: what it does to the output of its first product
+# (``swiglu``: that product is ``[gate | up]``, twice the expert's width)
+_ACTIVATIONS = {"swiglu": (_gated, 2), "relu2": (_relu2, 1)}
+
+
 class DroplessMoE(nn.Module):
-    """Top-k routed SwiGLU experts beside a shared expert, no capacity:
+    """Top-k routed experts beside a shared expert, no capacity:
     ``y = sum_k s_k Expert_{i_k}(x) + Shared(x)`` over tokens ``x [N, dim]``.
 
-    The router scores ALL ``num_experts`` in float32 (softmax, from the
-    float32-cast input) and takes the ``top_k`` largest; the gates are those
-    scores as they are (``norm_topk_prob`` renormalises them over the chosen
-    k) times ``routed_scaling_factor``, applied in the combine.
+    The router scores ALL ``num_experts`` in float32 (from the float32-cast
+    input) and takes ``top_k`` of them.  ``scoring="softmax"``: the scores
+    are a softmax and the ``top_k`` largest are chosen.  ``"sigmoid"``: each
+    score is a sigmoid of its own logit, and the choice is made on ``score +
+    e_score_correction_bias`` (a float32 vector a layer, learned to balance
+    load): the bias moves the CHOICE and never the gate.  Either way the
+    gates are the scores themselves at the chosen (``norm_topk_prob``
+    renormalises them over the chosen k) times ``routed_scaling_factor``,
+    applied in the combine.
+
+    ``activation="swiglu"``: an expert is ``(silu(x W_gate) * x W_up)
+    W_down``, gate and up side by side in ``w_gate_up``.  ``"relu2"``: an
+    expert is NOT gated, ``relu(x W_up)^2 W_down``, one up product
+    (``w_up``).  The shared expert has the same activation.
+
+    ``latent = l > 0``: the routed experts live in a latent of width ``l``
+    between a shared down-projection and a shared up-projection, ``r =
+    (sum_k s_k Expert_{i_k}(x latent_down)) latent_up``; the router and the
+    shared expert read the full width.  The up-projection is linear and has
+    no bias, so the shares' routed parts still add up.
 
     ``experts_held = (first, count)`` tells the layer which experts live
     here (default: all).  It still routes over all of them and returns only
@@ -225,9 +258,13 @@ class DroplessMoE(nn.Module):
     give the two halves apart so that shares can be added up with it counted
     once; ``__call__`` is their sum.
 
-    Parameters (``dtype``): ``router [dim, E]``, ``w_gate_up [held, dim, 2h]``
-    (gate first), ``w_down [held, h, dim]``, and with ``shared_hidden > 0``
-    ``shared_gate_up [dim, 2s]``, ``shared_down [s, dim]``.
+    Parameters (``dtype``), with ``d = latent or dim``: ``router [dim, E]``,
+    ``w_gate_up [held, d, 2h]`` (gate first; ``relu2``: ``w_up [held, d,
+    h]``), ``w_down [held, h, d]``; with ``shared_hidden > 0``
+    ``shared_gate_up [dim, 2s]`` (``relu2``: ``shared_up [dim, s]``),
+    ``shared_down [s, dim]``; with ``latent`` ``latent_down [dim, l]``,
+    ``latent_up [l, dim]``; with ``scoring="sigmoid"``
+    ``e_score_correction_bias [E]`` (float32).
     """
 
     dim: int
@@ -243,6 +280,9 @@ class DroplessMoE(nn.Module):
     # of this many (a 32 x 2048 prefill would otherwise hold its six-fold
     # copy of the activations at once)
     token_chunk: int = 8192
+    scoring: str = "softmax"
+    activation: str = "swiglu"
+    latent: int = 0
 
     @property
     def _held(self) -> Tuple[int, int]:
@@ -258,15 +298,30 @@ class DroplessMoE(nn.Module):
                 f"experts_held {self.experts_held} is not a range of the "
                 f"{E} experts"
             )
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r} is not softmax or sigmoid")
+        if self.activation not in _ACTIVATIONS:
+            raise ValueError(
+                f"activation {self.activation!r} is not one of {sorted(_ACTIVATIONS)}")
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
+        wide = _ACTIVATIONS[self.activation][1]
+        up = "w_gate_up" if wide == 2 else "w_up"
+        width = self.latent or self.dim
         self.router = self.param("router", init, (self.dim, E), self.dtype)
-        self.w_gate_up = self.param(
-            "w_gate_up", init, (held, self.dim, 2 * self.hidden), self.dtype)
+        if self.scoring == "sigmoid":
+            self.correction_bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros, (E,), jnp.float32)
+        self.w_first = self.param(up, init, (held, width, wide * self.hidden), self.dtype)
         self.w_down = self.param(
-            "w_down", init, (held, self.hidden, self.dim), self.dtype)
+            "w_down", init, (held, self.hidden, width), self.dtype)
+        if self.latent:
+            self.latent_down = self.param(
+                "latent_down", init, (self.dim, self.latent), self.dtype)
+            self.latent_up = self.param(
+                "latent_up", init, (self.latent, self.dim), self.dtype)
         if self.shared_hidden:
-            self.shared_gate_up = self.param(
-                "shared_gate_up", init, (self.dim, 2 * self.shared_hidden),
+            self.shared_first = self.param(
+                "shared_" + up[2:], init, (self.dim, wide * self.shared_hidden),
                 self.dtype)
             self.shared_down = self.param(
                 "shared_down", init, (self.shared_hidden, self.dim), self.dtype)
@@ -281,7 +336,8 @@ class DroplessMoE(nn.Module):
         if not self.shared_hidden:
             return jnp.zeros_like(x)
         with jax.named_scope("moe_shared"):
-            return swiglu(x, self.shared_gate_up, self.shared_down)
+            act = _ACTIVATIONS[self.activation][0]
+            return jnp.dot(act(jnp.dot(x, self.shared_first), x.dtype), self.shared_down)
 
     def routed_part(self, x, token_mask=None):
         """The held experts' part of the gated sum.  ``token_mask [N]``
@@ -291,20 +347,35 @@ class DroplessMoE(nn.Module):
         if token_mask is None:
             token_mask = jnp.ones((n,), bool)
         if n <= self.token_chunk:
-            return self._routed(x, token_mask)
-        ys, sizes = in_token_chunks(self._routed, self.token_chunk, x, token_mask)
-        return ys.reshape(-1, x.shape[1])[:n], sizes.sum(axis=0)
+            y, sizes = self._routed(x, token_mask)
+        else:
+            ys, sizes = in_token_chunks(self._routed, self.token_chunk, x, token_mask)
+            y, sizes = ys.reshape(-1, ys.shape[-1])[:n], sizes.sum(axis=0)
+        if self.latent:
+            with jax.named_scope("moe_latent_up"):
+                y = jnp.dot(y, self.latent_up)
+        return y, sizes
 
     def _routed(self, x, token_mask):
         n, k = x.shape[0], self.top_k
         first, held = self._held
+        rows_in = x
+        if self.latent:
+            # only the dispatched rows are latent: the router reads x whole
+            with jax.named_scope("moe_latent_down"):
+                rows_in = jnp.dot(x, self.latent_down)
         with jax.named_scope("moe_route"):
             logits = jnp.dot(
                 x.astype(jnp.float32), self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
             )
-            scores = jax.nn.softmax(logits, axis=-1)
-            gates, experts = jax.lax.top_k(scores, k)  # [n, k]
+            if self.scoring == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                _, experts = jax.lax.top_k(scores + self.correction_bias, k)
+                gates = jnp.take_along_axis(scores, experts, axis=-1)
+            else:
+                scores = jax.nn.softmax(logits, axis=-1)
+                gates, experts = jax.lax.top_k(scores, k)  # [n, k]
             if self.norm_topk_prob:
                 gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
             gates = gates * self.routed_scaling_factor
@@ -319,9 +390,10 @@ class DroplessMoE(nn.Module):
             rows = n * k
             padded = -(-rows // GMM_ROW_TILE) * GMM_ROW_TILE
             token_of = jnp.pad(order // k, (0, padded - rows))
-            lhs = x[token_of]  # [padded, dim]
-        h = grouped_matmul(lhs, self.w_gate_up, sizes, self.dtype)
-        out = grouped_matmul(_gated(h, self.dtype), self.w_down, sizes, self.dtype)
+            lhs = rows_in[token_of]  # [padded, dim or latent]
+        act = _ACTIVATIONS[self.activation][0]
+        h = grouped_matmul(lhs, self.w_first, sizes, self.dtype)
+        out = grouped_matmul(act(h, self.dtype), self.w_down, sizes, self.dtype)
         with jax.named_scope("moe_combine"):
             # back to (token, choice) order; the rows of no group are
             # undefined and are taken out by ``where``, never by a product

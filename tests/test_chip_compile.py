@@ -79,12 +79,12 @@ def _paged_decode(q, k_pool, v_pool, tables, lengths):
     return paged_decode(q, k_pool, v_pool, tables, lengths, scale=128 ** -0.5)
 
 
-def _decode_call(rows, group, blocks, table):
-    """One decode step's call of the paged kernel: ``rows`` slots of 8 K/V
-    heads x ``group`` query heads of 128 over a pool of ``blocks`` blocks
-    of 16 positions, ``table`` entries a row."""
-    return [((rows, 8, group, 128), None), ((blocks, 16, 8, 128), None),
-            ((blocks, 16, 8, 128), None), ((rows, table), I32), ((rows,), I32)]
+def _decode_call(rows, group, blocks, table, kv_heads=8):
+    """One decode step's call of the paged kernel: ``rows`` slots of
+    ``kv_heads`` K/V heads x ``group`` query heads of 128 over a pool of
+    ``blocks`` blocks of 16 positions, ``table`` entries a row."""
+    return [((rows, kv_heads, group, 128), None), ((blocks, 16, kv_heads, 128), None),
+            ((blocks, 16, kv_heads, 128), None), ((rows, table), I32), ((rows,), I32)]
 
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -141,6 +141,12 @@ CASES = {
     "paged_decode_solar_open2": (
         _paged_decode, _decode_call(32, 8, 9216, 288), BF16, ["paged_decode"],
     ),
+    # nemotron-3-super-120b.serve.burst32: 32 slots x 96 blocks, 2 K/V heads
+    # read by groups of 16 query heads
+    "paged_decode_nemotron_h": (
+        _paged_decode, _decode_call(32, 16, 4096, 96, kv_heads=2), BF16,
+        ["paged_decode"],
+    ),
     "paged_decode_f32": (
         _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
     ),
@@ -176,21 +182,36 @@ def test_kernel_compiles_for_v5e(chip, name):
     )
 
 
+EXPERT_LAYERS = {
+    # DeepSeek-V2-Lite: 64 SwiGLU experts of 2048 x 1408, six a token
+    "deepseek_v2_lite": (2048, dict(
+        num_experts=64, top_k=6, hidden=1408, shared_hidden=2816)),
+    # Nemotron-3-Super: 128 of 512 relu2 experts of 1024 x 2688 in a latent,
+    # 22 a token, a shared expert of 5376 at the full width
+    "nemotron_h_latent": (4096, dict(
+        num_experts=512, top_k=22, hidden=2688, shared_hidden=5376,
+        experts_held=(0, 128), scoring="sigmoid", activation="relu2",
+        latent=1024, norm_topk_prob=True, routed_scaling_factor=5.0)),
+}
+
+
 @pytest.mark.parametrize("tokens", [32, 2048], ids=["decode_32_rows", "prefill_2048"])
-def test_grouped_products_of_the_expert_layer_compile_for_v5e(chip, tokens, monkeypatch):
-    """The dropless expert layer at DeepSeek-V2-Lite's widths (64 experts of
-    2048 x 1408, six a token): its two grouped products are the megablox
-    Pallas kernel, under the scope the benchmark's readers look for."""
+@pytest.mark.parametrize("family", sorted(EXPERT_LAYERS))
+def test_grouped_products_of_the_expert_layer_compile_for_v5e(
+        chip, family, tokens, monkeypatch):
+    """The dropless expert layer at the served widths: its two grouped
+    products are the megablox Pallas kernel, under the scope the
+    benchmark's readers look for."""
     from pytorch_distributed_training_tpu.ops import flash_attention as gate
     from pytorch_distributed_training_tpu.ops.moe import DroplessMoE
 
     # the layer asks ``jax.default_backend()``, which is the CPU here
     monkeypatch.setattr(gate, "flash_enabled", lambda: True)
-    layer = DroplessMoE(dim=2048, num_experts=64, top_k=6, hidden=1408,
-                        shared_hidden=2816, dtype=BF16)
-    x = jax.ShapeDtypeStruct((tokens, 2048), BF16, sharding=chip)
+    dim, form = EXPERT_LAYERS[family]
+    layer = DroplessMoE(dim=dim, dtype=BF16, **form)
+    x = jax.ShapeDtypeStruct((tokens, dim), BF16, sharding=chip)
     shapes = jax.eval_shape(
-        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((8, 2048), BF16)))
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros((8, dim), BF16)))
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), shapes)
     text = jax.jit(layer.apply).lower(params, x).compile().as_text()

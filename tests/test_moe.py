@@ -259,3 +259,154 @@ def test_moe_aux_loss_in_objective():
     _, loss = step(state, tokens, labels)
     np.testing.assert_allclose(float(loss), ce + aux, atol=1e-5)
     assert abs(float(loss) - ce) > 1e-4  # aux is genuinely nonzero in there
+
+
+# ------------------------------------------------- dropless experts' options
+# ops/moe.py::DroplessMoE beyond softmax-routed SwiGLU: sigmoid scores chosen
+# under a correction bias, ``relu2`` experts, experts in a latent.  The
+# oracles: a per-expert loop written here from the layer's docstring, and
+# the Nemotron-H reference's expert layer (benchmark/reference/nemotron_h.py,
+# which shares nothing with the program) for the whole form and the shares.
+from nemotron_toy import CONFIG, MODEL_KEYS, load_reference  # noqa: E402
+
+from pytorch_distributed_training_tpu.ops.moe import DroplessMoE  # noqa: E402
+
+DIM, WIDTH, EXPERTS, TOP = 32, 16, 8, 3
+
+
+def _tokens(n=10, seed=11):
+    return jax.random.normal(jax.random.PRNGKey(seed), (n, DIM), jnp.float32)
+
+
+def test_dropless_defaults_give_the_outputs_they_gave_before_the_options():
+    """The numbers of the layer as it stood before ``scoring``,
+    ``activation`` and ``latent`` existed (read from that commit on this
+    CPU): the defaults are bit for bit what two accepted cells run."""
+    layer = DroplessMoE(dim=DIM, num_experts=EXPERTS, top_k=TOP, hidden=WIDTH,
+                        shared_hidden=24, norm_topk_prob=True,
+                        routed_scaling_factor=1.5, experts_held=(2, 4))
+    x = _tokens()
+    params = layer.init(jax.random.PRNGKey(3), x)
+    assert sorted(params["params"]) == [
+        "router", "shared_down", "shared_gate_up", "w_down", "w_gate_up"]
+    y, sizes = layer.apply(params, x)
+    spelled = layer.clone(scoring="softmax", activation="swiglu", latent=0)
+    np.testing.assert_array_equal(np.asarray(spelled.apply(params, x)[0]), np.asarray(y))
+    assert np.asarray(sizes).tolist() == [2, 7, 4, 1]
+    np.testing.assert_array_equal(
+        np.asarray(y)[:2, :4],
+        np.asarray([[-0.24654839932918549, 0.2906823456287384,
+                     -0.4046250879764557, 0.05341293290257454],
+                    [-0.4536212086677551, 0.18737046420574188,
+                     -0.31964337825775146, -0.04433639347553253]], np.float32))
+    assert float(np.asarray(y).sum()) == -5.642613410949707
+
+
+def _by_hand(p, x, *, scoring, activation, latent, scale=2.0, first=0, held=EXPERTS):
+    """The layer as its docstring writes it, an expert at a time."""
+    logits = x @ p["router"]
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen = jax.lax.top_k(scores + p["e_score_correction_bias"], TOP)[1]
+    else:
+        scores = jax.nn.softmax(logits, -1)
+        chosen = jax.lax.top_k(scores, TOP)[1]
+    gates = jnp.take_along_axis(scores, chosen, -1)
+    gates = gates / gates.sum(-1, keepdims=True) * scale
+    full = jnp.zeros_like(scores).at[jnp.arange(len(x))[:, None], chosen].set(gates)
+
+    def act(h):
+        if activation == "relu2":
+            return jnp.square(jax.nn.relu(h))
+        return jax.nn.silu(h[..., :h.shape[-1] // 2]) * h[..., h.shape[-1] // 2:]
+
+    up = "w_up" if activation == "relu2" else "w_gate_up"
+    u = x @ p["latent_down"] if latent else x
+    routed = sum(
+        full[:, first + e, None] * (act(u @ p[up][e]) @ p["w_down"][e])
+        for e in range(held))
+    if latent:
+        routed = routed @ p["latent_up"]
+    shared = act(x @ p["shared_" + up[2:]]) @ p["shared_down"]
+    return routed + shared
+
+
+@pytest.mark.parametrize("latent", [0, 8], ids=["full_width", "latent"])
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_dropless_options_against_an_expert_at_a_time(scoring, activation, latent):
+    layer = DroplessMoE(dim=DIM, num_experts=EXPERTS, top_k=TOP, hidden=WIDTH,
+                        shared_hidden=24, norm_topk_prob=True,
+                        routed_scaling_factor=2.0, scoring=scoring,
+                        activation=activation, latent=latent)
+    x = _tokens(seed=5)
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    width = latent or DIM
+    wide = 1 if activation == "relu2" else 2
+    first = "w_up" if activation == "relu2" else "w_gate_up"
+    assert p[first].shape == (EXPERTS, width, wide * WIDTH)
+    assert p["w_down"].shape == (EXPERTS, WIDTH, width)
+    assert ("latent_down" in p) == ("latent_up" in p) == bool(latent)
+    if scoring == "sigmoid":
+        assert p["e_score_correction_bias"].dtype == jnp.float32
+        p = dict(p, e_score_correction_bias=0.3 * jax.random.normal(
+            jax.random.PRNGKey(2), (EXPERTS,)))
+    y, sizes = layer.apply({"params": p}, x)
+    assert int(sizes.sum()) == len(x) * TOP
+    want = _by_hand(p, x, scoring=scoring, activation=activation, latent=latent)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+
+
+def test_the_correction_bias_moves_the_choice_and_not_the_gate():
+    """A bias that lifts expert 5 above every other: each token now
+    chooses it, and pays it with its own sigmoid score, not the lifted one."""
+    layer = DroplessMoE(dim=DIM, num_experts=EXPERTS, top_k=1, hidden=WIDTH,
+                        norm_topk_prob=False, scoring="sigmoid",
+                        activation="relu2")
+    x = _tokens(seed=9)
+    p = layer.init(jax.random.PRNGKey(4), x)["params"]
+    plain, sizes = layer.apply({"params": p}, x)
+    assert int(sizes[5]) < len(x)  # not everybody's first choice unbiased
+    lifted = dict(p, e_score_correction_bias=jnp.zeros((EXPERTS,)).at[5].set(10.0))
+    y, sizes = layer.apply({"params": lifted}, x)
+    assert np.asarray(sizes).tolist() == [0, 0, 0, 0, 0, len(x), 0, 0]
+    score = jax.nn.sigmoid(x @ p["router"])[:, 5:6]  # below 1: the gate, not 10 + it
+    want = score * (jnp.square(jax.nn.relu(x @ p["w_up"][5])) @ p["w_down"][5])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-6)
+    assert np.abs(np.asarray(y) - np.asarray(plain)).max() > 1e-3
+
+
+def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """16 latent experts top-6 in four shares of 4 (the cell: 512 top-22 in
+    four of 128): the four shares' routed parts and the shared expert ONCE
+    add up to what the reference gives for the whole layer with all 16."""
+    ref = load_reference()
+    whole = dict(CONFIG, n_routed_experts=16, serve={"model": {"n_routed_experts": 16}})
+    sizes = ref.sizes_of(whole)
+    params = jax.device_get(ref.make_params(3, sizes))
+    layer_ref = jax.tree.map(jnp.asarray, params["layers"][1])  # an E layer
+    weights = jax.tree.map(
+        lambda a: jnp.asarray(a).astype(jnp.float32),
+        ref.to_checkpoint_tree(params)["layer1"]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, whole["hidden_size"]), jnp.float32)
+    want = ref.experts_layer(x, layer_ref, arch=ref.arch_of(params))
+    form = dict(
+        dim=whole["hidden_size"], num_experts=16, top_k=whole["num_experts_per_tok"],
+        hidden=whole["moe_intermediate_size"],
+        shared_hidden=whole["moe_shared_expert_intermediate_size"],
+        norm_topk_prob=True, routed_scaling_factor=whole["routed_scaling_factor"],
+        scoring="sigmoid", activation="relu2", latent=whole["moe_latent_size"])
+    assert MODEL_KEYS["experts_held"] == [4, 4]  # the model's own share is one of them
+    total = None
+    for first in range(0, 16, 4):
+        share = DroplessMoE(experts_held=(first, 4), **form)
+        mine = dict(weights, w_up=weights["w_up"][first:first + 4],
+                    w_down=weights["w_down"][first:first + 4])
+        part, counts = share.apply({"params": mine}, x, method="routed_part")
+        assert counts.shape == (4,)
+        total = part if total is None else total + part
+    total = total + share.apply({"params": mine}, x, method="shared_part")
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=2e-5)
+    # one share alone is NOT the layer: the other twelve experts count
+    alone = share.apply({"params": mine}, x)[0]
+    assert np.abs(np.asarray(alone) - np.asarray(want)).max() > 1e-2

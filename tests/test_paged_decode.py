@@ -104,11 +104,11 @@ def _call(rng, lengths, kv_heads, group):
 RAGGED = [1, BS, BS + 1, STEP - 1, STEP, STEP + 1, 2 * STEP + BS, T * BS, 0]
 
 
-@pytest.mark.parametrize("group,as_stored", [(1, False), (8, True), (2, False)],
-                         ids=["G1_lm", "G8_gqa", "G2"])
+@pytest.mark.parametrize("group,as_stored", [(1, False), (8, True), (2, False), (16, True)],
+                         ids=["G1_lm", "G8_gqa", "G2", "G16_2kv_nemotron"])
 def test_kernel_matches_the_gather_arm_on_ragged_rows(group, as_stored):
     rng = np.random.default_rng(group)
-    kv_heads = 2
+    kv_heads = 2  # G16: 32 query heads over 2 K/V heads, nemotron_h's attention
     lengths = RAGGED
     tables = _tables(rng, lengths)
     want, got = _both(_pool(rng, kv_heads), *_call(rng, lengths, kv_heads, group),
@@ -220,6 +220,7 @@ def test_a_nan_in_a_live_row_stays_in_the_row_that_owns_it():
 @pytest.mark.parametrize("head_dim,kv_heads,dtype,ok", [
     (128, 8, jnp.bfloat16, True),    # lm271m, solar-open2-250b
     (128, 8, jnp.float32, True),
+    (128, 2, jnp.bfloat16, True),    # nemotron-3-super-120b: two heads, one sublane
     (128, 1, jnp.float32, True),
     (128, 1, jnp.bfloat16, False),   # half a 32-bit sublane a row
     (64, 8, jnp.bfloat16, False),    # half a lane tile
