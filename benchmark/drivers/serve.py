@@ -143,6 +143,9 @@ def run(cell: dict, args, out_dir: str, ledger, t_start: float):
         errors=sorted({r.error for r in records if r.error})[:5],
         ttft_samples=len(counted),
         gap_samples=len(loadgen.gaps_ms(records)),
+        # every stamp in the window, the lead-in's too: the manifest's metric
+        # until PR 36, kept so that a run can be laid beside those records
+        tokens_stamped_per_s=loadgen.tokens_per_s(records, t0, float(args.seconds)),
     )
 
     # the engine is closed and freed: now the reference

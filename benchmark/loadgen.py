@@ -20,6 +20,7 @@ Stdlib and numpy only; nothing of the program is imported.
 """
 from __future__ import annotations
 
+import heapq
 import math
 import threading
 import time
@@ -232,11 +233,42 @@ def lag_ms(records: List[Served]) -> List[float]:
 
 
 def tokens_per_s(records: List[Served], t0: float, seconds: float) -> float:
-    """Output tokens the client received inside the window, a second: every
-    token of every request, lead-in requests too, by its own time stamp (a
-    count by whole requests swings with which long request straddles the
-    window's end)."""
+    """Every token the client stamped inside the window, a second: the
+    lead-in's requests' too.  NOT a manifest metric (PERF.md, PR 36): the
+    lead-in offers another rate than the window, so an engine that carries
+    less of its backlog into the window reads LOWER here.  ``sweep.py``
+    reads capacity above the knee with it, and a run's ``notes`` keep it as
+    ``tokens_stamped_per_s`` to be laid beside the records before PR 36."""
     inside = sum(
         1 for r in records for t in r.token_times if t0 <= t <= t0 + seconds
     )
     return inside / seconds
+
+
+def goodput_tokens_per_s(records: List[Served], t0: float, seconds: float) -> float:
+    """Output tokens of the window's OWN requests that the client received
+    inside the window, a second: over the requests due in the window and not
+    failed, each token by its own time stamp.  A lead-in request counts
+    nothing; a request that was refused, errored or came back short counts
+    nothing, not even what it did deliver; a request that ends in the drain
+    counts what it delivered before the window closed.  Below the knee it
+    rises with every speed-up (less of the window's work is left for the
+    drain) and falls with every shed or stalled request."""
+    own = [r for r in counted(records) if not failed(r)]
+    return tokens_per_s(own, t0, seconds)
+
+
+def replay(trace: List[Arrival], slots: int, gap_s: float) -> List[Served]:
+    """The trace served by an engine of ``slots`` rows that gives every
+    request in a row one token each ``gap_s``, first come first served: no
+    chip, no prefill, no jitter; the window opens at 0.  What a shorter
+    token gap alone does to a count (PERF.md section 6, PR 36)."""
+    free = [-math.inf] * slots            # when each row is free again
+    out = []
+    for a in trace:
+        start = max(a.due_s, heapq.heappop(free))
+        times = [start + (k + 1) * gap_s for k in range(a.gen_len)]
+        heapq.heappush(free, times[-1])
+        out.append(Served(a, due=a.due_s, submitted=a.due_s, token_times=times,
+                          tokens=np.arange(a.gen_len), finished=times[-1]))
+    return out

@@ -50,7 +50,7 @@ def result_of(proc):
 def test_dry_run_of_the_toy_reports_the_cell_s_metrics(copy):
     result = result_of(dryrun.run_cell(copy, TOY))
     assert result["attempted"] == 40 and result["failed"] == 0
-    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_goodput_tokens_per_s", "setup_s"}
 
 
 def test_counters_of_the_expert_layer_through_dry(copy):
@@ -140,14 +140,21 @@ def test_readers_take_the_decode_program_s_scopes_only(tmp_path):
 
 def test_manifest_entries_of_the_configuration():
     manifest = load_json(os.path.join(dryrun.REPO, "BENCHMARK.json"))
-    assert manifest["configs"][-1]["name"] == "deepseek-v2-lite"
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == list(NEW)
-    for metric in manifest["per_layer"][-5:]:
-        assert metric["workloads"] == [CELL]
+    # by name and cell, wherever an entry stands: later configurations add
+    # theirs behind these, and their cells to the readers they share
+    assert "deepseek-v2-lite" in [c["name"] for c in manifest["configs"]]
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "deepseek-v2-lite", "serve.steady32", 1)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for name in NEW:
+        assert CELL in by_name[name]["workloads"]
+        assert by_name[name]["moves"] == "serve_itl_p95_ms"
+    for name in ("mla_attention_ms_per_decode_step", "moe_gmm_roofline_pct"):
+        assert by_name[name]["workloads"] == [CELL]
     reported = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
                 if "workloads" not in m or CELL in m["workloads"]}
-    assert {"setup_s", "serve_itl_p95_ms", "serve_tokens_per_s",
+    assert {"setup_s", "serve_itl_p95_ms", "serve_goodput_tokens_per_s",
             "decode_step_device_ms", "device_idle_pct.serve"} <= reported
     traffic = load_json(os.path.join(dryrun.BENCH, "traffic", "serve.steady32.json"))
     assert isinstance(traffic["rate_rps"], float) and traffic["drain_s"] == 60.0

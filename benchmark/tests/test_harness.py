@@ -48,7 +48,7 @@ def test_result_line_of_a_serving_cell(copy):
     assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert result["attempted"] == 40 and result["failed"] == 0
     assert set(result["metrics"]) == {
-        "serve_itl_p95_ms", "serve_tokens_per_s", "setup_s",
+        "serve_itl_p95_ms", "serve_goodput_tokens_per_s", "setup_s",
     }
 
 

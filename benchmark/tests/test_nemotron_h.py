@@ -56,7 +56,7 @@ def result_of(proc):
 def test_dry_run_of_the_toy_reports_the_cell_s_metrics(copy):
     result = result_of(dryrun.run_cell(copy, TOY))
     assert result["attempted"] == 40 and result["failed"] == 0
-    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_goodput_tokens_per_s", "setup_s"}
 
 
 def test_counters_and_gauges_through_dry(copy):
@@ -205,7 +205,7 @@ def test_manifest_entries_of_the_configuration():
     assert {by_name[n]["layer"] for n in NEW[:4]} == {"state-space layer (Mamba-2)"}
     reported = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
                 if "workloads" not in m or CELL in m["workloads"]}
-    assert {"setup_s", "serve_itl_p95_ms", "serve_tokens_per_s", "moe_ms_per_decode_step",
+    assert {"setup_s", "serve_itl_p95_ms", "serve_goodput_tokens_per_s", "moe_ms_per_decode_step",
             "decode_step_device_ms", "device_idle_pct.serve", "tick_host_ms_p50",
             "gqa_attention_ms_per_decode_step", "moe_load_max_over_mean"} <= reported
     # their counts are of another expert (three matrices of the full width)

@@ -53,7 +53,7 @@ def result_of(proc):
 def test_dry_run_of_the_toy_reports_the_cell_s_metrics(copy):
     result = result_of(dryrun.run_cell(copy, TOY))
     assert result["attempted"] == 40 and result["failed"] == 0
-    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    assert set(result["metrics"]) == {"serve_itl_p95_ms", "serve_goodput_tokens_per_s", "setup_s"}
 
 
 def test_counters_and_gauges_through_dry(copy):
@@ -175,17 +175,22 @@ def test_readers_over_a_hand_made_trace(tmp_path):
 
 def test_manifest_entries_of_the_configuration():
     manifest = load_json(os.path.join(dryrun.REPO, "BENCHMARK.json"))
-    assert manifest["configs"][-1]["name"] == "solar-open2-250b"
-    assert manifest["configs"][-1]["reduced"] == [
-        "num_hidden_layers", "n_routed_experts", "vocab_size"]
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["workloads"][-1]["chips"] == 1
-    assert [m["name"] for m in manifest["per_layer"][-6:]] == list(NEW)
-    for metric in manifest["per_layer"][-6:]:
-        assert metric["workloads"] == [CELL] and metric["moves"] == "serve_itl_p95_ms"
+    # by name and cell, wherever an entry stands: later configurations add
+    # theirs behind these, and their cells to the readers they share
+    entry = next(c for c in manifest["configs"] if c["name"] == "solar-open2-250b")
+    assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "solar-open2-250b", "serve.long32", 1)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    for name in NEW:
+        assert by_name[name]["moves"] == "serve_itl_p95_ms"
+        shared = name == "gqa_attention_ms_per_decode_step"  # since PR 34
+        assert by_name[name]["workloads"][0] == CELL
+        assert shared or by_name[name]["workloads"] == [CELL]
     reported = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
                 if "workloads" not in m or CELL in m["workloads"]}
-    assert {"setup_s", "serve_itl_p95_ms", "serve_tokens_per_s", "moe_ms_per_decode_step",
+    assert {"setup_s", "serve_itl_p95_ms", "serve_goodput_tokens_per_s", "moe_ms_per_decode_step",
             "decode_step_device_ms", "device_idle_pct.serve"} <= reported
     # its reader takes its counts from the whole run and read 101-115% here;
     # the cell reports the share that counts the traced seconds' held experts

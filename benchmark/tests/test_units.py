@@ -9,8 +9,9 @@ import os
 import pytest
 
 from benchmark import loadgen, spans, trace
-from benchmark.common import worst_leaf_gap
+from benchmark.common import load_json, worst_leaf_gap
 from benchmark.kernels import lm_step, peaks, resnet50_step
+from benchmark.tests import dryrun
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -196,6 +197,95 @@ def test_percentile_and_due_time_arithmetic_on_a_scripted_stream():
     assert loadgen.tokens_per_s(records, 1.0, 0.25) == pytest.approx(4.0)
     assert loadgen.percentile(list(range(1, 101)), 95) == 95
     assert loadgen.percentile([], 95) is None
+
+
+def scripted(index, due, times, gen, counted=True):
+    arrival = loadgen.Arrival(index, due, 8, gen, None, counted)
+    return loadgen.Served(arrival, due=due, submitted=due, token_times=times,
+                          tokens=list(range(len(times))))
+
+
+GOODPUT_CASES = {
+    # window [10, 12]; the old count reads every stamp inside it
+    "a_lead_in_request_counts_nothing":
+        ([scripted(0, 9.0, [10.1, 10.2, 10.3], 3, counted=False)], 0, 3),
+    "a_request_straddling_the_close_counts_its_inside_stamps":
+        ([scripted(0, 11.0, [11.5, 12.0, 12.5, 13.0], 4)], 2, 2),
+    "a_request_that_came_back_short_counts_nothing":
+        ([scripted(0, 10.5, [10.6, 10.7], 3)], 0, 2),
+    "a_stamp_before_the_window_opens_is_outside":
+        ([scripted(0, 10.0, [9.99, 10.0, 11.0], 3)], 2, 2),
+    "all_together": ([
+        scripted(0, 9.0, [9.5, 10.1, 10.2], 3, counted=False),
+        scripted(1, 10.0, [10.4, 10.8, 11.2], 3),
+        scripted(2, 11.0, [11.5, 12.0, 12.5, 13.0], 4),
+        scripted(3, 11.2, [11.3], 2),
+    ], 5, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOODPUT_CASES))
+def test_goodput_counts_the_window_s_own_requests(case):
+    records, goodput_tokens, stamped_tokens = GOODPUT_CASES[case]
+    assert loadgen.goodput_tokens_per_s(records, 10.0, 2.0) == pytest.approx(
+        goodput_tokens / 2.0)
+    assert loadgen.tokens_per_s(records, 10.0, 2.0) == pytest.approx(
+        stamped_tokens / 2.0)
+
+
+def test_a_refused_request_counts_nothing_in_the_goodput():
+    rec = scripted(0, 10.5, [10.6, 10.7], 2)
+    assert loadgen.goodput_tokens_per_s([rec], 10.0, 2.0) == 1.0
+    rec.error = "RuntimeError('shed')"
+    assert loadgen.goodput_tokens_per_s([rec], 10.0, 2.0) == 0.0
+    assert loadgen.tokens_per_s([rec], 10.0, 2.0) == 1.0
+
+
+# The replay of PERF.md section 6 (PR 36): each cell's arrivals served by an
+# engine of the cell's slots at a fixed gap a token, slowest first.  The
+# numbers are the replay's own (no chip): tokens/s as (goodput, every stamp).
+REPLAY = {
+    "serve.steady32": ("deepseek-v2-lite", {
+        37.5: (439.1, 540.9), 31.7: (450.2, 539.0), 21.0: (466.3, 523.5),
+        10.0: (478.8, 515.4)}),
+    "serve.steady": ("lm271m", {
+        12.5: (463.5, 469.5), 9.6: (464.1, 468.1), 6.7: (464.9, 467.3),
+        4.0: (465.6, 467.5)}),
+    "serve.long32": ("solar-open2-250b", {
+        25.0: (315.8, 360.4), 19.3: (322.8, 363.5), 9.0: (333.5, 359.3)}),
+    "serve.burst32": ("nemotron-3-super-120b", {
+        30.0: (531.8, 600.2), 25.5: (539.9, 599.9), 15.0: (568.4, 603.0)}),
+}
+
+
+@pytest.mark.parametrize("traffic_name", sorted(REPLAY))
+def test_replay_goodput_rises_as_the_token_gap_shortens(traffic_name):
+    config_name, expected = REPLAY[traffic_name]
+    traffic = load_json(os.path.join(dryrun.BENCH, "traffic", traffic_name + ".json"))
+    config = load_json(os.path.join(dryrun.BENCH, "configs", config_name + ".json"))
+    slots = config["serve"]["serving"]["scheduler"]["slots"]
+    trace = loadgen.make_trace(traffic, 30.0)
+    offered = sum(a.gen_len for a in trace if a.counted) / 30.0
+    goodput, stamped = [], []
+    for gap_ms in sorted(expected, reverse=True):
+        records = loadgen.replay(trace, slots, gap_ms / 1e3)
+        assert not any(loadgen.failed(r) for r in records)
+        goodput.append(loadgen.goodput_tokens_per_s(records, 0.0, 30.0))
+        stamped.append(loadgen.tokens_per_s(records, 0.0, 30.0))
+        assert goodput[-1] == pytest.approx(expected[gap_ms][0], rel=0.01)
+        assert stamped[-1] == pytest.approx(expected[gap_ms][1], rel=0.01)
+    # a faster engine leaves less of the window's work for the drain: the
+    # goodput rises with every step and stays under what was offered ...
+    assert goodput == sorted(goodput) and len(set(goodput)) == len(goodput)
+    assert goodput[-1] < offered
+    # ... where the count of every stamp can read ABOVE the offered rate
+    # (the lead-in's backlog) and in no cell rises with every step
+    assert stamped != sorted(stamped)
+    if traffic_name == "serve.steady32":
+        # what refused PR 27: 31.7 -> 21.0 ms a token read 2.9% FEWER stamps
+        assert stamped == sorted(stamped, reverse=True)
+        assert stamped[0] > offered > goodput[-1]
+        assert stamped[2] < 0.98 * stamped[1] and goodput[2] > 1.03 * goodput[1]
 
 
 # ----------------------------------------------------------------- kernels
