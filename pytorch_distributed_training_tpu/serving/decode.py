@@ -254,6 +254,10 @@ class _PagedFns:
     ``gen_index[r]`` from the logits at ``last_col`` (0 for a fresh
     prompt; the hot-restart replay path passes the index of the last
     already-delivered token so the resample is bitwise reproducible).
+    ``row_keys``, here and in the two decode programs, is ``uint32 [B, 2]``
+    key DATA, one row a batch row (what a stack of legacy ``PRNGKey``s is);
+    the scheduler hands it over as ONE host ``numpy`` array, and the
+    program folds each row's ``gen_index`` into its key on the device.
     ``decode_step(params, pool, prev_tok, pos, block_tables, row_keys,
     gen_index, adapter_ids) -> (tok, finite, pool)`` — ONE single-token
     step for every slot; the scheduler's host loop supplies fresh inputs
@@ -333,11 +337,12 @@ def build_paged_fns(
     padding — one program handles cold prefill, prefix-hit suffix prefill,
     and S=1 decode alike), ``block_tables`` is [B, T] physical block ids
     covering each row's whole reserved footprint, ``last_col`` [B] is the
-    column of each row's final real token, ``row_keys`` [B] the per-row
-    PRNG keys, ``gen_index`` [B] each row's generated-token index (rows
-    sit at DIFFERENT indices under continuous batching).  Every array is
-    fixed-width; inactive rows ride along with position -1 (their scatter
-    drops, their sampled token is ignored host-side).
+    column of each row's final real token, ``row_keys`` ``uint32 [B, 2]``
+    the per-row PRNG keys as key data, ``gen_index`` [B] each row's
+    generated-token index (rows sit at DIFFERENT indices under continuous
+    batching).  Every array is fixed-width; inactive rows ride along with
+    position -1 (their scatter drops, their sampled token is ignored
+    host-side).
 
     ``adapter_ids`` [B] int32 (-1 = base model) reaches the model only
     when it was cloned with LoRA factors — non-LoRA builds trace it as an

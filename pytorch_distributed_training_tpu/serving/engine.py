@@ -587,14 +587,15 @@ class InferenceEngine:
         name): see :meth:`_warm_pool_programs`."""
         sched = self.scheduler
         sched.require_idle()
-        pad_key = sched._pad_key
         T = sched.table_blocks
         W = sched.slots_n
         pos = np.full((W,), -1, np.int32)
         tables = np.zeros((W, T), np.int32)
         zeros = np.zeros((W,), np.int32)
         aids = np.full((W,), -1, np.int32)
-        keys = jnp.stack([pad_key] * W)
+        # the tick's own kind of argument (a host uint32 [n, 2] array), so
+        # that each program's jit cache holds the ONE entry the ticks hit
+        keys = sched._pad_keys(W)
 
         def no_slot(n):
             # a model that carries a state: every row's slot is -1 (padding)
@@ -602,7 +603,7 @@ class InferenceEngine:
 
         def prefills(fns, params):
             for bb in sched.batch_buckets:
-                bkeys = jnp.stack([pad_key] * bb)
+                bkeys = sched._pad_keys(bb)
                 for sb in sched.seq_buckets:
                     yield (fns.prefill, (params,), (
                         np.zeros((bb, sb), np.int32),

@@ -43,8 +43,11 @@ either evicts the one poisoned request (poison-bisect over
 ``_decode_probe``, or the on-device ``isfinite`` output guard for NaN
 emitters) or hot-restarts the engine, rebuilding the compiled programs
 and pool and replaying every in-flight request token-identically
-(``_replay``; the per-row per-token-index ``fold_in`` keys make the
-resample bitwise reproducible).  ``drain()`` gives SIGTERM a bounded
+(``_replay``; a request's sampling key is a ``uint32 [2]`` row of key data
+held on the HOST from ``submit`` on, a call's ``row_keys`` are those rows
+in ONE ``numpy`` array beside ``pos`` and ``tables``, and the program folds
+each row's token index into its key on the device, so the resample is
+bitwise reproducible).  ``drain()`` gives SIGTERM a bounded
 graceful shutdown and ``health()`` the readiness/liveness snapshot; an
 optional tick watchdog (engine/watchdog.py) turns a hung step into a
 diagnosed restart.  The ``serve_*`` kinds in engine/fault.py drive all
@@ -115,8 +118,21 @@ from .speculative import greedy_accept
 __all__ = ["ContinuousScheduler"]
 
 
+def _cpu_device():
+    """The host's own JAX device, or None (= the default device) in a
+    process whose ``JAX_PLATFORMS`` names no CPU backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 class _PagedRequest:
-    """One request's slot-side state: prompt, reservation, token stream."""
+    """One request's slot-side state: prompt, reservation, token stream.
+
+    ``row_key`` is the request's sampling key as the scheduler keeps it: the
+    key's raw data, a ``numpy`` ``uint32 [2]`` row on the host, made once at
+    ``submit`` and copied into a row of every call's ``row_keys``."""
 
     __slots__ = (
         "prompt", "max_new", "future", "enqueued_at", "deadline",
@@ -394,8 +410,15 @@ class ContinuousScheduler:
                 getattr(self._draft_model, "lora_adapters", 0) > 0
             )
             self._build_draft()
-        self._pad_key = jax.random.PRNGKey(0)
-        self._base_rng = jax.random.PRNGKey(int(seed))
+        # sampling keys are host rows of key data (see _PagedRequest); they
+        # are made on the CPU backend, where the process has one, so that a
+        # submit neither waits behind the device's step nor dispatches to it
+        # (jitted: the bare function spends 0.4 ms in python wrappers a call)
+        self._key_device = _cpu_device()
+        self._fold_in = jax.jit(jax.random.fold_in)
+        with jax.default_device(self._key_device):
+            self._pad_key = np.asarray(jax.random.PRNGKey(0))
+            self._base_rng = np.asarray(jax.random.PRNGKey(int(seed)))
         self._seq_no = 0  # guarded by: self._cond
         self._req_no = 0  # guarded by: self._cond
 
@@ -536,6 +559,26 @@ class ContinuousScheduler:
         live = np.where(pos >= 0, pos // bs + 1, 0).sum()
         return float(live) / (self.slots_n * self.table_blocks)
 
+    def _pad_keys(self, n: int) -> np.ndarray:
+        """The ``row_keys`` argument of a paged call of ``n`` batch rows,
+        every row the pad key: ONE host ``uint32 [n, 2]`` array, which the
+        caller overwrites at its live rows with ``req.row_key``."""
+        return np.tile(self._pad_key, (n, 1))
+
+    def _key_row(self, rng) -> np.ndarray:
+        """A caller's sampling key (a typed key, or key data on the device
+        or the host) as the host row :class:`_PagedRequest` keeps."""
+        dtype = getattr(rng, "dtype", None)
+        if dtype is not None and jax.dtypes.issubdtype(dtype, jax.dtypes.prng_key):
+            rng = jax.random.key_data(rng)
+        row = np.asarray(rng, np.uint32)
+        if row.shape != self._pad_key.shape:
+            raise ValueError(
+                f"rng must be ONE sampling key, uint32 {self._pad_key.shape} "
+                f"of key data; got shape {row.shape}"
+            )
+        return row
+
     # ------------------------------------------------------------------ #
     # client side
 
@@ -560,7 +603,8 @@ class ContinuousScheduler:
         budget (its slot retires early instead of padding the batch with
         dead decode steps — the whole point of iteration-level
         scheduling); ``rng`` overrides the request's sampling key (a
-        PRNGKey) so tests can replay the whole-batch path row for row.
+        PRNGKey, typed or as ``uint32 [2]`` key data, on the device or the
+        host) so tests can replay the whole-batch path row for row.
 
         ``replay_tokens`` pre-populates the request's generated stream:
         admission takes the hot-restart replay path (``_replay``) instead
@@ -613,6 +657,8 @@ class ContinuousScheduler:
                     f"max_new_tokens ({mnt}); a fully-generated request "
                     "has nothing left to decode"
                 )
+        # a caller's key is read to the host before the lock is taken
+        row_key = None if rng is None else self._key_row(rng)
         with self._cond:
             if self._closed:
                 raise RuntimeError("scheduler is closed")
@@ -633,13 +679,16 @@ class ContinuousScheduler:
                     f"serving backlog full ({self.max_backlog} waiting); "
                     "request shed"
                 )
-            if rng is None:
-                rng = jax.random.fold_in(self._base_rng, self._seq_no)
+            if row_key is None:
+                with jax.default_device(self._key_device):
+                    row_key = np.asarray(
+                        self._fold_in(self._base_rng, self._seq_no)
+                    )
                 self._seq_no += 1
             req = _PagedRequest(
                 prompt, mnt,
                 deadline=(time.monotonic() + dl / 1000.0) if dl else None,
-                on_token=on_token, row_key=rng,
+                on_token=on_token, row_key=row_key,
             )
             req.rid = self._req_no
             self._req_no += 1
@@ -1354,7 +1403,7 @@ class ContinuousScheduler:
         tables = np.zeros((bb, self.table_blocks), np.int32)
         last_col = np.zeros((bb,), np.int32)
         aids = np.full((bb,), -1, np.int32)
-        keys = [self._pad_key] * bb
+        keys = self._pad_keys(bb)
         for i, req in enumerate(newly):
             cl = req.admission.cached_len
             tokens[i, : suffix[i]] = req.prompt[cl:]
@@ -1367,7 +1416,7 @@ class ContinuousScheduler:
         slots = [r.slot for r in newly] + [-1] * (bb - len(newly))
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
-            last_col, jnp.stack(keys), np.zeros((bb,), np.int32), aids,
+            last_col, keys, np.zeros((bb,), np.int32), aids,
             *self._state_rows(slots),
         )
         rb0 = time.perf_counter()
@@ -1424,7 +1473,7 @@ class ContinuousScheduler:
             tables[i, : len(dids)] = dids
             last_col[i] = n - 1
             aids[i] = req.adapter if self._draft_lora else -1
-        keys = jnp.stack([self._pad_key] * bb)
+        keys = self._pad_keys(bb)
         _tok, _finite, self._draft_pool = self._draft_fns.prefill(
             self._draft_params, self._draft_pool, tokens, positions, tables,
             last_col, keys, np.zeros((bb,), np.int32), aids,
@@ -1449,7 +1498,7 @@ class ContinuousScheduler:
         tables = np.zeros((bb, self.table_blocks), np.int32)
         last_col = np.zeros((bb,), np.int32)
         aids = np.full((bb,), -1, np.int32)
-        keys = [self._pad_key] * bb
+        keys = self._pad_keys(bb)
         for i, req in enumerate(reqs):
             cl = req.admission.cached_len
             tokens[i, : suffix[i]] = req.prompt[cl:]
@@ -1462,7 +1511,7 @@ class ContinuousScheduler:
         slots = [r.slot for r in reqs] + [-1] * (bb - len(reqs))
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
-            last_col, jnp.stack(keys), np.zeros((bb,), np.int32), aids,
+            last_col, keys, np.zeros((bb,), np.int32), aids,
             *self._state_rows(slots),
         )
         tok = np.asarray(tok)
@@ -1493,7 +1542,7 @@ class ContinuousScheduler:
             tables = np.zeros((W, self.table_blocks), np.int32)
             gi = np.zeros((W,), np.int32)
             aids = np.full((W,), -1, np.int32)
-            keys = [self._pad_key] * W
+            keys = self._pad_keys(W)
             for req in step_reqs:
                 i = req.slot
                 prev[i] = req.tokens[k - 1]
@@ -1505,7 +1554,7 @@ class ContinuousScheduler:
                 keys[i] = req.row_key
             tok, finite, self._pool, *_ = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
-                self._pool, prev, pos, tables, jnp.stack(keys), gi, aids,
+                self._pool, prev, pos, tables, keys, gi, aids,
                 *self._slot_rows(pos),
             )
             tok = np.asarray(tok)
@@ -1620,7 +1669,7 @@ class ContinuousScheduler:
         tables = np.zeros((W, self.table_blocks), np.int32)
         gen_idx = np.zeros((W,), np.int32)
         aids = np.full((W,), -1, np.int32)
-        keys = [self._pad_key] * W
+        keys = self._pad_keys(W)
         for req in reqs:
             i = req.slot
             prev[i] = req.tokens[-1]
@@ -1660,7 +1709,7 @@ class ContinuousScheduler:
             tok, finite, self._pool, *moe = self._fns.decode_step(
                 self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables,
-                jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
+                keys, gen_idx, aids, *self._slot_rows(pos),
             )
         rb0 = time.perf_counter()
         with self._phase("readback"):
@@ -1710,7 +1759,7 @@ class ContinuousScheduler:
         tok, _, self._pool, *_ = self._fns.decode_step(
             self._qparams if self._quant else self.params,
             self._pool, prev, pos, tables,
-            jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
+            keys, gen_idx, aids, *self._slot_rows(pos),
         )
         # surface async dispatch errors here, inside the probe's try
         jax.block_until_ready(tok)
@@ -1778,7 +1827,7 @@ class ContinuousScheduler:
                 tok, finite, self._pool, *moe = self._fns.decode_step_fed(
                     self._qparams if self._quant else self.params,
                     self._pool, prev, fresh_mask, fresh_tok, pos, tables,
-                    jnp.stack(keys), gen_idx, aids, *self._slot_rows(pos),
+                    keys, gen_idx, aids, *self._slot_rows(pos),
                 )
             for req in disp:
                 req.dispatched += 1
@@ -1814,7 +1863,7 @@ class ContinuousScheduler:
         tables = np.zeros((W, self.table_blocks), np.int32)
         gen_idx = np.zeros((W,), np.int32)
         aids = np.full((W,), -1, np.int32)
-        keys = [self._pad_key] * W
+        keys = self._pad_keys(W)
         rows = []
         for req in disp:
             i = req.slot
@@ -1972,7 +2021,7 @@ class ContinuousScheduler:
             # proposal commits, and even a self-draft would drift off the
             # target (acceptance < 1 for no reason) ---------------------
             draft_tok = np.zeros((W, k), np.int32)
-            pad_keys = jnp.stack([self._pad_key] * W)
+            pad_keys = self._pad_keys(W)
             for j in range(k + 1):
                 prev = np.zeros((W,), np.int32)
                 pos = np.full((W,), -1, np.int32)

@@ -1603,7 +1603,6 @@ def pool_program_args(sched, name):
     (no request active: every row rides at position -1)."""
     W, T = sched.slots_n, sched.table_blocks
     prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
-    keys = jnp.stack(keys)
     tokens = np.zeros((W, 8), np.int32)
     positions = np.full((W, 8), -1, np.int32)
     oob = np.full((W,), sched._kv.num_blocks * sched._kv.block_size, np.int32)
@@ -1705,4 +1704,186 @@ def test_warmup_is_refused_beside_queued_work(lm_and_params):
         sched.require_idle()
     _run_scheduler_to_done(sched, [fut])
     sched.require_idle()
+    sched.close()
+
+
+# --------------------------------------------------------------------- #
+# a request's sampling key is a host row: one program a decode tick
+
+
+class _Spy:
+    """Stands in for one program of ``_PagedFns``: files the arguments of
+    every call, then calls it (``_cache_size`` and ``lower`` pass through)."""
+
+    def __init__(self, fn, name, calls, rewrite=None):
+        self._fn, self._name, self._calls = fn, name, calls
+        self._rewrite = rewrite
+
+    def __call__(self, *args):
+        self._calls.append((self._name, args))
+        if self._rewrite is not None:
+            args = self._rewrite(self._name, args)
+        return self._fn(*args)
+
+    def __getattr__(self, attr):
+        return getattr(self._fn, attr)
+
+
+# per program: where ``row_keys`` sits, and the first argument the TICK
+# builds (before it: params, the pool and, for the fed step, the carried
+# token, which is the previous step's output and stays on the device)
+_KEYS_AT = {"prefill": 6, "decode_step": 5, "decode_step_fed": 7}
+_HOST_FROM = {"prefill": 2, "decode_step": 2, "decode_step_fed": 3,
+              "verify": 2, "copy_rows": 1}
+
+
+def _spy_on(sched, rewrite=None):
+    calls = []
+    for fns in (sched._fns, sched._draft_fns):
+        if fns is not None:
+            for name in _HOST_FROM:
+                setattr(fns, name, _Spy(getattr(fns, name), name, calls, rewrite))
+    return calls
+
+
+def _assert_host_built(calls):
+    """Every argument a tick built is ONE ``numpy`` array, the key rows
+    ``uint32 [batch rows, 2]``: nothing was built on the device a slot."""
+    assert calls
+    for name, args in calls:
+        for a in args[_HOST_FROM[name]:]:
+            assert type(a) is np.ndarray, (name, type(a))
+        if name in _KEYS_AT:
+            keys = args[_KEYS_AT[name]]
+            assert keys.dtype == np.uint32, (name, keys.dtype)
+            assert keys.shape == (args[_KEYS_AT[name] + 1].shape[0], 2), name
+
+
+@pytest.mark.parametrize("body", ["sync", "async_ring", "speculative"])
+def test_a_tick_hands_the_programs_host_arrays_only(
+    lm_and_params, mode_prompts, body
+):
+    model, params = lm_and_params
+    sched = _paged_sched(model, params, seed=5, **_decode_bodies()[body])
+    calls = _spy_on(sched)
+    results = _sched_results(sched, mode_prompts)
+    assert all(r["gen_len"] >= 1 for r in results)
+    _assert_host_built(calls)
+    step = {"sync": "decode_step", "async_ring": "decode_step_fed",
+            "speculative": "verify"}[body]
+    assert {"prefill", step} <= {name for name, _ in calls}
+    # the prefill's rows: request i's key is fold_in(PRNGKey(seed), i),
+    # made once at submit; a padding row rides the pad key
+    keys = next(a for n, a in calls if n == "prefill")[_KEYS_AT["prefill"]]
+    base = jax.random.PRNGKey(5)
+    for i in range(3):
+        np.testing.assert_array_equal(
+            keys[i], np.asarray(jax.random.fold_in(base, i)))
+    np.testing.assert_array_equal(keys[3], np.asarray(jax.random.PRNGKey(0)))
+    sched.close()
+
+
+def test_probe_and_replay_hand_the_programs_host_arrays_only(
+    lm_and_params, mode_prompts, plain_sched_results
+):
+    """The supervisor's two paths beside the tick: the bisect's probe and
+    the restart's chunked replay of delivered tokens."""
+    model, params = lm_and_params
+    sched = _paged_sched(model, params)
+    calls = _spy_on(sched)
+    delivered = plain_sched_results[0][1]["tokens"]
+    assert len(delivered) >= 3
+    # (greedy: any key replays the stream; a replay must name one)
+    fut = sched.submit(mode_prompts[1], replay_tokens=list(delivered[:2]),
+                       rng=jax.random.PRNGKey(3))
+    # admitted through _replay (a prefill, then the second delivered token
+    # by a decode chunk), and the tick's own decode step behind it
+    sched.tick()
+    assert [n for n, _ in calls] == ["prefill", "decode_step", "decode_step"]
+    sched._decode_probe([r for r in sched._slots if r is not None])
+    assert len(calls) == 4
+    _run_scheduler_to_done(sched, [fut])
+    _assert_host_built(calls)
+    np.testing.assert_array_equal(fut.result()["tokens"], delivered)
+    sched.close()
+
+
+@pytest.mark.parametrize("mode", ["sync", "async_ring"])
+def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
+    """A warm-up BEFORE any traffic, then prefills and decode ticks: the
+    program count stays where the warm-up left it, so each program's jit
+    cache holds the one entry the ticks hit and nothing compiles (or is
+    laid out anew) while requests are served."""
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = _warm_engine_cfg(**({"async_depth": 1} if mode == "async_ring" else {}))
+    rng = np.random.default_rng(5)
+    with InferenceEngine.from_config(cfg) as engine:
+        # one prefill bucket and the decode step, and the fed step beside it
+        n = 3 if mode == "async_ring" else 2
+        assert engine.warmup()["programs"] == n
+        assert engine.compile_count() == n
+        calls = _spy_on(engine.scheduler)
+        futs = [
+            engine.submit(rng.integers(2, VOCAB, ln).astype(np.int32))
+            for ln in (3, 8, 5, 2, 7)
+        ]
+        assert sum(f.result(timeout=120)["gen_len"] for f in futs) > 5
+        step = "decode_step_fed" if mode == "async_ring" else "decode_step"
+        names = [name for name, _ in calls]
+        assert names.count("prefill") >= 1 and names.count(step) >= 3
+        assert engine.compile_count() == n
+
+
+def test_sampled_streams_are_those_of_stacked_device_keys(
+    lm_and_params, mode_prompts
+):
+    """A host row a request gives the program the key data that a
+    ``jnp.stack`` of device-resident keys gave it: the sampled streams are
+    bit for bit the same, for the scheduler's own key (``fold_in`` of its
+    seed and the request's number) and for a caller's, typed, legacy or
+    already on the host."""
+    model, params = lm_and_params
+    typed = jax.random.key(9)
+    legacy = jax.random.fold_in(jax.random.PRNGKey(7), 1)
+    on_host = np.asarray(jax.random.PRNGKey(21))
+    prompts = [*mode_prompts, mode_prompts[0]]
+
+    sched = _paged_sched(model, params, temperature=0.8, seed=11)
+    got = _sched_results(
+        sched, prompts,
+        [{}, {"rng": legacy}, {"rng": typed}, {"rng": on_host}],
+    )
+    sched.close()
+
+    def stacked(name, args):
+        # what the programs were handed before: one device key a row,
+        # expanded and concatenated on the device
+        if name not in _KEYS_AT:
+            return args
+        at = _KEYS_AT[name]
+        keys = jnp.stack([jnp.asarray(row) for row in args[at]])
+        return (*args[:at], keys, *args[at + 1:])
+
+    ref = _paged_sched(model, params, temperature=0.8, seed=3)
+    _spy_on(ref, rewrite=stacked)
+    want = _sched_results(
+        ref, prompts,
+        [{"rng": jax.random.fold_in(jax.random.PRNGKey(11), 0)},
+         {"rng": legacy}, {"rng": jax.random.key_data(typed)},
+         {"rng": jax.random.PRNGKey(21)}],
+    )
+    ref.close()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a["tokens"], b["tokens"])
+    # the same prompt under two keys draws two streams: the keys count
+    assert not np.array_equal(got[0]["tokens"], got[3]["tokens"])
+
+
+def test_submit_refuses_what_is_not_one_key(lm_and_params):
+    model, params = lm_and_params
+    sched = _paged_sched(model, params)
+    with pytest.raises(ValueError, match="ONE sampling key"):
+        sched.submit(np.asarray([3, 4], np.int32),
+                     rng=jax.random.split(jax.random.PRNGKey(0), 2))
     sched.close()
