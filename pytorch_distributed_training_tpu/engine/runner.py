@@ -1065,13 +1065,18 @@ class Runner:
                     # under the peer-loss guard and is synced to completion, so
                     # a peer dying MID-collective turns an indefinite hang into
                     # a diagnosed PeerLostError within the heartbeat timeout
-                    with tel.span("step_dispatch", step=self.iter):
+                    with tel.span("step_dispatch", step=self.iter, cpu=True):
                         self._elastic.guard(
                             self._synced_train_iter, g_img, g_label,
                             what=f"train step {self.iter}",
                         )
                 else:
-                    with tel.span("step_dispatch", step=self.iter):
+                    # cpu=True: the span also records the loop thread's own
+                    # CPU time, so wall less the nested device_block less
+                    # cpu_ms is what the thread spent OFF the CPU: waiting
+                    # for a core, the interpreter lock or a runtime thread
+                    # (two thread_time() reads a step)
+                    with tel.span("step_dispatch", step=self.iter, cpu=True):
                         self.train_iter(g_img, g_label)
                 self._advance_pipeline()
                 if self._watchdog:

@@ -461,10 +461,12 @@ class ContinuousScheduler:
         self._phase_ms: Dict[str, float] = {}  # confined: _loop
 
         # async-pipeline state (all confined: _loop).  _inflight holds
-        # (tok_dev, finite_dev, rows) per dispatched-but-undrained step;
-        # _carry_tok is the LAST dispatch's on-device token vector — the
-        # next step's prev_tok input.  _last_dispatch/_tick_block_s feed
-        # the decode_dispatch_gap_ms / tick_host_ms histograms.
+        # (tok_dev, finite_dev, rows, moe, dispatching tick) per
+        # dispatched-but-undrained step; _carry_tok is the LAST dispatch's
+        # on-device token vector — the next step's prev_tok input.
+        # _last_dispatch feeds decode_dispatch_gap_ms; _tick_block_s is a
+        # fresh prefill's own read, which tick() takes out of tick_host_ms
+        # beside the readback phase.
         self._inflight: deque = deque()  # confined: _loop
         self._carry_tok = None  # confined: _loop
         # (tick_no, perf_counter) of the latest decode dispatch
@@ -1025,9 +1027,10 @@ class ContinuousScheduler:
         try:
             try:
                 # tick_host_ms = tick wall minus time BLOCKED on device
-                # readbacks (the decode paths accumulate their np.asarray
-                # waits into _tick_block_s) — the host-overhead number the
-                # async pipeline exists to hide
+                # readbacks: the decode bodies' ``readback`` phase, and the
+                # fresh prefill's own read, which no phase times alone and
+                # _prefill_fresh adds to _tick_block_s — the host-overhead
+                # number the async pipeline exists to hide
                 self._tick_block_s = 0.0
                 self._phase_ms = {}
                 t_tick0 = time.perf_counter()
@@ -1035,9 +1038,11 @@ class ContinuousScheduler:
                     did = self._tick_inner()
                 if did:
                     wall_ms = (time.perf_counter() - t_tick0) * 1000.0
-                    self.metrics.record_tick(
-                        max(wall_ms - self._tick_block_s * 1000.0, 0.0)
+                    blocked_ms = (
+                        self._phase_ms.get("readback", 0.0)
+                        + self._tick_block_s * 1000.0
                     )
+                    self.metrics.record_tick(max(wall_ms - blocked_ms, 0.0))
                     self.metrics.record_tick_phases(wall_ms, self._phase_ms)
             finally:
                 if self._watchdog is not None:
@@ -1093,6 +1098,8 @@ class ContinuousScheduler:
             newly = self._admit()
         self._tick_phase = "prefill"
         if newly:
+            # the rows already decoding sit through this prefill: each of
+            # their next gaps has it inside
             decoding = self.active() - len(newly)
             suffix = [r.prompt.size - r.admission.cached_len for r in newly]
             with self._phase(
@@ -1101,6 +1108,7 @@ class ContinuousScheduler:
                     max(suffix), self.seq_buckets, "prefill suffix"
                 ),
                 reqs=[r.rid for r in newly],
+                stalled=decoding, padded_tokens=self._padded_tokens(newly),
             ):
                 self._prefill(newly)
             if decoding > 0:
@@ -1365,6 +1373,26 @@ class ContinuousScheduler:
         if self._extra_blocks:
             return ids[: len(ids) - self._extra_blocks]
         return ids
+
+    def _padded_tokens(self, newly: List[_PagedRequest]) -> int:
+        """Tokens the target's prefill calls of this tick run, padding
+        included: ``_prefill`` makes ONE bucketed call over the fresh
+        admissions and one over the replayed, each padded to its own batch
+        bucket x its own sequence bucket."""
+        total = 0
+        for group in (
+            [r for r in newly if not r.tokens], [r for r in newly if r.tokens]
+        ):
+            if group:
+                longest = max(
+                    r.prompt.size - r.admission.cached_len for r in group
+                )
+                total += self._bucket_for(
+                    len(group), self.batch_buckets, "admitted rows"
+                ) * self._bucket_for(
+                    longest, self.seq_buckets, "prefill suffix"
+                )
+        return total
 
     def _prefill(self, newly: List[_PagedRequest]) -> None:
         """Prefill every request admitted this tick.
@@ -1711,13 +1739,14 @@ class ContinuousScheduler:
                 self._pool, prev, pos, tables,
                 keys, gen_idx, aids, *self._slot_rows(pos),
             )
-        rb0 = time.perf_counter()
-        with self._phase("readback"):
-            tok = np.asarray(tok)
+        with self._phase("readback", for_step=self._tick_no):
+            # the FIRST read waits for the launch, the device's step and
+            # the copy; the guard's and the expert counts' wait for nothing
+            with self._readback_wait(self._tick_no):
+                tok = np.asarray(tok)
             finite = np.asarray(finite)
             self._record_moe(moe, n_active)
         t1 = time.perf_counter()
-        self._tick_block_s += t1 - rb0
         with self._phase("deliver"):
             for req in active:
                 if not finite[req.slot]:
@@ -1736,6 +1765,14 @@ class ContinuousScheduler:
             total_blocks=self._kv.num_blocks,
             live_block_share=self._live_block_share(pos),
         )
+
+    def _readback_wait(self, for_step: int):
+        """The span around a decode body's first read of a step's output,
+        a child of ``readback`` and NOT a phase (no place in ``_phase_ms``
+        or ``TICK_PHASES``).  ``for_step`` is the tick that dispatched the
+        step this read drains: this tick on the sync and speculative
+        bodies, an earlier one on the async ring."""
+        return span("readback_wait", step=self._tick_no, for_step=for_step)
 
     def _record_moe(self, moe, n_rows: int) -> None:
         """File a decode step's expert counts (``decode.py``: the fourth
@@ -1832,7 +1869,7 @@ class ContinuousScheduler:
             for req in disp:
                 req.dispatched += 1
             self._carry_tok = tok
-            self._inflight.append((tok, finite, rows, moe))
+            self._inflight.append((tok, finite, rows, moe, self._tick_no))
             self.metrics.record_iteration(
                 active_slots=len(disp), total_slots=self.slots_n,
                 blocks_in_use=self._kv.blocks_in_use,
@@ -1847,10 +1884,10 @@ class ContinuousScheduler:
         t0 = time.perf_counter()
         while len(self._inflight) > target:
             pushed += self._drain_entry(self._inflight.popleft())
-        t1 = time.perf_counter()
-        self._tick_block_s += t1 - t0
         if pushed:
-            self.metrics.record_decode(n_tokens=pushed, decode_s=t1 - t0)
+            self.metrics.record_decode(
+                n_tokens=pushed, decode_s=time.perf_counter() - t0
+            )
 
     def _fed_arrays(self, disp: List[_PagedRequest]):
         """Fixed-width inputs of ``decode_step_fed`` with ``disp`` live,
@@ -1914,9 +1951,10 @@ class ContinuousScheduler:
         host stream was rolled back since dispatch are discarded — their
         token was never part of the committed stream.  Returns the
         number of tokens pushed."""
-        tok_dev, finite_dev, rows, moe = entry
-        with self._phase("readback"):
-            tok = np.asarray(tok_dev)
+        tok_dev, finite_dev, rows, moe, dispatched_at = entry
+        with self._phase("readback", for_step=dispatched_at):
+            with self._readback_wait(dispatched_at):
+                tok = np.asarray(tok_dev)
             finite = np.asarray(finite_dev)
             self._record_moe(moe, len(rows))
         pushed = 0
@@ -2085,10 +2123,9 @@ class ContinuousScheduler:
             logits, self._pool = self._fns.verify(
                 self.params, self._pool, ver_tok, ver_pos, vtables, aids,
             )
-        rb0 = time.perf_counter()
-        with self._phase("readback"):
-            logits = np.asarray(logits)
-        self._tick_block_s += time.perf_counter() - rb0
+        with self._phase("readback", for_step=self._tick_no):
+            with self._readback_wait(self._tick_no):
+                logits = np.asarray(logits)
 
         # -- host accept/reject + commit -------------------------------
         t1 = time.perf_counter()
@@ -2349,15 +2386,20 @@ class ContinuousScheduler:
                     or self._xfer_q
                     or any(s is not None for s in self._slots)
                 ):
+                    # loop_idle: only where the loop really sleeps, so a
+                    # reader tells "nothing to do" from the overhead
+                    # between two back-to-back ticks
                     if self.heartbeat_path is None:
-                        self._cond.wait()
+                        with span("loop_idle"):
+                            self._cond.wait()
                     else:
                         # bounded wait so an IDLE healthy replica keeps
                         # beating — external staleness must mean "wedged",
                         # never "merely quiet"
-                        self._cond.wait(
-                            timeout=max(self._hb_interval / 2.0, 0.01)
-                        )
+                        with span("loop_idle"):
+                            self._cond.wait(
+                                timeout=max(self._hb_interval / 2.0, 0.01)
+                            )
                         self._beat()
                 if (
                     self._closed
@@ -2381,4 +2423,5 @@ class ContinuousScheduler:
                     # (this is also what guarantees an admission-waiting
                     # request is swept AT its deadline, not at the next
                     # submit)
-                    self._cond.wait(timeout=self._next_wakeup_locked())
+                    with span("loop_idle"):
+                        self._cond.wait(timeout=self._next_wakeup_locked())

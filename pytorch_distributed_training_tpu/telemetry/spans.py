@@ -43,6 +43,13 @@ open on the same thread when this one started (``loader_wait`` and
 ``h2d_put`` inside ``data_wait``; a tick's phases inside ``tick``), null
 at the top.  Nesting is a property of the thread, not of a recorder, so
 the stack is one thread-local for the module.
+
+``span(..., cpu=True)`` also reads the thread's own CPU clock
+(``time.thread_time()``) at both ends and records ``cpu_ms``: a thread
+blocked on the device, a lock or a core burns no CPU time, so wall less
+``cpu_ms`` is what the thread spent OFF the CPU.  A close-time field: the
+profiler's annotation takes its arguments at enter, so ``cpu_ms`` is in the
+ring and the span file and not in a trace.
 """
 from __future__ import annotations
 
@@ -80,13 +87,14 @@ class _Span:
     scheduler opens several a tick."""
 
     __slots__ = ("_recorder", "_kind", "_step", "_extra", "_parent",
-                 "_annotation", "_t0", "_wall")
+                 "_annotation", "_t0", "_wall", "_cpu0")
 
-    def __init__(self, recorder, kind, step, extra):
+    def __init__(self, recorder, kind, step, extra, cpu=False):
         self._recorder = recorder
         self._kind = kind
         self._step = step
         self._extra = extra
+        self._cpu0 = cpu  # False, or from __enter__ on the thread's CPU clock
 
     def __enter__(self):
         kinds = _open_kinds()
@@ -103,9 +111,14 @@ class _Span:
             self._annotation.__enter__()
         self._wall = time.time()
         self._t0 = time.monotonic()
+        if self._cpu0 is not False:
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        if self._cpu0 is not False:
+            cpu_ms = (time.thread_time() - self._cpu0) * 1e3
+            self._extra = dict(self._extra, cpu_ms=round(cpu_ms, 3))
         dur_s = time.monotonic() - self._t0
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
@@ -136,12 +149,15 @@ class SpanRecorder:
         self._file = open(path, "a") if path else None
         self.enabled = True
 
-    def span(self, kind: str, step: Optional[int] = None, **extra):
+    def span(self, kind: str, step: Optional[int] = None, cpu: bool = False,
+             **extra):
         """Context manager around one phase; ``extra`` fields (``req``,
-        ``n``, ``bytes``...) ride in the record and in the annotation."""
+        ``n``, ``bytes``...) ride in the record and in the annotation.
+        ``cpu=True`` adds ``cpu_ms``, the thread's CPU time inside the
+        span, to the record alone."""
         if not self.enabled:
             return _NO_SPAN
-        return _Span(self, kind, step, extra)
+        return _Span(self, kind, step, extra, cpu)
 
     def record(self, kind: str, t0: float, dur_s: float,
                step: Optional[int] = None, **extra) -> None:

@@ -1507,6 +1507,181 @@ def test_scheduler_tick_phase_spans_and_request_records(
     assert hists["tick_prep_ms"]["count"] == n_ticks
     total = sum(snap[f"tick_{k}_ms_mean"] for k in TICK_PHASES)
     assert 0.5 * snap["tick_wall_ms_mean"] < total <= snap["tick_wall_ms_mean"]
+    # the child span of ``readback`` is no phase: the phases, the tick's
+    # account and the histograms are what they were without it
+    assert TICK_PHASES == ("admit", "prefill", "decode_prep", "decode_step",
+                           "readback", "deliver")
+    waits = [s for s in spans if s["kind"] == "readback_wait"]
+    reads = [s for s in spans if s["kind"] == "readback"]
+    assert len(waits) == len(reads) > 0
+    assert all(s["parent"] == "readback" for s in waits)
+    assert set(sched._phase_ms) <= set(TICK_PHASES)
+    assert "tick_readback_wait_ms" not in hists
+    assert not any("readback_wait" in key for key in snap)
+
+
+@pytest.mark.parametrize("depth", [0, 1], ids=["sync", "async_ring_1"])
+def test_readback_wait_names_the_tick_whose_step_it_drains(
+    lm_and_params, mode_prompts, depth
+):
+    """One ``readback_wait`` a drained step, inside that step's ``readback``
+    and around its first read only; both carry ``for_step``, the tick of the
+    ``decode_step`` whose output they drain: the same tick on the sync body,
+    the tick before on a ring of depth 1 (the endgame drains its own)."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rec = set_recorder(SpanRecorder(ring=4096))
+    try:
+        sched = _paged_sched(model, params, async_depth=depth)
+        _sched_results(sched, mode_prompts)
+        sched.close()
+    finally:
+        set_recorder(None)
+    spans = rec.recent()
+    steps = {s["step"] for s in spans if s["kind"] == "decode_step"}
+    waits = [s for s in spans if s["kind"] == "readback_wait"]
+    reads = [s for s in spans if s["kind"] == "readback"]
+    # every dispatched step is drained once, by one read and one wait
+    assert sorted(s["for_step"] for s in waits) == sorted(steps)
+    assert [(s["step"], s["for_step"]) for s in reads] == [
+        (s["step"], s["for_step"]) for s in waits]
+    for wait, read in zip(waits, reads):
+        assert wait["parent"] == "readback"
+        assert read["t"] <= wait["t"]
+        assert wait["t"] + wait["ms"] / 1e3 <= read["t"] + read["ms"] / 1e3 + 1e-6
+    lag = [s["step"] - s["for_step"] for s in waits]
+    if depth == 0:
+        assert lag == [0] * len(waits)
+    else:
+        # steady state drains the tick before; the last tick dispatches
+        # nothing and drains what is left, so it may drain its own neighbour
+        assert set(lag) <= {0, 1} and lag.count(1) >= len(lag) - 1
+        assert lag[0] == 1
+
+
+def test_loop_idle_only_where_the_loop_sleeps(lm_and_params):
+    """The running loop emits ``loop_idle`` while its queue is empty and
+    none between two back-to-back productive ticks."""
+    from pytorch_distributed_training_tpu.serving.scheduler import (
+        ContinuousScheduler,
+    )
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rec = set_recorder(SpanRecorder(ring=4096))
+    try:
+        sched = ContinuousScheduler(
+            model, params, slots=4, block_size=4, num_blocks=24,
+            batch_buckets=[4], seq_buckets=[8], max_new_tokens=6,
+            temperature=0.0, eos_id=None, start=True,
+        )
+        time.sleep(0.05)  # nothing queued: the loop sleeps
+        fut = sched.submit(np.asarray([3, 4, 5], np.int32))
+        assert fut.result(timeout=120)["gen_len"] == 6
+        sched.close()
+    finally:
+        set_recorder(None)
+    spans = rec.recent()
+    idles = [s for s in spans if s["kind"] == "loop_idle"]
+    assert idles and all(s["parent"] is None for s in idles)
+    productive = {s["step"] for s in spans if s["kind"] == "decode_step"}
+    ticks = sorted((s for s in spans
+                    if s["kind"] == "tick" and s["step"] in productive),
+                   key=lambda s: s["t"])
+    assert len(ticks) >= 5
+    # the first sleep ends when the request arrives, before its first tick
+    assert min(s["t"] for s in idles) < ticks[0]["t"]
+    for a, b in zip(ticks, ticks[1:]):
+        assert b["step"] == a["step"] + 1
+        gap = (a["t"] + a["ms"] / 1e3, b["t"])
+        assert not any(gap[0] <= s["t"] < gap[1] for s in idles), (a, b)
+
+
+def test_prefill_span_carries_the_stalled_rows_and_the_padded_size(
+    lm_and_params,
+):
+    """Three rows decoding when two more are admitted: the tick's
+    ``prefill`` span says 3 rows sat through it and that its one call ran
+    at the batch bucket of 4 x the sequence bucket of 8; the first
+    prefill met no decoding row."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, VOCAB, n).astype(np.int32)
+               for n in (5, 3, 6, 4, 2)]
+    rec = set_recorder(SpanRecorder(ring=1024))
+    try:
+        sched = _paged_sched(
+            model, params, slots=8, num_blocks=48, batch_buckets=[1, 4, 8],
+            seq_buckets=[8, 16], eos_id=None,
+        )
+        futs = [sched.submit(p) for p in prompts[:3]]
+        sched.tick()
+        sched.tick()
+        futs += [sched.submit(p) for p in prompts[3:]]
+        _run_scheduler_to_done(sched, futs)
+        sched.close()
+    finally:
+        set_recorder(None)
+    first, second = [s for s in rec.recent() if s["kind"] == "prefill"]
+    assert (first["rows"], first["stalled"]) == (3, 0)
+    assert first["tokens"] == 14 and first["padded_tokens"] == 4 * 8
+    assert (second["rows"], second["stalled"]) == (2, 3)
+    assert second["tokens"] == 6 and second["padded_tokens"] == 4 * 8
+    assert second["bucket"] == 8
+
+
+@pytest.mark.parametrize("body", ["sync", "async_ring", "speculative"])
+def test_tick_host_ms_is_the_wall_less_the_readback_phase(
+    lm_and_params, mode_prompts, body
+):
+    """One clock: a tick's blocked time is its ``readback`` phase (and a
+    fresh prefill's own read, which no phase times alone), so in a tick
+    without a prefill ``tick_host_ms`` is the wall less that phase."""
+    model, params = lm_and_params
+    sched = _paged_sched(model, params, **_decode_bodies()[body])
+    seen = []
+    record_tick = sched.metrics.record_tick
+    record_phases = sched.metrics.record_tick_phases
+
+    def spy_tick(host_ms):
+        seen.append({"host_ms": host_ms})
+        record_tick(host_ms)
+
+    def spy_phases(wall_ms, phase_ms):
+        seen[-1].update(wall_ms=wall_ms, phases=dict(phase_ms),
+                        prefill_read_ms=sched._tick_block_s * 1e3)
+        record_phases(wall_ms, phase_ms)
+
+    sched.metrics.record_tick = spy_tick
+    sched.metrics.record_tick_phases = spy_phases
+    _sched_results(sched, mode_prompts)
+    sched.close()
+    plain = [t for t in seen if "prefill" not in t["phases"]]
+    assert len(plain) < len(seen)
+    # (a ring's first ticks dispatch and drain nothing: no readback, all host)
+    # (and a speculative round commits up to three tokens: few ticks)
+    assert any(t["phases"].get("readback", 0.0) > 0.0 for t in plain)
+    for t in plain:
+        assert t["prefill_read_ms"] == 0.0
+        assert t["host_ms"] == pytest.approx(
+            t["wall_ms"] - t["phases"].get("readback", 0.0), abs=1e-9)
+    for t in seen:
+        if "prefill" in t["phases"]:
+            assert t["host_ms"] == pytest.approx(max(
+                t["wall_ms"] - t["phases"].get("readback", 0.0)
+                - t["prefill_read_ms"], 0.0), abs=1e-9)
 
 
 def test_prefill_stall_counts_only_prefills_that_met_decoding_rows(

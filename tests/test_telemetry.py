@@ -164,6 +164,55 @@ def test_span_recorder_ring_bounded():
     assert [r["step"] for r in recent] == [7, 8, 9]
 
 
+def _sleep(seconds):
+    import time
+
+    time.sleep(seconds)
+
+
+def _spin(seconds):
+    import time
+
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("work, on_cpu", [(_sleep, False), (_spin, True)],
+                         ids=["sleep", "busy_loop"])
+def test_span_cpu_option_records_the_threads_cpu_time(tmp_path, work, on_cpu):
+    """``cpu=True``: ``cpu_ms`` near 0 around a sleep (a blocked thread
+    burns no CPU time) and near the wall around a busy loop, in the ring and
+    in the span file, and never among the fields a caller passed."""
+    path = str(tmp_path / "spans.jsonl")
+    rec = SpanRecorder(path=path, ring=4)
+    with rec.span("step_dispatch", step=3, cpu=True, what="train"):
+        work(0.05)
+    rec.close()
+    (line,) = [json.loads(ln) for ln in open(path)]
+    assert line == rec.recent()[0]
+    assert line["ms"] >= 50.0 and line["what"] == "train" and "cpu" not in line
+    if on_cpu:
+        # (a busy thread can lose its core to a neighbour for a while)
+        assert 0.5 * line["ms"] <= line["cpu_ms"] <= line["ms"] + 0.5
+    else:
+        assert 0.0 <= line["cpu_ms"] < 10.0
+
+
+def test_span_without_the_cpu_option_records_no_cpu_time():
+    rec = SpanRecorder(ring=4)
+    set_recorder(rec)
+    with rec.span("step_dispatch", step=3):
+        pass
+    with span("step_dispatch", step=4, cpu=False):
+        pass
+    with span("step_dispatch", step=5, cpu=True):  # the free function too
+        pass
+    first, second, third = rec.recent()
+    assert "cpu_ms" not in first and "cpu_ms" not in second
+    assert "cpu" not in second and third["cpu_ms"] >= 0.0
+
+
 def test_free_span_function_routes_to_current_recorder(tmp_path):
     rec = SpanRecorder(ring=8)
     set_recorder(rec)
@@ -589,7 +638,7 @@ def test_telemetry_facade_end_to_end(tmp_path):
     for it in range(4):
         with tel.span("data_wait", step=it):
             pass
-        with tel.span("step_dispatch", step=it):
+        with tel.span("step_dispatch", step=it, cpu=True):  # as the Runner
             pass
         tel.note_step(0.01, applied=True, replayed=it == 1)
         tel.after_step(it)
@@ -613,6 +662,7 @@ def test_telemetry_facade_end_to_end(tmp_path):
         for ln in open(os.path.join(tmp_path, "spans_rank0.jsonl"))
     ]
     assert len(span_lines) == 8
+    assert [("cpu_ms" in ln) for ln in span_lines] == [False, True] * 4
     assert "summary" not in last  # snapshot stays structured; table is human
 
 
