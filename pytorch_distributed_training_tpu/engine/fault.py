@@ -73,7 +73,9 @@ tick):
                        diagnosed restart
 
 Lagged guard semantics under the async decode pipeline
-(``serving.scheduler.async_depth > 0``): the injection still lands at
+(``serving.scheduler.async_depth > 0``, which is what an engine serves —
+depth 1 — unless its configuration names 0 or carries a speculative
+draft): the injection still lands at
 tick T's DISPATCH, but its observable consequence moves to the drain of
 that step — up to ``async_depth`` ticks later.  ``serve_nan``'s
 non-finite flag is read at drain time (eviction one-or-more ticks late,

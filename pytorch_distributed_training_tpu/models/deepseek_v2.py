@@ -191,7 +191,7 @@ class DeepseekV2LM(nn.Module):
     def moe_shape(self) -> Optional[Tuple[int, int, int]]:
         """``(expert layers, experts a token, experts held)``; None for a
         model with no expert layer.  A model that states it returns the
-        step's expert counts from its decode programs (serving/decode.py)."""
+        step's expert counts from its decode program (serving/decode.py)."""
         layers = sum(self._is_expert_layer(i) for i in range(self.num_hidden_layers))
         held = (self.experts_held or (0, self.n_routed_experts))[1]
         return (layers, self.num_experts_per_tok, held) if layers else None

@@ -96,7 +96,7 @@ def add_moe_counts(counts, sizes):
 
 def sow_moe_stats(model, counts):
     """Sow a call's :func:`add_moe_counts` into ``moe_stats``:
-    ``serving/decode.py`` returns them from the decode programs of a model
+    ``serving/decode.py`` returns them from the decode program of a model
     that states ``moe_shape``."""
     model.sow("moe_stats", "experts_hit", counts[0])
     model.sow("moe_stats", "expert_load_max", counts[1])

@@ -454,8 +454,8 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
 
     Which call reads the pool how is decided by what the call shows:
 
-    - ``S == 1`` on a TPU (the scheduler's ``decode_step`` and
-      ``decode_step_fed``, of either served family), with rows the kernel
+    - ``S == 1`` on a TPU (the scheduler's ``decode_step``, of
+      every served family), with rows the kernel
       can read (:func:`..ops.paged_decode.fits`): the Pallas kernel
       :func:`..ops.paged_decode.paged_decode` walks each row's block table
       up to the row's own length, K and V as stored, scores, softmax and

@@ -31,7 +31,7 @@ float32 and ``kda_conv [slots, conv_size - 1, 3 H d_k]`` (the last rows of
 ``(q~, k~, v~)``).  A call names each batch row's slot in ``state_rows [B]``
 (-1 = padding: read as slot 0, written nowhere); a row whose first position
 is 0 starts from a zero state, so a slot is never cleared.  The scheduler's
-decode step says so itself (``rows_are_slots=True``, from the decode programs
+decode step says so itself (``rows_are_slots=True``, from the decode program
 of ``serving/decode.py``): one position a row, as many rows as slots, row
 ``i`` IS slot ``i`` and ``state_rows`` only says which rows live.  Padding
 positions (-1, at a row's end) change neither state nor convolution rows.

@@ -238,9 +238,9 @@ class _PagedFns:
     """Jit set + pool factory for the paged (block-table) cache mode.
 
     Each program consumes the pool it is given; use the one it returns.
-    ``pool`` is donated (``donate_argnames``) in all five, so the scatter
-    of a step's rows writes the buffers the caller handed over instead of
-    a copy of them, and nothing may hold a pool LEAF across a call (a
+    ``pool`` is donated (``donate_argnames``) in all four that take it, so
+    the scatter of a step's rows writes the buffers the caller handed over
+    instead of a copy of them, and nothing may hold a pool LEAF across a call (a
     slice or a ``tree_map`` result is a new buffer and is safe).  A call
     that raises before its dispatch (bad arguments, the scheduler's
     injected faults) leaves the pool as it was; one that raises after it
@@ -254,30 +254,32 @@ class _PagedFns:
     ``gen_index[r]`` from the logits at ``last_col`` (0 for a fresh
     prompt; the hot-restart replay path passes the index of the last
     already-delivered token so the resample is bitwise reproducible).
-    ``row_keys``, here and in the two decode programs, is ``uint32 [B, 2]``
+    ``row_keys``, here and in the decode program, is ``uint32 [B, 2]``
     key DATA, one row a batch row (what a stack of legacy ``PRNGKey``s is);
     the scheduler hands it over as ONE host ``numpy`` array, and the
     program folds each row's ``gen_index`` into its key on the device.
-    ``decode_step(params, pool, prev_tok, pos, block_tables, row_keys,
-    gen_index, adapter_ids) -> (tok, finite, pool)`` — ONE single-token
-    step for every slot; the scheduler's host loop supplies fresh inputs
-    per iteration, so this one program serves any mix of in-flight
-    requests.  In quant mode ``params`` here is the int8 tree.
-    ``decode_step_fed(params, pool, prev_tok, fresh_mask, fresh_tok, pos,
-    block_tables, row_keys, gen_index, adapter_ids)`` — the async-pipeline
-    twin of ``decode_step``: ``prev_tok`` is the PREVIOUS step's on-device
-    token output fed back without a host round-trip, and rows whose last
-    token the host knows better (just prefilled, refilled, or replayed)
-    are spliced in-graph via ``where(fresh_mask, fresh_tok, prev_tok)``.
-    Output carry (tok) is a valid ``prev_tok`` input to itself, so step
-    k+1 can be dispatched before step k's tokens are read back.
+    ``decode_step(params, pool, prev_tok, fresh_mask, fresh_tok, pos,
+    block_tables, row_keys, gen_index, adapter_ids) -> (tok, finite, pool)``
+    — ONE single-token step for every slot, and the ONE decode program a
+    model has; the scheduler's host loop supplies fresh inputs per
+    iteration, so it serves any mix of in-flight requests.  The token each
+    row is fed is ``where(fresh_mask, fresh_tok, prev_tok)``: ``prev_tok``
+    is a token array ON THE DEVICE, the previous step's own output fed back
+    without a host round-trip (the scheduler's ring), and ``fresh_tok`` the
+    tokens the host knows (a row just prefilled, refilled or replayed).
+    The output ``tok`` is a valid ``prev_tok`` of the next call, so step
+    k+1 can be dispatched before step k's tokens are read back.  A caller
+    that knows every row's token (the sync body, the supervisor's probe,
+    the replay, a speculative draft's steps) passes a mask of all rows and
+    any ``prev_tok`` of the right placement.  In quant mode ``params`` here
+    is the int8 tree.
     ``finite`` [B] bool is the on-device output guard: True iff every
     logit the row sampled from is finite — the serving mirror of the
     training anomaly guard, letting the scheduler evict a NaN-producing
     request without a Python exception (padding rows read stale pool
     rows, so only ACTIVE rows' flags are meaningful).
-    For a model with expert layers (``model.moe_shape``) the two
-    decode programs return a FOURTH value, ``moe_stats`` int32 [2]: the
+    For a model with expert layers (``model.moe_shape``) the decode
+    program returns a FOURTH value, ``moe_stats`` int32 [2]: the
     experts that received a token this step and the largest count at one
     expert, each summed over the expert layers; callers that do not want it
     unpack ``tok, finite, pool, *_``.
@@ -293,32 +295,28 @@ class _PagedFns:
     ``init_pool(params)`` — the zero pool pytree (``jax.eval_shape`` over
     the apply: correct flax cache paths, no throwaway compile).
     For a model that carries a state a sequence (``model.state_shape``) the
-    four programs that run the model take ``state_rows`` [B] as their last
+    three programs that run the model take ``state_rows`` [B] as their last
     argument and the pool tree holds the ``[slots, ...]`` state leaves too
     (:func:`build_paged_fns`).
     """
 
-    def __init__(self, prefill, decode_step, init_pool, verify, copy_rows,
-                 decode_step_fed):
+    def __init__(self, prefill, decode_step, init_pool, verify, copy_rows):
         self.prefill = prefill
         self.decode_step = decode_step
         self.init_pool = init_pool
         self.verify = verify
         self.copy_rows = copy_rows
-        self.decode_step_fed = decode_step_fed
 
     def _cache_size(self) -> int:
         """Distinct XLA programs compiled across all phases — the
         scheduler's compile count is bounded by the bucket grid for
-        prefill plus ONE program each for decode/verify/copy (plus one
-        for the self-feeding async decode step, compiled only when the
-        pipeline is enabled), independent of traffic."""
+        prefill plus ONE program each for decode/verify/copy,
+        independent of traffic."""
         return (
             self.prefill._cache_size()
             + self.decode_step._cache_size()
             + self.verify._cache_size()
             + self.copy_rows._cache_size()
-            + self.decode_step_fed._cache_size()
         )
 
 
@@ -359,8 +357,8 @@ def build_paged_fns(
     int32 as its last argument: the slot each batch row's state lives in
     (-1 = padding: nothing is written).  A row whose first position is 0
     starts from a zero state, so a slot needs no clearing between requests.
-    The two decode programs are the scheduler's fixed-width step, whose
-    batch row ``i`` IS slot ``i``: they say so to the model
+    The decode program is the scheduler's fixed-width step, whose
+    batch row ``i`` IS slot ``i``: it says so to the model
     (``rows_are_slots=True``) and ``state_rows`` there only tells the live
     rows from the padding.  ``copy_rows`` passes the state leaves by.  For any other model
     ``state_rows`` stays None and the programs are what they were.
@@ -384,7 +382,7 @@ def build_paged_fns(
     )
     has_lora = getattr(paged_model, "lora_adapters", 0) > 0
     # a model with expert layers (it says so) sows two counts a call into
-    # ``moe_stats``: the decode programs return them as a FOURTH output,
+    # ``moe_stats``: the decode program returns them as a FOURTH output,
     # int32 [2] = (experts that got a token, largest count at one expert),
     # each summed over the expert layers.  A model with none: the programs
     # and their outputs are what they were.
@@ -396,7 +394,7 @@ def build_paged_fns(
     # so the programs stay pure token-samplers and the stop conditions
     # (eos / per-request max_new) live in one place
     sample = _make_sampler(temperature)
-    # what the decode programs add to a call of a model that carries a state
+    # what the decode program adds to a call of a model that carries a state
     fixed_width = {"rows_are_slots": True} if carries_state else {}
 
     def _apply(params, pool, tokens, positions, block_tables, adapter_ids,
@@ -450,30 +448,16 @@ def build_paged_fns(
 
     @functools.partial(jax.jit, donate_argnames="pool")
     def decode_step(
-        params, pool, prev_tok, pos, block_tables, row_keys, gen_index,
-        adapter_ids=None, state_rows=None,
-    ):
-        if quant:
-            params = dequantize_tree(params, jnp.float32)
-        logits, variables = _apply(
-            params, pool, prev_tok[:, None], pos[:, None], block_tables,
-            adapter_ids, state_rows, **fixed_width,
-        )
-        tok = sample(logits[:, 0], _token_keys(row_keys, gen_index))
-        return _step_outputs(tok, logits[:, 0], variables)
-
-    @functools.partial(jax.jit, donate_argnames="pool")
-    def decode_step_fed(
         params, pool, prev_tok, fresh_mask, fresh_tok, pos, block_tables,
         row_keys, gen_index, adapter_ids=None, state_rows=None,
     ):
         if quant:
             params = dequantize_tree(params, jnp.float32)
-        # prev_tok is the previous step's ON-DEVICE token output; rows the
-        # host just (re)filled get their known last token spliced in here,
-        # so the pipeline never needs a host round-trip to mix fresh rows
-        # into the carried batch
-        prev = jnp.where(fresh_mask, fresh_tok, prev_tok)
+        # prev_tok is a step's ON-DEVICE token output; the rows whose last
+        # token the host knows (all of them on the sync paths, the ones
+        # just (re)filled on the ring) get it spliced in here, so the ring
+        # never needs a host round-trip to mix fresh rows into the carry
+        prev = jax.lax.select(fresh_mask, fresh_tok, prev_tok)
         logits, variables = _apply(
             params, pool, prev[:, None], pos[:, None], block_tables,
             adapter_ids, state_rows, **fixed_width,
@@ -524,6 +508,4 @@ def build_paged_fns(
             lambda s: jnp.zeros(s.shape, s.dtype), shapes
         )
 
-    return _PagedFns(
-        prefill, decode_step, init_pool, verify, copy_rows, decode_step_fed
-    )
+    return _PagedFns(prefill, decode_step, init_pool, verify, copy_rows)

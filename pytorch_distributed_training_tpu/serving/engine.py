@@ -239,6 +239,14 @@ class InferenceEngine:
                     spec = SpeculativeSpec(spec_k, draft_model, draft_params)
                 else:
                     spec = SpeculativeSpec(spec_k)
+            # the served default is the ring of depth 1 (tick k dispatches
+            # step k before it reads step k-1); a speculative round has
+            # nothing to pipeline and resolves to the sync body by itself.
+            # A configuration that names a depth gets it, and the
+            # scheduler refuses one beside a draft.
+            async_depth = sched_cfg.pop("async_depth", None)
+            if async_depth is None:
+                async_depth = 0 if spec is not None else 1
             self.scheduler = ContinuousScheduler(
                 model, self.params,
                 slots=int(sched_cfg.pop("slots", 8)),
@@ -259,7 +267,7 @@ class InferenceEngine:
                 quant=use_quant,
                 lora=self.lora_registry,
                 speculative=spec,
-                async_depth=int(sched_cfg.pop("async_depth", 0)),
+                async_depth=int(async_depth),
                 logger=self.logger,
                 replica_id=replica_id,
                 heartbeat_path=heartbeat_path,
@@ -615,20 +623,18 @@ class InferenceEngine:
                     ), 2)
 
         def decode(fns, params):
-            return (fns.decode_step, (params,),
-                    (zeros, pos, tables, keys, zeros, aids, *no_slot(W)), 2)
+            # ONE decode program a model, whichever body calls it:
+            # _zero_carry matches the program's own token-output sharding,
+            # so this call covers the ring's first and carried dispatches
+            # and the sync callers' (one cache entry)
+            return (fns.decode_step, (params,), (
+                sched._zero_carry(), np.zeros((W,), bool), zeros, pos,
+                tables, keys, zeros, aids, *no_slot(W),
+            ), 2)
 
         fns = sched._fns
         dparams = sched._qparams if sched._quant else sched.params
         calls = [*prefills(fns, sched.params), decode(fns, dparams)]
-        if sched._async_depth:
-            # _zero_carry matches the program's own token-output sharding,
-            # so this single call covers both the first dispatch and the
-            # steady-state carried-token dispatch (one cache entry)
-            calls.append((fns.decode_step_fed, (dparams,), (
-                sched._zero_carry(), np.zeros((W,), bool), zeros, pos,
-                tables, keys, zeros, aids, *no_slot(W),
-            ), 2))
         if sched._spec is not None:
             # the speculative round's extra programs on the target side:
             # the verify scorer and the fork's row copy

@@ -135,6 +135,11 @@ class ServingMetrics:
         self._decode_tokens = 0  # guarded by: self._lock
         self._prefill_s = 0.0  # guarded by: self._lock
         self._decode_s = 0.0  # guarded by: self._lock
+        # the decode steps dispatched, and those of them dispatched while
+        # an earlier step was still in the scheduler's ring (its tokens
+        # not yet read): the share says whether the ring engages
+        self._decode_dispatches = 0  # guarded by: self._lock
+        self._decode_overlapped = 0  # guarded by: self._lock
         # multi-tenant (serving.lora): per-adapter latency/len histograms,
         # lazily created in THIS private registry under adapter_<name>_*
         # — the same namespacing move replica_id makes in the process
@@ -272,6 +277,14 @@ class ServingMetrics:
         with self._lock:
             self._decode_tokens += int(n_tokens)
             self._decode_s += float(decode_s)
+
+    def record_decode_dispatch(self, inflight: int) -> None:
+        """One decode step about to be dispatched with ``inflight`` earlier
+        steps still in the scheduler's ring: 0 on the sync and speculative
+        bodies, the ring's length on the async one."""
+        with self._lock:
+            self._decode_dispatches += 1
+            self._decode_overlapped += inflight > 0
 
     def record_iteration(
         self,
@@ -418,6 +431,8 @@ class ServingMetrics:
             decode_tokens = self._decode_tokens
             prefill_s = self._prefill_s
             decode_s = self._decode_s
+            dispatches = self._decode_dispatches
+            overlapped = self._decode_overlapped
         out = {
             "requests": int(lat["count"]),
             "batches": int(sizes["count"]),
@@ -464,6 +479,11 @@ class ServingMetrics:
             out["kv_transfer_ms_p99"] = float(xfer["p99"])
         # async-pipeline observability (absent until a tick/dispatch-gap
         # sample lands, keeping batcher-path snapshots byte-stable)
+        if dispatches:
+            # near 1 in a steady window of the ring, 0 on the sync body
+            out["decode_steps_dispatched"] = dispatches
+            out["decode_steps_overlapped"] = overlapped
+            out["decode_overlap_share"] = overlapped / dispatches
         tick = self._tick_host_ms.snapshot()
         if tick["count"]:
             out["tick_host_ms_p50"] = float(tick["p50"])
@@ -588,7 +608,8 @@ def aggregate_snapshots(
     latency percentiles take the max across replicas (a bound, labeled as
     such by keeping the per-replica snapshots alongside); the prefix-cache
     hit rate is recomputed from the summed hit/miss block counters rather
-    than averaged.  ``health_*``/gauge-like fields are per-replica state
+    than averaged, and so is ``decode_overlap_share`` from the dispatch
+    counts.  ``health_*``/gauge-like fields are per-replica state
     and are left to the sub-snapshots.
     """
     out: Dict[str, float] = {"replicas": len(snapshots)}
@@ -611,4 +632,10 @@ def aggregate_snapshots(
     misses = sums.get("prefix_miss_blocks", 0)
     if hits + misses:
         out["prefix_hit_rate"] = float(hits / (hits + misses))
+    dispatched = sums.get("decode_steps_dispatched", 0)
+    if dispatched:
+        # a share, like the hit rate: from the summed counts, not summed
+        out["decode_overlap_share"] = float(
+            sums["decode_steps_overlapped"] / dispatched
+        )
     return out

@@ -53,16 +53,22 @@ optional tick watchdog (engine/watchdog.py) turns a hung step into a
 diagnosed restart.  The ``serve_*`` kinds in engine/fault.py drive all
 of it deterministically.
 
-Async decode pipeline (``async_depth > 0``, default-off): the sync loop
-above pays one full host round-trip per token — ``np.asarray(tok)``
-before the next dispatch — so the device idles for the whole host
-bookkeeping window every single-token step.  With a depth set, the
-sampled-token carry stays ON DEVICE (``decode_step_fed`` feeds its own
-output back as the next ``prev_tok``) and a bounded in-flight ring
-drains host readbacks one tick behind dispatch; host bookkeeping stays
-exact through per-request ``dispatched`` counters and the drained
-stream is bitwise token-identical to the sync path (greedy and
-sampled).  See :meth:`ContinuousScheduler._decode_step_async`.
+Async decode pipeline (``async_depth > 0``; what an engine serves unless
+its configuration names another depth: ``serving/engine.py`` builds this
+scheduler with depth 1, and with 0 beside a speculative draft): the sync
+loop (``async_depth: 0``, this constructor's own default) pays one full
+host round-trip per token — ``np.asarray(tok)`` before the next dispatch
+— so the device idles through the launch, its own step's return and the
+host's bookkeeping every single-token step, and the host idles through
+the device's step.  With a depth set, the sampled-token carry stays ON
+DEVICE (``decode_step`` takes its own output back as the next
+``prev_tok``) and a bounded in-flight ring drains host readbacks one
+tick behind dispatch: tick k dispatches step k and only then reads step
+k-1.  Host bookkeeping stays exact through per-request ``dispatched``
+counters and the drained stream is bitwise token-identical to the sync
+path (greedy and sampled).  Both bodies run the ONE ``decode_step``
+program of ``serving/decode.py``; the sync callers hand it a mask of all
+rows.  See :meth:`ContinuousScheduler._decode_step_async`.
 
 Where a tick's time goes (PR 24): every tick is a ``tick`` span whose
 children are its phases — ``admit``, ``prefill``, ``decode_prep``,
@@ -84,7 +90,9 @@ batcher path until the scheduler learns sharded block tables.
 
 Determinism for tests: construct with ``start=False`` and drive
 :meth:`tick` by hand — one tick = admit + prefill + one decode step, so a
-scripted arrival trace replays bit-identically.
+scripted arrival trace replays bit-identically (under a ring the step's
+tokens are delivered by the NEXT tick, and by the tick that finds nothing
+left to dispatch).
 """
 from __future__ import annotations
 
@@ -343,12 +351,13 @@ class ContinuousScheduler:
                 "sampled accept rule is serving/speculative.py's "
                 "sampled_accept, not yet wired to the scheduler)"
             )
-        # async decode pipeline (default-off): depth of the in-flight
-        # dispatch ring.  0 = today's synchronous loop (read every step's
-        # tokens back before dispatching the next); N >= 1 keeps up to N
-        # dispatched steps un-drained, with the sampled-token carry fed
-        # back ON DEVICE (decode_step_fed) so the accelerator never waits
-        # out the host's per-token bookkeeping window.
+        # async decode pipeline: depth of the in-flight dispatch ring.
+        # 0 = the synchronous loop (read every step's tokens back before
+        # dispatching the next); N >= 1 keeps up to N dispatched steps
+        # un-drained, with the sampled-token carry fed back ON DEVICE
+        # (decode_step's prev_tok) so the accelerator never waits out the
+        # host's per-token bookkeeping window.  An engine serves depth 1
+        # unless its configuration says otherwise (serving/engine.py).
         self._async_depth = int(async_depth)
         if self._async_depth < 0:
             raise ValueError(
@@ -469,6 +478,11 @@ class ContinuousScheduler:
         # beside the readback phase.
         self._inflight: deque = deque()  # confined: _loop
         self._carry_tok = None  # confined: _loop
+        # what a caller that knows every row's token hands decode_step as
+        # (prev_tok, fresh_mask): zeros on the device (_zero_carry, made
+        # once) and a mask of all rows
+        self._zero_tok = None  # confined: _loop
+        self._all_rows = np.ones((self.slots_n,), bool)
         # (tick_no, perf_counter) of the latest decode dispatch
         self._last_dispatch: Optional[tuple] = None  # confined: _loop
         self._tick_block_s = 0.0  # confined: _loop
@@ -1580,8 +1594,8 @@ class ContinuousScheduler:
                 gi[i] = k
                 aids[i] = req.adapter
                 keys[i] = req.row_key
-            tok, finite, self._pool, *_ = self._fns.decode_step(
-                self._qparams if self._quant else self.params,
+            tok, finite, self._pool, *_ = self._step_known(
+                self._fns, self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables, keys, gi, aids,
                 *self._slot_rows(pos),
             )
@@ -1729,13 +1743,13 @@ class ContinuousScheduler:
             self._poison_shim(active)
             prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(active)
         n_active = len(active)
-        self._note_dispatch_gap()
+        self._note_dispatch(inflight=0)
         # the span marks this tick as PRODUCTIVE serving work — the
         # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
         # preceding poison_bisect/serving_restart recovery span)
-        with self._phase("decode_step", active=n_active):
-            tok, finite, self._pool, *moe = self._fns.decode_step(
-                self._qparams if self._quant else self.params,
+        with self._phase("decode_step", active=n_active, inflight=0):
+            tok, finite, self._pool, *moe = self._step_known(
+                self._fns, self._qparams if self._quant else self.params,
                 self._pool, prev, pos, tables,
                 keys, gen_idx, aids, *self._slot_rows(pos),
             )
@@ -1793,22 +1807,36 @@ class ContinuousScheduler:
         pure: probing commits nothing the real step would not."""
         self._poison_shim(reqs)
         prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(reqs)
-        tok, _, self._pool, *_ = self._fns.decode_step(
-            self._qparams if self._quant else self.params,
+        tok, _, self._pool, *_ = self._step_known(
+            self._fns, self._qparams if self._quant else self.params,
             self._pool, prev, pos, tables,
             keys, gen_idx, aids, *self._slot_rows(pos),
         )
         # surface async dispatch errors here, inside the probe's try
         jax.block_until_ready(tok)
 
+    def _step_known(self, fns, params, pool, prev, *rest):
+        """``fns.decode_step`` for a caller that holds every row's last
+        token on the host (``prev``: the sync body, the probe, the replay,
+        a speculative draft's steps): all rows are fresh, and the carry is
+        the zeros the ring starts from, so target and draft each keep ONE
+        entry in their program's cache whichever body calls."""
+        return fns.decode_step(
+            params, pool, self._zero_carry(), self._all_rows, prev, *rest
+        )
+
     # ------------------------------------------------------------------ #
     # async decode pipeline (serving.scheduler.async_depth > 0)
 
-    def _note_dispatch_gap(self) -> None:
-        """Record the host-side gap between consecutive decode dispatch
-        enqueues — the number the pipeline exists to shrink.  Only gaps
-        between BACK-TO-BACK decode ticks count: an idle queue between
-        two dispatches is not host overhead."""
+    def _note_dispatch(self, inflight: int) -> None:
+        """One decode step is about to be dispatched with ``inflight``
+        steps still in the ring (0 on the sync and speculative bodies):
+        count it (``decode_overlap_share``) and record the host-side gap
+        between consecutive decode dispatch enqueues — the number the
+        pipeline exists to shrink.  Only gaps between BACK-TO-BACK decode
+        ticks count: an idle queue between two dispatches is not host
+        overhead."""
+        self.metrics.record_decode_dispatch(inflight)
         now = time.perf_counter()
         if (
             self._last_dispatch is not None
@@ -1823,11 +1851,15 @@ class ContinuousScheduler:
         """Pipelined decode: dispatch step *k* without waiting for step
         *k-1*'s host readback.
 
-        The sampled-token carry stays ON DEVICE — ``decode_step_fed``
-        feeds its own token output back as the next ``prev_tok``, with
-        rows the host just (re)filled spliced in via ``fresh_mask`` — and
-        a ring of up to ``async_depth`` dispatched steps drains one tick
-        behind dispatch.  Host state stays exact without the tokens: the
+        What an engine serves at depth 1 unless its configuration names
+        another depth.  The sampled-token carry stays ON DEVICE —
+        ``decode_step`` takes its own token output back as the next
+        ``prev_tok``, with rows the host just (re)filled spliced in via
+        ``fresh_mask`` — and a ring of up to ``async_depth`` dispatched
+        steps drains one tick behind dispatch: tick k dispatches step k
+        and only then reads step k-1, which the device finished while the
+        host was dispatching (or, where the device sets the pace, is
+        finishing).  Host state stays exact without the tokens: the
         per-request ``dispatched`` counter derives every position and
         sampling index, so the drained stream is bitwise identical to
         the sync path's (same per-row fold_in keys, same per-row pool
@@ -1841,7 +1873,8 @@ class ContinuousScheduler:
         footprint; the sampled overrun tokens are discarded at drain
         because the request has already retired (``admission is None``),
         and once its blocks recycle, any stale overrun rows are masked
-        exactly like every other recycled-block row.
+        exactly like every other recycled-block row.  A request's first
+        decode token is drained a tick after its prefill.
         """
         with self._phase("decode_prep"):
             active = [req for req in self._slots if req is not None]
@@ -1859,9 +1892,14 @@ class ContinuousScheduler:
                 # first dispatch of a pipeline run: every dispatched row
                 # is fresh by construction, the zeros are never sampled
                 prev = self._zero_carry()
-            self._note_dispatch_gap()
-            with self._phase("decode_step", active=len(disp)):
-                tok, finite, self._pool, *moe = self._fns.decode_step_fed(
+            # steps still in the ring as this one is dispatched: 1 in a
+            # steady window at depth 1 (the step the drain below reads)
+            inflight = len(self._inflight)
+            self._note_dispatch(inflight)
+            with self._phase(
+                "decode_step", active=len(disp), inflight=inflight
+            ):
+                tok, finite, self._pool, *moe = self._fns.decode_step(
                     self._qparams if self._quant else self.params,
                     self._pool, prev, fresh_mask, fresh_tok, pos, tables,
                     keys, gen_idx, aids, *self._slot_rows(pos),
@@ -1890,8 +1928,8 @@ class ContinuousScheduler:
             )
 
     def _fed_arrays(self, disp: List[_PagedRequest]):
-        """Fixed-width inputs of ``decode_step_fed`` with ``disp`` live,
-        derived from each row's ``dispatched`` counter, and the rows'
+        """Fixed-width inputs of the ring's ``decode_step`` with ``disp``
+        live, derived from each row's ``dispatched`` counter, and the rows'
         ``(request, slot, token index)`` for the drain."""
         W = self.slots_n
         fresh_mask = np.zeros((W,), bool)
@@ -1922,26 +1960,29 @@ class ContinuousScheduler:
 
     def _zero_carry(self):
         """A mesh-replicated, COMMITTED int32[slots] zeros vector whose
-        sharding matches ``decode_step_fed``'s token output.
+        sharding matches ``decode_step``'s token output, made once.
 
         The jit cache keys on input shardings: feeding an uncommitted
         ``jnp.zeros`` as the first carry and the committed program output
         as every later one would compile the SAME program twice (one
         re-layout entry).  Matching the output's replicated NamedSharding
-        up front keeps the async path at exactly one compiled program —
-        the compile-count pin the tests hold."""
-        z = jnp.zeros((self.slots_n,), jnp.int32)
-        leaf_sh = getattr(
-            jax.tree_util.tree_leaves(self.params)[0], "sharding", None
-        )
-        if isinstance(leaf_sh, jax.sharding.NamedSharding):
-            z = jax.device_put(
-                z,
-                jax.sharding.NamedSharding(
-                    leaf_sh.mesh, jax.sharding.PartitionSpec()
-                ),
+        up front keeps every caller — the ring's first and carried
+        dispatches, the sync body, probe and replay — at exactly one
+        compiled decode program — the compile-count pin the tests hold."""
+        if self._zero_tok is None:
+            z = jnp.zeros((self.slots_n,), jnp.int32)
+            leaf_sh = getattr(
+                jax.tree_util.tree_leaves(self.params)[0], "sharding", None
             )
-        return z
+            if isinstance(leaf_sh, jax.sharding.NamedSharding):
+                z = jax.device_put(
+                    z,
+                    jax.sharding.NamedSharding(
+                        leaf_sh.mesh, jax.sharding.PartitionSpec()
+                    ),
+                )
+            self._zero_tok = z
+        return self._zero_tok
 
     def _drain_entry(self, entry) -> int:
         """Materialize one ring entry's host readback and apply it.
@@ -2049,7 +2090,10 @@ class ContinuousScheduler:
             # no verify write can land past the reserved footprint
             k_eff = {r.slot: min(k, r.max_new - r.gen_idx) for r in active}
 
-        with self._phase("decode_step", active=len(active)):
+        # a round reads its own verify before the next is proposed:
+        # nothing is ever in the ring (decode_overlap_share stays 0)
+        self.metrics.record_decode_dispatch(0)
+        with self._phase("decode_step", active=len(active), inflight=0):
             # -- draft: k+1 greedy single-token steps (step j feeds the
             # committed tail for j=0, else proposal j-1, at position
             # P+j, producing proposal j).  Step k_eff is a pure K/V
@@ -2081,9 +2125,9 @@ class ContinuousScheduler:
                         aids[i] = req.adapter
                 if not any_row:
                     break
-                tok, _, self._draft_pool, *_ = self._draft_fns.decode_step(
-                    self._draft_params, self._draft_pool, prev, pos, dtables,
-                    pad_keys, gi, aids,
+                tok, _, self._draft_pool, *_ = self._step_known(
+                    self._draft_fns, self._draft_params, self._draft_pool,
+                    prev, pos, dtables, pad_keys, gi, aids,
                 )
                 if j < k:
                     draft_tok[:, j] = np.asarray(tok)
@@ -2308,6 +2352,7 @@ class ContinuousScheduler:
         # discarded steps' tokens were never delivered)
         self._inflight.clear()
         self._carry_tok = None
+        self._zero_tok = None
         self._last_dispatch = None
         with self._cond:
             inflight = [s for s in self._slots if s is not None]

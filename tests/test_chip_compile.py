@@ -247,9 +247,10 @@ def test_decode_step_reads_the_pool_where_it_lies(chip, monkeypatch):
     pool = jax.eval_shape(fns.init_pool, shapes)
     keys = jax.eval_shape(lambda: jnp.stack([jax.random.PRNGKey(0)] * slots))
     row = jax.ShapeDtypeStruct((slots,), I32)
+    mask = jax.ShapeDtypeStruct((slots,), jnp.bool_)
     args = jax.tree.map(on_chip, (
-        shapes, pool, row, row, jax.ShapeDtypeStruct((slots, table), I32),
-        keys, row, row))
+        shapes, pool, row, mask, row, row,
+        jax.ShapeDtypeStruct((slots, table), I32), keys, row, row))
     compiled = fns.decode_step.lower(*args).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines()

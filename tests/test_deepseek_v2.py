@@ -403,8 +403,8 @@ def test_pool_leaves_are_found_by_what_the_attention_declares(weights):
 
 
 def test_a_model_without_experts_keeps_its_programs_outputs():
-    """The decode programs of a model that states no ``moe_shape`` return
-    what they returned before there was one: token, finite flag, pool."""
+    """The decode program of a model that states no ``moe_shape`` returns
+    what it returned before there was one: token, finite flag, pool."""
     from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
 
     model = get_model("TransformerLM", num_classes=64, embed_dim=32, depth=1,
@@ -412,17 +412,20 @@ def test_a_model_without_experts_keeps_its_programs_outputs():
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
     fns = build_paged_fns(model, 4, 8)
     out = fns.decode_step(
-        params, fns.init_pool(params), np.zeros(2, np.int32), np.zeros(2, np.int32),
+        params, fns.init_pool(params), np.zeros(2, np.int32), np.ones(2, bool),
+        np.zeros(2, np.int32), np.zeros(2, np.int32),
         np.zeros((2, 2), np.int32), jnp.stack([jax.random.PRNGKey(0)] * 2),
         np.zeros(2, np.int32), np.full(2, -1, np.int32))
     assert len(out) == 3
 
 
-@pytest.mark.parametrize("name", ["prefill", "decode_step", "decode_step_fed"])
+@pytest.mark.parametrize("name", ["prefill", "decode_step", "decode_step.carried"])
 def test_every_latent_pool_leaf_is_donated_to_the_program(weights, name):
     """The latent pool's leaves (one a layer) are donated like a K/V pair's:
     the lowered program marks each for reuse, and the pool passed in is gone
-    once the call is made; the one returned is the pool."""
+    once the call is made; the one returned is the pool.  The one decode
+    program under a mask of all rows (a caller that knows every row's token)
+    and of none (``.carried``: the ring feeds each row the carried token)."""
     from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
 
     _, _, tree = weights
@@ -438,14 +441,16 @@ def test_every_latent_pool_leaf_is_donated_to_the_program(weights, name):
     args = {
         "prefill": (p, pool, np.zeros((2, 4), np.int32),
                     np.full((2, 4), -1, np.int32), tables, row, keys, row, none),
-        "decode_step": (p, pool, row, none, tables, keys, row, none),
-        "decode_step_fed": (p, pool, row, np.zeros(2, bool), row, none, tables,
-                            keys, row, none),
+        "decode_step": (p, pool, row, np.ones(2, bool), row, none, tables,
+                        keys, row, none),
+        "decode_step.carried": (p, pool, row, np.zeros(2, bool), row, none,
+                                tables, keys, row, none),
     }[name]
-    text = getattr(fns, name).lower(*args).as_text()
+    fn = getattr(fns, name.partition(".")[0])
+    text = fn.lower(*args).as_text()
     marked = text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
     assert marked == n_leaves
-    out = getattr(fns, name)(*args)
+    out = fn(*args)
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pool))
     assert jax.tree.structure(out[2]) == jax.tree.structure(pool)
     assert not any(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(out[2]))

@@ -269,7 +269,8 @@ def toy_fns(toy_lm):
     w, t = 4, 6
     keys = jnp.stack([jax.random.PRNGKey(1)] * w)
     i32 = lambda *shape: np.zeros(shape, np.int32)
-    decode = (params, pool, i32(w), i32(w), i32(w, t), keys, i32(w), i32(w))
+    decode = (params, pool, i32(w), np.ones(w, bool), i32(w), i32(w), i32(w, t),
+              keys, i32(w), i32(w))
     prefill = (params, pool, i32(w, 16), i32(w, 16), i32(w, t), i32(w), keys,
                i32(w), i32(w))
     return fns, decode, prefill, (w, t * 8, 2, 128)
@@ -290,14 +291,16 @@ def _gathers_of(text, shape):
     return re.findall(rf'"?stablehlo\.gather"?.*-> tensor<{dims}x', text)
 
 
-@pytest.mark.parametrize("name", ["decode_step", "decode_step_fed"])
+@pytest.mark.parametrize("name", ["decode_step", "decode_step.carried"])
 def test_decode_programs_hold_the_kernel_and_no_table_gather(toy_fns, name, monkeypatch):
+    """The ONE decode program, as the sync callers hand it its arguments (a
+    mask of all rows) and as the ring does (``.carried``: no row fresh)."""
     fns, decode, _, gathered = toy_fns
     args = decode
-    if name == "decode_step_fed":
-        params, pool, prev, *rest = decode
-        args = (params, pool, prev, np.zeros(prev.shape, bool), prev, *rest)
-    text = _lowered_for_tpu(getattr(fns, name), args, monkeypatch)
+    if name == "decode_step.carried":
+        params, pool, prev, mask, *rest = decode
+        args = (params, pool, prev, np.zeros_like(mask), *rest)
+    text = _lowered_for_tpu(fns.decode_step, args, monkeypatch)
     # the kernel is lowered ONCE (``paged_decode`` is a jitted function: a
     # layer's call is a call of it, so a program's set-up pays one
     # lowering whatever its depth) and called a layer, inside the scope
@@ -306,7 +309,7 @@ def test_decode_programs_hold_the_kernel_and_no_table_gather(toy_fns, name, monk
     assert len(kernels) == 1 and 'kernel_name = "paged_decode"' in kernels[0]
     assert len(re.findall(r"= call @paged_decode\(", text)) == 2
     assert len(re.findall(
-        r'loc\("jit\(\w+\)/TransformerLM/block\d/attn/paged_attention/'
+        r'loc\("jit\(decode_step\)/TransformerLM/block\d/attn/paged_attention/'
         r'[^"]*jit\(paged_decode\)"', text)) == 2
     assert not _gathers_of(text, gathered)
     # ... and the whole pool is still the program's to update in place

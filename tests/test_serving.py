@@ -1319,13 +1319,10 @@ def test_scheduler_async_parity_bitwise(lm_and_params, temperature, depth):
 
 def test_scheduler_async_compile_pin(lm_and_params, mode_prompts,
                                      plain_sched_results):
-    """The async pipeline adds AT MOST one program over the sync set.
-
-    Under traffic it compiles the same count: ``decode_step_fed``
-    replaces ``decode_step`` one-for-one (the sync program is never
-    invoked when async_depth > 0).  The pin also guards the sharding
+    """The async pipeline adds NO program over the sync set: both bodies
+    run the one ``decode_step``.  The pin also guards the sharding
     trap: the first dispatch's zero carry must hit the SAME cache entry
-    as the steady-state carried token, or the fed program doubles."""
+    as the steady-state carried token, or the program doubles."""
     model, params = lm_and_params
     _, base_compiles = plain_sched_results
     sched = _paged_sched(model, params, async_depth=2)
@@ -1355,6 +1352,99 @@ def test_scheduler_async_validation(lm_and_params):
         _paged_sched(
             model, params, async_depth=1, speculative=SpeculativeSpec(k=2),
         )
+
+
+@pytest.mark.parametrize("named, draft, want", [
+    (None, False, 1), (0, False, 0), (2, False, 2),
+    (None, True, 0), (0, True, 0), (1, True, "mutually exclusive"),
+], ids=["unnamed", "named_0", "named_2", "draft_unnamed", "draft_named_0",
+        "draft_named_1"])
+def test_engine_serves_the_ring_unless_the_configuration_says_otherwise(
+    named, draft, want
+):
+    """``serving.scheduler.async_depth`` left out: an engine serves the ring
+    of depth 1, and the sync body beside a speculative draft (the two are
+    exclusive, and a configuration that never named a depth must not be
+    refused for one); a depth that IS named is taken as it stands, and
+    refused beside a draft."""
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = _warm_engine_cfg(**({} if named is None else {"async_depth": named}))
+    if draft:
+        cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            InferenceEngine.from_config(cfg)
+        return
+    with InferenceEngine.from_config(cfg) as engine:
+        assert engine.scheduler._async_depth == want
+        assert (engine.scheduler._spec is not None) == draft
+        out = engine.submit(np.asarray([4, 8, 15], np.int32)).result(timeout=120)
+        assert out["gen_len"] == 4
+        share = engine.metrics.snapshot()["decode_overlap_share"]
+    # three decode steps behind the prefill's token: the first finds the
+    # ring empty, the others each find the one before them in it
+    assert share == (pytest.approx(2 / 3) if want else 0.0)
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2], ids=["sync", "ring_1", "ring_2"])
+def test_decode_overlap_share_counts_the_steps_dispatched_over_a_full_ring(
+    lm_and_params, depth
+):
+    """A scripted run, one request of 12 tokens, a tick at a time: of its
+    11 decode steps the ring dispatches all but the first while an earlier
+    step's tokens are still unread, the sync body none; the ``decode_step``
+    span carries the ring's length at the dispatch."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rec = set_recorder(SpanRecorder(ring=4096))
+    try:
+        sched = _paged_sched(model, params, async_depth=depth, eos_id=None,
+                             max_new_tokens=12, num_blocks=32)
+        fut = sched.submit(np.asarray([3, 4, 5], np.int32))
+        delivered = []
+        while not fut.done():
+            sched.tick()
+            delivered.append(len(sched._slots[0].tokens) if sched._slots[0] else 12)
+        sched.close()
+    finally:
+        set_recorder(None)
+    assert fut.result()["gen_len"] == 12
+    steps = 11
+    snap = sched.metrics.snapshot()
+    assert snap["decode_steps_dispatched"] == steps
+    inflight = [s["inflight"] for s in rec.recent() if s["kind"] == "decode_step"]
+    assert inflight == [min(k, depth) for k in range(steps)]
+    if depth == 0:
+        assert snap["decode_overlap_share"] == 0.0
+        # a token a tick: the prefill's, then each step's own
+        assert delivered == list(range(2, 13))
+    else:
+        assert snap["decode_overlap_share"] >= (steps - 1) / steps
+        assert snap["decode_steps_overlapped"] == steps - 1
+        # the ring delivers ``depth`` ticks behind its dispatch, and the
+        # tick that finds nothing left to dispatch drains what is in it
+        assert delivered[0] == 1 and delivered[-1] == 12
+        assert len(delivered) == steps + 1
+
+
+def test_decode_overlap_share_of_a_fleet_is_recomputed_from_the_counts():
+    from pytorch_distributed_training_tpu.serving.metrics import (
+        aggregate_snapshots,
+    )
+
+    a = {"decode_steps_dispatched": 10, "decode_steps_overlapped": 9,
+         "decode_overlap_share": 0.9}
+    b = {"decode_steps_dispatched": 30, "decode_steps_overlapped": 0,
+         "decode_overlap_share": 0.0}
+    out = aggregate_snapshots({"r0": a, "r1": b})
+    assert out["decode_steps_dispatched"] == 40
+    assert out["decode_overlap_share"] == pytest.approx(9 / 40)
+    assert "decode_overlap_share" not in aggregate_snapshots({"r0": {"requests": 1}})
 
 
 @pytest.mark.parametrize("depth", [0, 1], ids=["sync", "async"])
@@ -1750,16 +1840,21 @@ def test_decode_step_carries_the_trace_scopes(lm_and_params):
 
     model, params = lm_and_params
     sched = _paged_sched(model, params)
-    prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
-    text = sched._fns.decode_step.lower(
-        sched.params, sched._pool, prev, pos, tables, jnp.stack(keys),
-        gen_idx, aids,
-    ).as_text(debug_info=True)
+    lowered = sched._fns.decode_step.lower(
+        *pool_program_args(sched, "decode_step.carried"))
     sched.close()
-    names = set(re.findall(r'loc\("([^"]+)"', text))
+    names = set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
     assert [n for n in names if "/attn/paged_attention/" in n]
     assert "jit(decode_step)/sample" in names
     assert [n for n in names if "loss_head/head" in n]  # the logits matmul
+    # the ONE decode program, ring or sync: every operation of the compiled
+    # program is named under jit(decode_step), which is how a trace's
+    # readers find a decode step's scopes (the ``op_name`` an ``.xplane.pb``
+    # keeps; benchmark/decode_scopes.py::DECODE)
+    ops = set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+    ops = {n for n in ops if "jit(" in n}  # (a parameter is named by itself)
+    assert len(ops) > 20
+    assert not [n for n in ops if not n.startswith("jit(decode_step)/")]
 
 
 # --------------------------------------------------------------------- #
@@ -1773,9 +1868,18 @@ def donated_leaves(program, *args) -> int:
     return text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
 
 
+def pool_program(sched, name):
+    """``decode_step.carried`` is the one decode program under the ring's
+    arguments."""
+    return getattr(sched._fns, name.partition(".")[0])
+
+
 def pool_program_args(sched, name):
     """Arguments, in order, with which the scheduler calls ``_fns.<name>``
-    (no request active: every row rides at position -1)."""
+    (no request active: every row rides at position -1): ``decode_step`` as
+    a caller that knows every row's token hands them over (a mask of all
+    rows), ``decode_step.carried`` as the ring does (no row fresh: each is
+    fed the carried token)."""
     W, T = sched.slots_n, sched.table_blocks
     prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
     tokens = np.zeros((W, 8), np.int32)
@@ -1784,18 +1888,20 @@ def pool_program_args(sched, name):
     return {
         "prefill": (sched.params, sched._pool, tokens, positions, tables,
                     np.zeros((W,), np.int32), keys, gen_idx, aids),
-        "decode_step": (sched.params, sched._pool, prev, pos, tables, keys,
-                        gen_idx, aids),
-        "decode_step_fed": (sched.params, sched._pool, prev,
-                            np.zeros((W,), bool), prev, pos, tables, keys,
-                            gen_idx, aids),
+        "decode_step": (sched.params, sched._pool, sched._zero_carry(),
+                        sched._all_rows, prev, pos, tables, keys, gen_idx,
+                        aids),
+        "decode_step.carried": (sched.params, sched._pool,
+                                sched._zero_carry(), np.zeros((W,), bool),
+                                prev, pos, tables, keys, gen_idx, aids),
         "verify": (sched.params, sched._pool, tokens, positions,
                    np.zeros((W, T), np.int32), aids),
         "copy_rows": (sched._pool, oob, oob),
     }[name]
 
 
-POOL_PROGRAMS = ["prefill", "decode_step", "decode_step_fed", "verify", "copy_rows"]
+POOL_PROGRAMS = ["prefill", "decode_step", "decode_step.carried", "verify",
+                 "copy_rows"]
 
 
 @pytest.mark.parametrize("name", POOL_PROGRAMS)
@@ -1807,13 +1913,19 @@ def test_every_pool_leaf_is_donated_to_the_program(lm_and_params, name):
     n_leaves = len(jax.tree_util.tree_leaves(sched._pool))
     assert n_leaves >= 4  # a K and a V leaf a layer, two layers
     args = pool_program_args(sched, name)
-    assert donated_leaves(getattr(sched._fns, name), *args) == n_leaves
+    assert donated_leaves(pool_program(sched, name), *args) == n_leaves
     # ... and running it leaves the caller without the pool it passed
-    out = getattr(sched._fns, name)(*args)
+    out = pool_program(sched, name)(*args)
     new_pool = out if name == "copy_rows" else out[1 if name == "verify" else 2]
     sched.close()
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(sched._pool))
     assert not any(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(new_pool))
+
+
+# what each decode body's engine names under ``serving.scheduler``: the
+# ring is what an engine serves when nothing is named
+_ENGINE_BODIES = {"sync": {"async_depth": 0}, "async_ring": {},
+                  "speculative": {}}
 
 
 def _warm_engine_cfg(**scheduler_more):
@@ -1840,7 +1952,7 @@ def test_warmup_hands_the_scheduler_its_pool_back(mode):
     place: all of it."""
     from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
 
-    cfg = _warm_engine_cfg(**({"async_depth": 2} if mode == "async_ring" else {}))
+    cfg = _warm_engine_cfg(**_ENGINE_BODIES[mode])
     if mode == "speculative":
         cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
     prompt = np.asarray([4, 8, 15, 16, 23], np.int32)
@@ -1905,11 +2017,11 @@ class _Spy:
 
 
 # per program: where ``row_keys`` sits, and the first argument the TICK
-# builds (before it: params, the pool and, for the fed step, the carried
-# token, which is the previous step's output and stays on the device)
-_KEYS_AT = {"prefill": 6, "decode_step": 5, "decode_step_fed": 7}
-_HOST_FROM = {"prefill": 2, "decode_step": 2, "decode_step_fed": 3,
-              "verify": 2, "copy_rows": 1}
+# builds (before it: params, the pool and, for the decode step, the carried
+# token, which is a step's output, or the zeros the ring starts from, and
+# stays on the device)
+_KEYS_AT = {"prefill": 6, "decode_step": 7}
+_HOST_FROM = {"prefill": 2, "decode_step": 3, "verify": 2, "copy_rows": 1}
 
 
 def _spy_on(sched, rewrite=None):
@@ -1944,9 +2056,16 @@ def test_a_tick_hands_the_programs_host_arrays_only(
     results = _sched_results(sched, mode_prompts)
     assert all(r["gen_len"] >= 1 for r in results)
     _assert_host_built(calls)
-    step = {"sync": "decode_step", "async_ring": "decode_step_fed",
-            "speculative": "verify"}[body]
+    step = "verify" if body == "speculative" else "decode_step"
     assert {"prefill", step} <= {name for name, _ in calls}
+    # a caller that knows every row's token says so of ALL rows; the ring
+    # only of the rows it has nothing in flight for
+    masks = [args[3] for name, args in calls if name == "decode_step"]
+    assert all(m.dtype == bool for m in masks)
+    if body == "async_ring":
+        assert masks[0].any() and not all(m.all() for m in masks)
+    else:
+        assert all(m.all() for m in masks)
     # the prefill's rows: request i's key is fold_in(PRNGKey(seed), i),
     # made once at submit; a padding row rides the pad key
     keys = next(a for n, a in calls if n == "prefill")[_KEYS_AT["prefill"]]
@@ -1991,12 +2110,15 @@ def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
     laid out anew) while requests are served."""
     from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
 
-    cfg = _warm_engine_cfg(**({"async_depth": 1} if mode == "async_ring" else {}))
+    cfg = _warm_engine_cfg(**_ENGINE_BODIES[mode])
     rng = np.random.default_rng(5)
     with InferenceEngine.from_config(cfg) as engine:
-        # one prefill bucket and the decode step, and the fed step beside it
-        n = 3 if mode == "async_ring" else 2
+        assert engine.scheduler._async_depth == (mode == "async_ring")
+        # one prefill bucket and ONE decode step, whichever body runs it
+        n = 2
+        assert engine.scheduler._fns.decode_step._cache_size() == 0
         assert engine.warmup()["programs"] == n
+        assert engine.scheduler._fns.decode_step._cache_size() == 1
         assert engine.compile_count() == n
         calls = _spy_on(engine.scheduler)
         futs = [
@@ -2004,10 +2126,10 @@ def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
             for ln in (3, 8, 5, 2, 7)
         ]
         assert sum(f.result(timeout=120)["gen_len"] for f in futs) > 5
-        step = "decode_step_fed" if mode == "async_ring" else "decode_step"
         names = [name for name, _ in calls]
-        assert names.count("prefill") >= 1 and names.count(step) >= 3
+        assert names.count("prefill") >= 1 and names.count("decode_step") >= 3
         assert engine.compile_count() == n
+        assert engine.scheduler._fns.decode_step._cache_size() == 1
 
 
 def test_sampled_streams_are_those_of_stacked_device_keys(

@@ -262,8 +262,8 @@ def test_a_state_leaf_is_untouched_by(weights, model, what):
         keys = jnp.stack([jax.random.PRNGKey(0)] * SLOTS)
         zeros = np.zeros((SLOTS,), np.int32)
         _, _, after, _ = fns.decode_step(
-            weights[2], pool, zeros, pad, np.zeros((SLOTS, 40), np.int32), keys,
-            zeros, pad, pad)
+            weights[2], pool, zeros, np.ones((SLOTS,), bool), zeros, pad,
+            np.zeros((SLOTS, 40), np.int32), keys, zeros, pad, pad)
     flat_before = jax.tree_util.tree_flatten_with_path(before)[0]
     flat_after = jax.tree_util.tree_flatten_with_path(after)[0]
     states = 0
@@ -279,7 +279,7 @@ def test_a_state_leaf_is_untouched_by(weights, model, what):
 
 
 def test_a_decode_row_that_names_another_slot_is_answered_with_nan(weights, model):
-    """The decode programs' step (``rows_are_slots``) reads and writes the
+    """The decode program's step (``rows_are_slots``) reads and writes the
     state where it lies: row i is slot i.  A live row that names another
     slot is not served something else: its output is NaN, which the serving
     programs' output guard evicts."""
@@ -290,8 +290,8 @@ def test_a_decode_row_that_names_another_slot_is_answered_with_nan(weights, mode
     tables = np.tile(np.arange(40, dtype=np.int32), (SLOTS, 1))
     crossed = np.asarray([0, 2, -1], np.int32)  # row 1 names slot 2
     _, finite, _, _ = fns.decode_step(
-        weights[2], pool, zeros, pos, tables, keys, zeros,
-        np.full((SLOTS,), -1, np.int32), crossed)
+        weights[2], pool, zeros, np.ones((SLOTS,), bool), zeros, pos, tables,
+        keys, zeros, np.full((SLOTS,), -1, np.int32), crossed)
     assert list(np.asarray(finite)[:2]) == [True, False]
 
 
