@@ -33,8 +33,12 @@ float32 and ``kda_conv [slots, conv_size - 1, 3 H d_k]`` (the last rows of
 is 0 starts from a zero state, so a slot is never cleared.  The scheduler's
 decode step says so itself (``rows_are_slots=True``, from the decode program
 of ``serving/decode.py``): one position a row, as many rows as slots, row
-``i`` IS slot ``i`` and ``state_rows`` only says which rows live.  Padding
-positions (-1, at a row's end) change neither state nor convolution rows.
+``i`` IS slot ``i`` and ``state_rows`` only says which rows live.  That step
+updates the state leaf where it lies and the live rows only
+(:func:`.state_rows.step_live_rows`: a row that is not live is neither read
+nor written, its output is zeros; past half the slots live, one pass over
+all of them).  Padding positions (-1, at a row's end) change neither state
+nor convolution rows.
 """
 from __future__ import annotations
 
@@ -46,6 +50,7 @@ from flax import linen as nn
 
 from .attention import KDA_CONV, KDA_STATE
 from .moe import in_token_chunks
+from .state_rows import step_live_rows
 
 __all__ = ["KimiDeltaAttention", "delta_rule_chunked", "delta_rule_step"]
 
@@ -194,10 +199,12 @@ class KimiDeltaAttention(nn.Module):
                     f"one position a row; got {b} rows of {s} positions")
             # The leaves are read and written where they lie, no gathered
             # copy (at 32 rows a copy each way was 2.9 ms of a 13.8 ms
-            # step; PERF.md PR 32); ``state_rows`` says which rows are
-            # live.  A row that names another slot breaks the contract and
-            # is answered with NaN, which the output guard of the serving
-            # programs evicts: loud, not wrong.
+            # step; PERF.md PR 32), and of the state only the rows
+            # ``state_rows`` says are live (a pass over all 32 slots costs
+            # 0.60 ms a layer whatever lives, a live row 0.027; PERF.md
+            # PR 42).  A row that names another slot breaks the contract
+            # and is answered with NaN, which the output guard of the
+            # serving programs evicts: loud, not wrong.
             live = state_rows >= 0
             y, state.value, conv.value = self._rows(
                 p, x, positions, state.value, conv.value, live)
@@ -230,8 +237,9 @@ class KimiDeltaAttention(nn.Module):
         """One group of rows: ``state_in``, ``conv_in`` are what the rows'
         slots hold; a row whose first position is 0 starts a sequence and
         reads zeros instead.  ``live [B]`` (the aligned decode step): a row
-        that is not live keeps what its slot held.  The state's read, update
-        and write all lie under ``kda_step`` / ``kda_scan``."""
+        that is not live keeps what its slot held, and its state is not
+        touched.  The state's read, update and write all lie under
+        ``kda_step`` / ``kda_scan``."""
         b, s, dim = x.shape
         h, d, taps = self.num_heads, self.head_dim, self.conv_size
         f32 = jnp.float32
@@ -273,13 +281,14 @@ class KimiDeltaAttention(nn.Module):
             if s == 1:
                 with jax.named_scope("kda_step"):
                     state0 = jnp.where(old[:, None, None, None], state_in, 0.0)
-                    out, state1 = delta_rule_step(
-                        q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], beta[:, 0],
-                        state0)
+                    step_in = (
+                        q[:, 0], k[:, 0], v[:, 0], log_decay[:, 0], beta[:, 0])
+                    if live is not None:  # a fresh row is zeroed in the walk
+                        out, state1 = step_live_rows(
+                            delta_rule_step, state_in, live, old, step_in)
+                    else:
+                        out, state1 = delta_rule_step(*step_in, state0)
                     out = out[:, None]
-                    if live is not None:
-                        state1 = jnp.where(
-                            live[:, None, None, None], state1, state_in)
             else:
                 with jax.named_scope("kda_scan"):
                     state0 = jnp.where(old[:, None, None, None], state_in, 0.0)

@@ -40,8 +40,9 @@ names each batch row's slot in ``state_rows [B]`` (-1 = padding: read as
 slot 0, written nowhere); a row whose first position is 0 starts from a zero
 state, so a slot is never cleared; ``rows_are_slots=True`` is the scheduler's
 fixed-width decode step, whose row ``i`` IS slot ``i`` and ``state_rows``
-only says which rows live; padding positions (-1, at a row's end) change
-neither state nor convolution rows.
+only says which rows live, and which updates the state leaf where it lies,
+the live rows only (:func:`.state_rows.step_live_rows`); padding positions
+(-1, at a row's end) change neither state nor convolution rows.
 """
 from __future__ import annotations
 
@@ -52,6 +53,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from .attention import MAMBA_CONV, MAMBA_STATE
+from .state_rows import step_live_rows
 
 __all__ = ["Mamba2Mixer", "ssd_chunked", "ssd_step"]
 
@@ -220,10 +222,10 @@ class Mamba2Mixer(nn.Module):
                     f"rows_are_slots is the decode step over all {slots} slots, "
                     f"one position a row; got {b} rows of {s} positions")
             # The leaves are read and written where they lie, no gathered
-            # copy; ``state_rows`` says which rows are live.  A row that
-            # names another slot breaks the contract and is answered with
-            # NaN, which the output guard of the serving programs evicts:
-            # loud, not wrong (ops/kda.py).
+            # copy, and of the state only the rows ``state_rows`` says are
+            # live.  A row that names another slot breaks the contract and
+            # is answered with NaN, which the output guard of the serving
+            # programs evicts: loud, not wrong (ops/kda.py).
             live = state_rows >= 0
             y, state.value, conv.value = self._rows(
                 params, x, positions, state.value, conv.value, live)
@@ -277,8 +279,9 @@ class Mamba2Mixer(nn.Module):
         """One group of rows: ``state_in``, ``conv_in`` are what the rows'
         slots hold; a row whose first position is 0 starts a sequence and
         reads zeros instead.  ``live [B]`` (the aligned decode step): a row
-        that is not live keeps what its slot held.  The state's read, update
-        and write all lie under ``mamba_step`` / ``mamba_scan``."""
+        that is not live keeps what its slot held, and its state is not
+        touched.  The state's read, update and write all lie under
+        ``mamba_step`` / ``mamba_scan``."""
         b, s, _ = x.shape
         h, p, g, n = self.num_heads, self.head_dim, self.n_groups, self.state_size
         taps = self.conv_size
@@ -315,13 +318,14 @@ class Mamba2Mixer(nn.Module):
             state0 = jnp.where(old[:, None, None, None], state_in, 0.0)
             if s == 1:
                 with jax.named_scope("mamba_step"):
-                    y, state1 = ssd_step(
-                        xs[:, 0], b_in[:, 0], c_out[:, 0], dt[:, 0],
-                        log_decay[:, 0], state0)
+                    step_in = (xs[:, 0], b_in[:, 0], c_out[:, 0], dt[:, 0],
+                               log_decay[:, 0])
+                    if live is not None:  # a fresh row is zeroed in the walk
+                        y, state1 = step_live_rows(
+                            ssd_step, state_in, live, old, step_in)
+                    else:
+                        y, state1 = ssd_step(*step_in, state0)
                     y = y[:, None]
-                    if live is not None:
-                        state1 = jnp.where(
-                            live[:, None, None, None], state1, state_in)
             else:
                 with jax.named_scope("mamba_scan"):
                     y, state1 = ssd_chunked(

@@ -69,6 +69,12 @@ class ServingMetrics:
         self._live_block_share = self._registry.histogram(
             "paged_live_block_share", _RESERVOIR
         )
+        # of the slots of a ``[slots, ...]`` state leaf, the share a decode
+        # step's rows live in: what the state's walk reads and writes
+        # (ops/state_rows.py); only a model that carries a state records it
+        self._state_live_row_share = self._registry.histogram(
+            "state_live_row_share", _RESERVOIR
+        )
         # disaggregated serving (PR 19): per-import host-staging wall
         # time; the byte/block counters ride the counter namespace
         self._kv_transfer_ms = self._registry.histogram(
@@ -293,13 +299,17 @@ class ServingMetrics:
         blocks_in_use: int,
         total_blocks: int,
         live_block_share: Optional[float] = None,
+        state_live_row_share: Optional[float] = None,
     ) -> None:
         """Scheduler-state sample at one decode iteration
-        (``live_block_share``: of a single-position step's block tables)."""
+        (``live_block_share``: of a single-position step's block tables;
+        ``state_live_row_share``: of a state-carrying model's slots)."""
         self._slot_occ.observe(active_slots / max(total_slots, 1))
         self._block_util.observe(blocks_in_use / max(total_blocks, 1))
         if live_block_share is not None:
             self._live_block_share.observe(float(live_block_share))
+        if state_live_row_share is not None:
+            self._state_live_row_share.observe(float(state_live_row_share))
 
     def record_tick(self, host_ms: float) -> None:
         """One scheduler tick's HOST overhead: wall time minus the spans
@@ -473,6 +483,10 @@ class ServingMetrics:
         if share["count"]:
             out["paged_live_block_share_mean"] = float(share["mean"])
             out["paged_live_block_share_p50"] = float(share["p50"])
+        share = self._state_live_row_share.snapshot()
+        if share["count"]:
+            out["state_live_row_share_mean"] = float(share["mean"])
+            out["state_live_row_share_p50"] = float(share["p50"])
         xfer = self._kv_transfer_ms.snapshot()
         if xfer["count"]:
             out["kv_transfer_ms_p50"] = float(xfer["p50"])

@@ -575,6 +575,14 @@ class ContinuousScheduler:
         live = np.where(pos >= 0, pos // bs + 1, 0).sum()
         return float(live) / (self.slots_n * self.table_blocks)
 
+    def _state_live_row_share(self, pos):
+        """Of a fixed-width decode call's slots, the share whose row lives:
+        what the step reads and writes of a ``[slots, ...]`` state leaf;
+        ``None`` for a model that carries no state."""
+        if self._state_shape is None:
+            return None
+        return float((pos >= 0).sum()) / self.slots_n
+
     def _pad_keys(self, n: int) -> np.ndarray:
         """The ``row_keys`` argument of a paged call of ``n`` batch rows,
         every row the pad key: ONE host ``uint32 [n, 2]`` array, which the
@@ -1778,6 +1786,7 @@ class ContinuousScheduler:
             blocks_in_use=self._kv.blocks_in_use,
             total_blocks=self._kv.num_blocks,
             live_block_share=self._live_block_share(pos),
+            state_live_row_share=self._state_live_row_share(pos),
         )
 
     def _readback_wait(self, for_step: int):
@@ -1913,6 +1922,7 @@ class ContinuousScheduler:
                 blocks_in_use=self._kv.blocks_in_use,
                 total_blocks=self._kv.num_blocks,
                 live_block_share=self._live_block_share(pos),
+                state_live_row_share=self._state_live_row_share(pos),
             )
         # drain one tick behind dispatch (ring bounded at async_depth);
         # when nothing is left to dispatch, drain EVERYTHING so the

@@ -262,3 +262,58 @@ def test_decode_step_reads_the_pool_where_it_lies(chip, monkeypatch):
     pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
                      for s in jax.tree.leaves(pool))
     assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes == 4 * 2 ** 25
+
+
+def _state_layers():
+    from pytorch_distributed_training_tpu.ops.kda import KimiDeltaAttention
+    from pytorch_distributed_training_tpu.ops.mamba2 import Mamba2Mixer
+
+    return {
+        # solar-open2-250b.serve.long32: a [64, 128, 128] state a slot
+        "kda": KimiDeltaAttention(
+            num_heads=64, head_dim=128, dtype=BF16, decode=True, state_slots=32),
+        # nemotron-3-super-120b.serve.burst32: a [128, 64, 128] state a slot
+        "mamba2": Mamba2Mixer(
+            num_heads=128, head_dim=64, n_groups=8, state_size=128, dtype=BF16,
+            decode=True, state_slots=32),
+    }
+
+
+@pytest.mark.parametrize("family", ["kda", "mamba2"])
+def test_the_state_s_decode_step_walks_its_leaf_in_place(chip, family):
+    """One state-carrying layer at its published widths, 32 slots, the
+    aligned decode step with its cache donated: the compiled program writes
+    both leaves where they lie (all their bytes aliased), holds no copy of
+    the 134 MB state leaf, and its temporaries are a twentieth of it: both
+    ``while``s of ``ops/state_rows.py`` (the walk of the live rows, the one
+    dense trip past half the slots live) carry the leaf and write into it."""
+    import re
+
+    import numpy as np
+
+    layer = _state_layers()[family]
+    slots, dim = 32, 4096
+    x = jnp.zeros((slots, 1, dim), BF16)
+    pos, rows = jnp.zeros((slots, 1), I32), jnp.zeros((slots,), I32)
+    shapes = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), x, pos, rows, True))
+
+    def step(params, cache, x, pos, rows):
+        y, changed = layer.apply(
+            {"params": params, "cache": cache}, x, pos, rows,
+            rows_are_slots=True, mutable=["cache"])
+        return y, changed["cache"]
+
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (shapes["params"], shapes["cache"], x, pos, rows))
+    compiled = jax.jit(step, donate_argnums=1).lower(*args).compile()
+    nbytes = lambda s: int(np.prod(s.shape)) * s.dtype.itemsize  # noqa: E731
+    leaves = jax.tree.leaves(shapes["cache"])
+    account = compiled.memory_analysis()
+    assert account.alias_size_in_bytes == sum(map(nbytes, leaves))
+    state = max(leaves, key=nbytes)
+    assert state.dtype == jnp.float32 and state.shape[0] == slots
+    assert account.temp_size_in_bytes < nbytes(state) // 20
+    leaf = "f32[" + ",".join(map(str, state.shape)) + "]"
+    assert not re.search(re.escape(leaf) + r"\{[^}]*\} copy\(", compiled.as_text())

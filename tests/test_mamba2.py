@@ -111,10 +111,11 @@ def test_prefill_then_steps_through_the_slots_is_one_full_forward(ref, weights):
     """Two rows of unequal lengths, neither a multiple of the chunk, in ONE
     padded call into slots 2 and 0 whose leaves hold another sequence's
     leftovers; then six decode steps over all slots (row i is slot i, slot 1
-    is padding): every output row is the reference's over the same inputs."""
+    is padding) and a seventh that slot 2 takes alone: every output row is
+    the reference's over the same inputs."""
     arch, layer, tree = weights
     lens, slots, bucket, steps = [37, 18], [2, 0], 40, 6
-    rows = [inputs(1, n + steps, seed=n)[0] for n in lens]
+    rows = [inputs(1, n + steps + 1, seed=n)[0] for n in lens]
     want = [np.asarray(ref.mamba_layer(r, layer, arch=arch)) for r in rows]
     x = jnp.stack([jnp.pad(r[:n], ((0, bucket - n), (0, 0))) for r, n in zip(rows, lens)])
     layer_fn = mixer(decode=True, state_slots=SLOTS)
@@ -137,6 +138,20 @@ def test_prefill_then_steps_through_the_slots_is_one_full_forward(ref, weights):
         for slot, i in row_of_slot.items():
             np.testing.assert_allclose(
                 np.asarray(out[slot, 0]), want[i][lens[i] + k], atol=TOLERANCE)
+    # one step more with most rows dead: slot 2 alone lives, and what the
+    # two slots that sit it out hold is what it was, bit for bit
+    x = jnp.zeros((SLOTS, 1, DIM)).at[2, 0].set(rows[0][lens[0] + steps])
+    pos = np.asarray([[-1], [-1], [lens[0] + steps]], np.int32)
+    before = cache_of(changed)
+    out, changed = layer_fn.apply(
+        {"params": tree, "cache": changed["cache"]}, x, pos,
+        np.asarray([-1, -1, 2], np.int32), rows_are_slots=True, mutable=["cache"])
+    np.testing.assert_allclose(
+        np.asarray(out[2, 0]), want[0][lens[0] + steps], atol=TOLERANCE)
+    assert np.isfinite(np.asarray(out)).all()
+    for old, new in zip(before, cache_of(changed)):
+        np.testing.assert_array_equal(old[:2], new[:2])
+        assert (old[2] != new[2]).any()
 
 
 def test_rows_of_unequal_length_end_each_at_its_own_last_position(weights):

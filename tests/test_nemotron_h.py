@@ -155,11 +155,12 @@ def test_prefill_then_decode_through_pool_and_state_matches_one_full_forward(
         ref, weights, model):
     """Two rows of unequal lengths, neither a multiple of the scan's chunk,
     prefilled in one call into slots 2 and 0; then six decode steps a row
-    through the pool AND the state, a padding row riding along: every logit
-    row is the reference's full forward over the same tokens."""
+    through the pool AND the state, a padding row riding along, and a seventh
+    that slot 2 takes alone: every logit row is the reference's full forward
+    over the same tokens."""
     _, params, tree = weights
     _, clone, pool = paged(model, weights)
-    rows = [tokens_of(150 + 6, seed=1), tokens_of(70 + 6, seed=2)]
+    rows = [tokens_of(150 + 7, seed=1), tokens_of(70 + 7, seed=2)]
     lens, slots, bucket, table = [150, 70], [2, 0], 160, 40
     tokens = np.zeros((2, bucket), np.int32)
     positions = np.full((2, bucket), -1, np.int32)
@@ -193,6 +194,22 @@ def test_prefill_then_decode_through_pool_and_state_matches_one_full_forward(
         for slot, i in row_of_slot.items():
             np.testing.assert_allclose(
                 np.asarray(logits[slot, 0]), want[i][lens[i] + k], atol=TOLERANCE)
+    # one step more with most rows dead: slot 2 alone lives, and the state
+    # leaves of the two slots that sit it out are what they were, bit for bit
+    tok, pos = np.zeros((SLOTS, 1), np.int32), np.full((SLOTS, 1), -1, np.int32)
+    tok[2, 0], pos[2, 0] = rows[0][lens[0] + 6], lens[0] + 6
+    before = jax.tree_util.tree_flatten_with_path(
+        jax.device_get(variables["cache"]))[0]
+    logits, variables = step(variables["cache"], tok, pos, step_tables,
+                             state_rows=np.asarray([-1, -1, 2], np.int32))
+    np.testing.assert_allclose(
+        np.asarray(logits[2, 0]), want[0][lens[0] + 6], atol=TOLERANCE)
+    assert np.isfinite(np.asarray(logits)).all()
+    after = jax.tree_util.tree_flatten_with_path(variables["cache"])[0]
+    for (path, old), (_, new) in zip(before, after):
+        if is_state_leaf(path):
+            np.testing.assert_array_equal(old[:2], np.asarray(new)[:2])
+            assert (old[2] != np.asarray(new)[2]).any()
 
 
 def scheduler(model, tree, **more):
@@ -240,6 +257,19 @@ def test_two_arrivals_in_one_tick_through_the_scheduler_are_the_reference_s_forw
         np.testing.assert_array_equal(rows.argmax(-1), served)
         best_two = np.sort(rows, axis=-1)[:, -2:]
         assert (best_two[:, 1] - best_two[:, 0]).min() > 10 * TOLERANCE
+
+
+def test_the_share_of_live_state_rows_is_observed_a_decode_step(weights, model):
+    """``state_live_row_share``: of the slots of a ``[slots, ...]`` state
+    leaf, those a decode step's rows live in, which is what the step's walk
+    (``ops/state_rows.py``) reads and writes.  One request in four slots."""
+    with scheduler(model, weights[2], slots=4, batch_buckets=[1, 4]) as sched:
+        assert sched._state_live_row_share(np.asarray([7, -1, 0, -1])) == 0.5
+        assert "state_live_row_share_mean" not in sched.metrics.snapshot()
+        serve(sched, tokens_of(9, seed=22))
+        snapshot = sched.metrics.snapshot()
+    assert snapshot["state_live_row_share_mean"] == pytest.approx(0.25)
+    assert snapshot["state_live_row_share_p50"] == pytest.approx(0.25)
 
 
 def test_a_slot_reused_by_a_second_request_gives_what_a_fresh_engine_gives(weights, model):

@@ -369,3 +369,6 @@ def test_live_block_share_is_observed_a_decode_step():
     # a prompt of 5 and up to 3 more positions: always 2 of the 12 entries
     assert snap["paged_live_block_share_mean"] == pytest.approx(2 / 12)
     assert snap["paged_live_block_share_p50"] == pytest.approx(2 / 12)
+    # the model carries no state: no share of live state rows to report
+    assert sched._state_live_row_share(np.asarray([5, -1, 0, -1])) is None
+    assert not [k for k in snap if k.startswith("state_live_row_share")]
