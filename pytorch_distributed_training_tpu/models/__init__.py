@@ -6,18 +6,12 @@ num_classes) -> model``.  Case-insensitive on the name; the reference configs
 use ``ResNet50`` (config/ResNet50.yml:31).
 
 Families: the reference's ResNet-18/34/50/101/152 (README.md:7-13) plus a
-ViT family (ViT-Ti16/S16/B16) and a decoder-only ``TransformerLM`` (the
-long-context / sequence-parallel model) added beyond the reference — the
-config surface only pins ``model.name``, so new names slot straight in —
-``DeepseekV2`` (:mod:`.deepseek_v2`: latent attention, dropless experts;
-served, not trained) and ``SolarOpen2`` (:mod:`.solar_open2`: gated
-delta-rule linear layers that carry a state a sequence, one grouped-query
-layer without positions in four, dropless experts in every layer; served,
-not trained) and ``NemotronH`` (:mod:`.nemotron_h`: ONE mixer a layer by a
-pattern — Mamba-2 state-space layers that carry a second kind of state,
-latent experts, grouped-query attention with 2 K/V heads; served, not
-trained).  The three served families share their norm, head and expert
-layer through :mod:`.lm_parts`.
+ViT family (ViT-Ti16/S16/B16) and five language-model families added beyond
+the reference — the config surface only pins ``model.name``, so new names
+slot straight in.  ``_LM_FAMILIES`` below lists them once, each with its
+module and what sets it apart; ``TransformerLM`` trains and serves, the other
+four are served, not trained, and share their norm, head, dense MLP and
+expert layer through :mod:`.lm_parts`.
 
 What a model IS is stated by its class, not compared by name:
 ``is_language_model`` (tokens in, logits out; the ``num_classes`` slot is
@@ -34,6 +28,7 @@ import jax.numpy as jnp
 
 from .deepseek_v2 import DeepseekV2LM
 from .nemotron_h import NemotronHLM
+from .olmo_hybrid import OlmoHybridLM
 from .resnet import RESNET_CONFIGS, BasicBlock, Bottleneck, ResNet
 from .solar_open2 import SolarOpen2LM
 from .transformer_lm import TransformerLM
@@ -45,6 +40,7 @@ __all__ = [
     "model_class",
     "DeepseekV2LM",
     "NemotronHLM",
+    "OlmoHybridLM",
     "SolarOpen2LM",
     "ResNet",
     "BasicBlock",
@@ -58,8 +54,20 @@ _CANONICAL.update({name.lower(): name for name in VIT_CONFIGS})
 # the language-model families: name -> class (the class takes
 # ``vocab_size=num_classes`` and the ``model:`` section's keys verbatim)
 _LM_FAMILIES = {
-    "TransformerLM": TransformerLM, "DeepseekV2": DeepseekV2LM,
-    "SolarOpen2": SolarOpen2LM, "NemotronH": NemotronHLM,
+    # the decoder-only long-context / sequence-parallel model; trained too
+    "TransformerLM": TransformerLM,
+    # latent attention, dropless experts
+    "DeepseekV2": DeepseekV2LM,
+    # KDA linear layers that carry a state a sequence, one grouped-query
+    # layer without positions in four, dropless experts in every layer
+    "SolarOpen2": SolarOpen2LM,
+    # ONE mixer a layer by a pattern: Mamba-2 layers that carry a second
+    # kind of state, latent experts, grouped-query attention of 2 K/V heads
+    "NemotronH": NemotronHLM,
+    # dense, post-norm: Gated DeltaNet layers (one decay a head, a
+    # rectangular state: the third kind) three to one with full attention
+    # under a QK-norm over the whole projection
+    "OlmoHybrid": OlmoHybridLM,
 }
 _CANONICAL.update({name.lower(): name for name in _LM_FAMILIES})
 
@@ -98,7 +106,7 @@ def get_model(
         section here (e.g. ``embed_dim/depth/num_heads/max_len/seq_axis``
         for ``TransformerLM``).
 
-    For a language model (``TransformerLM``, ``DeepseekV2``, ``SolarOpen2``, ``NemotronH``) the reference's
+    For a language model (a name in ``_LM_FAMILIES``) the reference's
     ``num_classes`` slot is the vocabulary size (``dataset.n_classes`` in the
     config).
     """

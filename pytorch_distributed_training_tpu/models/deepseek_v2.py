@@ -36,34 +36,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.mla import MLAttention
-from ..ops.moe import in_token_chunks, swiglu
 from .lm_parts import (
-    RMSNorm, add_moe_counts, expert_ffn, final_logits, sow_moe_stats,
+    GatedMLP, RMSNorm, add_moe_counts, expert_ffn, final_logits, sow_moe_stats,
 )
 
 __all__ = ["DeepseekV2LM"]
-
-
-class GatedMLP(nn.Module):
-    """``W_down(silu(W_gate x) * W_up x)``, gate and up side by side in one
-    tensor (the gate first); long calls run in pieces of ``token_chunk``."""
-
-    hidden: int
-    dtype: Any = jnp.float32
-    token_chunk: int = 8192
-
-    @nn.compact
-    def __call__(self, x):
-        dim = x.shape[-1]
-        init = nn.initializers.lecun_normal()
-        gate_up = self.param("gate_up", init, (dim, 2 * self.hidden), self.dtype)
-        down = self.param("down", init, (self.hidden, dim), self.dtype)
-        n = x.shape[0]
-        if n <= self.token_chunk:
-            return swiglu(x, gate_up, down)
-        out = in_token_chunks(
-            lambda piece: swiglu(piece, gate_up, down), self.token_chunk, x)
-        return out.reshape(-1, dim)[:n]
 
 
 _LAYER_FIELDS = (

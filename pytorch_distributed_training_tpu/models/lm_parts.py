@@ -1,11 +1,12 @@
 """What the served decoder families have in common, in one place:
-:class:`RMSNorm`, the untied :class:`Head`, the expert layer built from a
+:class:`RMSNorm`, the untied :class:`Head`, the dense SwiGLU
+:class:`GatedMLP`, the expert layer built from a
 model's published keys (:func:`expert_ffn`), the step's expert counts
-(:func:`add_moe_counts`, :func:`sow_moe_stats`) and the last norm and projection over one column a
-row (:func:`final_logits`).  :mod:`.deepseek_v2` and :mod:`.solar_open2`
-import them from here; the functions are called inside a model's compact
-``__call__`` and name their submodules there, so a family's parameter tree
-does not know they exist.
+(:func:`add_moe_counts`, :func:`sow_moe_stats`) and the last norm and
+projection over one column a row (:func:`final_logits`).  The served
+families import them from here; the functions are called inside a model's
+compact ``__call__`` and name their submodules there, so a family's
+parameter tree does not know they exist.
 """
 from __future__ import annotations
 
@@ -16,10 +17,10 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.mla import rms_norm
-from ..ops.moe import DroplessMoE
+from ..ops.moe import DroplessMoE, in_token_chunks, swiglu
 
 __all__ = [
-    "Head", "RMSNorm", "add_moe_counts", "expert_ffn", "final_logits",
+    "GatedMLP", "Head", "RMSNorm", "add_moe_counts", "expert_ffn", "final_logits",
     "sow_moe_stats",
 ]
 
@@ -48,6 +49,28 @@ class Head(nn.Module):
             (x.shape[-1], self.vocab_size), self.dtype,
         )
         return jnp.dot(x, kernel, preferred_element_type=jnp.float32)
+
+
+class GatedMLP(nn.Module):
+    """``W_down(silu(W_gate x) * W_up x)``, gate and up side by side in one
+    tensor (the gate first); long calls run in pieces of ``token_chunk``."""
+
+    hidden: int
+    dtype: Any = jnp.float32
+    token_chunk: int = 8192
+
+    @nn.compact
+    def __call__(self, x):
+        dim = x.shape[-1]
+        init = nn.initializers.lecun_normal()
+        gate_up = self.param("gate_up", init, (dim, 2 * self.hidden), self.dtype)
+        down = self.param("down", init, (self.hidden, dim), self.dtype)
+        n = x.shape[0]
+        if n <= self.token_chunk:
+            return swiglu(x, gate_up, down)
+        out = in_token_chunks(
+            lambda piece: swiglu(piece, gate_up, down), self.token_chunk, x)
+        return out.reshape(-1, dim)[:n]
 
 
 def expert_ffn(c, flat, token_mask):

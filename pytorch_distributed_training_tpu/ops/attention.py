@@ -41,7 +41,7 @@ from ..utils.vma import varying_axes_of
 
 __all__ = [
     "GroupedQueryAttention", "MultiHeadAttention", "dot_product_attention",
-    "is_state_leaf", "paged_attention", "pool_leaf_role",
+    "is_state_leaf", "paged_attention", "pool_leaf_role", "rms_norm",
 ]
 
 # The paged pool's leaves, under the names the attention modules give them.
@@ -52,14 +52,18 @@ KEY_POOL, VALUE_POOL, LATENT_POOL = "k_pool", "v_pool", "latent_pool"
 _POOL_ROLES = {KEY_POOL: "scored", VALUE_POOL: "value", LATENT_POOL: "scored"}
 # The cache tree's OTHER kind of leaf: ``[slots, ...]``, one entry a sequence
 # (:mod:`..ops.kda`: the delta-rule state and the convolution's last rows;
-# :mod:`..ops.mamba2`: the state-space state and its convolution's rows),
+# :mod:`..ops.mamba2`: the state-space state and its convolution's rows;
+# :mod:`..ops.gated_delta`: the scalar-decay delta rule's rectangular state
+# and its convolution's rows),
 # addressed by ``state_rows`` and never through a block table.  They have no
 # role among the pool's rows, whatever their leading size.
 KDA_STATE, KDA_CONV = "kda_state", "kda_conv"
 MAMBA_STATE, MAMBA_CONV = "mamba_state", "mamba_conv"
+GDN_STATE, GDN_CONV = "gdn_state", "gdn_conv"
 # query rows of one batch row whose scores the grouped path builds at once
 QUERY_BLOCK = 512
-STATE_LEAVES = (KDA_STATE, KDA_CONV, MAMBA_STATE, MAMBA_CONV)
+STATE_LEAVES = (
+    KDA_STATE, KDA_CONV, MAMBA_STATE, MAMBA_CONV, GDN_STATE, GDN_CONV)
 
 
 def _leaf_name(path) -> str:
@@ -431,6 +435,17 @@ class MultiHeadAttention(nn.Module):
         )
 
 
+def _stored_heads(kv_heads: int) -> int:
+    """K/V heads a row of the pool holds for ``kv_heads`` that are used.  A
+    row's heads are the sublanes of the device's tiles: past one tile of 8
+    they come in whole tiles (the device pads a ``[.., 30, 128]`` leaf to 32
+    heads in memory whatever its shape says, and Mosaic refuses to copy a
+    block out of it: "slice shape must be aligned to tiling (8), but is
+    30"), so the pool is declared with what it takes up.  Up to 8 heads, and
+    any multiple of 8, are stored as they are."""
+    return kv_heads if kv_heads <= 8 else -(-kv_heads // 8) * 8
+
+
 def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
                     num_blocks, dtype, as_stored, query_block=0):
     """Block-table gather attention against the shared paged KV pool, for
@@ -492,20 +507,28 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
         raise ValueError(
             f"{num_heads} query heads are no multiple of {kv_heads} K/V heads")
     pool_rows = nb * bs
+    # a row of the pool holds whole sublane tiles of heads: the heads that
+    # fill the last tile stay zeros, are never written and never scored
+    stored = _stored_heads(kv_heads)
     k_pool = module.variable(
-        "cache", KEY_POOL, jnp.zeros, (pool_rows, kv_heads, head_dim), dtype,
+        "cache", KEY_POOL, jnp.zeros, (pool_rows, stored, head_dim), dtype,
     )
     v_pool = module.variable(
-        "cache", VALUE_POOL, jnp.zeros, (pool_rows, kv_heads, head_dim), dtype,
+        "cache", VALUE_POOL, jnp.zeros, (pool_rows, stored, head_dim), dtype,
     )
     valid = positions >= 0  # [B, S]
     safe_pos = jnp.maximum(positions, 0)
     blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)  # [B, S]
     phys = jnp.where(valid, blk * bs + safe_pos % bs, pool_rows)  # OOB=drop
-    kp = k_pool.value.at[phys.reshape(-1)].set(
+
+    def written(pool):  # the call's rows of a leaf, the heads in use
+        rows = phys.reshape(-1)
+        return pool.at[rows] if stored == kv_heads else pool.at[rows, :kv_heads]
+
+    kp = written(k_pool.value).set(
         k.astype(dtype).reshape(b * s, kv_heads, head_dim), mode="drop"
     )
-    vp = v_pool.value.at[phys.reshape(-1)].set(
+    vp = written(v_pool.value).set(
         v.astype(dtype).reshape(b * s, kv_heads, head_dim), mode="drop"
     )
     k_pool.value, v_pool.value = kp, vp
@@ -513,17 +536,21 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
     from . import paged_decode
     from .flash_attention import flash_enabled
 
-    if s == 1 and flash_enabled() and paged_decode.fits(head_dim, kv_heads, dtype):
+    if s == 1 and flash_enabled() and paged_decode.fits(head_dim, stored, dtype):
         # one position a row, on a TPU: the kernel reads the pool where it
         # lies, each row's live blocks and no others.  A padding row
         # (position -1) reads key 0 of its table's first block, as below.
+        heads = q.reshape(b, kv_heads, group, head_dim)
+        if stored != kv_heads:  # zero queries for the tile's spare heads
+            heads = jnp.pad(
+                heads, ((0, 0), (0, stored - kv_heads), (0, 0), (0, 0)))
         out = paged_decode.paged_decode(
-            q.reshape(b, kv_heads, group, head_dim),
-            kp.reshape(nb, bs, kv_heads, head_dim),
-            vp.reshape(nb, bs, kv_heads, head_dim),
+            heads,
+            kp.reshape(nb, bs, stored, head_dim),
+            vp.reshape(nb, bs, stored, head_dim),
             block_tables, safe_pos[:, 0] + 1, scale=scale,
         )
-        return out.reshape(b, 1, num_heads, head_dim)
+        return out[:, :kv_heads].reshape(b, 1, num_heads, head_dim)
     t_blocks = block_tables.shape[1]
     length = t_blocks * bs
     # [B, L] physical rows in logical-position order (the row-at-a-time gather)
@@ -532,15 +559,18 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
         + jnp.arange(bs, dtype=jnp.int32)[None, None, :]
     ).reshape(b, length)
 
+    def used(gathered):  # without the heads that only fill the last tile
+        return gathered if stored == kv_heads else gathered[:, :, :kv_heads]
+
     def gather(pool, tables):
         """A batch's rows in logical order, ``[b, L, Hkv, hd]``.  Taken as
         stored they are gathered a BLOCK at a time (the ``[blocks, bs, ...]``
         view is a bitcast; a block is ``bs`` rows in one piece, where a row
         at a time ran at a tenth of the memory's rate, PERF.md PR 26)."""
         if not as_stored:
-            return pool[rows]
-        blocks = pool.reshape(nb, bs, kv_heads, head_dim)[tables]
-        return blocks.reshape(tables.shape[0], length, kv_heads, head_dim)
+            return used(pool[rows])
+        blocks = pool.reshape(nb, bs, stored, head_dim)[tables]
+        return used(blocks.reshape(tables.shape[0], length, stored, head_dim))
 
     wide = (lambda x: x) if as_stored else (lambda x: x.astype(jnp.float32))
     accumulate = jnp.float32 if as_stored else None
@@ -603,25 +633,40 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
     return out.astype(q.dtype)
 
 
+def rms_norm(x, weight, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * w``, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
 class GroupedQueryAttention(nn.Module):
     """Causal softmax attention with fewer K/V heads than query heads and NO
     position term (NoPE): ``q = x W_q`` (``H`` heads), ``k, v = x W_k, x W_v``
     (``Hkv`` heads), query head ``h`` reads K/V head ``h // (H / Hkv)``,
     scores ``q.k / sqrt(hd)``, softmax in float32, then with ``gate`` the
     output gate ``o * sigmoid(x W_gate)`` (element-wise, arXiv:2505.06708)
-    before ``W_o``.  No bias.
+    before ``W_o``.  No bias.  ``qk_norm`` (off by default: the programs of
+    the families that leave it out are what they were): an RMSNorm with a
+    learned weight over the WHOLE projection of ``q`` and of ``k``, before
+    the heads are split (Olmo 2's QK-norm, arXiv:2501.00656).
 
     ``decode=False``: plain causal attention over the call's own tokens.
     ``decode=True, paged=True``: K/V rows of ``Hkv`` heads in the paged pool
     (:func:`paged_attention`: a decode step through the paged kernel, a
     prefill's rows gathered as stored and a long call's scores built
-    ``QUERY_BLOCK`` query rows at a time), under the scope
+    ``query_block`` query rows at a time), under the scope
     ``gqa_attention``."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
     gate: bool = True
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    # a paged call longer than this builds its scores this many query rows
+    # of one batch row at a time
+    query_block: int = QUERY_BLOCK
     dtype: Any = jnp.float32
     decode: bool = False
     paged: bool = False
@@ -638,8 +683,15 @@ class GroupedQueryAttention(nn.Module):
         wv = self.param("wv", init, (dim, hkv * hd), self.dtype)
         wo = self.param("wo", init, (h * hd, dim), self.dtype)
         with jax.named_scope("gqa_attention"):
-            q = jnp.dot(x, wq).reshape(b, s, h, hd)
-            k = jnp.dot(x, wk).reshape(b, s, hkv, hd)
+            def whole(a, name):  # the norm over all of a projection's heads
+                if not self.qk_norm:
+                    return a
+                weight = self.param(
+                    name, nn.initializers.ones, (a.shape[-1],), self.dtype)
+                return rms_norm(a, weight, self.qk_norm_eps)
+
+            q = whole(jnp.dot(x, wq), "q_norm").reshape(b, s, h, hd)
+            k = whole(jnp.dot(x, wk), "k_norm").reshape(b, s, hkv, hd)
             v = jnp.dot(x, wv).reshape(b, s, hkv, hd)
             if self.decode and not self.paged:
                 raise ValueError(
@@ -650,7 +702,7 @@ class GroupedQueryAttention(nn.Module):
                     self, q, k, v, positions, block_tables,
                     block_size=self.kv_block_size,
                     num_blocks=self.kv_num_blocks, dtype=self.dtype,
-                    as_stored=True, query_block=QUERY_BLOCK,
+                    as_stored=True, query_block=self.query_block,
                 )
             else:
                 group = h // hkv

@@ -21,6 +21,11 @@ and products against the chunk's start state, between chunks the state is
 carried by ``lax.scan``.  With ``A_i`` the running sum of the log decays
 inside the chunk, every decay is formed as ``exp(A_i - A_j)`` with
 ``i >= j`` (an exponent <= 0, float32), never as a quotient of ``alpha``s.
+Shared with :class:`..ops.gated_delta.GatedDeltaNet` (one decay a head, a
+rectangular state): :func:`delta_rule_step`, which takes ``d_k`` and ``d_v``
+apart and any decay that broadcasts over the keys, and ``_a_log_init``; not
+:func:`delta_rule_chunked`, whose decay a channel stays inside the
+contractions (the scalar form is ``gated_delta.delta_rule_chunked_scalar``).
 Plain XLA, no Pallas kernel: the recurrence is three layers of four here
 and a kernel is a ``perf_opt`` PR's to bring, measured by
 ``kda_scan_roofline_pct`` (PERF.md).

@@ -36,16 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from .attention import LATENT_POOL
+from .attention import LATENT_POOL, rms_norm
 
 __all__ = ["MLAttention", "rms_norm", "yarn_inv_freq", "yarn_mscale"]
-
-
-def rms_norm(x, weight, eps: float):
-    """``x * rsqrt(mean(x^2) + eps) * w``, statistics in float32."""
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
 def yarn_mscale(scale: float, mscale: float) -> float:

@@ -147,6 +147,13 @@ CASES = {
         _paged_decode, _decode_call(32, 16, 4096, 96, kv_heads=2), BF16,
         ["paged_decode"],
     ),
+    # olmo-hybrid-7b.serve.reason32: 32 slots x 192 blocks, 30 K/V heads
+    # stored as the 32 of four sublane tiles (the kernel is refused a block
+    # of 30: "slice shape must be aligned to tiling (8)")
+    "paged_decode_olmo_hybrid": (
+        _paged_decode, _decode_call(32, 1, 4608, 192, kv_heads=32), BF16,
+        ["paged_decode"],
+    ),
     "paged_decode_f32": (
         _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
     ),
@@ -265,10 +272,15 @@ def test_decode_step_reads_the_pool_where_it_lies(chip, monkeypatch):
 
 
 def _state_layers():
+    from pytorch_distributed_training_tpu.ops.gated_delta import GatedDeltaNet
     from pytorch_distributed_training_tpu.ops.kda import KimiDeltaAttention
     from pytorch_distributed_training_tpu.ops.mamba2 import Mamba2Mixer
 
     return {
+        # olmo-hybrid-7b.serve.reason32: a [30, 96, 192] state a slot
+        "gated_delta": GatedDeltaNet(
+            num_heads=30, key_dim=96, value_dim=192, dtype=BF16, decode=True,
+            state_slots=32),
         # solar-open2-250b.serve.long32: a [64, 128, 128] state a slot
         "kda": KimiDeltaAttention(
             num_heads=64, head_dim=128, dtype=BF16, decode=True, state_slots=32),
@@ -279,12 +291,13 @@ def _state_layers():
     }
 
 
-@pytest.mark.parametrize("family", ["kda", "mamba2"])
+@pytest.mark.parametrize("family", ["kda", "mamba2", "gated_delta"])
 def test_the_state_s_decode_step_walks_its_leaf_in_place(chip, family):
     """One state-carrying layer at its published widths, 32 slots, the
     aligned decode step with its cache donated: the compiled program writes
     both leaves where they lie (all their bytes aliased), holds no copy of
-    the 134 MB state leaf, and its temporaries are a twentieth of it: both
+    the 134 MB (Gated DeltaNet: 71 MB) state leaf, and its temporaries are a
+    twentieth of it: both
     ``while``s of ``ops/state_rows.py`` (the walk of the live rows, the one
     dense trip past half the slots live) carry the leaf and write into it."""
     import re
@@ -309,9 +322,12 @@ def test_the_state_s_decode_step_walks_its_leaf_in_place(chip, family):
         (shapes["params"], shapes["cache"], x, pos, rows))
     compiled = jax.jit(step, donate_argnums=1).lower(*args).compile()
     nbytes = lambda s: int(np.prod(s.shape)) * s.dtype.itemsize  # noqa: E731
+    # as the device lays a leaf out: its last axis in whole tiles of 128
+    # lanes (the 192 of a Gated DeltaNet state take 256: a third more)
+    laid_out = lambda s: nbytes(s) // s.shape[-1] * (-(-s.shape[-1] // 128) * 128)  # noqa: E731
     leaves = jax.tree.leaves(shapes["cache"])
     account = compiled.memory_analysis()
-    assert account.alias_size_in_bytes == sum(map(nbytes, leaves))
+    assert account.alias_size_in_bytes == sum(map(laid_out, leaves))
     state = max(leaves, key=nbytes)
     assert state.dtype == jnp.float32 and state.shape[0] == slots
     assert account.temp_size_in_bytes < nbytes(state) // 20
