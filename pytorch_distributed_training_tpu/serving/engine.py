@@ -38,6 +38,7 @@ from ..ops.attention import is_state_leaf
 from .decode import build_generate_fn
 from .lora import LoraRegistry
 from .metrics import ServingMetrics
+from .prefill_plan import LinearCost
 from .scheduler import ContinuousScheduler
 from .speculative import SpeculativeSpec
 
@@ -590,6 +591,51 @@ class InferenceEngine:
                 aliased = n if aliased is None else min(aliased, n)
         return aliased
 
+    def _fit_prefill_cost(self, sched, grid) -> None:
+        """Tell the scheduler what a prefill call costs HERE, from a second,
+        timed run of two programs the warm-up has just compiled: the
+        smallest of the grid and its neighbour along the sequence buckets
+        (the longest; along the batch buckets where there is one sequence
+        bucket).  Each is timed as a tick runs it, from the dispatch to the
+        read of its token, and the line through the two points is the
+        estimate by which the scheduler groups a tick's admissions into
+        calls (``ContinuousScheduler.set_prefill_cost``).  The timed inputs
+        are a full call's, not the warm-up's padding: token ids drawn over
+        the vocabulary at LIVE positions, because an expert layer leaves a
+        padding position's pairs out of its grouped products and a call of
+        all padding then runs a fraction of a real one's (PERF.md, PR 44:
+        9.0 ms for 21.2).  The pool's contents still do not change: every
+        block-table entry is one past the pool (each scatter drops) and
+        every row's state slot is -1.  A grid of one program has nothing
+        to choose between and is not timed."""
+        import time
+
+        bb, sb = sched.batch_buckets, sched.seq_buckets
+        if len(sb) > 1:
+            pair = [(bb[0], sb[0]), (bb[0], sb[-1])]
+        elif len(bb) > 1:
+            pair = [(bb[0], sb[0]), (bb[1], sb[0])]
+        else:
+            return
+        points = []
+        rng = np.random.default_rng(0)
+        for rows, length in pair:
+            # (tokens, positions, tables, last_col, *the padding call's rest)
+            fn, head, (_, _, tables, _, *rest), _ = grid[rows, length]
+            full = (
+                rng.integers(
+                    0, self.model.vocab_size, (rows, length), dtype=np.int32
+                ),
+                np.tile(np.arange(length, dtype=np.int32), (rows, 1)),
+                np.full_like(tables, sched._kv.num_blocks),
+                np.full((rows,), length - 1, np.int32),
+            )
+            t0 = time.perf_counter()
+            tok, finite, sched._pool = fn(*head, sched._pool, *full, *rest)
+            np.asarray(tok), np.asarray(finite)
+            points.append((rows * length, (time.perf_counter() - t0) * 1e3))
+        sched.set_prefill_cost(*LinearCost.through(*points))
+
     def _warmup_scheduler(self) -> Optional[int]:
         """Returns ``pool_aliased_bytes`` (also set as the gauge of that
         name): see :meth:`_warm_pool_programs`."""
@@ -610,17 +656,18 @@ class InferenceEngine:
             return sched._state_rows(np.full((n,), -1, np.int32))
 
         def prefills(fns, params):
-            for bb in sched.batch_buckets:
-                bkeys = sched._pad_keys(bb)
-                for sb in sched.seq_buckets:
-                    yield (fns.prefill, (params,), (
-                        np.zeros((bb, sb), np.int32),
-                        np.full((bb, sb), -1, np.int32),
-                        np.zeros((bb, T), np.int32),
-                        np.zeros((bb,), np.int32), bkeys,
-                        np.zeros((bb,), np.int32),
-                        np.full((bb,), -1, np.int32), *no_slot(bb),
-                    ), 2)
+            # {(batch bucket, sequence bucket): the program's call}
+            return {
+                (bb, sb): (fns.prefill, (params,), (
+                    np.zeros((bb, sb), np.int32),
+                    np.full((bb, sb), -1, np.int32),
+                    np.zeros((bb, T), np.int32),
+                    np.zeros((bb,), np.int32), sched._pad_keys(bb),
+                    np.zeros((bb,), np.int32),
+                    np.full((bb,), -1, np.int32), *no_slot(bb),
+                ), 2)
+                for bb in sched.batch_buckets for sb in sched.seq_buckets
+            }
 
         def decode(fns, params):
             # ONE decode program a model, whichever body calls it:
@@ -634,7 +681,8 @@ class InferenceEngine:
 
         fns = sched._fns
         dparams = sched._qparams if sched._quant else sched.params
-        calls = [*prefills(fns, sched.params), decode(fns, dparams)]
+        grid = prefills(fns, sched.params)
+        calls = [*grid.values(), decode(fns, dparams)]
         if sched._spec is not None:
             # the speculative round's extra programs on the target side:
             # the verify scorer and the fork's row copy
@@ -647,11 +695,12 @@ class InferenceEngine:
             ), 1))
             calls.append((fns.copy_rows, (), (oob, oob), None))
         aliased = self._warm_pool_programs(calls, sched, "_pool")
+        self._fit_prefill_cost(sched, grid)
         if sched._spec is not None:
             # ... and the draft model's own prefill/decode set over its pool
             dfns, dparams = sched._draft_fns, sched._draft_params
             self._warm_pool_programs(
-                [*prefills(dfns, dparams), decode(dfns, dparams)],
+                [*prefills(dfns, dparams).values(), decode(dfns, dparams)],
                 sched, "_draft_pool",
             )
         if aliased is not None:

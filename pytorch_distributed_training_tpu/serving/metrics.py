@@ -390,6 +390,14 @@ class ServingMetrics:
         self._registry.gauge("kv_pool_bytes").set(float(kv_pool_bytes))
         self._registry.gauge("state_cache_bytes").set(float(state_cache_bytes))
 
+    def record_prefill_cost(self, fixed_ms: float, ms_per_ktoken: float) -> None:
+        """What the warm-up's timed calls put a prefill call at: ``fixed_ms
+        + ms_per_ktoken x batch bucket x sequence bucket / 1000``, the
+        estimate by which the scheduler groups a tick's fresh admissions
+        into calls."""
+        self._registry.gauge("prefill_call_fixed_ms").set(float(fixed_ms))
+        self._registry.gauge("prefill_call_ms_per_ktoken").set(float(ms_per_ktoken))
+
     def record_kv_transfer(
         self, *, nbytes: int, seconds: float, blocks: int
     ) -> None:
@@ -606,7 +614,7 @@ _AGG_MAX = (
     "block_util_max", "kv_transfer_ms_p50", "kv_transfer_ms_p99",
     "tick_host_ms_p50", "tick_host_ms_p99",
     "decode_dispatch_gap_ms_p50", "decode_dispatch_gap_ms_p99",
-    "scale_up_ready_ms",
+    "scale_up_ready_ms", "prefill_call_fixed_ms", "prefill_call_ms_per_ktoken",
     "queue_wait_ms_p95", "ttft_ms_p95", "itl_ms_p95", "prefill_stall_ms_p95",
 )
 

@@ -71,7 +71,9 @@ program of ``serving/decode.py``; the sync callers hand it a mask of all
 rows.  See :meth:`ContinuousScheduler._decode_step_async`.
 
 Where a tick's time goes (PR 24): every tick is a ``tick`` span whose
-children are its phases — ``admit``, ``prefill``, ``decode_prep``,
+children are its phases — ``admit``, ``prefill`` (one a CALL: a tick's fresh
+admissions run as the calls on the compiled grid whose summed estimated time
+is least, :meth:`ContinuousScheduler._prefill_calls`), ``decode_prep``,
 ``decode_step`` (the dispatch; telemetry/slo.py pairs recoveries with this
 kind), ``readback`` (blocked on the sampled tokens), ``deliver`` (push,
 ``on_token``, retire) — the same kinds from the sync, async-ring and
@@ -103,7 +105,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +122,7 @@ from .batcher import OverloadedError
 from .decode import build_paged_fns
 from .kv_pool import PagedKVPool
 from .metrics import ServingMetrics
+from .prefill_plan import LinearCost, bucket_for, plan_calls
 from .resilience import HungTickError, PoisonedRequestError, ServingSupervisor
 from .speculative import greedy_accept
 
@@ -182,6 +185,22 @@ class _PagedRequest:
     def gen_idx(self) -> int:
         """Generated-token count so far == index of the NEXT token."""
         return len(self.tokens)
+
+
+class _PrefillCall(NamedTuple):
+    """One call of the target's prefill program in a tick: its rows and the
+    point of the compiled grid it runs at."""
+
+    reqs: List["_PagedRequest"]
+    suffix: List[int]  # tokens past each row's cached prefix: what is fed
+    batch_bucket: int
+    seq_bucket: int
+    replay: bool = False
+
+    @property
+    def padded_tokens(self) -> int:
+        """Tokens the call runs, padding of rows and positions included."""
+        return self.batch_bucket * self.seq_bucket
 
 
 class _Phase:
@@ -486,6 +505,10 @@ class ContinuousScheduler:
         # (tick_no, perf_counter) of the latest decode dispatch
         self._last_dispatch: Optional[tuple] = None  # confined: _loop
         self._tick_block_s = 0.0  # confined: _loop
+        # what a prefill call is estimated to cost (set_prefill_cost, from
+        # the warm-up's thread); None = never measured: a tick's fresh
+        # admissions are one call
+        self._prefill_cost: Optional[LinearCost] = None  # guarded by: self._cond
 
         res = dict(resilience or {})
         wd = dict(res.pop("watchdog", None) or {})
@@ -1120,19 +1143,10 @@ class ContinuousScheduler:
             newly = self._admit()
         self._tick_phase = "prefill"
         if newly:
-            # the rows already decoding sit through this prefill: each of
-            # their next gaps has it inside
+            # the rows already decoding sit through this tick's prefills:
+            # each of their next gaps has them inside
             decoding = self.active() - len(newly)
-            suffix = [r.prompt.size - r.admission.cached_len for r in newly]
-            with self._phase(
-                "prefill", rows=len(newly), tokens=sum(suffix),
-                bucket=self._bucket_for(
-                    max(suffix), self.seq_buckets, "prefill suffix"
-                ),
-                reqs=[r.rid for r in newly],
-                stalled=decoding, padded_tokens=self._padded_tokens(newly),
-            ):
-                self._prefill(newly)
+            self._prefill(newly, decoding)
             if decoding > 0:
                 # what the rows already decoding waited for their next
                 # token while this tick's admissions were prefilled
@@ -1332,8 +1346,9 @@ class ContinuousScheduler:
         with self._cond:
             self._sweep_expired_locked()
             free = [i for i, s in enumerate(self._slots) if s is None]
-            # one prefill call per tick: cap admissions at the largest
-            # batch bucket so the call stays on the compiled grid
+            # cap a tick's admissions at the largest batch bucket: whether
+            # they run as one call or as several (_prefill_calls), every
+            # call stays on the compiled grid
             max_admit = min(len(free), self.batch_buckets[-1])
             while self._queue and len(newly) < max_admit:
                 req = self._queue[0]
@@ -1378,12 +1393,6 @@ class ContinuousScheduler:
                     self._bump("prefix_miss_blocks", cacheable - adm.n_shared)
         return newly
 
-    def _bucket_for(self, n: int, buckets: Sequence[int], kind: str) -> int:
-        for b in buckets:
-            if n <= b:
-                return b
-        raise ValueError(f"{kind} {n} exceeds largest bucket {buckets[-1]}")
-
     def _table_ids(self, req: _PagedRequest) -> List[int]:
         """The request's LOGICAL block table: the admission's footprint
         blocks in order.  In speculative mode the admission carries one
@@ -1396,58 +1405,106 @@ class ContinuousScheduler:
             return ids[: len(ids) - self._extra_blocks]
         return ids
 
-    def _padded_tokens(self, newly: List[_PagedRequest]) -> int:
-        """Tokens the target's prefill calls of this tick run, padding
-        included: ``_prefill`` makes ONE bucketed call over the fresh
-        admissions and one over the replayed, each padded to its own batch
-        bucket x its own sequence bucket."""
-        total = 0
-        for group in (
-            [r for r in newly if not r.tokens], [r for r in newly if r.tokens]
-        ):
-            if group:
-                longest = max(
-                    r.prompt.size - r.admission.cached_len for r in group
-                )
-                total += self._bucket_for(
-                    len(group), self.batch_buckets, "admitted rows"
-                ) * self._bucket_for(
-                    longest, self.seq_buckets, "prefill suffix"
-                )
-        return total
+    def _prefill_calls(self, newly: List[_PagedRequest]) -> List[_PrefillCall]:
+        """The target's prefill calls of this tick, each at ITS batch bucket
+        x ITS sequence bucket: computed once, and the one source of what the
+        calls run, of their spans' fields and of their padded tokens.
 
-    def _prefill(self, newly: List[_PagedRequest]) -> None:
-        """Prefill every request admitted this tick.
-
-        Fresh requests (no tokens yet) go through one bucketed batch
-        call; requests re-admitted by a hot-restart carry their delivered
-        token stream and take the replay path instead.
+        The fresh admissions run as the partition that ``plan_calls`` finds
+        cheapest under the engine's own estimate of a call's time
+        (:meth:`set_prefill_cost`), in the order of their earliest arrival;
+        with no estimate, as ONE call.  The rows a hot-restart re-admitted
+        replay in one call of their own, last.
         """
-        replay = [r for r in newly if r.tokens]
+        def call(reqs, replay=False):
+            suffix = [r.prompt.size - r.admission.cached_len for r in reqs]
+            return _PrefillCall(
+                reqs, suffix,
+                bucket_for(len(reqs), self.batch_buckets, "admitted rows"),
+                bucket_for(max(suffix), self.seq_buckets, "prefill suffix"),
+                replay,
+            )
+
         fresh = [r for r in newly if not r.tokens]
-        if fresh:
-            self._prefill_fresh(fresh)
-        if replay:
-            self._replay(replay)
+        replay = [r for r in newly if r.tokens]
+        with self._cond:
+            cost = self._prefill_cost
+        if len(fresh) > 1 and cost is not None:
+            suffix = [r.prompt.size - r.admission.cached_len for r in fresh]
+            calls = [
+                call([fresh[i] for i in rows])
+                for rows in plan_calls(
+                    suffix, self.batch_buckets, self.seq_buckets, cost
+                )
+            ]
+        else:
+            calls = [call(fresh)] if fresh else []
+        return calls + ([call(replay, replay=True)] if replay else [])
+
+    def set_prefill_cost(self, fixed_ms: float, ms_per_ktoken: float) -> None:
+        """What this engine observed a prefill call to cost, ``fixed_ms +
+        ms_per_ktoken x batch bucket x sequence bucket / 1000`` (the
+        warm-up's timed calls: ``serving/engine.py``).  From here on a
+        tick's fresh admissions run as the calls whose summed estimate is
+        least (:meth:`_prefill_calls`); a scheduler that was never told
+        keeps one call a tick."""
+        cost = LinearCost(float(fixed_ms), float(ms_per_ktoken))
+        with self._cond:
+            self._prefill_cost = cost
+        self.metrics.record_prefill_cost(*cost)
+
+    def _prefill(self, newly: List[_PagedRequest], decoding: int) -> None:
+        """Prefill every request admitted this tick, one ``prefill`` phase
+        span a call.
+
+        Fresh requests (no tokens yet) go through the bucketed calls of
+        :meth:`_prefill_calls`; requests re-admitted by a hot-restart carry
+        their delivered token stream and take the replay path instead.  A
+        span's ``rows``, ``tokens``, ``bucket``, ``reqs`` and
+        ``padded_tokens`` are its call's own; ``stalled`` is the
+        ``decoding`` rows on the tick's first call and 0 on the rest (a
+        live row waits once a tick, through all of them).
+        """
+        calls = self._prefill_calls(newly)
+        if sum(not r.tokens for r in newly) > 1:
+            self._bump("prefill_multi_row_ticks")
+            if sum(not c.replay for c in calls) > 1:
+                self._bump("prefill_split_ticks")
+        for call in calls:
+            with self._phase(
+                "prefill", rows=len(call.reqs), tokens=sum(call.suffix),
+                bucket=call.seq_bucket, reqs=[r.rid for r in call.reqs],
+                stalled=decoding, padded_tokens=call.padded_tokens,
+            ):
+                if call.replay:
+                    self._replay(call)
+                else:
+                    self._prefill_fresh(call)
+            decoding = 0
+            self._bump("prefill_calls")
         if self._spec is not None:
             # the draft pool needs the prompt K/V too (its own programs,
             # its own blocks); requests evicted by the target prefill's
             # output guard have already released both reservations
             live = [r for r in newly if r.admission is not None]
             if live:
-                self._draft_prefill(live)
+                # a span of the tick's prefill time that is no target call:
+                # no ``bucket`` and no ``padded_tokens``
+                with self._phase(
+                    "prefill", rows=len(live), draft=True, stalled=0,
+                ):
+                    self._draft_prefill(live)
 
-    def _prefill_fresh(self, newly: List[_PagedRequest]) -> None:
-        """One bucketed prefill over the fresh admissions of this tick.
+    def _prefill_fresh(self, call: _PrefillCall) -> None:
+        """One bucketed prefill call over fresh admissions of this tick: its
+        dispatch, the read of its tokens, and their push.
 
         Prefix-cache hits shorten the device work directly: only the
         SUFFIX past ``cached_len`` is fed (positions ``cached_len ..
-        prompt_len-1``), padded up to a seq bucket.
+        prompt_len-1``), padded up to the call's seq bucket.
         """
         t0 = time.perf_counter()
-        suffix = [r.prompt.size - r.admission.cached_len for r in newly]
-        bb = self._bucket_for(len(newly), self.batch_buckets, "admitted rows")
-        sb = self._bucket_for(max(suffix), self.seq_buckets, "prefill suffix")
+        newly, suffix, bb, sb, _ = call
         tokens = np.zeros((bb, sb), np.int32)
         positions = np.full((bb, sb), -1, np.int32)
         tables = np.zeros((bb, self.table_blocks), np.int32)
@@ -1506,8 +1563,8 @@ class ContinuousScheduler:
         zeros, which can only depress the acceptance rate, never change
         the committed stream (every emitted token is the target's).
         """
-        bb = self._bucket_for(len(reqs), self.batch_buckets, "draft rows")
-        sb = self._bucket_for(
+        bb = bucket_for(len(reqs), self.batch_buckets, "draft rows")
+        sb = bucket_for(
             max(r.prompt.size for r in reqs), self.seq_buckets, "draft prompt"
         )
         tokens = np.zeros((bb, sb), np.int32)
@@ -1529,7 +1586,7 @@ class ContinuousScheduler:
             last_col, keys, np.zeros((bb,), np.int32), aids,
         )
 
-    def _replay(self, reqs: List[_PagedRequest]) -> None:
+    def _replay(self, call: _PrefillCall) -> None:
         """Rebuild restart-surviving requests' KV state bit-exactly.
 
         Prompt K/V comes back through the bucketed prefill (prefix-cache
@@ -1540,9 +1597,7 @@ class ContinuousScheduler:
         the stored stream — verified per token, never re-delivered
         (clients already hold these tokens; ``on_token`` does not refire).
         """
-        suffix = [r.prompt.size - r.admission.cached_len for r in reqs]
-        bb = self._bucket_for(len(reqs), self.batch_buckets, "replayed rows")
-        sb = self._bucket_for(max(suffix), self.seq_buckets, "replay suffix")
+        reqs, suffix, bb, sb, _ = call
         tokens = np.zeros((bb, sb), np.int32)
         positions = np.full((bb, sb), -1, np.int32)
         tables = np.zeros((bb, self.table_blocks), np.int32)

@@ -225,14 +225,21 @@ def serve(sched, prompt):
     return future.result()["tokens"]
 
 
+@pytest.mark.parametrize(
+    "estimate, calls",
+    [(None, [(2, 32)]), ((0.0, 35.0), [(1, 32), (1, 16)])],
+    ids=["one_padded_call", "a_call_a_row"],
+)
 def test_two_arrivals_in_one_tick_through_the_scheduler_are_the_reference_s_forward(
-        ref, weights, model):
+        ref, weights, model, estimate, calls):
     """A burst: two requests of unequal length waiting when the tick comes
-    are ONE padded prefill of 4 rows x 32 positions, each row's state taken
-    at its own last position; then decode steps side by side.  Every served
-    token is the reference's first choice over prompt + served tokens (its
-    full forward: no cache, no chunks), by a margin the tolerance cannot
-    close."""
+    are ONE padded prefill of 4 rows x 32 positions (a scheduler with no
+    estimate of a call's time) or a call a row at its own bucket (time
+    taken to follow the padded tokens), each row's state taken at its own
+    last position in its own slot; then decode steps side by side.  Every
+    served token is the reference's first choice over prompt + served
+    tokens (its full forward: no cache, no chunks), by a margin the
+    tolerance cannot close."""
     from pytorch_distributed_training_tpu.telemetry.spans import SpanRecorder, set_recorder
 
     _, params, tree = weights
@@ -240,6 +247,8 @@ def test_two_arrivals_in_one_tick_through_the_scheduler_are_the_reference_s_forw
     rec = set_recorder(SpanRecorder(ring=512))
     try:
         with scheduler(model, tree, slots=4, batch_buckets=[1, 4]) as sched:
+            if estimate is not None:
+                sched.set_prefill_cost(*estimate)
             futures = [sched.submit(p) for p in prompts]
             while not all(f.done() for f in futures):
                 sched.tick()
@@ -247,7 +256,7 @@ def test_two_arrivals_in_one_tick_through_the_scheduler_are_the_reference_s_forw
     finally:
         set_recorder(None)
     prefills = [s for s in rec.recent() if s["kind"] == "prefill"]
-    assert [(s["rows"], s["bucket"]) for s in prefills] == [(2, 32)]
+    assert [(s["rows"], s["bucket"]) for s in prefills] == calls
     assert snapshot["moe_experts_hit_count"] > 0
     for prompt, future in zip(prompts, futures):
         served = future.result()["tokens"]
@@ -257,6 +266,39 @@ def test_two_arrivals_in_one_tick_through_the_scheduler_are_the_reference_s_forw
         np.testing.assert_array_equal(rows.argmax(-1), served)
         best_two = np.sort(rows, axis=-1)[:, -2:]
         assert (best_two[:, 1] - best_two[:, 0]).min() > 10 * TOLERANCE
+
+
+def test_the_warm_up_s_timed_calls_leave_pool_and_state_as_they_were():
+    """The engine times two prefill programs on a FULL call's inputs (live
+    positions: an expert layer leaves a padding position out of its
+    products): block tables one past the pool and state slots of -1, so
+    no row of the pool and no slot of the state is written, and a request
+    served after the warm-up gets what it got before."""
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = {
+        "dataset": {"name": "synthetic_text", "n_classes": VOCAB},
+        "model": dict(MODEL_KEYS, name="NemotronH"),
+        "serving": {
+            "dtype": "float32", "max_batch_size": 8, "max_delay_ms": 2,
+            "batch_buckets": [8], "seq_buckets": [16, 32], "max_new_tokens": 6,
+            "temperature": 0.0,
+            "scheduler": {"enabled": True, "slots": 4, "block_size": BLOCK,
+                          "num_blocks": BLOCKS, "prefix_cache": False},
+        },
+    }
+    prompt = tokens_of(23, seed=3)
+    with InferenceEngine.from_config(cfg) as engine:
+        sched = engine.scheduler
+        before = engine.submit(prompt).result(timeout=120)["tokens"]
+        held = jax.device_get(jax.tree_util.tree_leaves(sched._pool))
+        assert sum(bool(leaf.any()) for leaf in held) >= 12  # rows AND states
+        engine.warmup()
+        assert sched._prefill_cost is not None
+        for old, new in zip(held, jax.tree_util.tree_leaves(sched._pool)):
+            np.testing.assert_array_equal(old, np.asarray(new))
+        after = engine.submit(prompt).result(timeout=120)["tokens"]
+        np.testing.assert_array_equal(after, before)
 
 
 def test_the_share_of_live_state_rows_is_observed_a_decode_step(weights, model):

@@ -1694,42 +1694,121 @@ def test_loop_idle_only_where_the_loop_sleeps(lm_and_params):
         assert not any(gap[0] <= s["t"] < gap[1] for s in idles), (a, b)
 
 
+# what a prefill call is taken to cost -> the (rows, batch bucket, sequence
+# bucket) of the calls that prefill a tick's admissions of 12 and 2 tokens
+# under batch_buckets [1, 4, 8] and seq_buckets [8, 16]
+_PREFILL_SPLITS = {
+    # no estimate (an engine that was not warmed): the one call, as before
+    "no_estimate": (None, [(2, 4, 16)]),
+    # a call is mostly its dispatch: the batch is kept
+    "batch_nearly_free": ((10.0, 0.01), [(2, 4, 16)]),
+    # time follows the padded tokens: a call a row, in the order of arrival
+    "by_padded_tokens": ((0.0, 35.0), [(1, 1, 16), (1, 1, 8)]),
+}
+
+
+@pytest.mark.parametrize("cost", sorted(_PREFILL_SPLITS))
 def test_prefill_span_carries_the_stalled_rows_and_the_padded_size(
-    lm_and_params,
+    lm_and_params, cost,
 ):
-    """Three rows decoding when two more are admitted: the tick's
-    ``prefill`` span says 3 rows sat through it and that its one call ran
-    at the batch bucket of 4 x the sequence bucket of 8; the first
-    prefill met no decoding row."""
+    """Three rows decoding when two more are admitted in one tick: one
+    ``prefill`` span a CALL, each with its own ``rows``, ``tokens``,
+    ``bucket``, ``reqs`` and ``padded_tokens`` = its batch bucket x its
+    sequence bucket; the 3 rows that sat through the tick's prefills are
+    ``stalled`` on its first call only; the first tick's prefills met no
+    decoding row.  However the calls are grouped, every request's greedy
+    tokens are those of the one-call path."""
     from pytorch_distributed_training_tpu.telemetry import (
         SpanRecorder,
         set_recorder,
     )
 
     model, params = lm_and_params
+    estimate, want = _PREFILL_SPLITS[cost]
     rng = np.random.default_rng(11)
     prompts = [rng.integers(2, VOCAB, n).astype(np.int32)
-               for n in (5, 3, 6, 4, 2)]
-    rec = set_recorder(SpanRecorder(ring=1024))
-    try:
-        sched = _paged_sched(
-            model, params, slots=8, num_blocks=48, batch_buckets=[1, 4, 8],
-            seq_buckets=[8, 16], eos_id=None,
-        )
+               for n in (5, 3, 6, 12, 2)]
+    buckets = dict(slots=8, num_blocks=48, batch_buckets=[1, 4, 8],
+                   seq_buckets=[8, 16], eos_id=None)
+
+    def serve(sched):
         futs = [sched.submit(p) for p in prompts[:3]]
         sched.tick()
         sched.tick()
         futs += [sched.submit(p) for p in prompts[3:]]
         _run_scheduler_to_done(sched, futs)
         sched.close()
+        return [f.result()["tokens"].tolist() for f in futs]
+
+    one_call = serve(_paged_sched(model, params, **buckets))
+    rec = set_recorder(SpanRecorder(ring=1024))
+    try:
+        sched = _paged_sched(model, params, **buckets)
+        if estimate is not None:
+            sched.set_prefill_cost(*estimate)
+        assert serve(sched) == one_call
     finally:
         set_recorder(None)
-    first, second = [s for s in rec.recent() if s["kind"] == "prefill"]
-    assert (first["rows"], first["stalled"]) == (3, 0)
-    assert first["tokens"] == 14 and first["padded_tokens"] == 4 * 8
-    assert (second["rows"], second["stalled"]) == (2, 3)
-    assert second["tokens"] == 6 and second["padded_tokens"] == 4 * 8
-    assert second["bucket"] == 8
+    spans = [s for s in rec.recent() if s["kind"] == "prefill"]
+    first = [s for s in spans if s["step"] == spans[0]["step"]]
+    second = [s for s in spans if s["step"] == spans[-1]["step"]]
+    assert len(first) + len(second) == len(spans)
+    assert all(s["stalled"] == 0 for s in first)
+    assert sum(s["rows"] for s in first) == 3
+    assert sum(s["tokens"] for s in first) == 14
+    assert [(s["rows"], s["padded_tokens"] // s["bucket"], s["bucket"])
+            for s in second] == want
+    assert [s["stalled"] for s in second] == [3] + [0] * (len(want) - 1)
+    assert sum(s["tokens"] for s in second) == 14
+    assert sorted(r for s in second for r in s["reqs"]) == [3, 4]
+    if len(want) == 2:
+        assert [s["tokens"] for s in second] == [12, 2]
+        assert [s["reqs"] for s in second] == [[3], [4]]
+        assert first == sorted(first, key=lambda s: s["reqs"])
+    snap = sched.metrics.snapshot()
+    assert snap["prefill_calls"] == len(spans)
+    assert snap["prefill_multi_row_ticks"] == 2
+    assert snap.get("prefill_split_ticks", 0) == (2 if len(want) == 2 else 0)
+    assert ("prefill_call_fixed_ms" in snap) == (estimate is not None)
+    if estimate is not None:
+        assert (snap["prefill_call_fixed_ms"],
+                snap["prefill_call_ms_per_ktoken"]) == estimate
+    # the rows decoding waited ONCE, through all of the tick's calls
+    assert snap["prefill_stall_ms_count"] == 1
+    assert snap["prefill_stall_ms_p50"] >= sum(
+        s["ms"] for s in second) - 0.01
+
+
+def test_draft_prefill_is_a_span_of_its_own_beside_the_target_calls(
+    lm_and_params, mode_prompts
+):
+    """Beside a speculative draft a tick's prefill time is the target's
+    calls, each a span with its ``bucket`` and ``padded_tokens``, and the
+    draft pool's one call: a ``prefill`` span that says ``draft``, stalls
+    no row again and carries neither (the benchmark's readers of prefill
+    tokens pass it by)."""
+    from pytorch_distributed_training_tpu.telemetry import (
+        SpanRecorder,
+        set_recorder,
+    )
+
+    model, params = lm_and_params
+    rec = set_recorder(SpanRecorder(ring=1024))
+    try:
+        sched = _paged_sched(model, params, batch_buckets=[1, 4],
+                             **_decode_bodies()["speculative"])
+        sched.set_prefill_cost(0.0, 35.0)
+        _sched_results(sched, mode_prompts)
+        sched.close()
+    finally:
+        set_recorder(None)
+    spans = [s for s in rec.recent() if s["kind"] == "prefill"]
+    assert len({s["step"] for s in spans}) == 1
+    target, draft = spans[:-1], spans[-1]
+    assert [s["rows"] for s in target] == [1, 1, 1]
+    assert all(s["padded_tokens"] == s["bucket"] == 8 for s in target)
+    assert (draft["draft"], draft["rows"], draft["stalled"]) == (True, 3, 0)
+    assert "bucket" not in draft and "padded_tokens" not in draft
 
 
 @pytest.mark.parametrize("body", ["sync", "async_ring", "speculative"])
@@ -2130,6 +2209,75 @@ def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
         assert names.count("prefill") >= 1 and names.count("decode_step") >= 3
         assert engine.compile_count() == n
         assert engine.scheduler._fns.decode_step._cache_size() == 1
+
+
+@pytest.mark.parametrize(
+    "batch_buckets, seq_buckets, timed",
+    [
+        # the smallest program and the longest of its row count
+        # (the engine rounds a batch bucket up to the devices it runs on)
+        ([8, 16], [8, 16], [(8, 8), (8, 16)]),
+        # one sequence bucket: the next batch bucket instead
+        ([8, 16], [8], [(8, 8), (16, 8)]),
+        # a grid of one program has nothing to choose between
+        ([8], [8], []),
+    ],
+    ids=["along_seq", "along_batch", "one_program"],
+)
+def test_warmup_times_two_prefill_programs_and_tells_the_scheduler(
+    batch_buckets, seq_buckets, timed
+):
+    """The estimate a tick's admissions are grouped by is the engine's own
+    observation: the warm-up runs two of its compiled prefill programs a
+    second time, timed, on a full call's inputs (ids over the vocabulary at
+    live positions), and hands the scheduler the line through them; an
+    engine that was not warmed has none.  The timed calls compile nothing,
+    write nothing into the pool and hand it back."""
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = _warm_engine_cfg()
+    cfg["serving"].update(batch_buckets=batch_buckets, seq_buckets=seq_buckets)
+    prompts = [np.asarray([4, 8, 15, 16, 23], np.int32),
+               np.asarray([42, 7], np.int32)]
+    with InferenceEngine.from_config(cfg) as engine:
+        sched = engine.scheduler
+        assert sched._prefill_cost is None
+        assert "prefill_call_fixed_ms" not in engine.metrics.snapshot()
+        before = [f.result(timeout=120)["tokens"]
+                  for f in [engine.submit(p) for p in prompts]]
+        calls = _spy_on(sched)
+        held = jax.device_get(jax.tree_util.tree_leaves(sched._pool))
+        assert any(leaf.any() for leaf in held)  # the requests' rows
+        engine.warmup()
+        ran = [args[2].shape for name, args in calls if name == "prefill"]
+        grid = [(b, s) for b in batch_buckets for s in seq_buckets]
+        assert ran == grid + timed
+        for _, args in [c for c in calls if c[0] == "prefill"][len(grid):]:
+            # a full call's work: ids over the vocabulary at live positions
+            # (an expert layer skips padding); no block of the pool is named
+            assert 0 <= args[2].min() and args[2].max() < VOCAB
+            assert len(np.unique(args[2])) > 8
+            assert (args[3] == np.arange(args[3].shape[1])).all()
+            assert (args[4] == sched._kv.num_blocks).all()
+            assert (args[5] == args[3].shape[1] - 1).all()
+        for old, new in zip(held, jax.tree_util.tree_leaves(sched._pool)):
+            np.testing.assert_array_equal(old, np.asarray(new))
+        snap = engine.metrics.snapshot()
+        if timed:
+            assert tuple(sched._prefill_cost) == (
+                snap["prefill_call_fixed_ms"], snap["prefill_call_ms_per_ktoken"])
+            assert min(sched._prefill_cost) >= 0.0
+        else:
+            assert sched._prefill_cost is None
+            assert "prefill_call_fixed_ms" not in snap
+        warm = engine.compile_count()
+        leaves = jax.tree_util.tree_leaves(sched._pool)
+        assert leaves and not any(leaf.is_deleted() for leaf in leaves)
+        after = [f.result(timeout=120)["tokens"]
+                 for f in [engine.submit(p) for p in prompts]]
+        for a, b in zip(after, before):
+            np.testing.assert_array_equal(a, b)
+        assert engine.compile_count() == warm
 
 
 def test_sampled_streams_are_those_of_stacked_device_keys(
