@@ -475,7 +475,13 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
       :func:`..ops.paged_decode.paged_decode` walks each row's block table
       up to the row's own length, K and V as stored, scores, softmax and
       accumulation in float32; ``as_stored`` and ``query_block`` have
-      nothing to say there.  No copy of the table's rows exists.
+      nothing to say there.  No copy of the table's rows exists.  The
+      LATENT leaf, whose rows have no head axis, has the same contract and
+      a kernel of its own, chosen the same way by
+      :class:`..ops.mla.MLAttention`:
+      :func:`..ops.mla_paged_decode.mla_paged_decode` (the absorbed form:
+      all heads against the one ``rank + rope`` row a position, the value
+      the row's first ``rank`` lanes).
     - ``S > 1`` (whole-prompt and chunked prefill, the speculative
       ``verify``), and any call off a TPU: the GATHER arms below, as they
       were: each row's FULL table is gathered into ``[B, L, Hkv, hd]`` and

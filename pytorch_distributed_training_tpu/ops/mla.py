@@ -19,15 +19,28 @@ Two forms compute the same function and read the same rows:
     the latent rows themselves and nothing of a head's width is ever built
     for a cached position.  One query a row (decode).
 
-The paged path is :meth:`..attention.MultiHeadAttention._paged_attention`'s
-contract over ONE pool leaf ``[pool_rows, rank + rope]`` a layer in the
-``"cache"`` collection: scatter this call's rows (padding dropped out of
-bounds), gather each row's logical sequence through its block table, mask
-keys to ``key_pos <= q_pos``, and zero dead rows before they meet any
-product, so that a NaN in a row stays with the request that owns it.
+The paged path is :func:`..attention.paged_attention`'s contract over ONE
+pool leaf ``[pool_rows, rank + rope]`` a layer in the ``"cache"``
+collection: scatter this call's rows (padding dropped out of bounds), read
+each row's logical sequence through its block table, mask keys to
+``key_pos <= q_pos``, and zero dead rows before they meet any product, so
+that a NaN in a row stays with the request that owns it.  Which call reads
+the pool how is decided by what the call shows, as there:
+
+  - ONE position a row in the absorbed form on a TPU (the scheduler's
+    ``decode_step``), with a leaf the kernel can read
+    (:func:`..mla_paged_decode.fits`): the Pallas kernel
+    :func:`..mla_paged_decode.mla_paged_decode` walks each row's block
+    table up to the row's own length over the leaf where it lies.  No copy
+    of a table's rows exists; the masking and the zeroing are the kernel's.
+  - More positions a row (whole-prompt prefill through the expanded form,
+    the speculative ``verify``), any backend but a TPU, a leaf that does
+    not fit: each row's FULL table is gathered, a block at a time, into
+    ``[B, L, rank + rope]`` and that copy is masked, zeroed and scored.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -36,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from . import mla_paged_decode
 from .attention import LATENT_POOL, rms_norm
 
 __all__ = ["MLAttention", "rms_norm", "yarn_inv_freq", "yarn_mscale"]
@@ -148,6 +162,10 @@ class MLAttention(nn.Module):
         k_pe = _rotate(k_pe, cos, sin)
         rows = jnp.concatenate([c, k_pe], axis=-1).astype(self.dtype)  # [B,S,r+dr]
         scale = (dn + dr) ** -0.5 * m_all * m_all
+        w_kv = wkv_b.reshape(r, h, dn + dv)
+
+        def project(out):  # [B, S, H, dv] float32
+            return jnp.dot(out.reshape(b, s, h * dv).astype(self.dtype), wo)
 
         if self.paged:
             bs, nb = self.kv_block_size, self.kv_num_blocks
@@ -166,12 +184,28 @@ class MLAttention(nn.Module):
                 rows.reshape(b * s, r + dr), mode="drop"
             )
             pool.value = filled
+            from .flash_attention import flash_enabled
+
+            blocks = filled.reshape(nb, bs, r + dr)  # a bitcast: rows split only
+            if (s == 1 <= self.absorb_max_queries and flash_enabled()
+                    and mla_paged_decode.fits(r, bs, self.dtype)):
+                # one position a row, on a TPU: the kernel reads the leaf
+                # where it lies, each row's live blocks and no others.  A
+                # padding row (position -1) reads key 0 of its table's first
+                # block, as below.
+                def over_pool(q_lat, q_pe):
+                    return mla_paged_decode.mla_paged_decode(
+                        q_lat[:, 0], q_pe[:, 0], blocks, block_tables,
+                        safe_pos[:, 0] + 1, scale=scale)[:, None]
+
+                return project(
+                    _absorbed(q_nope, q_pe, w_kv, dn, self.dtype, over_pool))
             length = block_tables.shape[1] * bs
             # gathered a BLOCK at a time: a block's rows lie together in
             # the pool, so one table entry moves a [bs, r + dr] slab (row by
             # row the same gather was a third of a decode step; splitting
             # only the row axis keeps the view free of a relayout)
-            keys = filled.reshape(nb, bs, r + dr)[block_tables].reshape(
+            keys = blocks[block_tables].reshape(
                 b, length, r + dr
             )  # [B, L, r+dr] in logical-position order
             key_pos = jnp.arange(length, dtype=jnp.int32)
@@ -185,15 +219,19 @@ class MLAttention(nn.Module):
         # (causal, so live for any query of the row = live for its last one)
         keys = jnp.where(live.any(axis=1)[:, :, None], keys, 0)
 
-        w_kv = wkv_b.reshape(r, h, dn + dv)
         if s <= self.absorb_max_queries:
-            out = _absorbed(q_nope, q_pe, keys, live, w_kv, r, dn, scale)
+            # the CPU's dot thunk has no bfloat16 x bfloat16 = float32 for
+            # these shapes: there the operands are upcast, which rounds nothing
+            dt = keys.dtype if jax.default_backend() == "tpu" else jnp.float32
+            out = _absorbed(
+                q_nope, q_pe, w_kv, dn, dt,
+                functools.partial(_over_rows, keys.astype(dt), live, r, scale))
         else:
             out = jax.lax.map(
                 lambda a: _expanded(*a, w_kv, r, dn, scale),
                 (q_nope, q_pe, keys, live),
             )
-        return jnp.dot(out.reshape(b, s, h * dv).astype(self.dtype), wo)
+        return project(out)
 
 
 def _softmax(scores, live):
@@ -216,25 +254,31 @@ def _expanded(q_nope, q_pe, keys, live, w_kv, r, dn, scale):
     return jnp.einsum("hsl,lhd->shd", p, v.astype(f32))
 
 
-def _absorbed(q_nope, q_pe, keys, live, w_kv, r, dn, scale):
-    """All rows at once: ``q_* [B, S, H, d]``, ``keys [B, L, r+dr]``.  The
-    products against the rows take them as they are stored (no float32
-    copy of ``[B, L, r+dr]`` is made: at 32 rows of 2,560 positions that
-    copy was the step's largest operation) and accumulate in float32."""
+def _absorbed(q_nope, q_pe, w_kv, dn, dt, attend):
+    """All rows at once, ``q_* [B, S, H, d]``: ``W_kv_b``'s two halves on the
+    query's side, operands in ``dt``, products accumulated in float32.
+    ``attend(q_lat [B, S, H, r], q_pe)`` is the attention over the latent
+    rows themselves, ``o_lat [B, S, H, r]`` float32: :func:`_over_rows` of a
+    gathered copy, or the kernel over the pool."""
     f32 = jnp.float32
-    # the CPU's dot thunk has no bfloat16 x bfloat16 = float32 for these
-    # shapes: there the operands are upcast, which rounds nothing
-    dt = keys.dtype if jax.default_backend() == "tpu" else f32
-    c, k_pe = keys[..., :r].astype(dt), keys[..., r:].astype(dt)
     w_uk, w_uv = w_kv[..., :dn].astype(dt), w_kv[..., dn:].astype(dt)
-    q_nope = q_nope.astype(dt)
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk, preferred_element_type=f32)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(dt), w_uk,
+                       preferred_element_type=f32)
+    o_lat = attend(q_lat.astype(dt), q_pe.astype(dt))
+    return jnp.einsum("bshr,rhd->bshd", o_lat.astype(dt), w_uv,
+                      preferred_element_type=f32)
+
+
+def _over_rows(keys, live, r, scale, q_lat, q_pe):
+    """``keys [B, L, r+dr]`` taken as they are stored (no float32 copy of
+    them is made: at 32 rows of 2,560 positions that copy was the step's
+    largest operation), scores, softmax and sums in float32."""
+    f32 = jnp.float32
+    c, k_pe = keys[..., :r], keys[..., r:]
     scores = (
-        jnp.einsum("bshr,blr->bhsl", q_lat.astype(dt), c, preferred_element_type=f32)
-        + jnp.einsum("bshd,bld->bhsl", q_pe.astype(dt), k_pe,
-                     preferred_element_type=f32)
+        jnp.einsum("bshr,blr->bhsl", q_lat, c, preferred_element_type=f32)
+        + jnp.einsum("bshd,bld->bhsl", q_pe, k_pe, preferred_element_type=f32)
     ) * scale
     p = _softmax(scores, live[:, None])
-    o_lat = jnp.einsum("bhsl,blr->bshr", p.astype(dt), c, preferred_element_type=f32)
-    return jnp.einsum("bshr,rhd->bshd", o_lat.astype(dt), w_uv,
+    return jnp.einsum("bhsl,blr->bshr", p.astype(keys.dtype), c,
                       preferred_element_type=f32)

@@ -25,6 +25,7 @@ from pytorch_distributed_training_tpu.ops.fused_elementwise import (
     _make_add_ln,
     _make_bias_gelu,
 )
+from pytorch_distributed_training_tpu.ops.mla_paged_decode import mla_paged_decode
 from pytorch_distributed_training_tpu.ops.paged_decode import paged_decode
 
 
@@ -77,6 +78,10 @@ def _bias_gelu(u, bias):
 
 def _paged_decode(q, k_pool, v_pool, tables, lengths):
     return paged_decode(q, k_pool, v_pool, tables, lengths, scale=128 ** -0.5)
+
+
+def _mla_paged_decode(q_lat, q_pe, pool, tables, lengths):
+    return mla_paged_decode(q_lat, q_pe, pool, tables, lengths, scale=192 ** -0.5)
 
 
 def _decode_call(rows, group, blocks, table, kv_heads=8):
@@ -156,6 +161,16 @@ CASES = {
     ),
     "paged_decode_f32": (
         _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
+    ),
+    # deepseek-v2-lite.serve.steady32: 32 slots x 160 blocks, 16 heads over
+    # the ONE latent row a position, 512 + 64 lanes, 6,144 blocks of 16 (a
+    # ``make_async_copy`` of such a block is refused: "slice shape must be
+    # aligned to tiling (128), but is 576"; the pipeline's own copy is not)
+    "mla_paged_decode_deepseek_v2_lite": (
+        _mla_paged_decode,
+        [((32, 16, 512), None), ((32, 16, 64), None), ((6144, 16, 576), None),
+         ((32, 160), I32), ((32,), I32)],
+        BF16, ["mla_paged_decode"],
     ),
 }
 

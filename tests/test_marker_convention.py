@@ -107,5 +107,5 @@ def test_every_pallas_call_in_ops_is_named():
         "flash_fwd", "flash_fwd_stream", "flash_bwd", "flash_bwd_dq",
         "flash_bwd_dkv", "flash_bwd_dq_stream", "flash_bwd_dkv_stream",
         "fused_ce_fwd", "fused_ce_bwd", "fused_add_ln", "fused_bias_gelu",
-        "paged_decode",
+        "paged_decode", "mla_paged_decode",
     ])
