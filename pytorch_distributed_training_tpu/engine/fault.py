@@ -72,16 +72,15 @@ tick):
                        watchdog must fire and convert the stall into a
                        diagnosed restart
 
-Lagged guard semantics under the async decode pipeline
-(``serving.scheduler.async_depth > 0``, which is what an engine serves —
-depth 1 — unless its configuration names 0 or carries a speculative
-draft): the injection still lands at
+Lagged guard semantics under the decode ring (what an engine serves, at
+depth 1; a scheduler built with ``async_depth=0`` reads each step in its own
+tick and lags nothing): the injection still lands at
 tick T's DISPATCH, but its observable consequence moves to the drain of
-that step — up to ``async_depth`` ticks later.  ``serve_nan``'s
+that step — up to the ring's depth in ticks later.  ``serve_nan``'s
 non-finite flag is read at drain time (eviction one-or-more ticks late,
 attribution unchanged); ``serve_raise`` surfaces when the dispatch
 itself runs, and the supervisor drains the in-flight ring
-(``flush_async``) before poison-bisecting so the sync probe sees a
+(``flush_async``) before poison-bisecting so the probe sees a
 state-consistent pool.  The isolation contract is identical either way:
 exactly the faulted request fails, survivors stay bit-exact.
 

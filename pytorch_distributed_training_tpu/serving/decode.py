@@ -269,9 +269,9 @@ class _PagedFns:
     tokens the host knows (a row just prefilled, refilled or replayed).
     The output ``tok`` is a valid ``prev_tok`` of the next call, so step
     k+1 can be dispatched before step k's tokens are read back.  A caller
-    that knows every row's token (the sync body, the supervisor's probe,
-    the replay, a speculative draft's steps) passes a mask of all rows and
-    any ``prev_tok`` of the right placement.  In quant mode ``params`` here
+    that knows every live row's token (the ring at depth 0, the
+    supervisor's probe, the replay, a speculative draft's steps) passes a
+    mask of those rows and any ``prev_tok`` of the right placement.  In quant mode ``params`` here
     is the int8 tree.
     ``finite`` [B] bool is the on-device output guard: True iff every
     logit the row sampled from is finite — the serving mirror of the
@@ -454,8 +454,9 @@ def build_paged_fns(
         if quant:
             params = dequantize_tree(params, jnp.float32)
         # prev_tok is a step's ON-DEVICE token output; the rows whose last
-        # token the host knows (all of them on the sync paths, the ones
-        # just (re)filled on the ring) get it spliced in here, so the ring
+        # token the host knows (every live row of a probe, a replay or a
+        # draft's step, the ones just (re)filled on the ring) get it
+        # spliced in here, so the ring
         # never needs a host round-trip to mix fresh rows into the carry
         prev = jax.lax.select(fresh_mask, fresh_tok, prev_tok)
         logits, variables = _apply(

@@ -38,7 +38,7 @@ from ..ops.attention import is_state_leaf
 from .decode import build_generate_fn
 from .lora import LoraRegistry
 from .metrics import ServingMetrics
-from .prefill_plan import LinearCost
+from .prefill_plan import LinearCost, bucket_for
 from .scheduler import ContinuousScheduler
 from .speculative import SpeculativeSpec
 
@@ -240,20 +240,18 @@ class InferenceEngine:
                     spec = SpeculativeSpec(spec_k, draft_model, draft_params)
                 else:
                     spec = SpeculativeSpec(spec_k)
-            # the served default is the ring of depth 1 (tick k dispatches
-            # step k before it reads step k-1); a speculative round has
-            # nothing to pipeline and resolves to the sync body by itself.
-            # A configuration that names a depth gets it, and the
-            # scheduler refuses one beside a draft.
-            async_depth = sched_cfg.pop("async_depth", None)
-            if async_depth is None:
-                async_depth = 0 if spec is not None else 1
-            self.scheduler = ContinuousScheduler(
-                model, self.params,
+            sched_keys = dict(
                 slots=int(sched_cfg.pop("slots", 8)),
                 block_size=int(sched_cfg.pop("block_size", 16)),
                 num_blocks=int(sched_cfg.pop("num_blocks", 64)),
                 prefix_cache=bool(sched_cfg.pop("prefix_cache", True)),
+            )
+            if sched_cfg:  # before a scheduler and its thread exist
+                raise ValueError(
+                    f"unknown serving.scheduler keys: {sorted(sched_cfg)}"
+                )
+            self.scheduler = ContinuousScheduler(
+                model, self.params, **sched_keys,
                 batch_buckets=self.batch_buckets,
                 seq_buckets=self.seq_buckets,
                 max_new_tokens=max_new_tokens,
@@ -268,17 +266,16 @@ class InferenceEngine:
                 quant=use_quant,
                 lora=self.lora_registry,
                 speculative=spec,
-                async_depth=int(async_depth),
+                # the ring of depth 1: tick k dispatches step k before it
+                # reads step k-1.  A speculative round reads its own verify
+                # before the next is proposed: nothing to hold in a ring
+                async_depth=0 if spec is not None else 1,
                 logger=self.logger,
                 replica_id=replica_id,
                 heartbeat_path=heartbeat_path,
                 heartbeat_interval_s=heartbeat_interval_s,
                 liveness_timeout_s=liveness_timeout_s,
             )
-            if sched_cfg:
-                raise ValueError(
-                    f"unknown serving.scheduler keys: {sorted(sched_cfg)}"
-                )
         else:
             if resilience is not None:
                 raise ValueError(
@@ -643,13 +640,10 @@ class InferenceEngine:
         sched.require_idle()
         T = sched.table_blocks
         W = sched.slots_n
-        pos = np.full((W,), -1, np.int32)
-        tables = np.zeros((W, T), np.int32)
-        zeros = np.zeros((W,), np.int32)
-        aids = np.full((W,), -1, np.int32)
-        # the tick's own kind of argument (a host uint32 [n, 2] array), so
-        # that each program's jit cache holds the ONE entry the ticks hit
-        keys = sched._pad_keys(W)
+        # the tick's own kind of argument (a step with no live row; its
+        # keys a host uint32 [n, 2] array), so that each program's jit
+        # cache holds the ONE entry the ticks hit
+        step = sched._step_inputs(())
 
         def no_slot(n):
             # a model that carries a state: every row's slot is -1 (padding)
@@ -670,13 +664,12 @@ class InferenceEngine:
             }
 
         def decode(fns, params):
-            # ONE decode program a model, whichever body calls it:
-            # _zero_carry matches the program's own token-output sharding,
-            # so this call covers the ring's first and carried dispatches
-            # and the sync callers' (one cache entry)
+            # ONE decode program a model, whoever calls it: _zero_carry
+            # matches the program's own token-output sharding, so this
+            # call covers the ring's first and carried dispatches and the
+            # probe's and the replay's (one cache entry)
             return (fns.decode_step, (params,), (
-                sched._zero_carry(), np.zeros((W,), bool), zeros, pos,
-                tables, keys, zeros, aids, *no_slot(W),
+                sched._zero_carry(), *step, *no_slot(W),
             ), 2)
 
         fns = sched._fns
@@ -691,7 +684,7 @@ class InferenceEngine:
             oob = np.full((W * sched._kv.block_size,), n_rows, np.int32)
             calls.append((fns.verify, (sched.params,), (
                 np.zeros((W, k + 1), np.int32),
-                np.full((W, k + 1), -1, np.int32), tables, aids,
+                np.full((W, k + 1), -1, np.int32), step.tables, step.aids,
             ), 1))
             calls.append((fns.copy_rows, (), (oob, oob), None))
         aliased = self._warm_pool_programs(calls, sched, "_pool")
@@ -789,12 +782,6 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------ #
 
-    def _bucket_for(self, n: int, buckets: Sequence[int], kind: str) -> int:
-        for b in buckets:
-            if n <= b:
-                return b
-        raise ValueError(f"{kind} {n} exceeds largest bucket {buckets[-1]}")
-
     def _next_rng(self):
         self._batch_counter += 1
         return jax.random.fold_in(self._rng, self._batch_counter)
@@ -819,8 +806,8 @@ class InferenceEngine:
         import time
 
         lens = [req.payload.size for req in requests]
-        bb = self._bucket_for(len(requests), self.batch_buckets, "batch size")
-        sb = self._bucket_for(max(lens), self.seq_buckets, "prompt length")
+        bb = bucket_for(len(requests), self.batch_buckets, "batch size")
+        sb = bucket_for(max(lens), self.seq_buckets, "prompt length")
         tokens = np.zeros((bb, sb), np.int32)
         prompt_len = np.ones((bb,), np.int32)  # pad rows: 1-token dummy
         for i, req in enumerate(requests):
@@ -864,7 +851,7 @@ class InferenceEngine:
         return results, phase
 
     def _run_images(self, requests: List[Request]) -> List[Any]:
-        bb = self._bucket_for(len(requests), self.batch_buckets, "batch size")
+        bb = bucket_for(len(requests), self.batch_buckets, "batch size")
         first = requests[0].payload
         img = np.zeros((bb,) + first.shape, first.dtype)
         for i, req in enumerate(requests):

@@ -286,8 +286,8 @@ class ServingMetrics:
 
     def record_decode_dispatch(self, inflight: int) -> None:
         """One decode step about to be dispatched with ``inflight`` earlier
-        steps still in the scheduler's ring: 0 on the sync and speculative
-        bodies, the ring's length on the async one."""
+        steps still in the scheduler's ring: its length, so 0 at depth 0
+        and in a speculative round."""
         with self._lock:
             self._decode_dispatches += 1
             self._decode_overlapped += inflight > 0
@@ -313,8 +313,9 @@ class ServingMetrics:
 
     def record_tick(self, host_ms: float) -> None:
         """One scheduler tick's HOST overhead: wall time minus the spans
-        spent blocked on device readbacks — what the accelerator idles
-        through between dispatches on the sync path."""
+        spent blocked on device readbacks — what the accelerator would
+        idle through between dispatches were each step read before the
+        next is sent (depth 0)."""
         self._tick_host_ms.observe(float(host_ms))
 
     def record_tick_phases(
@@ -362,9 +363,9 @@ class ServingMetrics:
 
     def record_dispatch_gap(self, gap_ms: float) -> None:
         """Host wall time between two consecutive decode dispatch
-        enqueues during back-to-back decode ticks.  The sync path's gap
-        includes the full readback + bookkeeping window; the async
-        pipeline's is bookkeeping only."""
+        enqueues during back-to-back decode ticks.  At depth 0 the gap
+        includes the full readback + bookkeeping window; a ring that
+        holds steps leaves bookkeeping only."""
         self._dispatch_gap_ms.observe(float(gap_ms))
 
     def record_scale_up_ready(self, ms: float) -> None:
@@ -502,7 +503,7 @@ class ServingMetrics:
         # async-pipeline observability (absent until a tick/dispatch-gap
         # sample lands, keeping batcher-path snapshots byte-stable)
         if dispatches:
-            # near 1 in a steady window of the ring, 0 on the sync body
+            # near 1 in a steady window of the ring, 0 at depth 0
             out["decode_steps_dispatched"] = dispatches
             out["decode_steps_overlapped"] = overlapped
             out["decode_overlap_share"] = overlapped / dispatches

@@ -53,22 +53,23 @@ optional tick watchdog (engine/watchdog.py) turns a hung step into a
 diagnosed restart.  The ``serve_*`` kinds in engine/fault.py drive all
 of it deterministically.
 
-Async decode pipeline (``async_depth > 0``; what an engine serves unless
-its configuration names another depth: ``serving/engine.py`` builds this
-scheduler with depth 1, and with 0 beside a speculative draft): the sync
-loop (``async_depth: 0``, this constructor's own default) pays one full
-host round-trip per token — ``np.asarray(tok)`` before the next dispatch
-— so the device idles through the launch, its own step's return and the
-host's bookkeeping every single-token step, and the host idles through
-the device's step.  With a depth set, the sampled-token carry stays ON
-DEVICE (``decode_step`` takes its own output back as the next
-``prev_tok``) and a bounded in-flight ring drains host readbacks one
-tick behind dispatch: tick k dispatches step k and only then reads step
-k-1.  Host bookkeeping stays exact through per-request ``dispatched``
-counters and the drained stream is bitwise token-identical to the sync
-path (greedy and sampled).  Both bodies run the ONE ``decode_step``
-program of ``serving/decode.py``; the sync callers hand it a mask of all
-rows.  See :meth:`ContinuousScheduler._decode_step_async`.
+One decode body (:meth:`ContinuousScheduler._ring_step`): the sampled
+token stays ON DEVICE — ``decode_step`` takes its own output back as the
+next ``prev_tok``, and the rows whose last token the host holds are spliced
+in through ``fresh_mask`` — and a ring of up to ``async_depth`` dispatched
+steps is read back behind the dispatch: at depth 1, which is what an engine
+serves, tick k dispatches step k and only then reads step k-1, so the device
+does not idle through the launch, its own step's return and the host's
+bookkeeping every token.  At depth 0 (this constructor's default: the
+lock-step tests and ``engine/chaos.py`` tick by hand) the same body reads
+each step in the tick that dispatched it: every row is then fresh every
+tick.  Host bookkeeping stays exact through per-request ``dispatched``
+counters, and the stream is bitwise token-identical at every depth (greedy
+and sampled).  Beside a speculative draft a tick runs a round instead
+(:meth:`ContinuousScheduler._spec_round`), which has nothing to
+pipeline.  Every caller of the ONE ``decode_step`` program — the ring, the
+probe, the replay, a draft's steps — takes its host arrays from
+``serving/step_inputs.py``.
 
 Where a tick's time goes (PR 24): every tick is a ``tick`` span whose
 children are its phases — ``admit``, ``prefill`` (one a CALL: a tick's fresh
@@ -76,8 +77,8 @@ admissions run as the calls on the compiled grid whose summed estimated time
 is least, :meth:`ContinuousScheduler._prefill_calls`), ``decode_prep``,
 ``decode_step`` (the dispatch; telemetry/slo.py pairs recoveries with this
 kind), ``readback`` (blocked on the sampled tokens), ``deliver`` (push,
-``on_token``, retire) — the same kinds from the sync, async-ring and
-speculative bodies; each phase's milliseconds also land in a histogram of
+``on_token``, retire) — the same kinds from the ring at every depth and from
+a speculative round; each phase's milliseconds also land in a histogram of
 :class:`ServingMetrics` for every productive tick.  A request is stamped at
 submit, first admission, first and every later token, and leaves one
 ``request`` record on the span ring when it retires; ``queue_wait_ms``,
@@ -92,9 +93,9 @@ batcher path until the scheduler learns sharded block tables.
 
 Determinism for tests: construct with ``start=False`` and drive
 :meth:`tick` by hand — one tick = admit + prefill + one decode step, so a
-scripted arrival trace replays bit-identically (under a ring the step's
-tokens are delivered by the NEXT tick, and by the tick that finds nothing
-left to dispatch).
+scripted arrival trace replays bit-identically (at a depth above 0 the
+step's tokens are delivered by a LATER tick, and by the tick that finds
+nothing left to dispatch).
 """
 from __future__ import annotations
 
@@ -125,6 +126,7 @@ from .metrics import ServingMetrics
 from .prefill_plan import LinearCost, bucket_for, plan_calls
 from .resilience import HungTickError, PoisonedRequestError, ServingSupervisor
 from .speculative import greedy_accept
+from .step_inputs import step_inputs
 
 __all__ = ["ContinuousScheduler"]
 
@@ -167,12 +169,13 @@ class _PagedRequest:
         self.adapter = -1  # LoRA adapter id; -1 = base model
         self.adapter_name: Optional[str] = None
         self.draft_admission = None  # speculative mode: draft-pool blocks
-        # async pipeline: generated tokens DETERMINED so far — drained
+        # the ring: generated tokens DETERMINED so far — drained
         # into ``tokens`` plus steps still in the in-flight ring.  The
         # host derives every dispatch input (position, sampling index)
         # from this counter, so only the token VALUE needs to stay on
-        # device.  Invariant: dispatched >= len(tokens); equal in sync
-        # mode and whenever the ring is empty for this row.
+        # device.  Invariant: dispatched >= len(tokens); equal whenever
+        # the ring holds no step of this row (always, between ticks at
+        # depth 0).
         self.dispatched = 0
         # the request's life, stamped by the scheduler on the clock of
         # ``enqueued_at``: first admission, first and latest token pushed
@@ -206,7 +209,7 @@ class _PrefillCall(NamedTuple):
 class _Phase:
     """One phase of the current tick: a span of ``kind`` whose duration is
     also added to the scheduler's account of this tick (a phase may run
-    more than once a tick: the async ring drains several entries)."""
+    more than once a tick: the ring's endgame drains several entries)."""
 
     __slots__ = ("_sched", "_kind", "_span", "_t0")
 
@@ -370,13 +373,12 @@ class ContinuousScheduler:
                 "sampled accept rule is serving/speculative.py's "
                 "sampled_accept, not yet wired to the scheduler)"
             )
-        # async decode pipeline: depth of the in-flight dispatch ring.
-        # 0 = the synchronous loop (read every step's tokens back before
-        # dispatching the next); N >= 1 keeps up to N dispatched steps
-        # un-drained, with the sampled-token carry fed back ON DEVICE
-        # (decode_step's prev_tok) so the accelerator never waits out the
-        # host's per-token bookkeeping window.  An engine serves depth 1
-        # unless its configuration says otherwise (serving/engine.py).
+        # how many dispatched steps the ring may hold unread (_ring_step).
+        # 0 = a step is read in the tick that dispatched it; N >= 1 = it is
+        # read up to N ticks later, the sampled token fed back ON DEVICE
+        # meanwhile (decode_step's prev_tok), so the accelerator never waits
+        # out the host's per-token bookkeeping.  An engine serves depth 1
+        # (serving/engine.py); ticking by hand in lock step wants 0.
         self._async_depth = int(async_depth)
         if self._async_depth < 0:
             raise ValueError(
@@ -488,7 +490,7 @@ class ContinuousScheduler:
         # this tick's milliseconds by phase kind (see _Phase)
         self._phase_ms: Dict[str, float] = {}  # confined: _loop
 
-        # async-pipeline state (all confined: _loop).  _inflight holds
+        # the ring's state (all confined: _loop).  _inflight holds
         # (tok_dev, finite_dev, rows, moe, dispatching tick) per
         # dispatched-but-undrained step; _carry_tok is the LAST dispatch's
         # on-device token vector — the next step's prev_tok input.
@@ -497,11 +499,10 @@ class ContinuousScheduler:
         # beside the readback phase.
         self._inflight: deque = deque()  # confined: _loop
         self._carry_tok = None  # confined: _loop
-        # what a caller that knows every row's token hands decode_step as
-        # (prev_tok, fresh_mask): zeros on the device (_zero_carry, made
-        # once) and a mask of all rows
+        # the prev_tok of a call that carries nothing — the ring's first
+        # dispatch, the probe, the replay, a draft's steps: zeros on the
+        # device (_zero_carry, made once)
         self._zero_tok = None  # confined: _loop
-        self._all_rows = np.ones((self.slots_n,), bool)
         # (tick_no, perf_counter) of the latest decode dispatch
         self._last_dispatch: Optional[tuple] = None  # confined: _loop
         self._tick_block_s = 0.0  # confined: _loop
@@ -1072,10 +1073,10 @@ class ContinuousScheduler:
         try:
             try:
                 # tick_host_ms = tick wall minus time BLOCKED on device
-                # readbacks: the decode bodies' ``readback`` phase, and the
+                # readbacks: the decode body's ``readback`` phase, and the
                 # fresh prefill's own read, which no phase times alone and
                 # _prefill_fresh adds to _tick_block_s — the host-overhead
-                # number the async pipeline exists to hide
+                # number the ring exists to hide
                 self._tick_block_s = 0.0
                 self._phase_ms = {}
                 t_tick0 = time.perf_counter()
@@ -1108,11 +1109,11 @@ class ContinuousScheduler:
                 "scheduler tick %d failed in phase %r; invoking supervisor",
                 self._tick_no, self._tick_phase,
             )
-            # async pipeline: settle the in-flight dispatch ring BEFORE
-            # recovery.  The supervisor's bisect probes and replays
-            # assume sync-equivalent host state, and a step that was
-            # merely in flight when an unrelated row poisoned the tick
-            # must not confound attribution.  No-op in sync mode.
+            # settle the in-flight dispatch ring BEFORE recovery.  The
+            # supervisor's bisect probes and replays assume every row's
+            # last token on the host, and a step that was merely in
+            # flight when an unrelated row poisoned the tick must not
+            # confound attribution.  Nothing to settle at depth 0.
             self.flush_async()
             return self._supervisor.handle_tick_failure(exc)
 
@@ -1157,11 +1158,9 @@ class ContinuousScheduler:
         if n_active:
             self._tick_phase = "decode"
             if self._spec is not None:
-                self._spec_decode_step()
-            elif self._async_depth:
-                self._decode_step_async()
+                self._spec_round()
             else:
-                self._decode_step()
+                self._ring_step()
         self._publish_pool_gauges()
         return bool(newly) or n_active > 0 or did_xfer
 
@@ -1641,27 +1640,9 @@ class ContinuousScheduler:
             step_reqs = [r for r in live if r.gen_idx > k]
             if not step_reqs:
                 break
-            W = self.slots_n
-            prev = np.zeros((W,), np.int32)
-            pos = np.full((W,), -1, np.int32)
-            tables = np.zeros((W, self.table_blocks), np.int32)
-            gi = np.zeros((W,), np.int32)
-            aids = np.full((W,), -1, np.int32)
-            keys = self._pad_keys(W)
-            for req in step_reqs:
-                i = req.slot
-                prev[i] = req.tokens[k - 1]
-                pos[i] = req.prompt.size + k - 1
-                ids = self._table_ids(req)
-                tables[i, : len(ids)] = ids
-                gi[i] = k
-                aids[i] = req.adapter
-                keys[i] = req.row_key
-            tok, finite, self._pool, *_ = self._step_known(
-                self._fns, self._qparams if self._quant else self.params,
-                self._pool, prev, pos, tables, keys, gi, aids,
-                *self._slot_rows(pos),
-            )
+            tok, finite, *_ = self._step(self._step_inputs(
+                self._step_row(r, k, r.tokens[k - 1]) for r in step_reqs
+            ))
             tok = np.asarray(tok)
             finite = np.asarray(finite)
             for req in step_reqs:
@@ -1765,28 +1746,47 @@ class ContinuousScheduler:
     # ------------------------------------------------------------------ #
     # decode
 
-    def _decode_arrays(self, reqs: List[_PagedRequest]):
-        """Fixed-width decode inputs with ``reqs`` live and every other
-        slot riding along at position -1."""
-        W = self.slots_n
-        prev = np.zeros((W,), np.int32)
-        pos = np.full((W,), -1, np.int32)
-        tables = np.zeros((W, self.table_blocks), np.int32)
-        gen_idx = np.zeros((W,), np.int32)
-        aids = np.full((W,), -1, np.int32)
-        keys = self._pad_keys(W)
-        for req in reqs:
-            i = req.slot
-            prev[i] = req.tokens[-1]
-            # prev = generated token gen_idx-1 at global position
-            # prompt_len + gen_idx - 1; feeding it samples token gen_idx
-            pos[i] = req.prompt.size + req.gen_idx - 1
-            ids = self._table_ids(req)
-            tables[i, : len(ids)] = ids
-            gen_idx[i] = req.gen_idx
-            aids[i] = req.adapter
-            keys[i] = req.row_key
-        return prev, pos, tables, gen_idx, aids, keys
+    def _step_row(self, req: _PagedRequest, index: int, token: Optional[int]):
+        """``req``'s row (``step_inputs.StepRow``) of a target step that
+        samples its token ``index``, fed ``token`` (None = the carried one)."""
+        return (
+            req.slot, req.prompt.size, index, token, self._table_ids(req),
+            req.adapter, req.row_key,
+        )
+
+    def _step_inputs(self, rows):
+        return step_inputs(
+            self.slots_n, self.table_blocks, self._pad_key, rows
+        )
+
+    def _step(self, inputs, carry=None):
+        """Dispatch the target's ``decode_step`` over ``inputs`` and rebind
+        the pool; returns ``(tok, finite, *moe)``, still on the device.  A
+        call that carries nothing passes the committed zeros the ring
+        starts from, so the program keeps ONE entry in its cache whoever
+        calls (:meth:`_zero_carry`)."""
+        tok, finite, self._pool, *moe = self._fns.decode_step(
+            self._qparams if self._quant else self.params, self._pool,
+            self._zero_carry() if carry is None else carry,
+            *inputs, *self._slot_rows(inputs.pos),
+        )
+        return (tok, finite, *moe)
+
+    def _record_iteration(self, live: int, pos=None) -> None:
+        """File one decode iteration's sample of the scheduler's state:
+        ``live`` rows, the pool's blocks and, of a single-position step
+        (``pos`` = its positions), the shares of the block tables and of
+        the state rows it reads.  A speculative round reads several
+        positions a row and files neither."""
+        shares = {} if pos is None else dict(
+            live_block_share=self._live_block_share(pos),
+            state_live_row_share=self._state_live_row_share(pos),
+        )
+        self.metrics.record_iteration(
+            active_slots=live, total_slots=self.slots_n,
+            blocks_in_use=self._kv.blocks_in_use,
+            total_blocks=self._kv.num_blocks, **shares,
+        )
 
     def _poison_shim(self, reqs: List[_PagedRequest]) -> None:
         """Injected per-request dispatch failure (``serve_raise``).  The
@@ -1798,58 +1798,12 @@ class ContinuousScheduler:
                     f"injected decode-dispatch failure (tick {self._tick_no})"
                 )
 
-    def _decode_step(self) -> None:
-        """One single-token step for every occupied slot."""
-        t0 = time.perf_counter()
-        with self._phase("decode_prep"):
-            active = [req for req in self._slots if req is not None]
-            self._poison_shim(active)
-            prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(active)
-        n_active = len(active)
-        self._note_dispatch(inflight=0)
-        # the span marks this tick as PRODUCTIVE serving work — the
-        # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
-        # preceding poison_bisect/serving_restart recovery span)
-        with self._phase("decode_step", active=n_active, inflight=0):
-            tok, finite, self._pool, *moe = self._step_known(
-                self._fns, self._qparams if self._quant else self.params,
-                self._pool, prev, pos, tables,
-                keys, gen_idx, aids, *self._slot_rows(pos),
-            )
-        with self._phase("readback", for_step=self._tick_no):
-            # the FIRST read waits for the launch, the device's step and
-            # the copy; the guard's and the expert counts' wait for nothing
-            with self._readback_wait(self._tick_no):
-                tok = np.asarray(tok)
-            finite = np.asarray(finite)
-            self._record_moe(moe, n_active)
-        t1 = time.perf_counter()
-        with self._phase("deliver"):
-            for req in active:
-                if not finite[req.slot]:
-                    # on-device output guard: evict the NaN emitter, every
-                    # other row's logits are untouched (disjoint block
-                    # tables)
-                    self._evict_poisoned(
-                        req, cause=None, trigger="non-finite decode logits"
-                    )
-                    continue
-                self._push_token(req, int(tok[req.slot]))
-        self.metrics.record_decode(n_tokens=n_active, decode_s=t1 - t0)
-        self.metrics.record_iteration(
-            active_slots=n_active, total_slots=self.slots_n,
-            blocks_in_use=self._kv.blocks_in_use,
-            total_blocks=self._kv.num_blocks,
-            live_block_share=self._live_block_share(pos),
-            state_live_row_share=self._state_live_row_share(pos),
-        )
-
     def _readback_wait(self, for_step: int):
         """The span around a decode body's first read of a step's output,
         a child of ``readback`` and NOT a phase (no place in ``_phase_ms``
         or ``TICK_PHASES``).  ``for_step`` is the tick that dispatched the
-        step this read drains: this tick on the sync and speculative
-        bodies, an earlier one on the async ring."""
+        step this read drains: this tick at depth 0 and in a speculative
+        round, an earlier one on a ring that holds steps."""
         return span("readback_wait", step=self._tick_no, for_step=for_step)
 
     def _record_moe(self, moe, n_rows: int) -> None:
@@ -1870,34 +1824,21 @@ class ContinuousScheduler:
         failed step's, so the pool scatter is idempotent and sampling is
         pure: probing commits nothing the real step would not."""
         self._poison_shim(reqs)
-        prev, pos, tables, gen_idx, aids, keys = self._decode_arrays(reqs)
-        tok, _, self._pool, *_ = self._step_known(
-            self._fns, self._qparams if self._quant else self.params,
-            self._pool, prev, pos, tables,
-            keys, gen_idx, aids, *self._slot_rows(pos),
-        )
+        tok, *_ = self._step(self._step_inputs(
+            self._step_row(r, r.gen_idx, r.tokens[-1]) for r in reqs
+        ))
         # surface async dispatch errors here, inside the probe's try
         jax.block_until_ready(tok)
 
-    def _step_known(self, fns, params, pool, prev, *rest):
-        """``fns.decode_step`` for a caller that holds every row's last
-        token on the host (``prev``: the sync body, the probe, the replay,
-        a speculative draft's steps): all rows are fresh, and the carry is
-        the zeros the ring starts from, so target and draft each keep ONE
-        entry in their program's cache whichever body calls."""
-        return fns.decode_step(
-            params, pool, self._zero_carry(), self._all_rows, prev, *rest
-        )
-
     # ------------------------------------------------------------------ #
-    # async decode pipeline (serving.scheduler.async_depth > 0)
+    # the decode body: a ring of dispatched steps, read behind the dispatch
 
     def _note_dispatch(self, inflight: int) -> None:
         """One decode step is about to be dispatched with ``inflight``
-        steps still in the ring (0 on the sync and speculative bodies):
-        count it (``decode_overlap_share``) and record the host-side gap
-        between consecutive decode dispatch enqueues — the number the
-        pipeline exists to shrink.  Only gaps between BACK-TO-BACK decode
+        steps still in the ring (0 at depth 0): count it
+        (``decode_overlap_share``) and record the host-side gap between
+        consecutive decode dispatch enqueues — the number the ring exists
+        to shrink.  Only gaps between BACK-TO-BACK decode
         ticks count: an idle queue between two dispatches is not host
         overhead."""
         self.metrics.record_decode_dispatch(inflight)
@@ -1911,23 +1852,23 @@ class ContinuousScheduler:
             )
         self._last_dispatch = (self._tick_no, now)
 
-    def _decode_step_async(self) -> None:
-        """Pipelined decode: dispatch step *k* without waiting for step
-        *k-1*'s host readback.
+    def _ring_step(self) -> None:
+        """One single-token step for every occupied slot: dispatch step *k*,
+        then read back what the ring holds beyond ``async_depth`` steps —
+        step *k* itself at depth 0, step *k-1* at depth 1, which is what an
+        engine serves.
 
-        What an engine serves at depth 1 unless its configuration names
-        another depth.  The sampled-token carry stays ON DEVICE —
-        ``decode_step`` takes its own token output back as the next
-        ``prev_tok``, with rows the host just (re)filled spliced in via
-        ``fresh_mask`` — and a ring of up to ``async_depth`` dispatched
-        steps drains one tick behind dispatch: tick k dispatches step k
-        and only then reads step k-1, which the device finished while the
-        host was dispatching (or, where the device sets the pace, is
-        finishing).  Host state stays exact without the tokens: the
-        per-request ``dispatched`` counter derives every position and
-        sampling index, so the drained stream is bitwise identical to
-        the sync path's (same per-row fold_in keys, same per-row pool
-        writes in the same order).
+        The sampled-token carry stays ON DEVICE — ``decode_step`` takes its
+        own token output back as the next ``prev_tok``, with rows the host
+        just (re)filled spliced in via ``fresh_mask`` — so at depth 1 tick k
+        reads step k-1 only after it has dispatched step k, which the
+        device finished while the host was dispatching (or, where the
+        device sets the pace, is finishing).  Host state stays exact
+        without the tokens: the per-request ``dispatched`` counter derives
+        every position and sampling index, so the drained stream is bitwise
+        identical at every depth (same per-row fold_in keys, same per-row
+        pool writes in the same order).  At depth 0 nothing is ever left in
+        flight: every row is fresh every tick and the carry is never read.
 
         Lag consequences, all bounded by ``async_depth``: retire/refill
         and the NaN output guard observe tokens late, so a row can
@@ -1938,7 +1879,11 @@ class ContinuousScheduler:
         because the request has already retired (``admission is None``),
         and once its blocks recycle, any stale overrun rows are masked
         exactly like every other recycled-block row.  A request's first
-        decode token is drained a tick after its prefill.
+        decode token is drained ``async_depth`` ticks after its prefill.
+
+        ``record_decode`` files the tokens PUSHED and the time of the drain
+        (the read and the delivery), not the preparation or the dispatch: a
+        row the output guard evicts is no token.
         """
         with self._phase("decode_prep"):
             active = [req for req in self._slots if req is not None]
@@ -1948,40 +1893,30 @@ class ContinuousScheduler:
             # overrun
             disp = [r for r in active if r.dispatched < r.max_new]
             if disp:
-                (fresh_mask, fresh_tok, pos, tables, gen_idx, aids, keys,
-                 rows) = self._fed_arrays(disp)
+                inputs, rows = self._fed_arrays(disp)
         if disp:
-            prev = self._carry_tok
-            if prev is None:
-                # first dispatch of a pipeline run: every dispatched row
-                # is fresh by construction, the zeros are never sampled
-                prev = self._zero_carry()
             # steps still in the ring as this one is dispatched: 1 in a
             # steady window at depth 1 (the step the drain below reads)
             inflight = len(self._inflight)
             self._note_dispatch(inflight)
+            # the span marks this tick as PRODUCTIVE serving work — the
+            # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
+            # preceding poison_bisect/serving_restart recovery span)
             with self._phase(
                 "decode_step", active=len(disp), inflight=inflight
             ):
-                tok, finite, self._pool, *moe = self._fns.decode_step(
-                    self._qparams if self._quant else self.params,
-                    self._pool, prev, fresh_mask, fresh_tok, pos, tables,
-                    keys, gen_idx, aids, *self._slot_rows(pos),
-                )
+                # the first dispatch of a run carries nothing: every
+                # dispatched row is fresh by construction, and the zeros
+                # are never sampled
+                tok, finite, *moe = self._step(inputs, self._carry_tok)
             for req in disp:
                 req.dispatched += 1
             self._carry_tok = tok
             self._inflight.append((tok, finite, rows, moe, self._tick_no))
-            self.metrics.record_iteration(
-                active_slots=len(disp), total_slots=self.slots_n,
-                blocks_in_use=self._kv.blocks_in_use,
-                total_blocks=self._kv.num_blocks,
-                live_block_share=self._live_block_share(pos),
-                state_live_row_share=self._state_live_row_share(pos),
-            )
-        # drain one tick behind dispatch (ring bounded at async_depth);
-        # when nothing is left to dispatch, drain EVERYTHING so the
-        # endgame cannot strand determined tokens in flight
+            self._record_iteration(len(disp), inputs.pos)
+        # drain behind the dispatch (ring bounded at async_depth); when
+        # nothing is left to dispatch, drain EVERYTHING so the endgame
+        # cannot strand determined tokens in flight
         target = self._async_depth if disp else 0
         pushed = 0
         t0 = time.perf_counter()
@@ -1993,35 +1928,17 @@ class ContinuousScheduler:
             )
 
     def _fed_arrays(self, disp: List[_PagedRequest]):
-        """Fixed-width inputs of the ring's ``decode_step`` with ``disp``
-        live, derived from each row's ``dispatched`` counter, and the rows'
-        ``(request, slot, token index)`` for the drain."""
-        W = self.slots_n
-        fresh_mask = np.zeros((W,), bool)
-        fresh_tok = np.zeros((W,), np.int32)
-        pos = np.full((W,), -1, np.int32)
-        tables = np.zeros((W, self.table_blocks), np.int32)
-        gen_idx = np.zeros((W,), np.int32)
-        aids = np.full((W,), -1, np.int32)
-        keys = self._pad_keys(W)
-        rows = []
-        for req in disp:
-            i = req.slot
-            d = req.dispatched
-            if d == req.gen_idx:
-                # nothing of this row is in flight: its last token is
-                # host-known (fresh prefill, refill, or post-recovery
-                # rollback) and overrides the stale carry in-graph
-                fresh_mask[i] = True
-                fresh_tok[i] = req.tokens[-1]
-            pos[i] = req.prompt.size + d - 1
-            ids = self._table_ids(req)
-            tables[i, : len(ids)] = ids
-            gen_idx[i] = d
-            aids[i] = req.adapter
-            keys[i] = req.row_key
-            rows.append((req, i, d))
-        return fresh_mask, fresh_tok, pos, tables, gen_idx, aids, keys, rows
+        """The ring's step inputs with ``disp`` live, derived from each
+        row's ``dispatched`` counter, and the rows' ``(request, slot, token
+        index)`` for the drain.  A row with nothing in flight (``dispatched
+        == gen_idx``: fresh prefill, refill, post-recovery rollback, and
+        every row at depth 0) hands over its last token, which overrides
+        the stale carry in-graph; any other is fed the carried one."""
+        rows = [(req, req.slot, req.dispatched) for req in disp]
+        return self._step_inputs(
+            self._step_row(req, d, req.tokens[-1] if d == req.gen_idx else None)
+            for req, _, d in rows
+        ), rows
 
     def _zero_carry(self):
         """A mesh-replicated, COMMITTED int32[slots] zeros vector whose
@@ -2032,8 +1949,8 @@ class ContinuousScheduler:
         as every later one would compile the SAME program twice (one
         re-layout entry).  Matching the output's replicated NamedSharding
         up front keeps every caller — the ring's first and carried
-        dispatches, the sync body, probe and replay — at exactly one
-        compiled decode program — the compile-count pin the tests hold."""
+        dispatches, the probe and the replay — at exactly one compiled
+        decode program — the compile-count pin the tests hold."""
         if self._zero_tok is None:
             z = jnp.zeros((self.slots_n,), jnp.int32)
             leaf_sh = getattr(
@@ -2087,13 +2004,13 @@ class ContinuousScheduler:
         host-known stream.
 
         ``tick`` calls this on any failure BEFORE invoking the
-        supervisor: probes and replays assume sync-equivalent host state
-        (``_decode_probe`` re-dispatches from ``tokens[-1]``), and
+        supervisor: probes and replays assume every row's last token on
+        the host (``_decode_probe`` re-dispatches from ``tokens[-1]``), and
         attribution must not blame a request for a step that was merely
         in flight when an unrelated row poisoned the tick.  Runs on the
         tick thread only.  Discarded steps cost nothing —
         re-dispatching them reproduces the same tokens and the same
-        idempotent pool writes.  No-op in sync mode (the ring is empty).
+        idempotent pool writes.  At depth 0 the ring is empty already.
         """
         while self._inflight:
             entry = self._inflight.popleft()
@@ -2104,7 +2021,7 @@ class ContinuousScheduler:
                 # of the same failure — discard, the rollback below makes
                 # re-dispatch exact
                 self.logger.warning(
-                    "async ring drain failed mid-recovery; discarding %d "
+                    "ring drain failed mid-recovery; discarding %d "
                     "remaining in-flight step(s)", len(self._inflight),
                 )
                 self._inflight.clear()
@@ -2118,7 +2035,7 @@ class ContinuousScheduler:
     # ------------------------------------------------------------------ #
     # speculative decoding (serving/speculative.py)
 
-    def _spec_decode_step(self) -> None:
+    def _spec_round(self) -> None:
         """One speculative round for every occupied slot, replacing the
         single-token decode step: k+1 greedy draft steps on the draft
         pool (the last a pure K/V backfill of the final proposal), one
@@ -2168,31 +2085,21 @@ class ContinuousScheduler:
             # proposal commits, and even a self-draft would drift off the
             # target (acceptance < 1 for no reason) ---------------------
             draft_tok = np.zeros((W, k), np.int32)
-            pad_keys = self._pad_keys(W)
             for j in range(k + 1):
-                prev = np.zeros((W,), np.int32)
-                pos = np.full((W,), -1, np.int32)
-                dtables = np.zeros((W, self.table_blocks), np.int32)
-                gi = np.zeros((W,), np.int32)
-                aids = np.full((W,), -1, np.int32)
-                any_row = False
-                for req in active:
-                    i = req.slot
-                    if j > k_eff[i]:
-                        continue
-                    any_row = True
-                    prev[i] = req.tokens[-1] if j == 0 else draft_tok[i, j - 1]
-                    pos[i] = req.prompt.size + req.gen_idx - 1 + j
-                    dids = req.draft_admission.block_ids
-                    dtables[i, : len(dids)] = dids
-                    gi[i] = req.gen_idx + j
-                    if self._draft_lora:
-                        aids[i] = req.adapter
-                if not any_row:
+                # the draft's own tables, the pad key (it is greedy), and
+                # the row's adapter only where the draft has factors
+                rows = [
+                    (r.slot, r.prompt.size, r.gen_idx + j,
+                     r.tokens[-1] if j == 0 else draft_tok[r.slot, j - 1],
+                     r.draft_admission.block_ids,
+                     r.adapter if self._draft_lora else -1, None)
+                    for r in active if j <= k_eff[r.slot]
+                ]
+                if not rows:
                     break
-                tok, _, self._draft_pool, *_ = self._step_known(
-                    self._draft_fns, self._draft_params, self._draft_pool,
-                    prev, pos, dtables, pad_keys, gi, aids,
+                tok, _, self._draft_pool, *_ = self._draft_fns.decode_step(
+                    self._draft_params, self._draft_pool,
+                    self._zero_carry(), *self._step_inputs(rows),
                 )
                 if j < k:
                     draft_tok[:, j] = np.asarray(tok)
@@ -2271,11 +2178,7 @@ class ContinuousScheduler:
         if accepted:
             self._bump("spec_accepted", accepted)
         self.metrics.record_decode(n_tokens=emitted_total, decode_s=t1 - t0)
-        self.metrics.record_iteration(
-            active_slots=len(active), total_slots=self.slots_n,
-            blocks_in_use=self._kv.blocks_in_use,
-            total_blocks=self._kv.num_blocks,
-        )
+        self._record_iteration(len(active))
 
     # ------------------------------------------------------------------ #
     # retirement and recovery

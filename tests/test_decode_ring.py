@@ -1,11 +1,13 @@
-"""The ring of depth 1 against the sync body, over the four served families at
-their test widths: the same arrivals through both bodies leave the same
-tokens, the same ``finite`` flags a step and, bit for bit, the same pool
-(token rows and, where a family carries one, the per-slot state).
+"""Depth 0 against depth 1 of the scheduler's one decode body, over the four
+served families at their test widths: the same arrivals at both depths leave
+the same tokens, the same ``finite`` flags a step and, bit for bit, the same
+pool (token rows and, where a family carries one, the per-slot state).
 
-Both bodies run the ONE ``decode_step`` program (serving/decode.py); what
-differs is who knows a row's last token: the host (a mask of all rows) or the
-device (the carried output, with the rows just prefilled spliced in).
+Both run the ONE ``decode_step`` program (serving/decode.py) from the one
+body (``ContinuousScheduler._ring_step``); what differs is who knows a row's
+last token: at depth 0 the host (each step is read in its own tick, so the
+mask names every live row), at depth 1 the device (the carried output, with
+the rows just prefilled spliced in).
 """
 import importlib.util
 import os
@@ -110,6 +112,7 @@ def _run(model, params, vocab, depth, temperature):
 
 @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
 def test_the_ring_serves_what_the_sync_body_serves(family, temperature):
+    # ("sync" = the body at depth 0: a step is read in the tick that sent it)
     model, params, vocab = family
     sync = _run(model, params, vocab, 0, temperature)
     ring = _run(model, params, vocab, 1, temperature)
@@ -131,7 +134,7 @@ def test_the_ring_serves_what_the_sync_body_serves(family, temperature):
     assert len(flat_a) == len(flat_b) > 0
     for (path, a), (_, b) in zip(flat_a, flat_b):
         np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(path))
-    # the ring did engage, the sync body did not, one decode program each
+    # depth 1 did overlap, depth 0 did not, one decode program each
     assert sync[3]["decode_overlap_share"] == 0.0
     assert ring[3]["decode_overlap_share"] == 7 / 8  # all but the first
     assert sync[4] == ring[4] == 1

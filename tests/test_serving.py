@@ -1274,11 +1274,12 @@ def test_engine_mode_config_validation(lm_and_params):
 
 
 # --------------------------------------------------------------------- #
-# async decode pipeline (serving.scheduler.async_depth)
+# the decode ring (ContinuousScheduler's async_depth: 0 = a step is read in
+# its own tick, "sync" below; an engine serves 1)
 
 
 def _async_mixed_case(lm_and_params, temperature, depth):
-    """Run the same mixed workload sync and async: 6 prompts through 2
+    """Run the same mixed workload at depth 0 and at ``depth``: 6 prompts through 2
     slots (refill happens while the pipeline is full), mixed gen-lens via
     per-request caps and EOS retirement."""
     model, params = lm_and_params
@@ -1308,9 +1309,9 @@ def _async_mixed_case(lm_and_params, temperature, depth):
 @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("depth", [1, 2])
 def test_scheduler_async_parity_bitwise(lm_and_params, temperature, depth):
-    """The deferred-readback pipeline is bitwise token-identical to the
-    sync loop, greedy AND sampled, under mixed gen-lens (per-request
-    caps + EOS) and slot refill mid-pipeline."""
+    """A ring that holds steps is bitwise token-identical to depth 0,
+    greedy AND sampled, under mixed gen-lens (per-request caps + EOS) and
+    slot refill mid-pipeline."""
     sync, pipelined = _async_mixed_case(lm_and_params, temperature, depth)
     for i, (a, b) in enumerate(zip(sync, pipelined)):
         assert a["gen_len"] == b["gen_len"], f"request {i} gen_len diverged"
@@ -1319,8 +1320,8 @@ def test_scheduler_async_parity_bitwise(lm_and_params, temperature, depth):
 
 def test_scheduler_async_compile_pin(lm_and_params, mode_prompts,
                                      plain_sched_results):
-    """The async pipeline adds NO program over the sync set: both bodies
-    run the one ``decode_step``.  The pin also guards the sharding
+    """A ring that holds steps adds NO program over depth 0: one body, one
+    ``decode_step``.  The pin also guards the sharding
     trap: the first dispatch's zero carry must hit the SAME cache entry
     as the steady-state carried token, or the program doubles."""
     model, params = lm_and_params
@@ -1354,28 +1355,19 @@ def test_scheduler_async_validation(lm_and_params):
         )
 
 
-@pytest.mark.parametrize("named, draft, want", [
-    (None, False, 1), (0, False, 0), (2, False, 2),
-    (None, True, 0), (0, True, 0), (1, True, "mutually exclusive"),
-], ids=["unnamed", "named_0", "named_2", "draft_unnamed", "draft_named_0",
-        "draft_named_1"])
+@pytest.mark.parametrize("draft, want", [(False, 1), (True, 0)],
+                         ids=["unnamed", "draft_unnamed"])
 def test_engine_serves_the_ring_unless_the_configuration_says_otherwise(
-    named, draft, want
+    draft, want
 ):
-    """``serving.scheduler.async_depth`` left out: an engine serves the ring
-    of depth 1, and the sync body beside a speculative draft (the two are
-    exclusive, and a configuration that never named a depth must not be
-    refused for one); a depth that IS named is taken as it stands, and
-    refused beside a draft."""
+    """The ring's depth is no option: an engine serves depth 1, and depth 0
+    beside a speculative draft (a round reads its own verify before the
+    next is proposed, and the scheduler refuses a ring beside one)."""
     from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
 
-    cfg = _warm_engine_cfg(**({} if named is None else {"async_depth": named}))
+    cfg = _warm_engine_cfg()
     if draft:
         cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
-    if isinstance(want, str):
-        with pytest.raises(ValueError, match=want):
-            InferenceEngine.from_config(cfg)
-        return
     with InferenceEngine.from_config(cfg) as engine:
         assert engine.scheduler._async_depth == want
         assert (engine.scheduler._spec is not None) == draft
@@ -1387,13 +1379,34 @@ def test_engine_serves_the_ring_unless_the_configuration_says_otherwise(
     assert share == (pytest.approx(2 / 3) if want else 0.0)
 
 
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "draft"])
+def test_engine_refuses_a_configuration_that_names_async_depth(draft):
+    """``serving.scheduler.async_depth`` was a key until PR 46: a
+    configuration that still names it is told so, as of any key the
+    section does not have, and no scheduler thread is left behind."""
+    import threading
+
+    from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
+
+    cfg = _warm_engine_cfg(async_depth=0 if draft else 1)
+    if draft:
+        cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
+    before = {t for t in threading.enumerate() if t.name == "serving-scheduler"}
+    with pytest.raises(ValueError, match=r"unknown serving\.scheduler keys: "
+                                         r"\['async_depth'\]"):
+        InferenceEngine.from_config(cfg)
+    left = {t for t in threading.enumerate()
+            if t.name == "serving-scheduler" and t.is_alive()} - before
+    assert not left
+
+
 @pytest.mark.parametrize("depth", [0, 1, 2], ids=["sync", "ring_1", "ring_2"])
 def test_decode_overlap_share_counts_the_steps_dispatched_over_a_full_ring(
     lm_and_params, depth
 ):
     """A scripted run, one request of 12 tokens, a tick at a time: of its
     11 decode steps the ring dispatches all but the first while an earlier
-    step's tokens are still unread, the sync body none; the ``decode_step``
+    step's tokens are still unread, depth 0 none; the ``decode_step``
     span carries the ring's length at the dispatch."""
     from pytorch_distributed_training_tpu.telemetry import (
         SpanRecorder,
@@ -1449,8 +1462,8 @@ def test_decode_overlap_share_of_a_fleet_is_recomputed_from_the_counts():
 
 @pytest.mark.parametrize("depth", [0, 1], ids=["sync", "async"])
 def test_scheduler_tick_metrics_surface(lm_and_params, mode_prompts, depth):
-    """tick_host_ms / decode_dispatch_gap_ms land in the snapshot on
-    both decode paths (gap samples need back-to-back decode ticks, which
+    """tick_host_ms / decode_dispatch_gap_ms land in the snapshot at
+    both depths (gap samples need back-to-back decode ticks, which
     any multi-token request produces)."""
     model, params = lm_and_params
     sched = _paged_sched(model, params, async_depth=depth)
@@ -1522,6 +1535,7 @@ def _decode_bodies():
         SpeculativeSpec,
     )
 
+    # "sync" is the ring at depth 0: a step is read in the tick that sent it
     return {
         "sync": {},
         "async_ring": {"async_depth": 2},
@@ -1533,7 +1547,8 @@ def _decode_bodies():
 def test_scheduler_tick_phase_spans_and_request_records(
     lm_and_params, mode_prompts, body
 ):
-    """Every decode body splits a tick into the same kinds, each a child
+    """The ring at depth 0 and at depth 2 and a speculative round split a
+    tick into the same kinds, each a child
     of ``tick``; every retired request leaves one ``request`` record with
     its id and its four stamps in order; the counts of the life histograms
     follow the requests and tokens served."""
@@ -1616,8 +1631,8 @@ def test_readback_wait_names_the_tick_whose_step_it_drains(
 ):
     """One ``readback_wait`` a drained step, inside that step's ``readback``
     and around its first read only; both carry ``for_step``, the tick of the
-    ``decode_step`` whose output they drain: the same tick on the sync body,
-    the tick before on a ring of depth 1 (the endgame drains its own)."""
+    ``decode_step`` whose output they drain: the same tick at depth 0, the
+    tick before on a ring of depth 1 (the endgame drains its own)."""
     from pytorch_distributed_training_tpu.telemetry import (
         SpanRecorder,
         set_recorder,
@@ -1773,10 +1788,15 @@ def test_prefill_span_carries_the_stalled_rows_and_the_padded_size(
     if estimate is not None:
         assert (snap["prefill_call_fixed_ms"],
                 snap["prefill_call_ms_per_ktoken"]) == estimate
-    # the rows decoding waited ONCE, through all of the tick's calls
+    # the rows decoding waited ONCE, through all of the tick's calls.  What
+    # they waited is the phase account of that tick, whose clock starts
+    # after a call's span has opened and stops before it closes: never more
+    # than the spans' sum (the one observation is its own p50, exact; a
+    # span's ``ms`` is rounded to the microsecond), and most of it
     assert snap["prefill_stall_ms_count"] == 1
-    assert snap["prefill_stall_ms_p50"] >= sum(
-        s["ms"] for s in second) - 0.01
+    spanned = sum(s["ms"] for s in second)
+    assert 0.5 * spanned <= snap["prefill_stall_ms_p50"]
+    assert snap["prefill_stall_ms_p50"] <= spanned + 0.0005 * len(second)
 
 
 def test_draft_prefill_is_a_span_of_its_own_beside_the_target_calls(
@@ -1926,7 +1946,7 @@ def test_decode_step_carries_the_trace_scopes(lm_and_params):
     assert [n for n in names if "/attn/paged_attention/" in n]
     assert "jit(decode_step)/sample" in names
     assert [n for n in names if "loss_head/head" in n]  # the logits matmul
-    # the ONE decode program, ring or sync: every operation of the compiled
+    # the ONE decode program: every operation of the compiled
     # program is named under jit(decode_step), which is how a trace's
     # readers find a decode step's scopes (the ``op_name`` an ``.xplane.pb``
     # keeps; benchmark/decode_scopes.py::DECODE)
@@ -1955,12 +1975,13 @@ def pool_program(sched, name):
 
 def pool_program_args(sched, name):
     """Arguments, in order, with which the scheduler calls ``_fns.<name>``
-    (no request active: every row rides at position -1): ``decode_step`` as
-    a caller that knows every row's token hands them over (a mask of all
-    rows), ``decode_step.carried`` as the ring does (no row fresh: each is
-    fed the carried token)."""
+    (no request active: every row rides at position -1, the step of
+    ``step_inputs`` with no live row): ``decode_step`` with every row said
+    to be fresh, as where the host holds each live row's token,
+    ``decode_step.carried`` as a ring that holds steps does (no row fresh:
+    each is fed the carried token)."""
     W, T = sched.slots_n, sched.table_blocks
-    prev, pos, tables, gen_idx, aids, keys = sched._decode_arrays([])
+    _, prev, pos, tables, keys, gen_idx, aids = sched._step_inputs(())
     tokens = np.zeros((W, 8), np.int32)
     positions = np.full((W, 8), -1, np.int32)
     oob = np.full((W,), sched._kv.num_blocks * sched._kv.block_size, np.int32)
@@ -1968,7 +1989,7 @@ def pool_program_args(sched, name):
         "prefill": (sched.params, sched._pool, tokens, positions, tables,
                     np.zeros((W,), np.int32), keys, gen_idx, aids),
         "decode_step": (sched.params, sched._pool, sched._zero_carry(),
-                        sched._all_rows, prev, pos, tables, keys, gen_idx,
+                        np.ones((W,), bool), prev, pos, tables, keys, gen_idx,
                         aids),
         "decode_step.carried": (sched.params, sched._pool,
                                 sched._zero_carry(), np.zeros((W,), bool),
@@ -2001,12 +2022,6 @@ def test_every_pool_leaf_is_donated_to_the_program(lm_and_params, name):
     assert not any(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(new_pool))
 
 
-# what each decode body's engine names under ``serving.scheduler``: the
-# ring is what an engine serves when nothing is named
-_ENGINE_BODIES = {"sync": {"async_depth": 0}, "async_ring": {},
-                  "speculative": {}}
-
-
 def _warm_engine_cfg(**scheduler_more):
     return {
         "dataset": {"name": "synthetic_text", "n_classes": VOCAB},
@@ -2023,7 +2038,7 @@ def _warm_engine_cfg(**scheduler_more):
     }
 
 
-@pytest.mark.parametrize("mode", ["sync", "async_ring", "speculative"])
+@pytest.mark.parametrize("mode", ["async_ring", "speculative"])
 def test_warmup_hands_the_scheduler_its_pool_back(mode):
     """The warm-up runs the donating programs on the scheduler's own pool:
     what it leaves in ``_pool`` (and ``_draft_pool``) is alive, holds what
@@ -2031,7 +2046,7 @@ def test_warmup_hands_the_scheduler_its_pool_back(mode):
     place: all of it."""
     from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
 
-    cfg = _warm_engine_cfg(**_ENGINE_BODIES[mode])
+    cfg = _warm_engine_cfg()
     if mode == "speculative":
         cfg["serving"]["speculative"] = {"enabled": True, "k": 2}
     prompt = np.asarray([4, 8, 15, 16, 23], np.int32)
@@ -2137,14 +2152,17 @@ def test_a_tick_hands_the_programs_host_arrays_only(
     _assert_host_built(calls)
     step = "verify" if body == "speculative" else "decode_step"
     assert {"prefill", step} <= {name for name, _ in calls}
-    # a caller that knows every row's token says so of ALL rows; the ring
-    # only of the rows it has nothing in flight for
-    masks = [args[3] for name, args in calls if name == "decode_step"]
-    assert all(m.dtype == bool for m in masks)
+    # where the host holds every live row's token (depth 0, a draft's
+    # steps) the mask names exactly the live rows; a ring that holds steps
+    # names only the rows it has nothing in flight for
+    steps = [(args[3], args[5] >= 0) for name, args in calls
+             if name == "decode_step"]
+    assert all(m.dtype == bool and not (m & ~live).any() for m, live in steps)
     if body == "async_ring":
-        assert masks[0].any() and not all(m.all() for m in masks)
+        assert steps[0][0].any()
+        assert not all((m == live).all() for m, live in steps)
     else:
-        assert all(m.all() for m in masks)
+        assert all((m == live).all() and live.any() for m, live in steps)
     # the prefill's rows: request i's key is fold_in(PRNGKey(seed), i),
     # made once at submit; a padding row rides the pad key
     keys = next(a for n, a in calls if n == "prefill")[_KEYS_AT["prefill"]]
@@ -2181,7 +2199,7 @@ def test_probe_and_replay_hand_the_programs_host_arrays_only(
     sched.close()
 
 
-@pytest.mark.parametrize("mode", ["sync", "async_ring"])
+@pytest.mark.parametrize("mode", ["async_ring"])
 def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
     """A warm-up BEFORE any traffic, then prefills and decode ticks: the
     program count stays where the warm-up left it, so each program's jit
@@ -2189,11 +2207,11 @@ def test_warmup_hands_the_programs_the_ticks_kinds_of_argument(mode):
     laid out anew) while requests are served."""
     from pytorch_distributed_training_tpu.serving.engine import InferenceEngine
 
-    cfg = _warm_engine_cfg(**_ENGINE_BODIES[mode])
+    cfg = _warm_engine_cfg()
     rng = np.random.default_rng(5)
     with InferenceEngine.from_config(cfg) as engine:
-        assert engine.scheduler._async_depth == (mode == "async_ring")
-        # one prefill bucket and ONE decode step, whichever body runs it
+        assert engine.scheduler._async_depth == 1
+        # one prefill bucket and ONE decode step, whoever calls it
         n = 2
         assert engine.scheduler._fns.decode_step._cache_size() == 0
         assert engine.warmup()["programs"] == n

@@ -722,7 +722,7 @@ def test_all_four_serving_faults_in_one_run(lm_and_params):
 
 
 # --------------------------------------------------------------------- #
-# poison isolation under the async decode pipeline (async_depth > 0):
+# poison isolation under a ring that holds steps (async_depth > 0):
 # the finite guard / poison shim fire up to async_depth ticks AFTER the
 # faulted dispatch, so eviction happens at DRAIN time — attribution must
 # still name exactly the poisoned request, and the lagged retire must
@@ -733,7 +733,7 @@ def test_all_four_serving_faults_in_one_run(lm_and_params):
 def test_async_poison_isolation_nan_output_guard(lm_and_params, depth):
     """serve_nan with a full dispatch ring: the non-finite flag is
     observed one-or-more ticks late at drain, evicts ONLY the poisoned
-    slot, and the survivors stay bitwise equal to a SYNC clean run."""
+    slot, and the survivors stay bitwise equal to a clean run at depth 0."""
     model, params = lm_and_params
     _, clean = _run_under_spec(model, params, None, prefix_cache=False)
     ref = [f.result()["tokens"] for f in clean]
@@ -758,7 +758,7 @@ def test_async_poison_isolation_nan_output_guard(lm_and_params, depth):
 @pytest.mark.parametrize("depth", [1, 2])
 def test_async_poison_isolation_decode_raise(lm_and_params, depth):
     """serve_raise mid-pipeline: the supervisor drains the in-flight
-    ring (flush_async) BEFORE bisecting, so the sync probe sees a
+    ring (flush_async) BEFORE bisecting, so the probe sees a
     state-consistent pool and convicts exactly the faulted request."""
     model, params = lm_and_params
     _, clean = _run_under_spec(model, params, None, prefix_cache=False)
