@@ -70,7 +70,8 @@ def parse_topology(r, cfg: dict, train_cfg: dict, train_dataset) -> None:
     r.is_lm = bool(getattr(family, "is_language_model", False))
     refusal = getattr(family, "training_unsupported", None)
     if refusal:
-        # a served-only family must not fall into the TransformerLM paths
+        # a served-only family (models._LM_FAMILIES lists the five, each
+        # with its reason) must not fall into the TransformerLM paths
         raise ValueError(f"model.name: {model_name} cannot be trained: {refusal}")
     # MoE (model.moe_experts > 0, ops/moe.py): trains on the GSPMD path
     # whatever the parallelism degrees — the routing einsums and the

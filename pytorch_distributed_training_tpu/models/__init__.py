@@ -6,11 +6,11 @@ num_classes) -> model``.  Case-insensitive on the name; the reference configs
 use ``ResNet50`` (config/ResNet50.yml:31).
 
 Families: the reference's ResNet-18/34/50/101/152 (README.md:7-13) plus a
-ViT family (ViT-Ti16/S16/B16) and five language-model families added beyond
+ViT family (ViT-Ti16/S16/B16) and six language-model families added beyond
 the reference — the config surface only pins ``model.name``, so new names
 slot straight in.  ``_LM_FAMILIES`` below lists them once, each with its
 module and what sets it apart; ``TransformerLM`` trains and serves, the other
-four are served, not trained, and share their norm, head, dense MLP and
+five are served, not trained, and share their norm, head, dense MLP and
 expert layer through :mod:`.lm_parts`.
 
 What a model IS is stated by its class, not compared by name:
@@ -27,6 +27,7 @@ from typing import Any, Optional
 import jax.numpy as jnp
 
 from .deepseek_v2 import DeepseekV2LM
+from .laguna import LagunaLM
 from .nemotron_h import NemotronHLM
 from .olmo_hybrid import OlmoHybridLM
 from .resnet import RESNET_CONFIGS, BasicBlock, Bottleneck, ResNet
@@ -39,6 +40,7 @@ __all__ = [
     "list_models",
     "model_class",
     "DeepseekV2LM",
+    "LagunaLM",
     "NemotronHLM",
     "OlmoHybridLM",
     "SolarOpen2LM",
@@ -68,6 +70,11 @@ _LM_FAMILIES = {
     # rectangular state: the third kind) three to one with full attention
     # under a QK-norm over the whole projection
     "OlmoHybrid": OlmoHybridLM,
+    # window and global softmax layers three to one, each with its own head
+    # count and rotary term and a gate a head: the window layers' K/V rows
+    # in a ring a slot beside the pool (the fourth slot-addressed kind),
+    # dropless experts beside a shared one after a dense first layer
+    "Laguna": LagunaLM,
 }
 _CANONICAL.update({name.lower(): name for name in _LM_FAMILIES})
 

@@ -254,6 +254,8 @@ class NemotronHLM(nn.Module):
             "attention_bias": (self.attention_bias, False),
             "mlp_bias": (self.mlp_bias, False),
             "use_bias": (self.use_bias, False),
+            # GroupedQueryAttention has a ``window`` since the Laguna family;
+            # this family's config carries null and no layer is given one
             "sliding_window": (self.sliding_window, None),
             "n_group": (self.n_group, 1),
             "topk_group": (self.topk_group, 1),

@@ -25,11 +25,20 @@ through the one :func:`paged_attention`) and a LATENT row
 shared by every head).  Serving code
 finds the leaves through :func:`pool_leaf_role`, which the attention
 modules answer, never by spelling a leaf's name itself.
+
+A WINDOW layer (:class:`GroupedQueryAttention` with ``window``) keeps no row
+in the pool: a query reads its ``window`` newest keys and nothing older, so
+its K/V rows live in a RING a slot, two leaves ``[slots * window, Hkv, hd]``
+among the slot-addressed leaves (``STATE_LEAVES``), position ``p`` of slot
+``s`` at row ``s * window + p % window``.  The pool's block table, the
+admission and the frees know nothing of it: a slot's ring is a fresh row's
+from its first position on.
 """
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,10 +47,12 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.sequence import ring_attention, ulysses_attention
 from ..utils.vma import varying_axes_of
+from .rotary import rotate_halves
 
 __all__ = [
     "GroupedQueryAttention", "MultiHeadAttention", "dot_product_attention",
     "is_state_leaf", "paged_attention", "pool_leaf_role", "rms_norm",
+    "window_attention",
 ]
 
 # The paged pool's leaves, under the names the attention modules give them.
@@ -54,16 +65,22 @@ _POOL_ROLES = {KEY_POOL: "scored", VALUE_POOL: "value", LATENT_POOL: "scored"}
 # (:mod:`..ops.kda`: the delta-rule state and the convolution's last rows;
 # :mod:`..ops.mamba2`: the state-space state and its convolution's rows;
 # :mod:`..ops.gated_delta`: the scalar-decay delta rule's rectangular state
-# and its convolution's rows),
+# and its convolution's rows;
+# :class:`GroupedQueryAttention` with ``window``: the ring of the layer's K/V
+# rows, ``window`` rows a slot),
 # addressed by ``state_rows`` and never through a block table.  They have no
 # role among the pool's rows, whatever their leading size.
 KDA_STATE, KDA_CONV = "kda_state", "kda_conv"
 MAMBA_STATE, MAMBA_CONV = "mamba_state", "mamba_conv"
 GDN_STATE, GDN_CONV = "gdn_state", "gdn_conv"
+# a window layer's ring of K/V rows a slot: the fourth slot-addressed kind
+WINDOW_KEYS, WINDOW_VALUES = "window_k", "window_v"
 # query rows of one batch row whose scores the grouped path builds at once
 QUERY_BLOCK = 512
+WINDOW_LEAVES = (WINDOW_KEYS, WINDOW_VALUES)
 STATE_LEAVES = (
-    KDA_STATE, KDA_CONV, MAMBA_STATE, MAMBA_CONV, GDN_STATE, GDN_CONV)
+    KDA_STATE, KDA_CONV, MAMBA_STATE, MAMBA_CONV, GDN_STATE, GDN_CONV,
+) + WINDOW_LEAVES
 
 
 def _leaf_name(path) -> str:
@@ -646,30 +663,202 @@ def rms_norm(x, weight, eps: float):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-class GroupedQueryAttention(nn.Module):
-    """Causal softmax attention with fewer K/V heads than query heads and NO
-    position term (NoPE): ``q = x W_q`` (``H`` heads), ``k, v = x W_k, x W_v``
-    (``Hkv`` heads), query head ``h`` reads K/V head ``h // (H / Hkv)``,
-    scores ``q.k / sqrt(hd)``, softmax in float32, then with ``gate`` the
-    output gate ``o * sigmoid(x W_gate)`` (element-wise, arXiv:2505.06708)
-    before ``W_o``.  No bias.  ``qk_norm`` (off by default: the programs of
-    the families that leave it out are what they were): an RMSNorm with a
-    learned weight over the WHOLE projection of ``q`` and of ``k``, before
-    the heads are split (Olmo 2's QK-norm, arXiv:2501.00656).
+def _banded_scores(q, k, v, window: int, scale: float):
+    """A window layer's attention over the call's OWN keys, ``q [B, S, Hkv,
+    G, hd]`` and ``k``, ``v [B, S, Hkv, hd]`` at positions ``0 .. S - 1``
+    (the column IS the position: a prompt is prefilled whole, padding to its
+    right): query ``i`` reads the keys ``i - window < j <= i``.  A block of
+    ``band = min(window, S)`` query rows is scored against its own block of
+    keys and the one before it, ``S x 2 band`` products and one ``[Hkv, G,
+    band, 2 band]`` array of float32 scores at a time, where the masked
+    ``[S, S]`` form builds ``S x S``.  Returns ``[B, S, Hkv, G, hd]`` in
+    float32."""
+    b, s, hkv, group, hd = q.shape
+    band = min(window, s)
+    if s % band:
+        raise ValueError(
+            f"a window layer's call of {s} positions is no multiple of its "
+            f"window {window}: give the scheduler sequence buckets that are")
+    blocks = s // band
 
-    ``decode=False``: plain causal attention over the call's own tokens.
-    ``decode=True, paged=True``: K/V rows of ``Hkv`` heads in the paged pool
-    (:func:`paged_attention`: a decode step through the paged kernel, a
-    prefill's rows gathered as stored and a long call's scores built
-    ``query_block`` query rows at a time), under the scope
-    ``gqa_attention``."""
+    def in_blocks(a):  # [B, S, ...] -> [B * blocks, band, ...]
+        return a.reshape((b * blocks, band) + a.shape[2:])
+
+    def before(a):  # the block before each block, zeros before the first
+        shifted = jnp.pad(a, ((0, 0), (band, 0)) + ((0, 0),) * (a.ndim - 2))
+        return in_blocks(shifted[:, :s])
+
+    first = jnp.tile(jnp.arange(blocks, dtype=jnp.int32) * band, b)
+    rows = jnp.arange(band, dtype=jnp.int32)
+    cols = jnp.arange(2 * band, dtype=jnp.int32) - band
+
+    def one_block(args):
+        q_rows, k_prev, k_own, v_prev, v_own, at = args
+        keys = jnp.concatenate([k_prev, k_own], axis=0)  # [2 band, Hkv, hd]
+        values = jnp.concatenate([v_prev, v_own], axis=0)
+        scores = jnp.einsum(
+            "qhgd,khd->hgqk", q_rows, keys, preferred_element_type=jnp.float32,
+        ) * scale
+        ahead = cols[None, :] - rows[:, None]  # key position - query position
+        seen = (ahead <= 0) & (ahead > -window) & (at + cols >= 0)[None, :]
+        p = nn.softmax(jnp.where(seen, scores, float("-inf")), axis=-1)
+        return jnp.einsum(
+            "hgqk,khd->qhgd", p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+
+    out = jax.lax.map(one_block, (
+        in_blocks(q), before(k), in_blocks(k), before(v), in_blocks(v), first))
+    return out.reshape(b, s, hkv, group, hd)
+
+
+def window_attention(module, q, k, v, positions, slots, *, window: int,
+                     state_slots: int, block_size: int, dtype):
+    """A window layer against its ring, for ``q [B, S, H, hd]`` and ``k``,
+    ``v [B, S, Hkv, hd]`` (already rotated: a key is stored as it is
+    scored): query position ``p`` reads the keys at ``p - window < j <= p``,
+    its own among them, and nothing older is kept.
+
+    The ring lives as two leaves ``[state_slots * window, Hkv, hd]`` in
+    ``module``'s "cache" collection; position ``p`` of slot ``s`` lies at row
+    ``s * window + p % window``.  ``positions [B, S]`` as in
+    :func:`paged_attention` (-1 = padding); ``slots [B]`` int32 names each
+    batch row's slot (-1 = padding: nothing is written).
+
+    - ``S == 1``, a decode step: the row's key and value overwrite the one
+      that left the window, then the query reads the ring's ``min(p + 1,
+      window)`` written rows.  Softmax does not care in which order keys
+      come, so on a TPU this is :func:`..ops.paged_decode.paged_decode` AS
+      IT IS over the ring, the slot's ``window / block_size`` blocks its
+      table and ``min(p + 1, window)`` its length; elsewhere the slot's rows
+      are gathered, masked past that length and scored.
+    - ``S > 1``, a prefill: the prompt is prefilled WHOLE (positions ``0 ..
+      n - 1`` in columns ``0 .. n - 1``; a model that carries slot-addressed
+      leaves has no prefix cache and no prefill in pieces, and the scheduler
+      refuses a call that starts anywhere else), so the call
+      scores its own keys in a band (:func:`_banded_scores`) and reads no
+      cache, then writes the last ``min(n, window)`` real positions into the
+      ring (a padding column writes nothing).
+    """
+    b, s, num_heads, head_dim = q.shape
+    kv_heads = k.shape[2]
+    group = num_heads // kv_heads
+    if group * kv_heads != num_heads:
+        raise ValueError(
+            f"{num_heads} query heads are no multiple of {kv_heads} K/V heads")
+    if state_slots < 1:
+        raise ValueError(
+            f"a window layer keeps a ring a slot: state_slots >= 1, got {state_slots}")
+    if positions is None or slots is None:
+        raise ValueError("a window layer's paged call needs positions and slots")
+    ring_rows = state_slots * window
+    ring = [
+        module.variable(
+            "cache", name, jnp.zeros, (ring_rows, kv_heads, head_dim), dtype)
+        for name in (WINDOW_KEYS, WINDOW_VALUES)
+    ]
+    scale = 1.0 / math.sqrt(head_dim)
+    safe_slot = jnp.maximum(slots, 0)
+    grouped = q.reshape(b, s, kv_heads, group, head_dim)
+    if s > 1:
+        out = _banded_scores(grouped, k, v, window, scale)
+        # the newest ``window`` real positions of each row, by column
+        n = jnp.max(positions, axis=1) + 1  # [B]
+        cols = n[:, None] - window + jnp.arange(window, dtype=jnp.int32)[None, :]
+        keep = (cols >= 0) & (slots >= 0)[:, None]
+        rows = jnp.where(
+            keep, safe_slot[:, None] * window + cols % window, ring_rows)
+        take = jnp.clip(cols, 0, s - 1)[:, :, None, None]
+        for leaf, new in zip(ring, (k, v)):
+            newest = jnp.take_along_axis(new, take, axis=1).astype(dtype)
+            leaf.value = leaf.value.at[rows.reshape(-1)].set(
+                newest.reshape(b * window, kv_heads, head_dim), mode="drop")
+        return out.reshape(b, s, num_heads, head_dim).astype(q.dtype)
+    pos = positions[:, 0]
+    live = (pos >= 0) & (slots >= 0)
+    safe_pos = jnp.maximum(pos, 0)
+    row = jnp.where(live, safe_slot * window + safe_pos % window, ring_rows)
+    for leaf, new in zip(ring, (k, v)):
+        leaf.value = leaf.value.at[row].set(new[:, 0].astype(dtype), mode="drop")
+    lengths = jnp.minimum(safe_pos + 1, window)  # a padding row reads row 0
+    from . import paged_decode
+    from .flash_attention import flash_enabled
+
+    if (flash_enabled() and block_size > 0 and window % block_size == 0
+            and paged_decode.fits(head_dim, kv_heads, dtype)):
+        per_slot = window // block_size
+        tables = safe_slot[:, None] * per_slot + jnp.arange(
+            per_slot, dtype=jnp.int32)[None, :]
+        in_ring_blocks = (state_slots * per_slot, block_size, kv_heads, head_dim)
+        out = paged_decode.paged_decode(
+            grouped[:, 0], ring[0].value.reshape(in_ring_blocks),
+            ring[1].value.reshape(in_ring_blocks), tables, lengths, scale=scale)
+        return out.reshape(b, 1, num_heads, head_dim)
+    by_slot = (state_slots, window, kv_heads, head_dim)
+    keys = ring[0].value.reshape(by_slot)[safe_slot]  # [B, window, Hkv, hd]
+    values = ring[1].value.reshape(by_slot)[safe_slot]
+    written = jnp.arange(window, dtype=jnp.int32)[None, :] < lengths[:, None]
+    scores = jnp.einsum(
+        "bhgd,bkhd->bhgk", grouped[:, 0], keys,
+        preferred_element_type=jnp.float32) * scale
+    p = nn.softmax(
+        jnp.where(written[:, None, None, :], scores, float("-inf")), axis=-1)
+    # an unwritten row may hold what an evicted request left there, a NaN too
+    values = jnp.where(written[:, :, None, None], values, 0)
+    out = jnp.einsum(
+        "bhgk,bkhd->bhgd", p.astype(values.dtype), values,
+        preferred_element_type=jnp.float32)
+    return out.reshape(b, 1, num_heads, head_dim).astype(q.dtype)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal softmax attention with fewer K/V heads than query heads: ``q =
+    x W_q`` (``H`` heads), ``k, v = x W_k, x W_v`` (``Hkv`` heads), query
+    head ``h`` reads K/V head ``h // (H / Hkv)``, scores ``q.k / sqrt(hd)``,
+    softmax in float32, then the output gate and ``W_o``.  No bias.
+    Everything below is static and off by default: a family that leaves it
+    out runs the program it ran.
+
+    ``rotary_dim > 0``: a rotary position term over the first ``rotary_dim``
+    lanes of every head of ``q`` and ``k`` (:func:`.rotary.rotate_halves`:
+    pairs ``(i, i + rotary_dim / 2)``), its ``rotary_dim / 2`` frequencies
+    ``rotary_inv_freq`` and its amplitude ``rotary_amp`` handed in by the
+    model (a default term, or YaRN's blend and attention factor,
+    :func:`.rotary.yarn_inv_freq`); the other lanes pass unchanged.  0 (the
+    default): NO position term (NoPE).  A key enters the cache rotated.
+
+    ``window > 0``: a query reads its ``window`` newest keys, its own among
+    them (``i - window < j <= i``), and the layer keeps a ring a slot beside
+    the pool (:func:`window_attention`).
+
+    ``gate``: ``True``, ``o * sigmoid(x W_gate)`` with
+    ``W_gate [dim, H * hd]``, one number a value (arXiv:2505.06708);
+    ``"head"``, ``W_gate [dim, H]``, ONE number a head (the same paper's
+    headwise form); ``False``, none.
+
+    ``qk_norm``: an RMSNorm with a learned weight over the WHOLE projection
+    of ``q`` and of ``k``, before the heads are split (Olmo 2's QK-norm,
+    arXiv:2501.00656).
+
+    ``decode=False``: plain causal (and windowed) attention over the call's
+    own tokens at positions ``0 .. S - 1``.  ``decode=True, paged=True``: K/V
+    rows of ``Hkv`` heads in the paged pool (:func:`paged_attention`: a
+    decode step through the paged kernel, a prefill's rows gathered as
+    stored and a long call's scores built ``query_block`` query rows at a
+    time) or, a window layer, in its ring.  Device scopes: ``gqa_attention``
+    around the layer and, inside it, ``rotary``, ``full_attention`` or
+    ``window_attention`` (scores, softmax and weighted sum, with the cache's
+    write and read) and ``head_gate``."""
 
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    gate: bool = True
+    gate: Any = True
     qk_norm: bool = False
     qk_norm_eps: float = 1e-6
+    rotary_dim: int = 0
+    rotary_inv_freq: Optional[Tuple[float, ...]] = None
+    rotary_amp: float = 1.0
+    window: int = 0
     # a paged call longer than this builds its scores this many query rows
     # of one batch row at a time
     query_block: int = QUERY_BLOCK
@@ -678,11 +867,23 @@ class GroupedQueryAttention(nn.Module):
     paged: bool = False
     kv_block_size: int = 0
     kv_num_blocks: int = 0
+    # slots of a window layer's ring (the scheduler's slots)
+    state_slots: int = 0
 
     @nn.compact
-    def __call__(self, x, positions=None, block_tables=None):
+    def __call__(self, x, positions=None, block_tables=None, state_rows=None):
         b, s, dim = x.shape
         h, hkv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        gate = self.gate
+        if not (gate is True or gate is False or gate == "head"):
+            raise ValueError(f"gate is True, 'head' or False, got {gate!r}")
+        if self.rotary_dim and (
+                self.rotary_dim % 2 or self.rotary_dim > hd
+                or len(self.rotary_inv_freq or ()) != self.rotary_dim // 2):
+            raise ValueError(
+                f"rotary_dim {self.rotary_dim} of a head of {hd} needs "
+                f"{self.rotary_dim // 2} frequencies, got "
+                f"{len(self.rotary_inv_freq or ())}")
         init = nn.initializers.lecun_normal()
         wq = self.param("wq", init, (dim, h * hd), self.dtype)
         wk = self.param("wk", init, (dim, hkv * hd), self.dtype)
@@ -703,13 +904,30 @@ class GroupedQueryAttention(nn.Module):
                 raise ValueError(
                     "GroupedQueryAttention has no contiguous cache: decode "
                     "mode is the paged pool's (paged=True)")
-            if self.decode:
-                out = paged_attention(
-                    self, q, k, v, positions, block_tables,
-                    block_size=self.kv_block_size,
-                    num_blocks=self.kv_num_blocks, dtype=self.dtype,
-                    as_stored=True, query_block=self.query_block,
-                )
+            if self.rotary_dim:
+                with jax.named_scope("rotary"):
+                    at = jnp.broadcast_to(
+                        jnp.arange(s, dtype=jnp.int32), (b, s)
+                    ) if positions is None else jnp.maximum(positions, 0)
+                    turn = functools.partial(
+                        rotate_halves, positions=at,
+                        inv_freq=self.rotary_inv_freq, amplitude=self.rotary_amp)
+                    q, k = turn(q), turn(k)
+            scores_scope = "window_attention" if self.window else "full_attention"
+            if self.decode and self.window:
+                with jax.named_scope(scores_scope):
+                    out = window_attention(
+                        self, q, k, v, positions, state_rows, window=self.window,
+                        state_slots=self.state_slots,
+                        block_size=self.kv_block_size, dtype=self.dtype)
+            elif self.decode:
+                with jax.named_scope(scores_scope):
+                    out = paged_attention(
+                        self, q, k, v, positions, block_tables,
+                        block_size=self.kv_block_size,
+                        num_blocks=self.kv_num_blocks, dtype=self.dtype,
+                        as_stored=True, query_block=self.query_block,
+                    )
             else:
                 group = h // hkv
                 scores = jnp.einsum(
@@ -717,13 +935,21 @@ class GroupedQueryAttention(nn.Module):
                     preferred_element_type=jnp.float32,
                 ) / math.sqrt(hd)
                 causal = jnp.tril(jnp.ones((s, s), bool))
+                if self.window:  # the masked [S, S] form: i - window < j <= i
+                    causal &= ~jnp.tril(jnp.ones((s, s), bool), -self.window)
                 p = nn.softmax(jnp.where(causal, scores, float("-inf")), axis=-1)
                 out = jnp.einsum(
                     "bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                     preferred_element_type=jnp.float32,
                 ).astype(x.dtype)
+            if gate == "head":
+                with jax.named_scope("head_gate"):
+                    w_gate = self.param("w_gate", init, (dim, h), self.dtype)
+                    out = out.reshape(b, s, h, hd) * jax.nn.sigmoid(
+                        jnp.dot(x, w_gate).astype(jnp.float32)
+                    ).astype(out.dtype)[..., None]
             out = out.reshape(b, s, h * hd)
-            if self.gate:
+            if gate is True:
                 w_gate = self.param("w_gate", init, (dim, h * hd), self.dtype)
                 out = out * jax.nn.sigmoid(
                     jnp.dot(x, w_gate).astype(jnp.float32)).astype(out.dtype)

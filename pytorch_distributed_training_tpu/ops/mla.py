@@ -41,44 +41,17 @@ the pool how is decided by what the call shows, as there:
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from flax import linen as nn
 
 from . import mla_paged_decode
 from .attention import LATENT_POOL, rms_norm
+from .rotary import yarn_inv_freq, yarn_mscale
 
 __all__ = ["MLAttention", "rms_norm", "yarn_inv_freq", "yarn_mscale"]
-
-
-def yarn_mscale(scale: float, mscale: float) -> float:
-    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
-
-
-def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]) -> np.ndarray:
-    """Rotary frequencies ``[dim / 2]``; with a YaRN ``rope_scaling`` the
-    published ones divided by ``factor`` where a dimension turns fewer than
-    ``beta_slow`` times over the original context, kept where it turns more
-    than ``beta_fast`` times, a linear ramp between."""
-    freq = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if not scaling:
-        return freq.astype(np.float32)
-    original = scaling["original_max_position_embeddings"]
-
-    def correction_dim(rotations):
-        return dim * math.log(original / (rotations * 2 * math.pi)) / (
-            2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(scaling["beta_fast"])), 0)
-    high = min(math.ceil(correction_dim(scaling["beta_slow"])), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
-    return (freq / scaling["factor"] * ramp + freq * (1 - ramp)).astype(np.float32)
 
 
 def _rotate(x, cos, sin):

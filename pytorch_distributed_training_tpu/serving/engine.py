@@ -34,7 +34,7 @@ from ..parallel.mesh import (
     replicated_sharding,
 )
 from .batcher import DynamicBatcher, Request
-from ..ops.attention import is_state_leaf
+from ..ops.attention import WINDOW_LEAVES, is_state_leaf
 from .decode import build_generate_fn
 from .lora import LoraRegistry
 from .metrics import ServingMetrics
@@ -705,6 +705,11 @@ class InferenceEngine:
         self.metrics.record_cache_bytes(
             kv_pool_bytes=sum(int(leaf.nbytes) for _, leaf in flat) - state,
             state_cache_bytes=state,
+            pool_rows=sched._kv.num_blocks * sched._kv.block_size,
+            # the rings' part of ``state``: a leaf a window layer declared
+            window_ring_bytes=sum(
+                int(leaf.nbytes) for path, leaf in flat
+                if getattr(path[-1], "key", None) in WINDOW_LEAVES),
         )
         return aliased
 

@@ -383,13 +383,25 @@ class ServingMetrics:
         copying the pool before every step."""
         self._registry.gauge("pool_aliased_bytes").set(float(nbytes))
 
-    def record_cache_bytes(self, *, kv_pool_bytes: int, state_cache_bytes: int) -> None:
+    def record_cache_bytes(
+        self, *, kv_pool_bytes: int, state_cache_bytes: int, pool_rows: int,
+        window_ring_bytes: int = 0,
+    ) -> None:
         """The cache tree as the warm-up found it: bytes of the paged pool's
-        token rows and bytes of the per-slot state beside them (0 for a
+        token rows and bytes of the per-slot leaves beside them (0 for a
         model that carries none).  ``pool_aliased_bytes`` is their sum
-        where every program updates the whole tree in place."""
+        where every program updates the whole tree in place.
+        ``pool_bytes_per_token`` is what one more position of a row costs in
+        the pool (the layers that keep a row's whole history), and
+        ``window_ring_bytes``, a part of ``state_cache_bytes``, what the
+        window layers' rings hold whatever the rows' lengths (recorded only
+        for a model that has such layers)."""
         self._registry.gauge("kv_pool_bytes").set(float(kv_pool_bytes))
         self._registry.gauge("state_cache_bytes").set(float(state_cache_bytes))
+        self._registry.gauge("pool_bytes_per_token").set(
+            float(kv_pool_bytes) / max(int(pool_rows), 1))
+        if window_ring_bytes:
+            self._registry.gauge("window_ring_bytes").set(float(window_ring_bytes))
 
     def record_prefill_cost(self, fixed_ms: float, ms_per_ktoken: float) -> None:
         """What the warm-up's timed calls put a prefill call at: ``fixed_ms

@@ -350,6 +350,10 @@ class ContinuousScheduler:
         # assumes that a cache is blocks of token rows refuses it here, with
         # the reason, rather than serve something else in silence.
         self._state_shape = getattr(model, "state_shape", None)
+        # (window layers, window, full layers) of a model some of whose
+        # layers read a row's newest ``window`` positions only, from a ring
+        # a slot (one kind of slot-addressed leaf); None for any other
+        self._window_shape = getattr(model, "window_shape", None)
         if self._state_shape is not None:
             if prefix_cache:
                 raise ValueError(self._state_refusal(
@@ -606,6 +610,35 @@ class ContinuousScheduler:
         if self._state_shape is None:
             return None
         return float((pos >= 0).sum()) / self.slots_n
+
+    def _window_reads(self, pos) -> dict:
+        """What a fixed-width decode call's live rows read of their history,
+        as fields of its ``decode_step`` span: ``window_keys``, the positions
+        a WINDOW layer reads (``min(length, window)`` a row), and
+        ``full_keys``, those a full layer reads (every one).  ``{}`` for a
+        model with no window layer."""
+        if self._window_shape is None:
+            return {}
+        lengths = pos[pos >= 0].astype(np.int64) + 1
+        return dict(
+            window_keys=int(np.minimum(lengths, self._window_shape[1]).sum()),
+            full_keys=int(lengths.sum()),
+        )
+
+    def _refuse_a_piece(self, positions: np.ndarray) -> None:
+        """A model with window layers is prefilled a whole prompt a call: its
+        layers take a multi-token call's column for the position (a full
+        layer's table is cut to the call's own blocks, a window layer's band
+        and ring write count from column 0: ``models/laguna.py``,
+        ``ops/attention.py::window_attention``).  The scheduler owns what a
+        call holds, so it refuses here a call that starts anywhere else (a
+        prefix hit's suffix, a prefill in pieces) rather than let the layers
+        read other keys in silence."""
+        if self._window_shape is not None and (positions[:, 0] > 0).any():
+            raise ValueError(self._state_refusal(
+                f"a prefill call whose rows start at {positions[:, 0].tolist()}",
+                "its window layers take a call's column for the position: "
+                "a prompt is prefilled whole, from position 0"))
 
     def _pad_keys(self, n: int) -> np.ndarray:
         """The ``row_keys`` argument of a paged call of ``n`` batch rows,
@@ -1520,6 +1553,7 @@ class ContinuousScheduler:
             aids[i] = req.adapter
             keys[i] = req.row_key
         slots = [r.slot for r in newly] + [-1] * (bb - len(newly))
+        self._refuse_a_piece(positions)
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
             last_col, keys, np.zeros((bb,), np.int32), aids,
@@ -1613,6 +1647,7 @@ class ContinuousScheduler:
             aids[i] = req.adapter
             keys[i] = req.row_key
         slots = [r.slot for r in reqs] + [-1] * (bb - len(reqs))
+        self._refuse_a_piece(positions)
         tok, finite, self._pool = self._fns.prefill(
             self.params, self._pool, tokens, positions, tables,
             last_col, keys, np.zeros((bb,), np.int32), aids,
@@ -1903,7 +1938,8 @@ class ContinuousScheduler:
             # serve-side MTTR endpoint (telemetry/slo.py pairs it with the
             # preceding poison_bisect/serving_restart recovery span)
             with self._phase(
-                "decode_step", active=len(disp), inflight=inflight
+                "decode_step", active=len(disp), inflight=inflight,
+                **self._window_reads(inputs.pos),
             ):
                 # the first dispatch of a run carries nothing: every
                 # dispatched row is fresh by construction, and the zeros

@@ -159,6 +159,16 @@ CASES = {
         _paged_decode, _decode_call(32, 1, 4608, 192, kv_heads=32), BF16,
         ["paged_decode"],
     ),
+    # config/serve-laguna-xs2.yml, a full layer: 32 slots x 544 blocks, 48 query
+    # heads in groups of 6
+    "paged_decode_laguna_full": (
+        _paged_decode, _decode_call(32, 6, 17408, 544), BF16, ["paged_decode"],
+    ),
+    # ... and a window layer: the SAME kernel over the ring, 32 slots of 32
+    # blocks (512 positions), 64 query heads in groups of 8
+    "paged_decode_laguna_ring": (
+        _paged_decode, _decode_call(32, 8, 1024, 32), BF16, ["paged_decode"],
+    ),
     "paged_decode_f32": (
         _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
     ),

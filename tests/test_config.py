@@ -87,7 +87,7 @@ def test_all_shipped_configs_validate_against_generated_schema():
 
     repo = pathlib.Path(__file__).parent.parent
     pkg = repo / "pytorch_distributed_training_tpu"
-    assert len(list((repo / "config").glob("*.yml"))) == 19
+    assert len(list((repo / "config").glob("*.yml"))) == 20
     ctx = core.AnalysisContext(package_root=pkg, repo_root=repo)
     findings = ConfigSchemaPass().run(core.collect_modules(pkg, repo), ctx)
     assert findings == [], "\n".join(f.format() for f in findings)

@@ -1,7 +1,8 @@
-"""Depth 0 against depth 1 of the scheduler's one decode body, over the four
+"""Depth 0 against depth 1 of the scheduler's one decode body, over five
 served families at their test widths: the same arrivals at both depths leave
 the same tokens, the same ``finite`` flags a step and, bit for bit, the same
-pool (token rows and, where a family carries one, the per-slot state).
+pool (token rows and, where a family carries them, the per-slot state or
+the window layers' rings, which every row here wraps).
 
 Both run the ONE ``decode_step`` program (serving/decode.py) from the one
 body (``ContinuousScheduler._ring_step``); what differs is who knows a row's
@@ -19,6 +20,7 @@ import pytest
 
 import nemotron_toy
 import test_deepseek_v2 as deepseek_toy
+import test_laguna as laguna_toy
 import test_solar_open2 as solar_toy
 from pytorch_distributed_training_tpu.models import get_model
 from pytorch_distributed_training_tpu.serving.scheduler import ContinuousScheduler
@@ -61,6 +63,7 @@ FAMILIES = {
         _reference("solar_open2"), solar_toy, "SolarOpen2"),
     "nemotron_h": lambda: _from_reference(
         nemotron_toy.load_reference(), nemotron_toy, "NemotronH"),
+    "laguna": lambda: _from_reference(_reference("laguna"), laguna_toy, "Laguna"),
 }
 
 

@@ -37,7 +37,8 @@ def test_param_count_parity(name):
 def test_all_names_resolve():
     assert set(list_models()) == (
         set(TORCHVISION_PARAM_COUNTS) | VIT_NAMES
-        | {"TransformerLM", "DeepseekV2", "SolarOpen2", "NemotronH", "OlmoHybrid"}
+        | {"TransformerLM", "DeepseekV2", "SolarOpen2", "NemotronH", "OlmoHybrid",
+           "Laguna"}
     )
     for name in list_models():
         get_model(name, num_classes=10)

@@ -680,7 +680,7 @@ def test_shipped_configs_validate_against_generated_schema():
     modules = core.collect_modules(PKG, REPO)
     findings = ConfigSchemaPass().run(modules, ctx)
     assert findings == [], "\n".join(f.format() for f in findings)
-    assert len(list((REPO / "config").glob("*.yml"))) == 19
+    assert len(list((REPO / "config").glob("*.yml"))) == 20
     # and the real schema covers the sections the YAMLs actually use
     dump = schema_as_json(extract_schema(modules))
     for section in ("training", "serving.scheduler", "training.checkpoint"):
