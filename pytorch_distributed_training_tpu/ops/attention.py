@@ -22,7 +22,7 @@ Two layouts exist: a K/V PAIR (:class:`MultiHeadAttention` and
 :class:`GroupedQueryAttention`: two leaves ``[pool_rows, Hkv, hd]``, read
 through the one :func:`paged_attention`) and a LATENT row
 (:class:`..ops.mla.MLAttention`: one leaf ``[pool_rows, rank + rope]``
-shared by every head).  Serving code
+shared by every head, its rows held in whole lane tiles).  Serving code
 finds the leaves through :func:`pool_leaf_role`, which the attention
 modules answer, never by spelling a leaf's name itself.
 

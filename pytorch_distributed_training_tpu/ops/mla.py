@@ -20,9 +20,11 @@ Two forms compute the same function and read the same rows:
     for a cached position.  One query a row (decode).
 
 The paged path is :func:`..attention.paged_attention`'s contract over ONE
-pool leaf ``[pool_rows, rank + rope]`` a layer in the ``"cache"``
-collection: scatter this call's rows (padding dropped out of bounds), read
-each row's logical sequence through its block table, mask keys to
+pool leaf ``[pool_rows, lanes_up(rank + rope)]`` a layer in the ``"cache"``
+collection (a row in whole lane tiles, zeros past ``rank + rope``: the leaf
+is then row-major at rest on a TPU and no program turns it round,
+:func:`..mla_paged_decode.lanes_up`): scatter this call's rows (padding
+dropped out of bounds), read each row's logical sequence through its block table, mask keys to
 ``key_pos <= q_pos``, and zero dead rows before they meet any product, so
 that a NaN in a row stays with the request that owns it.  Which call reads
 the pool how is decided by what the call shows, as there:
@@ -148,18 +150,22 @@ class MLAttention(nn.Module):
                     f"got {bs}/{nb}"
                 )
             pool_rows = nb * bs
+            # a fresh pool is zeros and every write writes zeros past
+            # ``r + dr``: those lanes are zero for ever, and no arm reads them
+            width = mla_paged_decode.lanes_up(r + dr)
             pool = self.variable(
-                "cache", LATENT_POOL, jnp.zeros, (pool_rows, r + dr), self.dtype
+                "cache", LATENT_POOL, jnp.zeros, (pool_rows, width), self.dtype
             )
             blk = jnp.take_along_axis(block_tables, safe_pos // bs, axis=1)
             phys = jnp.where(valid, blk * bs + safe_pos % bs, pool_rows)  # OOB=drop
             filled = pool.value.at[phys.reshape(-1)].set(
-                rows.reshape(b * s, r + dr), mode="drop"
+                jnp.pad(rows.reshape(b * s, r + dr), ((0, 0), (0, width - r - dr))),
+                mode="drop",
             )
             pool.value = filled
             from .flash_attention import flash_enabled
 
-            blocks = filled.reshape(nb, bs, r + dr)  # a bitcast: rows split only
+            blocks = filled.reshape(nb, bs, width)  # a bitcast: rows split only
             if (s == 1 <= self.absorb_max_queries and flash_enabled()
                     and mla_paged_decode.fits(r, bs, self.dtype)):
                 # one position a row, on a TPU: the kernel reads the leaf
@@ -178,7 +184,7 @@ class MLAttention(nn.Module):
             # the pool, so one table entry moves a [bs, r + dr] slab (row by
             # row the same gather was a third of a decode step; splitting
             # only the row axis keeps the view free of a relayout)
-            keys = blocks[block_tables].reshape(
+            keys = blocks[block_tables, :, :r + dr].reshape(
                 b, length, r + dr
             )  # [B, L, r+dr] in logical-position order
             key_pos = jnp.arange(length, dtype=jnp.int32)

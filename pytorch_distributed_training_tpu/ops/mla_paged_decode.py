@@ -3,23 +3,21 @@
 The sibling of :mod:`.paged_decode` for the leaf of :mod:`.mla`: a cached
 position is ONE row of ``rank + rope`` values (the normalised latent ``c``
 and the rotated key ``k_pe``) that every head shares, the key of a head is
-the whole row and its value the row's first ``rank`` lanes.  The gather arm
-of :class:`..ops.mla.MLAttention` copies every row's FULL block table into a
-``[B, L, rank + rope]`` array (at DeepSeek-V2-Lite's serving widths 94 MB a
-layer), rewrites the copy through a ``where`` and reads it three times more.
-This kernel reads the pool where it lies: per row it walks the block table
-up to the row's own length, 256 positions a loop step, each live block one
-``[bs, rank + rope]`` slab brought into VMEM by its own DMA, double-buffered
-(the next step's blocks, or the next row's first ones, are in flight while
-this step's are scored), and never asks for a block past the length.
+the whole row and its value the row's first ``rank`` lanes.  The leaf holds
+a row in whole lane tiles (:func:`lanes_up`), zeros past ``rank + rope``.
+The gather arm of :class:`..ops.mla.MLAttention` copies every row's FULL
+block table into a ``[B, L, rank + rope]`` array (at DeepSeek-V2-Lite's
+serving widths 94 MB a layer), rewrites the copy through a ``where`` and
+reads it three times more.  This kernel reads the pool where it lies: per
+row it walks the block table up to the row's own length, 256 positions a
+loop step, each live block one ``[bs, width]`` slab brought into VMEM by its
+own DMA, double-buffered (the next step's blocks, or the next row's first
+ones, are in flight while this step's are scored), and never asks for a
+block past the length.
 
 The walk is the kernel's GRID, one grid step a loop step of a row, and the
-DMAs are the pipeline's own.  A row of the leaf is ``rank + rope`` = 4.5
-lane tiles at the served widths, and Mosaic refuses a ``make_async_copy``
-of such a slab ("slice shape must be aligned to tiling (128), but is 576";
-of the 64-lane tail alone likewise), while a BLOCK of an operand may be as
-wide as the operand whatever that is.  So the pool is handed over once a
-block of a loop step, and each operand's index map reads its block from the
+DMAs are the pipeline's own: the pool is handed over once a block of a loop
+step, and each operand's index map reads its block from the
 scalar-prefetched tables by way of three short lists (:func:`_walk`,
 :func:`_block`): the row's table entry where the entry is live, and where
 it is not the block the operand had before, which the pipeline does not
@@ -39,9 +37,10 @@ contraction, the serving output guard rests on a NaN staying in the row
 that made it).
 
 Heads.  All of them read the same row, so a step's blocks are ONE
-``[positions, rank + rope]`` matrix and every column the matrix unit scores
+``[positions, width]`` matrix and every column the matrix unit scores
 is one each head wants: there is no other head's column to mask.  The
-``rope`` lanes, half a lane tile, are scored in a product of their own.
+``rope`` lanes, half a lane tile, are scored in a product of their own, and
+the lanes past them in none.
 """
 from __future__ import annotations
 
@@ -53,10 +52,20 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["mla_paged_decode", "fits"]
+__all__ = ["mla_paged_decode", "fits", "lanes_up"]
 
 # positions a loop step scores: 16 blocks of 16 as DeepSeek-V2-Lite is served
 _STEP_POSITIONS = 256
+
+
+def lanes_up(width: int) -> int:
+    """``width`` rounded up to whole lane tiles of 128: what a row of the
+    leaf takes in the device's memory whatever its logical width, and the
+    leaf's last axis.  A last axis of 4.5 tiles (512 + 64) the TPU's
+    compiler lays out TRANSPOSED at rest, and every program that scatters
+    into the leaf or hands it to this kernel then turns the whole leaf
+    round and back, a layer; one of whole tiles it leaves row-major."""
+    return -(-width // 128) * 128
 
 
 def fits(rank: int, block_size: int, dtype) -> bool:
@@ -119,6 +128,7 @@ def _kernel(tables_ref, lengths_ref, row_ref, step_ref, source_ref, q_lat_ref,
             q_pe_ref, *refs, scale: float):
     *block_refs, o_ref, m_ref, l_ref = refs
     _, heads, rank = q_lat_ref.shape
+    rope = q_pe_ref.shape[-1]
     bs = block_refs[0].shape[0]
     step_positions = len(block_refs) * bs
     item = pl.program_id(0)
@@ -132,7 +142,7 @@ def _kernel(tables_ref, lengths_ref, row_ref, step_ref, source_ref, q_lat_ref,
         o_ref[row] = jnp.zeros((heads, rank), jnp.float32)
 
     rows = jnp.concatenate([ref[...] for ref in block_refs], axis=0)
-    c, k_pe = rows[:, :rank], rows[:, rank:]
+    c, k_pe = rows[:, :rank], rows[:, rank:rank + rope]
     s = (_dot(q_lat_ref[row], c, (1, 1)) + _dot(q_pe_ref[row], k_pe, (1, 1))) * scale
     position = lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(position < left, s, -jnp.inf)
@@ -158,8 +168,9 @@ def mla_paged_decode(q_lat, q_pe, pool, block_tables, lengths, *, scale: float,
     """Absorbed latent attention of one position a row over the pool.
 
     ``q_lat [B, H, rank]`` and ``q_pe [B, H, rope]`` in the pool's dtype;
-    ``pool [num_blocks, bs, rank + rope]`` (this call's own rows already
-    scattered in); ``block_tables [B, T]`` int32, the pool block holding
+    ``pool [num_blocks, bs, lanes_up(rank + rope)]`` (this call's own rows
+    already scattered in; the lanes past ``rank + rope`` are not read);
+    ``block_tables [B, T]`` int32, the pool block holding
     positions ``[t * bs, (t + 1) * bs)`` of row ``b``; ``lengths [B]``
     int32, at least 1: row ``b`` reads positions ``[0, lengths[b])`` and no
     block past them.  Returns ``o_lat [B, H, rank]`` float32, the
@@ -171,8 +182,9 @@ def mla_paged_decode(q_lat, q_pe, pool, block_tables, lengths, *, scale: float,
     """
     b, heads, rank = q_lat.shape
     _, bs, width = pool.shape
-    if q_pe.shape != (b, heads, width - rank) or not (
-            q_lat.dtype == q_pe.dtype == pool.dtype):
+    rope = q_pe.shape[-1]
+    if (q_pe.shape[:2] != (b, heads) or width != lanes_up(rank + rope)
+            or not q_lat.dtype == q_pe.dtype == pool.dtype):
         raise ValueError(
             f"q_lat {q_lat.shape} {q_lat.dtype} and q_pe {q_pe.shape} "
             f"{q_pe.dtype} do not read pool {pool.shape} {pool.dtype}")
@@ -194,7 +206,7 @@ def mla_paged_decode(q_lat, q_pe, pool, block_tables, lengths, *, scale: float,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(steps,),
-            in_specs=[whole(b, heads, rank), whole(b, heads, width - rank)]
+            in_specs=[whole(b, heads, rank), whole(b, heads, rope)]
             + [block(j) for j in range(step_blocks)],
             out_specs=whole(b, heads, rank),
             scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32)] * 2,

@@ -518,31 +518,35 @@ class InferenceEngine:
 
         Returns ``{"warmup_ms", "programs"}`` (programs = compile-count
         delta, 0 when everything was already warm — warmup is
-        idempotent).  On the scheduler path it also sets the gauge
+        idempotent).  On the scheduler path it also sets the gauges
         ``pool_aliased_bytes`` of ``metrics.snapshot()``, how much of the
-        pool the programs update in place, and logs it beside the program
-        count; and the gauges ``kv_pool_bytes`` and ``state_cache_bytes``,
-        the cache tree's token rows and its per-slot state (0 for a model
-        that carries none), whose sum a full donation aliases.  ``ServingFleet.add_replica`` calls this before routing
-        traffic to a new replica and publishes the wall time as the
-        ``scale_up_ready_ms`` gauge.
+        pool the programs update in place, and ``decode_program_temp_bytes``,
+        the temporaries of the compiled decode step (a whole pool leaf that
+        a step copies shows here, a leaf's bytes a copy, where the alias
+        cannot: the output reuses the input's buffer and is still written by
+        a copy), and logs them beside the program count; and the gauges
+        ``kv_pool_bytes`` and ``state_cache_bytes``, the cache tree's token
+        rows and its per-slot state (0 for a model that carries none), whose
+        sum a full donation aliases.  ``ServingFleet.add_replica`` calls
+        this before routing traffic to a new replica and publishes the wall
+        time as the ``scale_up_ready_ms`` gauge.
         """
         import time
 
         t0 = time.perf_counter()
         before = self.compile_count()
-        aliased = None
+        compiled = {}
         if not self.is_lm:
             self._warmup_classify()
         elif self.scheduler is not None:
-            aliased = self._warmup_scheduler()
+            compiled = self._warmup_scheduler()
         else:
             self._warmup_batcher()
         warmed = self.compile_count() - before
         ms = (time.perf_counter() - t0) * 1000.0
         self.logger.info(
             "engine warmup: %d program(s) compiled in %.0f ms%s", warmed, ms,
-            "" if aliased is None else f", pool_aliased_bytes={aliased}",
+            "".join(f", {name}={n}" for name, n in compiled.items()),
         )
         return {"warmup_ms": ms, "programs": float(warmed)}
 
@@ -567,26 +571,22 @@ class InferenceEngine:
         it, index of the pool among the outputs)`` once on the scheduler's
         pool ``attr``.  The programs consume the pool they are given
         (``decode.py``), so each call's returned pool is rebound there and
-        is the next call's argument.  Returns the fewest bytes any of the
-        programs updates in place (``alias_size_in_bytes`` of its compiled
-        form: the whole pool where the donation took, 0 where XLA fell back
-        to a copy; None where the backend does not say)."""
+        is the next call's argument.  Returns each program's memory
+        analysis, in the calls' order (of its compiled form; None where the
+        backend does not say)."""
         pool = getattr(sched, attr)
         self._compile_side_by_side(
             [(fn, (*head, pool, *tail)) for fn, head, tail, _ in calls]
         )
-        aliased = None
+        accounts = []
         for fn, head, tail, at in calls:
             out = fn(*head, pool, *tail)
             pool = out if at is None else out[at]
             setattr(sched, attr, pool)
             jax.block_until_ready(pool)
             # compiled by the call above: this only reads it back
-            mem = fn.lower(*head, pool, *tail).compile().memory_analysis()
-            if mem is not None:
-                n = int(mem.alias_size_in_bytes)
-                aliased = n if aliased is None else min(aliased, n)
-        return aliased
+            accounts.append(fn.lower(*head, pool, *tail).compile().memory_analysis())
+        return accounts
 
     def _fit_prefill_cost(self, sched, grid) -> None:
         """Tell the scheduler what a prefill call costs HERE, from a second,
@@ -633,9 +633,14 @@ class InferenceEngine:
             points.append((rows * length, (time.perf_counter() - t0) * 1e3))
         sched.set_prefill_cost(*LinearCost.through(*points))
 
-    def _warmup_scheduler(self) -> Optional[int]:
-        """Returns ``pool_aliased_bytes`` (also set as the gauge of that
-        name): see :meth:`_warm_pool_programs`."""
+    def _warmup_scheduler(self) -> Dict[str, int]:
+        """Returns the gauges it set from the compiled programs' memory
+        analyses, by name (none where the backend does not say):
+        ``pool_aliased_bytes``, the fewest bytes any program updates in
+        place (``alias_size_in_bytes``: the whole pool where the donation
+        took), and ``decode_program_temp_bytes``, the decode step's
+        ``temp_size_in_bytes`` (its alone: a large prefill bucket's
+        ``[B, H, S, L]`` scores would drown a copied leaf)."""
         sched = self.scheduler
         sched.require_idle()
         T = sched.table_blocks
@@ -687,7 +692,7 @@ class InferenceEngine:
                 np.full((W, k + 1), -1, np.int32), step.tables, step.aids,
             ), 1))
             calls.append((fns.copy_rows, (), (oob, oob), None))
-        aliased = self._warm_pool_programs(calls, sched, "_pool")
+        accounts = self._warm_pool_programs(calls, sched, "_pool")
         self._fit_prefill_cost(sched, grid)
         if sched._spec is not None:
             # ... and the draft model's own prefill/decode set over its pool
@@ -696,8 +701,16 @@ class InferenceEngine:
                 [*prefills(dfns, dparams).values(), decode(dfns, dparams)],
                 sched, "_draft_pool",
             )
-        if aliased is not None:
-            self.metrics.record_pool_aliased(aliased)
+        compiled = {}
+        if None not in accounts:
+            compiled = {
+                "pool_aliased_bytes": min(
+                    int(mem.alias_size_in_bytes) for mem in accounts),
+                # the grid's prefills come first, then the decode step
+                "decode_program_temp_bytes": int(
+                    accounts[len(grid)].temp_size_in_bytes),
+            }
+            self.metrics.record_pool_programs(**compiled)
         # the cache tree's two kinds of leaf, in bytes: the pool's token rows
         # and (a model that carries a state) the per-slot state beside them
         flat = jax.tree_util.tree_flatten_with_path(sched._pool)[0]
@@ -711,7 +724,7 @@ class InferenceEngine:
                 int(leaf.nbytes) for path, leaf in flat
                 if getattr(path[-1], "key", None) in WINDOW_LEAVES),
         )
-        return aliased
+        return compiled
 
     def _warmup_batcher(self) -> None:
         """Batcher-path warmup: one (prefill, decode) execution per

@@ -8,7 +8,8 @@ the layer's attention stores for one token, and this module never looks
 inside one; two layouts exist (ops/attention.py ``pool_leaf_role``): the
 K/V PAIR of ``MultiHeadAttention.paged`` (two leaves ``[rows, heads,
 head_dim]``) and the LATENT row of ``ops/mla.py::MLAttention.paged`` (one
-leaf ``[rows, kv_lora_rank + qk_rope_head_dim]`` shared by every head).
+leaf ``[rows, kv_lora_rank + qk_rope_head_dim]`` shared by every head, a
+row held in whole lane tiles).
 Blocks, tables, prefix keys and admission count rows, so both share this
 code unchanged.  The vLLM construction (PagedAttention,
 Kwon et al. SOSP'23) — cache memory stops being per-batch contiguous

@@ -376,12 +376,19 @@ class ServingMetrics:
             self._scale_up_ready_ms = float(ms)
         self._registry.gauge("scale_up_ready_ms").set(float(ms))
 
-    def record_pool_aliased(self, nbytes: int) -> None:
-        """Bytes of the paged pool that the warm-up's programs update in
-        place (the fewest over the programs, from their compiled forms):
-        the pool's size where the donation took, 0 where XLA fell back to
-        copying the pool before every step."""
-        self._registry.gauge("pool_aliased_bytes").set(float(nbytes))
+    def record_pool_programs(
+        self, *, pool_aliased_bytes: int, decode_program_temp_bytes: int
+    ) -> None:
+        """The warm-up's programs as compiled.  ``pool_aliased_bytes``: the
+        bytes of the paged pool whose buffers the outputs reuse (the fewest
+        over the programs): the pool's size where the donation took.  It
+        does not say that nothing is copied: a leaf the program turns into
+        another layout and back is written into its own buffer by a copy.
+        ``decode_program_temp_bytes``, the decode step's temporaries, does:
+        a whole leaf copied is a leaf's bytes there."""
+        self._registry.gauge("pool_aliased_bytes").set(float(pool_aliased_bytes))
+        self._registry.gauge("decode_program_temp_bytes").set(
+            float(decode_program_temp_bytes))
 
     def record_cache_bytes(
         self, *, kv_pool_bytes: int, state_cache_bytes: int, pool_rows: int,

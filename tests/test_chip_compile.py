@@ -173,12 +173,11 @@ CASES = {
         _paged_decode, _decode_call(8, 1, 1024, 80), F32, ["paged_decode"],
     ),
     # deepseek-v2-lite.serve.steady32: 32 slots x 160 blocks, 16 heads over
-    # the ONE latent row a position, 512 + 64 lanes, 6,144 blocks of 16 (a
-    # ``make_async_copy`` of such a block is refused: "slice shape must be
-    # aligned to tiling (128), but is 576"; the pipeline's own copy is not)
+    # the ONE latent row a position, 512 + 64 lanes in a row of five lane
+    # tiles, 6,144 blocks of 16
     "mla_paged_decode_deepseek_v2_lite": (
         _mla_paged_decode,
-        [((32, 16, 512), None), ((32, 16, 64), None), ((6144, 16, 576), None),
+        [((32, 16, 512), None), ((32, 16, 64), None), ((6144, 16, 640), None),
          ((32, 160), I32), ((32,), I32)],
         BF16, ["mla_paged_decode"],
     ),
@@ -358,3 +357,90 @@ def test_the_state_s_decode_step_walks_its_leaf_in_place(chip, family):
     assert account.temp_size_in_bytes < nbytes(state) // 20
     leaf = "f32[" + ",".join(map(str, state.shape)) + "]"
     assert not re.search(re.escape(leaf) + r"\{[^}]*\} copy\(", compiled.as_text())
+
+
+def copies_by_operand(text):
+    """Every ``copy`` of a compiled program's text (``compiled.as_text()``)
+    as ``(name, operand's shape and layout, result's shape and layout)``:
+    whose array a ``_copy.N`` line of a trace moves, and from which layout
+    into which.  ``bf16[98304,576]{0,1:T(8,128)(2,1)}`` is a leaf that lies
+    transposed (its first axis minor), ``{1,0:...}`` one that lies in rows."""
+    import re
+
+    array = r"\w+\[[\d,]*\](?:\{[^}]*\})?"
+    named = re.compile(rf"\s*(?:ROOT )?%?([\w.\-]+) = ({array})")
+    shapes = dict(m.groups() for m in map(named.match, text.splitlines()) if m)
+    copy = re.compile(named.pattern + rf" copy\((?:{array} )?%?([\w.\-]+)\)")
+    return [(m[1], shapes.get(m[3], "?"), m[2])
+            for m in map(copy.match, text.splitlines()) if m]
+
+
+def test_copies_are_listed_by_their_operand():
+    text = """
+  %p.1 = bf16[64,576]{0,1:T(8,128)(2,1)} parameter(0)
+  %copy.7 = bf16[64,576]{1,0:T(8,128)(2,1)} copy(%p.1), sharding={replicated}
+  %fusion.2 = bf16[64,576]{1,0:T(8,128)(2,1)} fusion(%copy.7, %q), kind=kLoop
+  ROOT %copy.9 = bf16[64,576]{0,1:T(8,128)(2,1)} copy(bf16[64,576]{1,0:T(8,128)(2,1)} %fusion.2)
+  %copy-start.1 = (s32[8]{0}, s32[8]{0}, u32[]) copy-start(%r)
+"""
+    rows, turned = "bf16[64,576]{1,0:T(8,128)(2,1)}", "bf16[64,576]{0,1:T(8,128)(2,1)}"
+    assert copies_by_operand(text) == [
+        ("copy.7", turned, rows), ("copy.9", rows, turned)]
+
+
+# deepseek-v2-lite.serve.steady32: a decode call of 32 slots x 160 blocks,
+# and a 1 x 512 prefill (its table the prompt's own 32 blocks: over the 160
+# of a slot the expanded form's float32 scores, [16, 512, 2560], are 84 MB
+# of temporaries and would drown what is asked about)
+@pytest.mark.parametrize("rows,positions,table,kernels", [
+    (32, 1, 160, ["mla_paged_decode"]), (1, 512, 32, [])],
+    ids=["decode_32_rows", "prefill_1x512"])
+def test_the_latent_leaf_lies_in_rows_and_no_program_turns_it(
+        chip, monkeypatch, rows, positions, table, kernels):
+    """One latent attention layer at the served widths over the leaf as
+    served (``[6144 x 16, lanes_up(512 + 64)]`` bfloat16, donated): the
+    scatter and the kernel of a decode call, the scatter and the block gather
+    of a prefill.  The leaf's parameter and result are row-major, no ``copy``
+    has an operand of the leaf's shape, and the temporaries are a fraction of
+    the leaf.  With a last axis of 576 (4.5 lane tiles) the compiler laid the
+    leaf out transposed and every program turned all 113 MB of it round in
+    front of the scatter and back in front of the output, a layer."""
+    import re
+
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.ops.mla import MLAttention
+    from pytorch_distributed_training_tpu.ops.mla_paged_decode import lanes_up
+
+    # the routing asks ``jax.default_backend()``, which is the CPU here
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    layer = MLAttention(
+        num_heads=16, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=512, dtype=BF16, decode=True, paged=True, kv_block_size=16,
+        kv_num_blocks=6144)
+    x = jnp.zeros((rows, positions, 2048), BF16)
+    pos, tables = jnp.zeros((rows, positions), I32), jnp.zeros((rows, table), I32)
+    shapes = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, pos, tables))
+    (leaf,) = jax.tree.leaves(shapes["cache"])
+    assert (leaf.shape, leaf.dtype) == ((6144 * 16, lanes_up(576)), BF16)
+
+    def call(params, cache, x, pos, tables):
+        y, changed = layer.apply(
+            {"params": params, "cache": cache}, x, pos, tables, mutable=["cache"])
+        return y, changed["cache"]
+
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (shapes["params"], shapes["cache"], x, pos, tables))
+    compiled = jax.jit(call, donate_argnums=1).lower(*args).compile()
+    text = compiled.as_text()
+    assert [re.search(r'op_name="[^"]*?(\w+)/pallas_call"', line).group(1)
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line] == kernels
+    dims = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+    header = text.split("entry_computation_layout=")[1].split("\n")[0]
+    at_rest = re.findall(re.escape(dims) + r"\{([\d,]+)", header)
+    assert at_rest == ["1,0", "1,0"]  # the parameter and the result
+    assert not [c for c in copies_by_operand(text) if c[1].startswith(dims)]
+    account = compiled.memory_analysis()
+    assert account.alias_size_in_bytes == leaf.shape[0] * leaf.shape[1] * 2
+    assert account.temp_size_in_bytes < 32 * 2 ** 20

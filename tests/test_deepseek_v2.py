@@ -397,8 +397,123 @@ def test_pool_leaves_are_found_by_what_the_attention_declares(weights):
     model, p = program("float32", tree)
     pool = build_paged_fns(model, 4, 8).init_pool(p)
     leaves = pool_row_leaves(pool, 32)
-    assert [leaf.shape for _, leaf in leaves] == [(32, 40)] * 3
+    # a row of 32 + 8 values in one whole lane tile
+    assert [leaf.shape for _, leaf in leaves] == [(32, 128)] * 3
     assert pool_row_leaves(pool, 31) == []
+
+
+def test_the_lanes_past_a_row_stay_zero_through_every_program(weights):
+    """A leaf's row is whole lane tiles and no program writes anything but
+    zeros past ``rank + rope``: after a prefill, two decode steps and a
+    ``copy_rows`` the pool's written rows hold values in their first 40
+    lanes and zeros in the other 88, as a fresh pool does everywhere."""
+    from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
+
+    _, _, tree = weights
+    model, p = program("bfloat16", tree)
+    fns = build_paged_fns(model, 4, 8)
+    pool = fns.init_pool(p)
+    assert all(not np.asarray(leaf, np.float32).any() for leaf in jax.tree.leaves(pool))
+    keys = jnp.stack([jax.random.PRNGKey(0)] * 2)
+    row, none = np.zeros(2, np.int32), np.full(2, -1, np.int32)
+    tables = np.asarray([[3, 5], [6, 0]], np.int32)
+    prompt = np.asarray([[7, 8, 9, 10, 11], [12, 13, 14, 0, 0]], np.int32)
+    positions = np.asarray([[0, 1, 2, 3, 4], [0, 1, 2, -1, -1]], np.int32)
+    tok, _, pool, *_ = fns.prefill(
+        p, pool, prompt, positions, tables, np.asarray([4, 2], np.int32), keys,
+        row, none)
+    for pos in ([5, 3], [6, 4]):
+        tok, _, pool, *_ = fns.decode_step(
+            p, pool, np.asarray(tok), np.ones(2, bool), np.asarray(tok),
+            np.asarray(pos, np.int32), tables, keys, row, none)
+    # row 0: positions 0-6 in blocks 3 and 5; row 1: 0-4 in blocks 6 and 0
+    written = np.asarray([12, 13, 14, 15, 20, 21, 22, 24, 25, 26, 27, 0])
+    copied = np.arange(4, 8)  # block 1 <- block 3; the rest dropped
+    oob = np.full(4, 32, np.int32)
+    pool = fns.copy_rows(pool, np.concatenate([np.arange(12, 16), oob]).astype(np.int32),
+                         np.concatenate([copied, oob]).astype(np.int32))
+    for leaf in jax.tree.leaves(pool):
+        leaf = np.asarray(leaf, np.float32)
+        assert leaf.shape == (32, 128)
+        assert not leaf[:, 40:].any()
+        live = np.concatenate([written, copied])
+        assert np.abs(leaf[live, :40]).max(axis=1).min() > 0
+        np.testing.assert_array_equal(leaf[copied], leaf[12:16])
+        assert not np.delete(leaf, live, axis=0).any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_kernel_s_arm_matches_one_full_forward(ref, dtype, monkeypatch):
+    """``test_prefill_then_paged_decode_matches_one_full_forward`` with the
+    decode steps through the kernel (interpreted; the prefill keeps the
+    gather arm): a latent of one lane tile, the narrowest the kernel reads,
+    in a leaf of two, blocks of 16, and dense layers only (the routing's
+    question is the expert layer's too, and its kernel has no CPU form)."""
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.ops import mla_paged_decode
+
+    config = dict(CONFIG, kv_lora_rank=128, first_k_dense_replace=3)
+    sizes = ref.sizes_of(config)
+    host = jax.device_get(ref.make_params(7, sizes))
+    params = jax.tree.map(jnp.asarray, host)
+    model, p = program(dtype, ref.to_checkpoint_tree(host), kv_lora_rank=128,
+                       first_k_dense_replace=3)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, VOCAB, n).astype(np.int32) for n in (9, 5)]
+    tables = np.asarray([[3, 1], [2, 0]], np.int32)
+    kernel, calls = mla_paged_decode.mla_paged_decode, []
+
+    def interpreted(q_lat, q_pe, leaf, *rest, **kw):
+        calls.append(leaf.shape)
+        return kernel(q_lat, q_pe, leaf, *rest, interpret=True, **kw)
+
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    monkeypatch.setattr(mla_paged_decode, "mla_paged_decode", interpreted)
+    seqs, served, _ = paged_run(model, p, prompts, tables, 6, 16, 4, jnp.nan)
+    assert set(calls) == {(4, 16, 256)}  # the decode call's trace, a layer
+    worst, mean = PREFILL_LIMITS[dtype]
+    for r, prompt in enumerate(prompts):
+        want = np.asarray(ref.logits_for(
+            params, np.array(seqs[r], np.int32), sizes["H"], "f32"))
+        want = want[len(prompt) - 1:]
+        assert np.isfinite(served[r]).all()
+        assert np.abs(served[r] - want).max() < worst
+        assert np.abs(served[r] - want).mean() < mean
+
+
+@pytest.mark.parametrize("family", ["latent_leaf", "key_value_pair"])
+def test_warmup_says_what_the_decode_program_keeps_beside_the_pool(weights, family):
+    """The scheduler path's warm-up reads the compiled decode step's
+    temporaries into the gauge ``decode_program_temp_bytes``, beside
+    ``pool_aliased_bytes``: a whole leaf that a step copies is a leaf's
+    bytes there, while the alias reads the whole pool either way."""
+    from pytorch_distributed_training_tpu.parallel.mesh import make_mesh
+    from pytorch_distributed_training_tpu.serving import InferenceEngine
+
+    if family == "latent_leaf":
+        model, p = program("float32", weights[2])
+    else:
+        model = get_model("TransformerLM", num_classes=VOCAB, embed_dim=32,
+                          depth=2, num_heads=4, max_len=32)
+        p = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
+    with InferenceEngine(
+        model, p, {}, make_mesh(), is_lm=True, batch_buckets=[4], seq_buckets=[8],
+        max_batch_size=4, max_delay_ms=1.0, max_new_tokens=4, temperature=0.0,
+        eos_id=None, scheduler={"enabled": True, "slots": 4, "block_size": 4,
+                                "num_blocks": 32, "prefix_cache": False},
+    ) as engine:
+        assert "decode_program_temp_bytes" not in engine.metrics.snapshot()
+        engine.warmup()
+        snapshot = engine.metrics.snapshot()
+        sched = engine.scheduler
+        pool_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(sched._pool))
+        step = sched._step_inputs(())
+        decode = sched._fns.decode_step.lower(
+            sched.params, sched._pool, sched._zero_carry(), *step,
+            *sched._state_rows(np.full((4,), -1, np.int32))).compile()
+    assert snapshot["pool_aliased_bytes"] == snapshot["kv_pool_bytes"] == pool_bytes
+    temp = decode.memory_analysis().temp_size_in_bytes
+    assert snapshot["decode_program_temp_bytes"] == temp > 0
 
 
 

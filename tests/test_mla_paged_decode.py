@@ -25,6 +25,7 @@ from test_paged_decode import _lowered_for_tpu
 BS, NB, T = 16, 32, 6  # 96 positions a row at most, three loop steps of 32
 STEP = 32
 RANK, ROPE, DIM = 128, 16, 64  # the narrowest latent the kernel reads
+lanes_up = mla_paged_decode.lanes_up
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 # float32: the online softmax reorders float32 sums.  bfloat16: the gather
 # arm upcasts its operands on the CPU (its dot has no bfloat16 product), the
@@ -69,16 +70,19 @@ def _dead_rows(tables, lengths):
     return dead.reshape(-1)
 
 
-def _call(rng, lengths, dtype, heads=4):
-    """A decode call: the module's parameters, a pool of random rows, the
-    hidden row of every slot at position ``length - 1`` (``length`` 0: a
-    padding row) and the tables."""
-    module = MLAttention(**dict(WIDTHS, num_heads=heads, dtype=DTYPES[dtype]))
+def _call(rng, lengths, dtype, heads=4, rope=ROPE, past=0.0):
+    """A decode call: the module's parameters, a pool of random rows (the
+    lanes past ``rank + rope`` hold ``past``: zeros, as every write leaves
+    them), the hidden row of every slot at position ``length - 1``
+    (``length`` 0: a padding row) and the tables."""
+    module = MLAttention(**dict(WIDTHS, num_heads=heads, qk_rope_head_dim=rope,
+                                dtype=DTYPES[dtype]))
     tables = _tables(rng, lengths)
     x = jnp.asarray(rng.standard_normal((len(lengths), 1, DIM)), DTYPES[dtype])
     positions = (np.asarray(lengths, np.int32) - 1)[:, None]
     params = module.init(jax.random.PRNGKey(0), x, positions, tables)["params"]
-    pool = rng.standard_normal((NB * BS, RANK + ROPE)).astype(np.float32)
+    pool = np.full((NB * BS, lanes_up(RANK + rope)), past, np.float32)
+    pool[:, :RANK + rope] = rng.standard_normal((NB * BS, RANK + rope))
     return module, params, pool, x, positions, tables
 
 
@@ -114,13 +118,45 @@ def _both(monkeypatch, module, params, pool, x, positions, tables):
 RAGGED = [1, BS - 1, BS, BS + 1, STEP - 1, STEP, STEP + 1, 2 * STEP + BS, T * BS, 0]
 
 
-@pytest.mark.parametrize("dtype,heads", [("float32", 4), ("bfloat16", 4), ("float32", 16),
-                                         ("bfloat16", 16)])
-def test_kernel_matches_the_gather_arm_on_ragged_rows(monkeypatch, dtype, heads):
+# rope 16: a row of 144 lanes in a leaf of 256; rope 128: a row that is whole
+# lane tiles already, and a leaf of its own width
+@pytest.mark.parametrize("dtype,heads,rope", [
+    ("float32", 4, ROPE), ("bfloat16", 4, ROPE), ("float32", 16, ROPE),
+    ("bfloat16", 16, ROPE), ("float32", 4, 128), ("bfloat16", 4, 128)])
+def test_kernel_matches_the_gather_arm_on_ragged_rows(monkeypatch, dtype, heads, rope):
     rng = np.random.default_rng(heads)
-    want, got = _both(monkeypatch, *_call(rng, RAGGED, dtype, heads))
+    call = _call(rng, RAGGED, dtype, heads, rope)
+    assert call[2].shape[1] == {ROPE: 256, 128: RANK + 128}[rope]
+    want, got = _both(monkeypatch, *call)
     assert np.abs(want).max() > 0.1
     np.testing.assert_allclose(got, want, **TOLERANCE[dtype])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_lanes_past_the_row_are_written_as_zeros_and_never_read(monkeypatch, dtype):
+    """A leaf's row is whole lane tiles: past ``rank + rope`` every write
+    writes zeros, and what lies there (here a NaN in every row of the pool)
+    reaches neither arm's scores nor its values."""
+    lengths = [1, BS + 1, 2 * STEP + BS, 0]
+    clean = _call(np.random.default_rng(5), lengths, dtype)
+    dirty = _call(np.random.default_rng(5), lengths, dtype, past=np.nan)
+    np.testing.assert_array_equal(clean[2][:, :RANK + ROPE], dirty[2][:, :RANK + ROPE])
+    want = _both(monkeypatch, *clean)
+    got = _both(monkeypatch, *dirty)
+    assert np.isfinite(got[0]).all() and np.isfinite(got[1]).all()
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    module, params, pool, x, positions, tables = dirty
+    _, state = module.apply(
+        {"params": params, "cache": {attention.LATENT_POOL: jnp.asarray(pool, module.dtype)}},
+        x, positions, tables, mutable=["cache"])
+    leaf = np.asarray(state["cache"][attention.LATENT_POOL], np.float32)
+    written = [tables[b, (n - 1) // BS] * BS + (n - 1) % BS
+               for b, n in enumerate(lengths) if n]
+    assert (leaf[written, RANK + ROPE:] == 0).all()
+    assert np.abs(leaf[written, :RANK + ROPE]).min(axis=1).max() > 0
+    others = np.setdiff1d(np.arange(NB * BS), written)
+    assert np.isnan(leaf[others, RANK + ROPE:]).all()
 
 
 @pytest.mark.parametrize("lengths", [RAGGED, [T * BS] * 3, [0, 0, 1, 0], [STEP + 1]],
@@ -236,11 +272,27 @@ def test_fits_says_which_leaves_the_kernel_reads(rank, block_size, dtype, ok):
     assert mla_paged_decode.fits(rank, block_size, dtype) is ok
 
 
+@pytest.mark.parametrize("rank,rope,width", [
+    (512, 64, 640),    # deepseek-v2-lite as served: 4.5 lane tiles a row
+    (512, 128, 640),   # whole tiles already: left as it is
+    (RANK, ROPE, 256), (120, 8, 128), (32, 8, 128),
+])
+def test_a_leaf_s_row_is_whole_lane_tiles(rank, rope, width):
+    assert lanes_up(rank + rope) == width
+    module = MLAttention(**dict(WIDTHS, kv_lora_rank=rank, qk_rope_head_dim=rope))
+    x, i32 = jnp.zeros((1, 1, DIM)), jnp.zeros((1, 1), jnp.int32)
+    cache = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), x, i32, i32))["cache"]
+    assert cache[attention.LATENT_POOL].shape == (NB * BS, width)
+
+
 @pytest.mark.parametrize("q_pe,pool", [
-    ((2, 4, 8), (4, 8, 144)),    # another rope width than the leaf's
-    ((3, 4, 16), (4, 8, 144)),   # another batch than the latent queries'
+    ((2, 4, 192), (4, 8, 256)),  # a rope whose row the leaf does not hold
+    ((3, 4, 16), (4, 8, 256)),   # another batch than the latent queries'
     ((2, 4, 16), (4, 8, 128)),   # a leaf with no rope lanes
-], ids=["rope", "batch", "leaf"])
+    ((2, 4, 16), (4, 8, 144)),   # a leaf whose row is not whole lane tiles
+    ((2, 4, 16), (4, 8, 384)),   # a leaf a lane tile wider than the row's
+], ids=["rope", "batch", "leaf", "untiled", "wide"])
 def test_queries_that_do_not_read_the_leaf_are_refused(q_pe, pool):
     with pytest.raises(ValueError, match="do not read pool"):
         mla_paged_decode.mla_paged_decode(
@@ -253,7 +305,7 @@ def test_a_leaf_of_another_dtype_is_refused():
     with pytest.raises(ValueError, match="do not read pool"):
         mla_paged_decode.mla_paged_decode(
             jnp.zeros((2, 4, 128)), jnp.zeros((2, 4, 16)),
-            jnp.zeros((4, 8, 144), jnp.bfloat16), jnp.zeros((2, 3), jnp.int32),
+            jnp.zeros((4, 8, 256), jnp.bfloat16), jnp.zeros((2, 3), jnp.int32),
             jnp.ones((2,), jnp.int32), scale=1.0, interpret=True)
 
 
