@@ -135,16 +135,6 @@ class DecoderLayer(nn.Module):
         c = self.config
         b, s, dim = x.shape
         rotary_dim, inv_freq, amplitude = self.rotary
-        if self.kind == FULL and c.state_slots and s > 1:
-            # a model with window layers is prefilled a whole prompt at a
-            # time (no prefix cache, no piece: its rings are addressed by
-            # slot; ``ContinuousScheduler._refuse_a_piece`` refuses any other
-            # call), so a call of ``s`` positions holds positions 0 .. s - 1
-            # and a full layer reads no block past them: the gather arm
-            # scores ``s x s``, not ``s x`` the whole table (8,704 positions
-            # in config/serve-laguna-xs2.yml: 8.5 x the products of a 1,024
-            # prompt)
-            block_tables = block_tables[:, :-(-s // c.kv_block_size)]
         y = RMSNorm(c.rms_norm_eps, c.dtype, name="attn_norm")(x)
         x = x + GroupedQueryAttention(
             num_heads=self.heads,
@@ -162,6 +152,11 @@ class DecoderLayer(nn.Module):
             kv_block_size=c.kv_block_size,
             kv_num_blocks=c.kv_num_blocks,
             state_slots=c.state_slots,
+            # a model with window layers is prefilled a whole prompt a call
+            # (no prefix cache, no piece: its rings are addressed by slot;
+            # ``ContinuousScheduler._refuse_a_piece`` refuses any other
+            # call), so a full layer may score the call's own keys
+            whole_prompts=bool(c.state_slots),
             name="attn",
         )(y, positions, block_tables, state_rows)
         flat = RMSNorm(c.rms_norm_eps, c.dtype, name="ffn_norm")(x).reshape(b * s, dim)

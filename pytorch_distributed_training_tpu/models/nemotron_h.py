@@ -111,6 +111,9 @@ class DecoderLayer(nn.Module):
             paged=c.paged,
             kv_block_size=c.kv_block_size,
             kv_num_blocks=c.kv_num_blocks,
+            # a model that carries a state is prefilled a whole prompt a call
+            # (``ContinuousScheduler._refuse_a_piece``)
+            whole_prompts=bool(c.state_slots),
             name="attn",
         )(y, positions, block_tables), None
 

@@ -52,7 +52,7 @@ from .rotary import rotate_halves
 __all__ = [
     "GroupedQueryAttention", "MultiHeadAttention", "dot_product_attention",
     "is_state_leaf", "paged_attention", "pool_leaf_role", "rms_norm",
-    "window_attention",
+    "whole_prompt_flash", "window_attention",
 ]
 
 # The paged pool's leaves, under the names the attention modules give them.
@@ -463,8 +463,25 @@ def _stored_heads(kv_heads: int) -> int:
     return kv_heads if kv_heads <= 8 else -(-kv_heads // 8) * 8
 
 
+def whole_prompt_flash(whole_prompts: bool, s: int, head_dim: int) -> bool:
+    """Whether :func:`paged_attention` scores a call of ``s`` positions a row
+    through the causal flash forward over the call's own K/V: the caller
+    states that the call holds whole prompts, the backend runs the kernel
+    and the shape is one it takes.  The scheduler counts its flash calls by
+    the same rule (``ContinuousScheduler._flash_layers``)."""
+    from .flash_attention import flash_enabled, flash_shapes_ok
+
+    return bool(
+        whole_prompts and s > 1 and flash_enabled() and flash_shapes_ok(s, head_dim))
+
+
+# tests set this to run the flash arm's kernel in the Pallas interpreter
+_FLASH_INTERPRET = False
+
+
 def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
-                    num_blocks, dtype, as_stored, query_block=0):
+                    num_blocks, dtype, as_stored, query_block=0,
+                    whole_prompts=False):
     """Block-table gather attention against the shared paged KV pool, for
     ``q [B, S, H, hd]`` and ``k``, ``v [B, S, Hkv, hd]`` with ``H`` a
     multiple of ``Hkv``: query head ``h`` reads K/V head ``h // (H / Hkv)``.
@@ -499,13 +516,29 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
       :func:`..ops.mla_paged_decode.mla_paged_decode` (the absorbed form:
       all heads against the one ``rank + rope`` row a position, the value
       the row's first ``rank`` lanes).
-    - ``S > 1`` (whole-prompt and chunked prefill, the speculative
-      ``verify``), and any call off a TPU: the GATHER arms below, as they
-      were: each row's FULL table is gathered into ``[B, L, Hkv, hd]`` and
-      scored.  A prefill scores hundreds of query rows against the gathered
-      copy and its cost is the products', not the gather's; a kernel for it
-      is the flash kernel's shape (query tiles against a block walk), not
-      this one's, and is left to its own change (ROADMAP Speed 3b).
+    - ``S > 1`` with ``whole_prompts`` on a TPU, ``S`` a multiple of 128
+      (:func:`whole_prompt_flash`; the prefill of a model that carries a
+      state a sequence, every bucket of the four served): the caller states
+      that every row's columns are positions ``0 .. n - 1`` and then
+      padding (the scheduler refuses such a model any other call,
+      ``ContinuousScheduler._refuse_a_piece``), so what the table would
+      give back is, value for value, the ``k.astype(dtype)`` and
+      ``v.astype(dtype)`` just written, and a causal mask by COLUMN is the
+      mask by position (a padding column lies after a row's last real one:
+      no real query sees it, and what a padding query reads is ignored).
+      The pool is written as ever and not read: the scores, softmax and sum
+      are the causal flash forward over the call's own K/V
+      (:func:`..ops.flash_attention.flash_prefill`: a K/V head read by its
+      group's query heads where it lies; bf16 operands, float32 scores,
+      softmax and accumulation, the probabilities rounded to the values'
+      dtype before the second product, as the gather arm has them).  No
+      ``[H, S, S]`` array exists.
+    - any other ``S > 1`` (a call that may start past 0: a prefix hit's
+      suffix, the speculative ``verify``; a shape the kernel does not
+      take), and any call off a TPU: the GATHER arms below, as they were:
+      each row's FULL table is gathered into ``[B, L, Hkv, hd]`` and
+      scored.  Left to a later change: the flash kernel's query tiles
+      against a walk of the block table, for the calls that start past 0.
 
     ``as_stored=False`` upcasts the gathered rows to float32 before the
     products (``TransformerLM``'s programs, unchanged); ``as_stored=True``
@@ -513,7 +546,8 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
     4,608 positions the float32 copy is 1.2 GB a step).  ``query_block > 0``
     builds the scores of a call longer than that for ``query_block`` query
     rows of ONE batch row at a time, so that no ``[B, H, S, L]`` array
-    exists.
+    exists.  ``whole_prompts`` (static): the caller's statement about what a
+    call of more than one position holds, above.
     """
     bs, nb = block_size, num_blocks
     if bs <= 0 or nb <= 0:
@@ -574,6 +608,18 @@ def paged_attention(module, q, k, v, positions, block_tables, *, block_size,
             block_tables, safe_pos[:, 0] + 1, scale=scale,
         )
         return out[:, :kv_heads].reshape(b, 1, num_heads, head_dim)
+    if whole_prompt_flash(whole_prompts, s, head_dim):
+        # the write before the scores, as the gather arm's read of the pool
+        # orders them: nothing else waits for a pool nobody reads, and the
+        # compiler otherwise defers every layer's write to the program's end
+        # and keeps its K and V until then (151 MB of a 1 x 8,192 prefill's
+        # temporaries over Laguna's five full layers)
+        kp, vp, k, v = jax.lax.optimization_barrier(
+            (kp, vp, k.astype(dtype), v.astype(dtype)))
+        k_pool.value, v_pool.value = kp, vp
+        from .flash_attention import flash_prefill
+
+        return flash_prefill(q, k, v, interpret=_FLASH_INTERPRET)
     t_blocks = block_tables.shape[1]
     length = t_blocks * bs
     # [B, L] physical rows in logical-position order (the row-at-a-time gather)
@@ -842,9 +888,14 @@ class GroupedQueryAttention(nn.Module):
     ``decode=False``: plain causal (and windowed) attention over the call's
     own tokens at positions ``0 .. S - 1``.  ``decode=True, paged=True``: K/V
     rows of ``Hkv`` heads in the paged pool (:func:`paged_attention`: a
-    decode step through the paged kernel, a prefill's rows gathered as
-    stored and a long call's scores built ``query_block`` query rows at a
-    time) or, a window layer, in its ring.  Device scopes: ``gqa_attention``
+    decode step through the paged kernel; a prefill that holds
+    ``whole_prompts`` through the causal flash forward over its own K/V;
+    any other call's rows gathered as stored and a long call's scores built
+    ``query_block`` query rows at a time) or, a window layer, in its ring.
+    ``whole_prompts``: the model's statement that a paged call of more than
+    one position holds each row's positions ``0 .. n - 1`` and then padding
+    (a model that carries a state a sequence is prefilled so and no other
+    way: ``serving/scheduler.py::_refuse_a_piece``).  Device scopes: ``gqa_attention``
     around the layer and, inside it, ``rotary``, ``full_attention`` or
     ``window_attention`` (scores, softmax and weighted sum, with the cache's
     write and read) and ``head_gate``."""
@@ -869,6 +920,8 @@ class GroupedQueryAttention(nn.Module):
     kv_num_blocks: int = 0
     # slots of a window layer's ring (the scheduler's slots)
     state_slots: int = 0
+    # a paged call of more than one position holds whole prompts
+    whole_prompts: bool = False
 
     @nn.compact
     def __call__(self, x, positions=None, block_tables=None, state_rows=None):
@@ -927,6 +980,7 @@ class GroupedQueryAttention(nn.Module):
                         block_size=self.kv_block_size,
                         num_blocks=self.kv_num_blocks, dtype=self.dtype,
                         as_stored=True, query_block=self.query_block,
+                        whole_prompts=self.whole_prompts,
                     )
             else:
                 group = h // hkv

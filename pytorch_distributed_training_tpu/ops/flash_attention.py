@@ -74,7 +74,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-__all__ = ["flash_attention", "flash_attention_lse", "flash_shapes_ok", "flash_enabled"]
+__all__ = [
+    "flash_attention", "flash_attention_lse", "flash_prefill",
+    "flash_shapes_ok", "flash_enabled",
+]
 
 _NEG = -1e30  # finite mask value; see module docstring
 # Preferred tile sizes; ``_pick_block`` halves them until they divide the
@@ -154,7 +157,11 @@ def _resident_ok(s_len: int, d: int) -> bool:
 
     if os.environ.get("PDT_FLASH_FORCE_STREAM", "0") != "0":
         return False
-    return 2 * s_len * d * 4 <= _VMEM_BYTES
+    # under the budget, not at it: at 8,192 x 128 bf16 (S * D = 1M exactly,
+    # a prefill's largest bucket) Mosaic refuses the resident forward, 18.63
+    # MB of scoped VMEM for 16; 6,144 x 128 compiles
+    # (tests/test_chip_compile.py asks both)
+    return 2 * s_len * d * 4 < _VMEM_BYTES
 
 
 def _fused_bwd_ok(
@@ -236,7 +243,7 @@ def _mask_above_diagonal(s):
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref,
+    q_ref, k_ref, v_ref, o_ref, lse_ref=None,
     *, scale, causal, block_q, block_k, sub, bf16_dots,
 ):
     i = pl.program_id(1)
@@ -275,7 +282,8 @@ def _fwd_kernel(
     def finish(rows, carry):
         m, l, acc = carry
         o_ref[0, rows, :] = (acc / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, rows, 0] = m + jnp.log(l)
+        if lse_ref is not None:  # a forward nobody differentiates has none
+            lse_ref[0, rows, 0] = m + jnp.log(l)
 
     carry = (
         jnp.full((block_q,), _NEG, jnp.float32),
@@ -535,7 +543,8 @@ def _fwd_stream_kernel(
     def _finalize():
         l = l_scr[...]
         o_ref[0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+        if lse_ref is not None:
+            lse_ref[0, :, 0] = (m_scr[...] + jnp.log(l))[:, 0]
 
 
 def _dq_stream_kernel(
@@ -717,7 +726,8 @@ def _causal_pairs(s_len: int, causal: bool, tiles):
 @functools.lru_cache(maxsize=None)
 def _make(
     causal: bool, interpret: bool, scale: float, out_f32: bool = False,
-    stream: bool = False, bf16_dots: bool = False,
+    stream: bool = False, bf16_dots: bool = False, group: int = 1,
+    lse: bool = True,
 ):
     """Build the custom-VJP'd flash attention for a static (causal, mode,
     scale, out-dtype, stream, dot-precision) tuple — scale is a trace-time
@@ -728,7 +738,25 @@ def _make(
     tile-streaming kernels (VMEM O(block*D) instead of O(S*D); chosen by
     the S·D dispatch in :func:`flash_attention_lse`).  ``bf16_dots`` keeps
     the MXU contractions in bf16 with f32 accumulation (set for bf16
-    inputs; see module docstring)."""
+    inputs; see module docstring).  ``group`` > 1: ``k`` and ``v`` hold one
+    folded head for every ``group`` of ``q``'s (``[BH / group, S, D]``) and
+    the blocks' index maps send query head ``b`` to K/V head ``b // group``;
+    consecutive grid steps of a group name the same block, which the
+    pipeline does not fetch again.  ``lse=False``: the forward has no
+    logsumexp output (``[BH, S, 1]`` float32 lies in 128 lanes on the device:
+    as many bytes as a float32 ``o``).  Both are the forward's alone:
+    :func:`flash_prefill`."""
+    # query head (folded) -> its K/V head; one a head as it always was
+    kv = (lambda b: b) if group == 1 else (lambda b: b // group)
+
+    def outputs(q, spec_o, spec_lse):
+        """``(out_specs, out_shape)`` of a forward over ``q [BH, S, D]``."""
+        specs = [spec_o, spec_lse]
+        shapes = [
+            _out_struct(q.shape, jnp.float32 if out_f32 else q.dtype, q),
+            _out_struct(q.shape[:2] + (1,), jnp.float32, q),
+        ]
+        return (specs, shapes) if lse else (specs[:1], shapes[:1])
 
     def _forward_stream(q, k, v):
         from jax.experimental.pallas import tpu as pltpu
@@ -740,8 +768,14 @@ def _make(
             _fwd_stream_kernel, scale=scale, causal=causal, block_q=bq,
             block_k=bk, nk=nk, bf16_dots=bf16_dots,
         )
+        if not lse:  # the scratch refs follow the outputs
+            with_lse = kern
+            kern = lambda q, k, v, o, *scratch: with_lse(  # noqa: E731
+                q, k, v, o, None, *scratch)
         qrow = lambda b, i, j: (b, i, 0)  # noqa: E731
-        krow = lambda b, i, j: (b, j, 0)  # noqa: E731
+        krow = lambda b, i, j: (kv(b), j, 0)  # noqa: E731
+        out_specs, out_shape = outputs(
+            q, pl.BlockSpec((1, bq, d), qrow), pl.BlockSpec((1, bq, 1), qrow))
         return pl.pallas_call(
             kern,
             grid=(bh, s_len // bq, nk),
@@ -751,14 +785,8 @@ def _make(
                 pl.BlockSpec((1, bk, d), krow),
                 pl.BlockSpec((1, bk, d), krow),
             ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d), qrow),
-                pl.BlockSpec((1, bq, 1), qrow),
-            ],
-            out_shape=[
-                _out_struct(q.shape, jnp.float32 if out_f32 else q.dtype, q),
-                _out_struct((bh, s_len, 1), jnp.float32, q),
-            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((bq, _LANES), jnp.float32),
                 pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -785,7 +813,12 @@ def _make(
             sub=sub, bf16_dots=bf16_dots,
         )
         row = lambda b, i: (b, i, 0)  # noqa: E731
-        full = lambda b, i: (b, 0, 0)  # noqa: E731
+        full = lambda b, i: (kv(b), 0, 0)  # noqa: E731
+        # lse rides as [bh, s, 1]: Mosaic requires the block's last two
+        # dims be (8k, 128m) or array-equal — a [bh, s] layout with (1, bq)
+        # blocks violates that
+        out_specs, out_shape = outputs(
+            q, pl.BlockSpec((1, bq, d), row), pl.BlockSpec((1, bq, 1), row))
         return pl.pallas_call(
             kern,
             grid=(bh, s_len // bq),
@@ -795,20 +828,16 @@ def _make(
                 pl.BlockSpec((1, s_len, d), full),
                 pl.BlockSpec((1, s_len, d), full),
             ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d), row),
-                # lse rides as [bh, s, 1]: Mosaic requires the block's last
-                # two dims be (8k, 128m) or array-equal — a [bh, s] layout
-                # with (1, bq) blocks violates that
-                pl.BlockSpec((1, bq, 1), row),
-            ],
-            out_shape=[
-                _out_struct(q.shape, jnp.float32 if out_f32 else q.dtype, q),
-                _out_struct((bh, s_len, 1), jnp.float32, q),
-            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
             interpret=interpret,
             name="flash_fwd",
         )(q, k, v)
+
+    if group > 1 or not lse:
+        # the forward alone (the backward kernels read the logsumexp, and a
+        # group's dK/dV would be a sum over its query heads)
+        return _forward
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -1045,11 +1074,36 @@ def flash_attention_lse(
     shifts the backward's delta; see ``attn_bwd``).  ``out_f32`` (default)
     returns o in f32 so a cross-block combine does not round each partial
     to the input dtype."""
+    b, s_len, h, _ = q.shape
+    out, lse = _folded(q, k, v, causal, sm_scale, interpret, out_f32)
+    lse = jnp.transpose(lse.reshape(b, h, s_len), (0, 2, 1))  # [B, S, H]
+    return out, lse
+
+
+def flash_prefill(q, k, v, sm_scale: Optional[float] = None, *,
+                  interpret: bool = False):
+    """The causal flash FORWARD as an inference call wants it: ``q [B, S, H,
+    D]`` over ``k``, ``v [B, S, Hkv, D]`` with ``H`` a multiple of ``Hkv``
+    (query head ``h`` reads K/V head ``h // (H / Hkv)`` through the blocks'
+    index maps: no repeated copy of K or V exists) ``-> [B, S, H, D]`` in
+    ``q``'s dtype, and no logsumexp output.  The kernels, tiles and
+    per-shape dispatch are :func:`flash_attention`'s; not differentiable."""
+    return _folded(q, k, v, True, sm_scale, interpret, False, lse=False)[0]
+
+
+def _folded(q, k, v, causal, sm_scale, interpret, out_f32, lse=True):
+    """``(out [B, S, H, D], lse [B * H, S, 1] or None)``: heads folded into
+    the batch dim for the kernels, ``k`` and ``v`` with their own head
+    count."""
     b, s_len, h, d = q.shape
+    kv_heads = k.shape[2]
+    if h % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(
+            f"{h} query heads over {kv_heads} / {v.shape[2]} K/V heads")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
 
     def fold(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, s_len, d)
+        return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], s_len, d)
 
     # per-shape dispatch: tuned resident-K/V kernels while they fit scoped
     # VMEM, tile-streaming kernels beyond (lifts the round-2 S<=8k@D=128
@@ -1065,10 +1119,9 @@ def flash_attention_lse(
         and all(x.dtype == jnp.bfloat16 for x in (q, k, v))
         and os.environ.get("PDT_FLASH_F32_DOTS", "0") == "0"
     )
-    out, lse = _make(
+    out = _make(
         bool(causal), bool(interpret), float(scale), bool(out_f32),
-        bool(stream), bool(bf16_dots),
+        bool(stream), bool(bf16_dots), h // kv_heads, bool(lse),
     )(fold(q), fold(k), fold(v))
-    out = jnp.swapaxes(out.reshape(b, h, s_len, d), 1, 2)
-    lse = jnp.transpose(lse.reshape(b, h, s_len), (0, 2, 1))  # [B, S, H]
-    return out, lse
+    return (jnp.swapaxes(out[0].reshape(b, h, s_len, d), 1, 2),
+            out[1] if lse else None)

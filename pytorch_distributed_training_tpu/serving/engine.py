@@ -694,6 +694,9 @@ class InferenceEngine:
             calls.append((fns.copy_rows, (), (oob, oob), None))
         accounts = self._warm_pool_programs(calls, sched, "_pool")
         self._fit_prefill_cost(sched, grid)
+        flash_layers = max(map(sched._flash_layers, sched.seq_buckets))
+        if flash_layers:  # a gauge that is 0 is left out, as a counter is
+            self.metrics.record_prefill_flash_layers(flash_layers)
         if sched._spec is not None:
             # ... and the draft model's own prefill/decode set over its pool
             dfns, dparams = sched._draft_fns, sched._draft_params

@@ -418,6 +418,13 @@ class ServingMetrics:
         self._registry.gauge("prefill_call_fixed_ms").set(float(fixed_ms))
         self._registry.gauge("prefill_call_ms_per_ktoken").set(float(ms_per_ktoken))
 
+    def record_prefill_flash_layers(self, layers: int) -> None:
+        """The most attention layers any prefill program of the compiled
+        grid scores through the flash forward over the call's own K/V
+        (``ContinuousScheduler._flash_layers``; beside it the counter
+        ``prefill_flash_calls`` of ``prefill_calls``)."""
+        self._registry.gauge("prefill_flash_layers").set(float(layers))
+
     def record_kv_transfer(
         self, *, nbytes: int, seconds: float, blocks: int
     ) -> None:

@@ -116,7 +116,7 @@ from ..engine import fault
 from ..engine.watchdog import StepWatchdog
 from ..telemetry.registry import get_registry
 from ..telemetry.spans import record as record_span, span
-from ..ops.attention import pool_leaf_role
+from ..ops.attention import pool_leaf_role, whole_prompt_flash
 from ..ops.quant import quantize_tree
 from . import kv_transfer
 from .batcher import OverloadedError
@@ -626,19 +626,35 @@ class ContinuousScheduler:
         )
 
     def _refuse_a_piece(self, positions: np.ndarray) -> None:
-        """A model with window layers is prefilled a whole prompt a call: its
-        layers take a multi-token call's column for the position (a full
-        layer's table is cut to the call's own blocks, a window layer's band
-        and ring write count from column 0: ``models/laguna.py``,
-        ``ops/attention.py::window_attention``).  The scheduler owns what a
-        call holds, so it refuses here a call that starts anywhere else (a
-        prefix hit's suffix, a prefill in pieces) rather than let the layers
-        read other keys in silence."""
-        if self._window_shape is not None and (positions[:, 0] > 0).any():
+        """A model that carries a state a sequence is prefilled a whole
+        prompt a call: its layers take a multi-token call's column for the
+        position (a state's scan starts from the slot's zero state at column
+        0, a window layer's band and ring write count from it, and a full
+        layer may score the call's own keys and read no table:
+        ``ops/attention.py::paged_attention``, ``whole_prompts``).  The
+        scheduler owns what a call holds, so it refuses here a call that
+        starts anywhere else (a prefix hit's suffix, a prefill in pieces)
+        rather than let the layers read other keys in silence."""
+        if self._state_shape is not None and (positions[:, 0] > 0).any():
             raise ValueError(self._state_refusal(
                 f"a prefill call whose rows start at {positions[:, 0].tolist()}",
-                "its window layers take a call's column for the position: "
+                "its layers take a call's column for the position: "
                 "a prompt is prefilled whole, from position 0"))
+
+    def _flash_layers(self, seq_bucket: int) -> int:
+        """Of a prefill call's attention layers, those that score through
+        the causal flash forward over the call's own K/V and read no table
+        (``ops/attention.py::whole_prompt_flash``, the rule the layers
+        themselves go by): the cache tree's K pools (one a full-attention
+        layer) where the model carries a state, so that its calls hold whole
+        prompts, and the call's shape is one the kernel takes on this
+        backend.  Static a program."""
+        n_rows = self._kv.num_blocks * self._kv.block_size
+        return sum(
+            whole_prompt_flash(
+                self._state_shape is not None, seq_bucket, leaf.shape[-1])
+            for path, leaf in jax.tree_util.tree_flatten_with_path(self._pool)[0]
+            if leaf.ndim == 3 and pool_leaf_role(path, leaf, n_rows) == "scored")
 
     def _pad_keys(self, n: int) -> np.ndarray:
         """The ``row_keys`` argument of a paged call of ``n`` batch rows,
@@ -1503,10 +1519,12 @@ class ContinuousScheduler:
             if sum(not c.replay for c in calls) > 1:
                 self._bump("prefill_split_ticks")
         for call in calls:
+            flash_layers = self._flash_layers(call.seq_bucket)
             with self._phase(
                 "prefill", rows=len(call.reqs), tokens=sum(call.suffix),
                 bucket=call.seq_bucket, reqs=[r.rid for r in call.reqs],
                 stalled=decoding, padded_tokens=call.padded_tokens,
+                flash_layers=flash_layers,
             ):
                 if call.replay:
                     self._replay(call)
@@ -1514,6 +1532,8 @@ class ContinuousScheduler:
                     self._prefill_fresh(call)
             decoding = 0
             self._bump("prefill_calls")
+            if flash_layers:
+                self._bump("prefill_flash_calls")
         if self._spec is not None:
             # the draft pool needs the prompt K/V too (its own programs,
             # its own blocks); requests evicted by the target prefill's

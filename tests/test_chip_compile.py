@@ -19,7 +19,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pytorch_distributed_training_tpu.ops.flash_attention import flash_attention
+from pytorch_distributed_training_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_prefill,
+)
 from pytorch_distributed_training_tpu.ops.fused_ce import fused_cross_entropy
 from pytorch_distributed_training_tpu.ops.fused_elementwise import (
     _make_add_ln,
@@ -60,6 +63,14 @@ def _flash_fwd(q, k, v):
 
 
 _flash_fwd_bwd = jax.grad(_attn_loss, argnums=(0, 1, 2))
+
+
+def _prefill_call(positions, heads, kv_heads):
+    """A whole-prompt prefill's call of the flash forward (``ops/attention.py
+    ::paged_attention``'s flash arm): one row of ``positions``, ``heads``
+    query heads of 128 over ``kv_heads`` K/V heads."""
+    return [((1, positions, heads, 128), None)] + [
+        ((1, positions, kv_heads, 128), None)] * 2
 
 
 def _ce_fwd_bwd(logits, labels):
@@ -109,6 +120,30 @@ CASES = {
         _flash_fwd_bwd, QKV_2K, BF16, ["flash_fwd", "flash_bwd"],
     ),
     "flash_fwd_bf16_4096": (_flash_fwd, QKV_4K, BF16, ["flash_fwd"]),
+    # the longest sequence the resident forward holds at 1,024-row tiles ...
+    "flash_fwd_bf16_6144": (
+        _flash_fwd, [((1, 6144, 8, 128), None)] * 3, BF16, ["flash_fwd"]),
+    # ... and the edge of its budget, S x D = 1M: Mosaic refuses the resident
+    # form there (18.63 MB of scoped VMEM for 16), the streamed one serves it
+    "flash_fwd_bf16_8192": (
+        _flash_fwd, [((1, 8192, 8, 128), None)] * 3, BF16, ["flash_fwd_stream"]),
+    # a prefill's full layers, whole prompts (laguna-xs2.serve.code32's
+    # buckets 1,024-8,192, 48 heads in groups of 6; solar-open2's 64 in groups
+    # of 8 at 4,096; nemotron-3's 32 in groups of 16 and olmo-hybrid's 30 a
+    # K/V head each at 1,024 and 256): no logsumexp output, a K/V head read
+    # by its group through the index maps
+    "flash_prefill_laguna_1024": (
+        flash_prefill, _prefill_call(1024, 48, 8), BF16, ["flash_fwd"]),
+    "flash_prefill_laguna_4096": (
+        flash_prefill, _prefill_call(4096, 48, 8), BF16, ["flash_fwd"]),
+    "flash_prefill_laguna_8192": (
+        flash_prefill, _prefill_call(8192, 48, 8), BF16, ["flash_fwd_stream"]),
+    "flash_prefill_solar_open2_4096": (
+        flash_prefill, _prefill_call(4096, 64, 8), BF16, ["flash_fwd"]),
+    "flash_prefill_nemotron_h_1024": (
+        flash_prefill, _prefill_call(1024, 32, 2), BF16, ["flash_fwd"]),
+    "flash_prefill_olmo_hybrid_256": (
+        flash_prefill, _prefill_call(256, 30, 30), BF16, ["flash_fwd"]),
     "flash_fused_bwd_bf16_3072x64": (
         _flash_fwd_bwd, QKV_3K_D64, BF16, ["flash_fwd", "flash_bwd"],
     ),
@@ -444,3 +479,113 @@ def test_the_latent_leaf_lies_in_rows_and_no_program_turns_it(
     account = compiled.memory_analysis()
     assert account.alias_size_in_bytes == leaf.shape[0] * leaf.shape[1] * 2
     assert account.temp_size_in_bytes < 32 * 2 ** 20
+
+
+def _arrays(text, dtype, last):
+    """The distinct ``dtype[..., last]`` arrays of three or more axes in a
+    compiled program's text."""
+    import re
+
+    return sorted(set(re.findall(rf"{dtype}\[(?:\d+,){{2,}}{last}\]", text)))
+
+
+@pytest.mark.parametrize("whole_prompts", [True, False], ids=["flash_arm", "gather_arm"])
+def test_a_whole_prompt_call_of_a_full_layer_builds_no_scores(chip, monkeypatch, whole_prompts):
+    """One full-attention layer of Laguna at the served widths (48 query
+    heads of 128 over 8, a pool of 17,408 blocks of 16, donated) on a 1 x
+    4,096 prefill call.  Told that the call holds whole prompts, the layer
+    writes the pool and scores through the flash forward: ONE kernel, no
+    float32 ``[.., 256, 4096]`` scores, no gathered copy of the table's rows,
+    and temporaries under the gather arm's scores alone; not told (a call that may start
+    past position 0), it is the gather arm as it was."""
+    import re
+
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.ops.attention import GroupedQueryAttention
+
+    # the routing asks ``jax.default_backend()``, which is the CPU here
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    positions, blocks = 4096, 17408
+    layer = GroupedQueryAttention(
+        num_heads=48, num_kv_heads=8, head_dim=128, gate="head", query_block=256,
+        dtype=BF16, decode=True, paged=True, kv_block_size=16, kv_num_blocks=blocks,
+        whole_prompts=whole_prompts)
+    x = jnp.zeros((1, positions, 2048), BF16)
+    # the gather arm over the call's own 256 blocks, as the parent cut them
+    pos, tables = jnp.zeros((1, positions), I32), jnp.zeros((1, positions // 16), I32)
+    shapes = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x, pos, tables))
+
+    def call(params, cache, x, pos, tables):
+        y, changed = layer.apply(
+            {"params": params, "cache": cache}, x, pos, tables, mutable=["cache"])
+        return y, changed["cache"]
+
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (shapes["params"], shapes["cache"], x, pos, tables))
+    compiled = jax.jit(call, donate_argnums=1).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)/pallas_call"', line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    account = compiled.memory_analysis()
+    assert account.alias_size_in_bytes == 2 * blocks * 16 * 8 * 128 * 2
+    scores = _arrays(text, "f32", positions)
+    gathered = _arrays(text, "bf16", "8,128")  # [1, 256, 16, 8, 128] and the like
+    if whole_prompts:
+        assert kernels == ["flash_fwd"] and not scores
+        assert not [a for a in gathered if ",256,16," in a]
+        # 101 MB (q, k, v, their folds, the output): under the [8, 6, 256,
+        # 4096] float32 scores alone
+        assert account.temp_size_in_bytes < 8 * 6 * 256 * 4096 * 4
+    else:
+        assert kernels == [] and "f32[1,8,6,256,4096]" in scores
+        assert account.temp_size_in_bytes > 8 * 6 * 256 * 4096 * 4  # 253 MB
+
+
+def test_laguna_s_prefill_program_scores_through_the_flash_forward(chip, monkeypatch):
+    """The first five layers of ``config/serve-laguna-xs2.yml`` (full, three
+    window layers, full; one dense and four expert layers) as the paged
+    build compiles a 1 x 4,096 prefill: the flash forward once a full layer
+    and nowhere else, no float32 scores of a full layer, the whole cache
+    tree updated in place.  The program's temporaries are the window layers'
+    band and the expert layers', which stand: 472.9 MB for the parent's
+    471.4 (and 2.090 GB for 2.077 over the whole 17 layers at 1 x 8,192)."""
+    import re
+
+    import numpy as np
+    import yaml
+
+    from pytorch_distributed_training_tpu.models import get_model
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.serving.decode import build_paged_fns
+
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "config", "serve-laguna-xs2.yml")) as f:
+        keys = dict(yaml.safe_load(f)["model"], num_hidden_layers=5)
+    keys.pop("name")
+    model = get_model("Laguna", num_classes=100352, dtype=BF16, **keys)
+    positions, table = 4096, 544
+    fns = build_paged_fns(model, 16, 17408, state_slots=32)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), I32)))["params"]
+    pool = jax.eval_shape(fns.init_pool, shapes)
+    row = jax.ShapeDtypeStruct((1,), I32)
+    call = jax.ShapeDtypeStruct((1, positions), I32)
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        (shapes, pool, call, call, jax.ShapeDtypeStruct((1, table), I32), row,
+         jax.ShapeDtypeStruct((1, 2), jnp.uint32), row, row, row))
+    compiled = fns.prefill.lower(*args).compile()
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "flash_fwd" in line]
+    assert len(flash) == 2
+    assert all(re.search(r"/layer[04]/attn/gqa_attention/full_attention/", line)
+               for line in flash)
+    assert not [a for a in _arrays(text, "f32", positions) if ",256," in a]
+    account = compiled.memory_analysis()
+    assert account.alias_size_in_bytes == sum(
+        int(np.prod(s.shape)) * s.dtype.itemsize for s in jax.tree.leaves(pool))
+    assert account.temp_size_in_bytes < 480 * 10 ** 6

@@ -578,6 +578,13 @@ def test_what_assumes_a_cache_of_token_rows_refuses_the_model(weights, model, wh
         with pytest.raises(ValueError, match="contiguous generate path has no slots"):
             build_generate_fn(model, 4)
 
+def test_the_prefill_program_alone_holds_the_flash_forward(weights, model, monkeypatch):
+    import paged_programs
+
+    with scheduler(model, weights[2], seq_buckets=[16, 128]) as sched:
+        paged_programs.check_prefill_alone_holds_the_flash_forward(sched, 2, monkeypatch)
+
+
 
 def test_replay_after_a_restart_rebuilds_the_rings_from_position_zero(weights, model):
     """A hot restart re-prefills the prompt and re-feeds the delivered
@@ -599,7 +606,7 @@ def test_replay_after_a_restart_rebuilds_the_rings_from_position_zero(weights, m
 @pytest.mark.parametrize("replay", [False, True], ids=["fresh", "replay"])
 def test_a_prefill_call_that_starts_past_position_zero_is_refused(weights, model, replay):
     """The layers take a multi-token call's column for the position (a full
-    layer's table cut to the call's blocks, a window layer's band and ring
+    layer scoring the call's own keys, a window layer's band and ring
     write), so the scheduler, which decides what a call holds, refuses a
     call whose rows start anywhere else: here a request made to look as if
     a prefix of one block were cached, fresh and on the replay path."""

@@ -376,6 +376,21 @@ def test_what_assumes_a_cache_of_token_rows_refuses_the_model(weights, model, wh
         with pytest.raises(ValueError, match="contiguous generate path has no slots"):
             build_generate_fn(model, 4)
 
+def test_a_prefill_call_that_starts_past_position_zero_is_refused(weights, model):
+    import paged_programs
+
+    with scheduler(model, weights[2]) as sched:
+        paged_programs.check_a_call_past_position_zero_is_refused(
+            sched, tokens_of(11, seed=6), BLOCK, "NemotronHLM")
+
+
+def test_the_prefill_program_alone_holds_the_flash_forward(weights, model, monkeypatch):
+    import paged_programs
+
+    with scheduler(model, weights[2], seq_buckets=[16, 128]) as sched:
+        paged_programs.check_prefill_alone_holds_the_flash_forward(sched, 1, monkeypatch)
+
+
 
 def test_replay_after_a_restart_rebuilds_the_state_from_position_zero(weights, model):
     """A hot restart re-prefills the prompt and re-feeds the delivered

@@ -469,6 +469,54 @@ def test_what_assumes_a_cache_of_token_rows_refuses_the_model(weights, model, wh
         with pytest.raises(ValueError, match="contiguous generate path has no slots"):
             build_generate_fn(model, 4)
 
+def test_a_prefill_call_that_starts_past_position_zero_is_refused(weights, model):
+    import paged_programs
+
+    with scheduler(model, weights[2]) as sched:
+        paged_programs.check_a_call_past_position_zero_is_refused(
+            sched, tokens_of(11, seed=6), BLOCK, "OlmoHybridLM")
+
+
+def test_a_served_run_counts_its_flash_prefill_calls(weights, model, monkeypatch):
+    """Where the backend runs the kernel and a bucket is a shape it takes,
+    a prefill call's full layers score through the flash forward over the
+    call's own K/V (interpreted here): the ``prefill`` span says how many
+    (``flash_layers``, the cache tree's one K pool of this cut), the counter
+    ``prefill_flash_calls`` counts such calls beside ``prefill_calls``, and
+    the tokens are the gather arm's.  A bucket of 16 is no shape of the
+    kernel's: its calls keep the gather arm and count nothing."""
+    from pytorch_distributed_training_tpu.ops import attention
+    from pytorch_distributed_training_tpu.ops import flash_attention as gate
+    from pytorch_distributed_training_tpu.telemetry.spans import get_recorder
+
+    tree = weights[2]
+    short, long = tokens_of(11, seed=6), tokens_of(40, seed=7)
+    more = dict(seq_buckets=[16, 128], num_blocks=BLOCKS)
+    with scheduler(model, tree, **more) as plain:
+        want = [serve(plain, short), serve(plain, long)]
+        assert plain._flash_layers(128) == 0
+        assert "prefill_flash_calls" not in plain.metrics.snapshot()
+    monkeypatch.setattr(gate, "flash_enabled", lambda: True)
+    monkeypatch.setattr(attention, "_FLASH_INTERPRET", True)
+    with scheduler(model, tree, **more) as sched:
+        assert [sched._flash_layers(sb) for sb in (16, 128)] == [0, 1]
+        got = [serve(sched, short), serve(sched, long)]
+        snap = sched.metrics.snapshot()
+        spans = [s for s in get_recorder().recent()
+                 if s["kind"] == "prefill" and s.get("bucket") in (16, 128)][-2:]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert snap["prefill_calls"] == 2 and snap["prefill_flash_calls"] == 1
+    assert [(s["bucket"], s["flash_layers"]) for s in spans] == [(16, 0), (128, 1)]
+
+
+def test_the_prefill_program_alone_holds_the_flash_forward(weights, model, monkeypatch):
+    import paged_programs
+
+    with scheduler(model, weights[2], seq_buckets=[16, 128]) as sched:
+        paged_programs.check_prefill_alone_holds_the_flash_forward(sched, 1, monkeypatch)
+
+
 
 def test_replay_after_a_restart_rebuilds_the_state_from_position_zero(weights, model):
     """A hot restart re-prefills the prompt and re-feeds the delivered
